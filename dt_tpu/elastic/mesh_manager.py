@@ -21,7 +21,7 @@ Mitigations from SURVEY.md §7 applied here:
 - epoch-boundary only (caller's contract),
 - snapshot in host RAM before teardown (``snapshot_state``),
 - the persistent compilation cache keyed by world size amortizes the
-  recompile (set ``DT_COMPILE_CACHE=/path`` — ``Module`` applies it via
+  recompile (``Module`` places it via
   ``dt_tpu.config.enable_compilation_cache``, which also zeroes the
   min-compile-time threshold so small rebuilt programs are cached too).
 """
@@ -145,20 +145,16 @@ class MeshManager:
                 num_processes=num_processes, process_id=process_id)
             self._initialized = True
         else:
-            # Rebuilding down to a SOLO world: a CPU collectives backend
-            # (gloo/mpi) requires a live jax.distributed client, which a
-            # 1-process world never creates — backend init would raise in
-            # make_gloo_tcp_collectives(distributed_client=None).  Park
-            # the impl (restored on the next multi-process initialize)
-            # and reset to local before the new backend builds.
-            try:
-                impl = jax.config._read("jax_cpu_collectives_implementation")
-            except (AttributeError, KeyError):
-                impl = None
-            if impl and impl != "none":
+            # Rebuilding down to a SOLO world: park a CPU collectives
+            # impl (gloo/mpi belong to a multi-process world; restored on
+            # the next multi-process initialize, where a regrown world
+            # without it would skip cross-host gradient averaging) and
+            # build the solo backend with local collectives only.
+            impl = jax.config.jax_cpu_collectives_implementation
+            if impl is not None:
                 self._saved_cpu_collectives = impl
                 jax.config.update("jax_cpu_collectives_implementation",
-                                  "none")
+                                  None)
         self.mesh = mesh_lib.make_mesh()
         return self.mesh
 

@@ -30,10 +30,12 @@ need the env contract.
 from __future__ import annotations
 
 import argparse
+import glob
 import logging
 import os
 import subprocess
 import sys
+import threading
 import time
 
 from dt_tpu import config
@@ -80,6 +82,66 @@ def _worker_env(base: dict, scheduler_port: int, worker_id: str,
         env["ELASTIC_TRAINING_ENABLED"] = "1"
     env.update(extra or {})
     return env
+
+
+def _local_tpu_chips() -> int:
+    """This host's TPU chips, counted from their device nodes — never
+    through the runtime: a process that initialises it claims every chip
+    it can see, and the launcher's workers need them.  0 when the job is
+    pinned to the CPU (``DT_FORCE_CPU=1``, which the workers inherit)."""
+    if config.env("DT_FORCE_CPU") == "1":
+        return 0
+    return len(glob.glob("/dev/accel[0-9]*")) or \
+        len(glob.glob("/dev/vfio/[0-9]*"))
+
+
+class _ChipPool:
+    """One chip per local worker process.  A chip belongs to one process
+    at a time, and N forked workers with the same environment would each
+    try to claim every chip of the host — all but the first hang or fail
+    in backend init.  So each worker's environment (and only its
+    environment) carries the runtime's own visibility variables: it sees
+    exactly one chip, as a 1x1x1 topology of its own.  A chip returns to
+    the pool when its worker exits (an elastic removal frees it for the
+    next joiner); with no chip free the launch is refused with a message
+    instead of leaving a worker in backend init."""
+
+    def __init__(self, num_chips: int):
+        self._owners: List[Optional[subprocess.Popen]] = [None] * num_chips
+        self._lock = threading.Lock()  # elastic joiners launch from threads
+
+    @property
+    def size(self) -> int:
+        return len(self._owners)
+
+    def popen(self, host: str, command: List[str],
+              env: dict) -> subprocess.Popen:
+        if not self.size:  # CPU job: nothing to hand out
+            return subprocess.Popen(command, env=env)
+        with self._lock:
+            free = [c for c, p in enumerate(self._owners)
+                    if p is None or p.poll() is not None]
+            if not free:
+                raise RuntimeError(
+                    f"no free TPU chip for worker {host}: this host has "
+                    f"{self.size} and each holds a live worker (a "
+                    "chip belongs to one process at a time)")
+            chip = free[0]
+            # each process is its own one-chip slice, so its slice-builder
+            # port must not collide with a neighbour's
+            port = 8476 + chip
+            proc = subprocess.Popen(command, env={
+                **env,
+                "TPU_VISIBLE_CHIPS": str(chip),
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+                "TPU_PROCESS_PORT": str(port),
+                "CLOUD_TPU_TASK_ID": "0",
+                "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"})
+            self._owners[chip] = proc
+        logger.info("worker %s owns TPU chip %d", host, chip)
+        return proc
 
 
 def _await_servers(sched, n_servers: int, timeout: float = 60.0) -> None:
@@ -150,6 +212,12 @@ def launch_local(num_workers: int, command: List[str],
         listed = _read_hosts(hostfile)
         if listed:
             hosts = listed[:num_workers] + hosts[len(listed):]
+    chips = _ChipPool(_local_tpu_chips())
+    if chips.size and num_workers > chips.size:
+        raise SystemExit(
+            f"launch: {num_workers} local workers but this host has "
+            f"{chips.size} TPU chip(s); a chip belongs to one "
+            "process at a time (DT_FORCE_CPU=1 runs the workers on the CPU)")
 
     procs = {}
     server_procs = {}
@@ -184,8 +252,8 @@ def launch_local(num_workers: int, command: List[str],
 
     def launch_new(host: str, epoch: int):
         logger.info("launching elastic worker %s (EPOCH_BEGIN=%d)", host, epoch)
-        procs[host] = subprocess.Popen(
-            command, env=_worker_env(
+        procs[host] = chips.popen(
+            host, command, _worker_env(
                 os.environ, sched.port, host, hostfile, elastic,
                 {"NEW_WORKER": "1", "EPOCH_BEGIN": str(epoch),
                  "TRAINING_CMD": " ".join(command), **secret_env,
@@ -222,11 +290,11 @@ def launch_local(num_workers: int, command: List[str],
             # workers' server list comes back empty (funnel fallback)
             _await_servers(sched, num_servers)
         for h in hosts:
-            procs[h] = subprocess.Popen(
-                command, env=_worker_env(os.environ, sched.port, h, hostfile,
-                                         elastic,
-                                         {"TRAINING_CMD": " ".join(command),
-                                          **secret_env, **endpoints_env}))
+            procs[h] = chips.popen(
+                h, command, _worker_env(os.environ, sched.port, h, hostfile,
+                                        elastic,
+                                        {"TRAINING_CMD": " ".join(command),
+                                         **secret_env, **endpoints_env}))
         return _reap_all(procs)
     finally:
         sched.close()

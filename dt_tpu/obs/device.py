@@ -6,9 +6,9 @@ The obs stack up to r17 sees the host and the wire — causal spans
 (``blackbox.py``) — but the compute plane itself was a black box:
 "compute" in the critical-path split is just step-minus-blocking-spans,
 a hang bundle could not tell a JIT-compile stall from a real wedge, and
-the ROADMAP-5 capture discipline had no compile/memory evidence to act
-on.  The reference was even blinder: its profiler needed a live process
-and saw op timelines only (``src/profiler/profiler.h:256``,
+there was no compile/memory evidence to act on.  The reference was
+even blinder: its profiler needed a live process and saw op timelines
+only (``src/profiler/profiler.h:256``,
 ``kvstore_dist_server.h:275-322``), and its memory story was an offline
 static table (``example/memcost``).  Elastic resizing makes the gap
 acute: every membership change risks a silent recompile storm and a
@@ -27,7 +27,7 @@ planes; ``tests/test_device_obs.py`` holds the guards):
   path inside a named ``compile.<what>`` span (so the blackbox
   open-span table — and therefore the hang watchdog — can SEE a
   compile in progress), timing exactly the compile, counting
-  ``DT_JAX_CACHE_DIR`` persistent-cache hits/misses (new cache files
+  persistent-cache hits/misses (new cache files
   after the compile = miss), and capturing XLA's own
   ``memory_analysis()`` (the ``tools/memcost.py`` static estimate, now
   live).  Off, :func:`instrument` returns the function UNCHANGED.
@@ -53,8 +53,8 @@ planes; ``tests/test_device_obs.py`` holds the guards):
   landing it in ``DT_BLACKBOX_DIR`` + ``manifest.jsonl``.
 
 jax-optional throughout: every jax touch is lazy and guarded, so
-jax-free tools (``tools/dtop.py``, ``tools/tpu_probe.py``) import this
-module through the path shim.
+jax-free tools (``tools/dtop.py``) import this module through the path
+shim.
 """
 
 from __future__ import annotations
@@ -203,16 +203,18 @@ def _sig_delta(prev: Dict[str, Any], new: Dict[str, Any]) -> List[str]:
 
 
 class _CacheProbe:
-    """``DT_JAX_CACHE_DIR``-aware persistent-cache accounting: count the
-    cache dir's entries before/after a compile — new files mean the
-    compiler wrote a fresh program (miss); none, with the cache
-    configured, means it was served from the cache (hit).  With no
-    cache dir configured the outcome is ``"off"`` (every retry pays the
-    full recompile — exactly what ROADMAP-5 says not to do)."""
+    """Persistent-cache accounting over the EFFECTIVE
+    ``jax_compilation_cache_dir`` (wherever ``JAX_COMPILATION_CACHE_DIR``
+    or ``config.enable_compilation_cache`` placed it): count the cache
+    dir's entries before/after a compile — new files mean the compiler
+    wrote a fresh program (miss); none, with the cache configured, means
+    it was served from the cache (hit).  With no cache dir configured the
+    outcome is ``"off"`` (every retry pays the full recompile)."""
 
     def __init__(self):
-        self.dir = config.env("DT_JAX_CACHE_DIR") or \
-            config.env("DT_COMPILE_CACHE")
+        import jax
+        self.dir = (jax.config.jax_enable_compilation_cache
+                    and jax.config.jax_compilation_cache_dir) or ""
         self.before = self._count()
 
     def _count(self) -> int:
@@ -230,9 +232,8 @@ class _CacheProbe:
 
 
 def cache_probe() -> _CacheProbe:
-    """Start a persistent-cache probe around a compile (``bench.py`` and
-    ``tools/tpu_probe.py`` use this directly, ungated — their rows ARE
-    the capture-discipline evidence)."""
+    """Start a persistent-cache probe around a compile (``bench.py``
+    uses this directly, ungated — its rows carry the outcome)."""
     return _CacheProbe()
 
 

@@ -157,7 +157,7 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "compile.compiles": ("counter", "XLA compiles observed by the device "
                                     "plane"),
     "compile.cache_hits": ("counter", "compiles served from the "
-                                      "DT_JAX_CACHE_DIR persistent cache"),
+                                      "persistent compilation cache"),
     "compile.cache_misses": ("counter", "compiles that wrote fresh "
                                         "persistent-cache entries"),
     "device.hbm_bytes": ("gauge", "per-device HBM bytes in use "

@@ -99,9 +99,15 @@ def fused_bn_inference(x: jax.Array, gamma: jax.Array, beta: jax.Array,
 
 
 def _bn_partials_kernel(x_ref, sum_ref, sumsq_ref):
+    # Mosaic requires the last two block dims to tile (8, 128) or equal
+    # the array's, and a (1, c) block over (nblk, c) does neither — so
+    # each block leaves 8 sublane-strided partial rows (row r sums block
+    # rows r, r+8, ...: whole-vreg adds, no cross-sublane reduce) and the
+    # wrapper's sum over all rows finishes the reduction
     x = x_ref[:].astype(jnp.float32)
-    sum_ref[:] = jnp.sum(x, axis=0, keepdims=True)
-    sumsq_ref[:] = jnp.sum(x * x, axis=0, keepdims=True)
+    x = x.reshape(x.shape[0] // 8, 8, x.shape[1])
+    sum_ref[:] = jnp.sum(x, axis=0)
+    sumsq_ref[:] = jnp.sum(x * x, axis=0)
 
 
 def _bn_train_fwd_impl(x, gamma, beta, running_mean, running_var,
@@ -112,7 +118,7 @@ def _bn_train_fwd_impl(x, gamma, beta, running_mean, running_var,
     c = x.shape[-1]
     x2 = x.reshape(-1, c)
     n = x2.shape[0]
-    rows = min(block_rows, n)
+    rows = _round_up(min(block_rows, n), 8)
     padded = _round_up(n, rows)
     x2p = jnp.pad(x2, ((0, padded - n), (0, 0))) if padded != n else x2
 
@@ -121,14 +127,14 @@ def _bn_train_fwd_impl(x, gamma, beta, running_mean, running_var,
     nblk = padded // rows
     sums, sumsqs = pl.pallas_call(
         _bn_partials_kernel,
-        out_shape=(jax.ShapeDtypeStruct((nblk, c), jnp.float32),
-                   jax.ShapeDtypeStruct((nblk, c), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((nblk * 8, c), jnp.float32),
+                   jax.ShapeDtypeStruct((nblk * 8, c), jnp.float32)),
         grid=(nblk,),
         in_specs=[pl.BlockSpec((rows, c), lambda i: (i, 0),
                                memory_space=pltpu.VMEM)],
-        out_specs=(pl.BlockSpec((1, c), lambda i: (i, 0),
+        out_specs=(pl.BlockSpec((8, c), lambda i: (i, 0),
                                 memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, c), lambda i: (i, 0),
+                   pl.BlockSpec((8, c), lambda i: (i, 0),
                                 memory_space=pltpu.VMEM)),
         interpret=interpret,
     )(x2p)

@@ -538,7 +538,11 @@ def with_multi_precision(inner: optax.GradientTransformation
     """
 
     def init(params):
-        master = jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params)
+        # jnp.array copies: ``astype`` on an f32 param (flax keeps params
+        # f32 under a bf16 compute dtype) returns the SAME buffer, and a
+        # TrainState holding one buffer twice cannot be donated
+        master = jax.tree_util.tree_map(
+            lambda p: jnp.array(p, dtype=jnp.float32), params)
         return MultiPrecisionState(master, inner.init(master))
 
     def update(grads, state, params):

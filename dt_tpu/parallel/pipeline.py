@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dt_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 
 def _pipeline_sharded(stacked_params, x, *, stage_fn, num_micro, axis_name):

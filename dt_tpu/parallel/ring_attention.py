@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dt_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 NEG_INF = -1e30
 

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dt_tpu.parallel._compat import shard_map
+from jax import shard_map
 from dt_tpu.parallel.ring_attention import full_attention
 
 

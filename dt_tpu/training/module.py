@@ -143,9 +143,8 @@ class Module:
         # reshards state through it (SURVEY.md §7 "mesh resize" hard part).
         self.mesh_manager = mesh_manager
         self.seed = seed
-        # Persistent compilation cache (no-op unless DT_JAX_CACHE_DIR /
-        # DT_COMPILE_CACHE is set): elastic world rebuilds re-hit cached
-        # programs instead of paying full recompiles (SURVEY §7
+        # Persistent compilation cache: elastic world rebuilds re-hit
+        # cached programs instead of paying full recompiles (SURVEY §7
         # mesh-resize mitigation).
         config_lib.enable_compilation_cache()
         # Whole-loss jax.checkpoint.  NOTE (r4, tools/memcost.py): a
@@ -243,12 +242,25 @@ class Module:
         reference's new-worker path, ``module.py:552-571``)."""
         rngs = {"params": jax.random.PRNGKey(self.seed),
                 "dropout": jax.random.PRNGKey(self.seed + 1)}
-        x = jnp.asarray(sample_data)
-        variables = self.model.init(rngs, x, training=False)
-        params = variables["params"]
-        batch_stats = variables.get("batch_stats", {})
-        state = TrainState.create(self.model.apply, params, self.tx,
-                                  batch_stats)
+        shape, dtype = np.shape(sample_data), jnp.result_type(sample_data)
+        replicated = mesh_lib.replicate_sharding(self.mesh)
+
+        def init(rngs):
+            # init reads the sample's shape only; its forward pass is
+            # dead code under jit
+            variables = self.model.init(rngs, jnp.zeros(shape, dtype),
+                                        training=False)
+            return TrainState.create(self.model.apply, variables["params"],
+                                     self.tx,
+                                     variables.get("batch_stats", {}))
+
+        # ONE compiled program (eager init is a compile per distinct tiny
+        # op on a chip), born replicated on the mesh: a state that first
+        # enters train_step uncommitted costs a second compile when it
+        # comes back carrying the step's NamedSharding
+        state = obs_device.instrument(
+            "init_params", jax.jit(init, out_shardings=replicated),
+            {"mesh": dict(self.mesh.shape)})(rngs)
         if initialize_from_kvstore:
             snap = getattr(self.kv, "_controller", None)
             snap = snap.fetch_snapshot() if snap is not None else None
@@ -258,7 +270,7 @@ class Module:
                             "batch_stats": state.batch_stats,
                             "opt_state": state.opt_state}
                 restored = flax.serialization.from_state_dict(template, snap)
-                state = state.replace(**restored)
+                state = jax.device_put(state.replace(**restored), replicated)
                 logger.info("bootstrapped params from kvstore snapshot")
         self.state = state
         return state
@@ -591,9 +603,11 @@ class Module:
                 mesh_lib.data_sharding(self.mesh, np.ndim(arr)),
                 np.asarray(arr))
         if self.mesh.size > 1:
-            return jax.device_put(jnp.asarray(arr),
-                                  mesh_lib.data_sharding(self.mesh,
-                                                         np.ndim(arr)))
+            # host array straight onto the data sharding: each device
+            # receives only its own rows (jnp.asarray first would land the
+            # whole global batch on device 0 and reshard device-to-device)
+            return jax.device_put(arr, mesh_lib.data_sharding(
+                self.mesh, np.ndim(arr)))
         return jnp.asarray(arr)
 
     # ------------------------------------------------------------------

@@ -129,10 +129,13 @@ def make_module(args, steps_per_epoch: int, kv=None):
     return mod
 
 
-def fit(args, mod, train, val):
+def fit(args, mod, train, val, batch_end_callback=()):
+    """``batch_end_callback``: extra per-batch callbacks, run before the
+    Speedometer (whose auto-reset clears the running metric)."""
     from dt_tpu.training import callbacks, checkpoint
-    cbs = [callbacks.Speedometer(args.batch_size, args.disp_batches,
-                                 num_workers_fn=lambda: mod.kv.num_workers)]
+    cbs = list(batch_end_callback) + [
+        callbacks.Speedometer(args.batch_size, args.disp_batches,
+                              num_workers_fn=lambda: mod.kv.num_workers)]
     epoch_cbs = []
     if args.model_prefix:
         epoch_cbs.append(callbacks.do_checkpoint(args.model_prefix))
@@ -144,10 +147,22 @@ def fit(args, mod, train, val):
         mod.state = checkpoint.load_checkpoint(args.model_prefix,
                                                args.load_epoch, mod.state)
         begin = args.load_epoch + 1
-    mod.fit(train, eval_data=val, num_epoch=args.num_epochs,
-            begin_epoch=begin,
+    mod.fit(train, eval_data=val, eval_metric=["acc", "ce"],
+            num_epoch=args.num_epochs, begin_epoch=begin,
             batch_end_callback=cbs, epoch_end_callback=epoch_cbs or None)
     return mod
+
+
+def state_digest(state) -> str:
+    """sha256 over the parameters and BN statistics: workers of one
+    synchronous job end bit-identical, and this is how they show it."""
+    import hashlib
+    import jax
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(
+            jax.device_get((state.params, state.batch_stats))):
+        h.update(np.ascontiguousarray(leaf).tobytes())
+    return h.hexdigest()
 
 
 def fit_elastic(args, mod, train, val, elastic_data_iterator):

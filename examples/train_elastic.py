@@ -59,6 +59,13 @@ def main():
     if ctrl is not None:
         mod.sync_mode = "host"  # CPU-process cluster; TPU pods use the mesh
     common.fit_elastic(args, mod, train, val, eit)
+    import jax
+    import logging
+    dev = jax.devices()[0]
+    logging.info("worker %s rank %d finished: step %d on %s (%s) "
+                 "state sha256 %s", getattr(ctrl, "host", "local"), kv.rank,
+                 int(mod.state.step), dev.platform, dev.device_kind,
+                 common.state_digest(mod.state))
 
 
 if __name__ == "__main__":

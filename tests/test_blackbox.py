@@ -389,19 +389,18 @@ def test_bundle_retention_pruned_oldest_first(tmp_path, monkeypatch):
     assert len(bb.read_manifest(d)) == 5  # the record survives pruning
 
 
-def test_manifest_accumulates_probe_style_rows(tmp_path):
-    """The tpu_probe capture discipline: rows from several 'attempts'
-    (distinct pids/outcomes) accumulate append-only and survive a torn
-    final line."""
+def test_manifest_accumulates_rows_across_processes(tmp_path):
+    """Rows from several processes (distinct pids/outcomes) accumulate
+    append-only and survive a torn final line."""
     d = str(tmp_path / "probe")
     for pid, outcome in ((101, "unavailable"), (102, "unavailable"),
                          (103, "success")):
         assert bb.manifest_append({"kind": "probe", "phase": "start",
                                    "ts_ms": pid * 1000, "pid": pid,
-                                   "host": "tpu_probe"}, dirpath=d)
+                                   "host": "prober"}, dirpath=d)
         assert bb.manifest_append({"kind": "probe", "phase": "end",
                                    "ts_ms": pid * 1000 + 500, "pid": pid,
-                                   "host": "tpu_probe",
+                                   "host": "prober",
                                    "outcome": outcome,
                                    "duration_s": 1500.0}, dirpath=d)
     with open(bb.manifest_path(d), "a") as f:

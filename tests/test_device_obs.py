@@ -141,16 +141,61 @@ def test_instrument_off_path_returns_fn_unchanged():
     assert dev.metrics_hook() is None
 
 
-def test_cache_probe_counts_persistent_cache_files(tmp_path, monkeypatch):
+@pytest.fixture
+def _restore_cache_dir():
+    import jax
+    orig = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_enable_compilation_cache)
+    yield
+    jax.config.update("jax_compilation_cache_dir", orig[0])
+    jax.config.update("jax_enable_compilation_cache", orig[1])
+
+
+def test_cache_placed_from_outside_is_left_alone(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and
+    ``enable_compilation_cache`` sets no directory in code (a fresh
+    interpreter — jax reads the variable at import)."""
+    d = str(tmp_path / "outside")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from dt_tpu import config\n"
+         "print(config.enable_compilation_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": d, "PYTHONPATH": REPO},
+        capture_output=True, text=True, timeout=120, cwd=str(tmp_path))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [d, d]
+
+
+def test_cache_defaults_to_checkout_xla_cache(_restore_cache_dir):
+    """Variable unset: the cache goes to the fixed ``<checkout>/.xla_cache``
+    (the path is part of the cache key — never a temp name), and a second
+    call changes nothing."""
+    import jax
+    from dt_tpu import config
+    jax.config.update("jax_compilation_cache_dir", None)
+    want = os.path.join(REPO, ".xla_cache")
+    assert config.enable_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert config.enable_compilation_cache() == want
+
+
+def test_cache_probe_follows_effective_dir(tmp_path, _restore_cache_dir):
+    import jax
     d = str(tmp_path / "jaxcache")
     os.makedirs(d)
-    monkeypatch.setenv("DT_JAX_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_enable_compilation_cache", True)  # conftest: off
     p = dev.cache_probe()
+    assert p.dir == d
     assert p.outcome() == "hit"  # configured + no new files
     open(os.path.join(d, "entry-0"), "w").write("x")
     assert p.outcome() == "miss"  # a fresh program was written
-    monkeypatch.delenv("DT_JAX_CACHE_DIR")
-    monkeypatch.delenv("DT_COMPILE_CACHE", raising=False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    assert dev.cache_probe().outcome() == "off"  # placed but disabled
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", None)
     assert dev.cache_probe().outcome() == "off"
 
 

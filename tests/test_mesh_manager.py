@@ -46,16 +46,10 @@ def test_solo_rebuild_parks_and_restores_cpu_collectives(monkeypatch):
     """Shrinking to a solo world must reset a gloo/mpi CPU-collectives
     config (the backend would otherwise demand a distributed client that
     a 1-process world never creates), and growing back must RESTORE it —
-    a regrown world with impl 'none' would silently skip cross-host
+    a regrown world without it would silently skip cross-host
     gradient averaging."""
     def read_impl():
-        try:
-            return jax.config._read("jax_cpu_collectives_implementation")
-        except (AttributeError, KeyError):
-            return None
-    if read_impl() is None:
-        import pytest
-        pytest.skip("jax version lacks jax_cpu_collectives_implementation")
+        return jax.config.jax_cpu_collectives_implementation
     orig = read_impl()
     # jax.distributed.initialize would need real peers; the regrow path
     # under test is the config handling AROUND it
@@ -65,7 +59,7 @@ def test_solo_rebuild_parks_and_restores_cpu_collectives(monkeypatch):
     try:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
         mm.initialize(num_processes=1, process_id=0)
-        assert read_impl() == "none"  # parked: solo backend builds clean
+        assert read_impl() is None  # parked: solo backend builds clean
         assert mm._saved_cpu_collectives == "gloo"
         mm.initialize(num_processes=2, process_id=0)
         assert read_impl() == "gloo"  # restored for the regrown world

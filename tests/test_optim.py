@@ -253,3 +253,22 @@ def test_make_factory():
     assert isinstance(s, optim.CosineScheduler)
     with pytest.raises(ValueError):
         optim.make("exotic")
+
+
+def test_multi_precision_state_donates():
+    """f32 params under ``multi_precision=True`` (what flax yields for a
+    bf16 compute dtype): the f32 masters must be fresh buffers, or the
+    TrainState holds one buffer twice and the donated train step raises
+    ``Attempt to donate the same buffer twice`` — Module donates on every
+    backend but the multi-device CPU, so only a chip would see it."""
+    from dt_tpu.training.train_state import TrainState
+    tx = optim.create("sgd", multi_precision=True, learning_rate=0.1,
+                      momentum=0.9)
+    params = {"w": jnp.ones((4, 4), jnp.float32),
+              "b": jnp.zeros((4,), jnp.bfloat16)}
+    state = TrainState.create(None, params, tx, {})
+    bump = jax.jit(lambda s: s.replace(step=s.step + 1), donate_argnums=0)
+    out = bump(state)
+    assert int(out.step) == 1
+    np.testing.assert_array_equal(np.asarray(out.opt_state.master["w"]),
+                                  np.asarray(out.params["w"]))

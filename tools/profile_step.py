@@ -112,7 +112,7 @@ def main():
     enable_compilation_cache()
     # r16 flight recorder: a wedged profile attempt leaves a bundle
     # (thread stacks pin the blocking call) instead of a bare rc —
-    # no-op unless DT_BLACKBOX=1 (bench_watchdog.sh arms it)
+    # no-op unless DT_BLACKBOX=1
     from dt_tpu.obs import blackbox
     blackbox.install(host="profile_step")
     # beats are per-stage and a healthy resnet152 compile alone runs
