@@ -291,11 +291,14 @@ _OBS_NAMES_RELPATH = "dt_tpu/obs/names.py"
 #: ``.observe`` (``dt_tpu/obs/metrics.py``) are held to the same catalog
 #: as spans/events/counters — a renamed gauge must fail the lint, not
 #: silently vanish from the Prometheus exposition and dtop health board
+#: PR 24 adds ``StepAccount.phase`` (``dt_tpu/obs/trace.py``): a phase of
+#: the step account is a span under ``DT_OBS=1``, named at its call site
 _OBS_EMITTERS = frozenset({"span", "complete_span", "event", "counter",
-                           "gauge", "observe"})
+                           "gauge", "observe", "phase"})
 _OBS_KIND_OF = {"span": "span", "complete_span": "span",
                 "event": "event", "counter": "counter",
-                "gauge": "gauge", "observe": "histogram"}
+                "gauge": "gauge", "observe": "histogram",
+                "phase": "span"}
 
 
 def _load_obs_registry(project: ProjectContext) -> Dict[str, Tuple[str,

@@ -27,8 +27,38 @@ from typing import Mapping, Tuple
 
 NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     # -- training plane (training/module.py, trainer.py) -------------------
-    "step": ("span", "one training step (fwd+bwd+sync+update), worker track"),
+    "step": ("span", "one iteration of fit's step loop, from its top to "
+                     "the next one's: the dispatch of one step and the "
+                     "metric flush of the step before (attrs: dispatched, "
+                     "flushed); worker track"),
+    # the step account (obs/trace.py StepAccount): each is a column of
+    # the always-live account rows and, under DT_OBS=1, a child span of
+    # `step` and a jax.profiler annotation
+    "step.input": ("span", "the feed's next(), in the loop and in the "
+                           "prefetch"),
+    "step.place": ("span", "_place of data and labels: the host-to-device "
+                           "enqueue"),
+    "step.dispatch": ("span", "the call of the compiled train/grad/apply "
+                              "step: the enqueue; blocks when the "
+                              "runtime's queue is full or a donated buffer "
+                              "is in use"),
+    "step.sync": ("span", "host-sync and async modes: gradient to the "
+                          "host, allreduce or overlap engine or "
+                          "push_flat, the average back to the device"),
+    "step.fetch": ("span", "the wait for the previous step's logits and "
+                           "their copy to the host"),
+    "step.metric": ("span", "softmax on the host and eval_metric.update"),
+    "step.callback": ("span", "the batch-end callbacks"),
+    "step.hooks": ("span", "the rest of an iteration: fault hooks, "
+                           "watchdog beat, capture tick, health "
+                           "sentinel's fetch, checkpoint cadence, drain "
+                           "poll"),
     "epoch": ("span", "one training epoch (Module.fit)"),
+    "epoch.rebuild": ("span", "mesh_manager.rebuild and the recompile of "
+                              "the steps for the new mesh"),
+    "epoch.data_reshard": ("span", "the elastic iterator factory rebuilding "
+                                   "the data iterators after a change"),
+    "epoch.snapshot": ("span", "_publish_snapshot at the epoch's end"),
     "eval": ("span", "one evaluation pass (Module.score)"),
     "trainer.step": ("span", "one Trainer.step (low-level training loop)"),
     # -- worker client (elastic/client.py) ---------------------------------

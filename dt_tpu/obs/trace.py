@@ -31,11 +31,21 @@ Design points
 - **Nesting** rides a per-tracer ``contextvars.ContextVar``: a span's
   record carries its parent span id, and events attach to the enclosing
   span, without any thread-local bookkeeping at the call sites.
+- **The step account** (:class:`StepAccount`) is counter-class: live with
+  tracing off.  ``Module.fit`` writes one row per iteration of its step
+  loop into a second bounded ring (``DT_OBS_RING`` rows): the wall-clock
+  start and the monotonic nanoseconds of each phase in
+  :data:`STEP_PHASES`, which sum to the iteration exactly.  With tracing
+  on the same boundaries are spans, children of the iteration's ``step``
+  span, and ``jax.profiler`` annotations.
 
 Record schema (flat tuples, ring/wire-compact)::
 
     ("X", rseq, name, ts_us, dur_us, tid, span_id, parent_id, attrs)  span
     ("i", rseq, name, ts_us, 0,      tid, event_id, parent_id, attrs) event
+
+Account rows are flat tuples too, their fields named by
+:data:`STEP_ROW_FIELDS`.
 
 ``rseq`` increases strictly in buffer order — the heartbeat export's
 at-least-once dedup key (the scheduler ignores records at-or-below the
@@ -178,35 +188,167 @@ class _Span:
     """A live span; created only when the tracer is enabled."""
 
     __slots__ = ("_tr", "name", "attrs", "_t0w", "_t0m", "_sid", "_parent",
-                 "_tok")
+                 "_tok", "_ann")
 
-    def __init__(self, tr: "Tracer", name: str, attrs: Optional[dict]):
+    def __init__(self, tr: "Tracer", name: str, attrs: Optional[dict],
+                 ann=None):
         self._tr = tr
         self.name = name
         self.attrs = attrs
+        # a jax.profiler annotation entered and left with the span, so
+        # that a profiler session carries it on its own timeline
+        self._ann = ann
 
     def __enter__(self):
+        return self.open(self._tr._wall(), self._tr._mono())
+
+    def open(self, t0w: int, t0m: int) -> "_Span":
+        """Enter at clock readings the caller has already taken (the
+        step account reads each clock once per phase boundary)."""
         tr = self._tr
-        self._t0w = tr._wall()
-        self._t0m = tr._mono()
+        self._t0w = t0w
+        self._t0m = t0m
         self._parent = tr._ctx.get()
         self._sid = tr._next_seq()
         self._tok = tr._ctx.set(self._sid)
-        tr._open_add(self._sid, self.name, self._t0w, self._t0m,
-                     self._parent, self.attrs)
+        tr._open_add(self._sid, self.name, t0w, t0m, self._parent,
+                     self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self.close(self._tr._mono())
+        return False
+
+    def close(self, t1m: int) -> None:
+        """Leave at a monotonic reading the caller has already taken."""
         tr = self._tr
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr._ctx.reset(self._tok)
         tr._open_pop(self._sid)
         if not tr.on():
-            return False  # open-table-only mode (blackbox armed, DT_OBS=0)
-        dur_us = max(tr._mono() - self._t0m, 0) // 1000
+            return  # open-table-only mode (blackbox armed, DT_OBS=0)
+        dur_us = max(t1m - self._t0m, 0) // 1000
         tr._push(("X", None, self.name, self._t0w // 1000, dur_us,
                   tr._ident(), self._sid, self._parent,
                   self.attrs))
-        return False
+
+
+def _annotation(name: str, step_num: Optional[int] = None):
+    """The ``jax.profiler`` annotation of a span, through
+    ``utils/profiler.annotate``; imported on first use so that this
+    module stays importable without jax (the scheduler, ``dtop``)."""
+    from dt_tpu.utils import profiler
+    return profiler.annotate(name, step_num=step_num)
+
+
+# ---------------------------------------------------------------------------
+# the step account: where each iteration of ``Module.fit`` spent its time
+# ---------------------------------------------------------------------------
+
+#: the phases of one iteration of ``fit``'s step loop (``obs/names.py`` says
+#: what each covers); the iteration is always in exactly one of them, so a
+#: row's phases sum to its length
+STEP_PHASES = ("step.input", "step.place", "step.dispatch", "step.sync",
+               "step.fetch", "step.metric", "step.callback", "step.hooks")
+#: one account row: the ``fit`` call (a per-tracer count), the epoch, the
+#: iteration within it, the step it dispatched and the batch whose metric
+#: it flushed (host-side counts; ``None`` where it did neither: the first
+#: iteration flushes nothing, the last dispatches nothing), its wall-clock
+#: start, its monotonic length, then the monotonic nanoseconds in each
+#: phase
+STEP_ROW_FIELDS = ("fit", "epoch", "iteration", "dispatched", "flushed",
+                   "wall_ns", "total_ns") + STEP_PHASES
+_PHASE_INDEX = {name: i for i, name in enumerate(STEP_PHASES)}
+_HOOKS = _PHASE_INDEX["step.hooks"]
+
+
+class StepAccount:
+    """One ``fit`` call's writer of account rows (:meth:`Tracer.
+    step_account`), used by the loop's own thread only.
+
+    ``begin`` opens an iteration at the top of the step loop (closing the
+    one before at the same clock reading), ``phase`` moves it into another
+    phase, ``end`` closes the last one; whatever is not inside a named
+    phase is ``step.hooks``.  A boundary costs one monotonic clock read
+    and writes into slots made once per ``fit`` call; a row is one tuple
+    and one append.  With tracing on (or the blackbox's open-span table
+    armed) every iteration is also a ``step`` span and every phase a
+    child span of it, opened and closed at the account's own readings."""
+
+    __slots__ = ("_tr", "fit", "_ns", "_cur", "_t", "_t0m", "_t0w",
+                 "_live", "epoch", "iteration", "dispatched", "flushed",
+                 "_step_span", "_phase_span")
+
+    def __init__(self, tr: "Tracer", fit: int):
+        self._tr = tr
+        self.fit = fit
+        self._ns = [0] * len(STEP_PHASES)
+        self._live = False
+        self._step_span = self._phase_span = None
+        #: set by the loop: the step this iteration dispatched, the batch
+        #: whose metric it flushed
+        self.dispatched: Optional[int] = None
+        self.flushed: Optional[int] = None
+
+    def begin(self, epoch: int, iteration: int,
+              step_num: Optional[int] = None) -> Optional[tuple]:
+        """Top of an iteration.  Returns the row of the iteration this
+        closes, if one was open.  ``step_num`` numbers the iteration in a
+        profiler session (``StepTraceAnnotation``)."""
+        tr = self._tr
+        now, wall = tr._mono(), tr._wall()
+        row = self._close(now) if self._live else None
+        self.epoch, self.iteration = epoch, iteration
+        self.dispatched = self.flushed = None
+        self._t0m = self._t = now
+        self._t0w = wall
+        self._cur = _HOOKS
+        self._live = True
+        if tr.on() or _ARM_OPEN_HOOK():
+            self._step_span = tr.span(
+                "step", {"epoch": epoch, "iteration": iteration},
+                annotate=True, step_num=step_num).open(wall, now)
+            self._phase_span = tr.span(
+                "step.hooks", annotate=True).open(wall, now)
+        return row
+
+    def phase(self, name: str) -> None:
+        """The iteration leaves the phase it was in and enters ``name``."""
+        now = self._tr._mono()
+        self._ns[self._cur] += now - self._t
+        self._t = now
+        self._cur = _PHASE_INDEX[name]
+        if self._phase_span is not None:
+            self._phase_span.close(now)
+            self._phase_span = self._tr.span(name, annotate=True).open(
+                self._tr._wall(), now)
+
+    def end(self) -> Optional[tuple]:
+        """Close the open iteration, if any (the loop's end, and every
+        way out of ``fit``: an iteration that an exception leaves still
+        writes its row, with the phases it got to).  Returns its row."""
+        return self._close(self._tr._mono()) if self._live else None
+
+    def _close(self, now: int) -> tuple:
+        ns = self._ns
+        ns[self._cur] += now - self._t
+        row = (self.fit, self.epoch, self.iteration, self.dispatched,
+               self.flushed, self._t0w, now - self._t0m) + tuple(ns)
+        for i in range(len(ns)):
+            ns[i] = 0
+        self._live = False
+        if self._step_span is not None:
+            self._phase_span.close(now)
+            self._step_span.attrs = {
+                "epoch": self.epoch, "iteration": self.iteration,
+                "dispatched": self.dispatched, "flushed": self.flushed}
+            self._step_span.close(now)
+            self._step_span = self._phase_span = None
+        self._tr._push_step_row(row)
+        return row
 
 
 class Tracer:
@@ -248,6 +390,10 @@ class Tracer:
         # begin() whose complete_span never runs (exception paths) must
         # not leak entries forever.
         self._open: Dict[int, dict] = {}  # guarded-by: _lock
+        # the step account's rows (StepAccount); live with tracing off,
+        # bounded like the record ring, oldest dropped first
+        self._step_rows: deque = deque(maxlen=self._cap)  # guarded-by: _lock
+        self._fits = 0  # guarded-by: _lock
         self._ctx: contextvars.ContextVar = contextvars.ContextVar(
             f"dt_obs_span_{id(self)}", default=None)
 
@@ -275,14 +421,22 @@ class Tracer:
                 self._dropped += 1
             self._records.append(rec)
 
-    def span(self, name: str, attrs: Optional[dict] = None):
+    def span(self, name: str, attrs: Optional[dict] = None,
+             annotate: bool = False, step_num: Optional[int] = None):
         """Context manager recording a complete ("X") span on exit; the
         disabled path returns a shared no-op singleton.  With only the
         blackbox open-span hook armed, the span enters/leaves the open
-        table (crash evidence) but records nothing."""
-        if not self.on() and not _ARM_OPEN_HOOK():
+        table (crash evidence) but records nothing.  ``annotate``: with
+        tracing on the span also enters a ``jax.profiler``
+        ``TraceAnnotation`` of its name (``StepTraceAnnotation`` where
+        ``step_num`` is given), so that any profiler session carries it;
+        for the training loop's spans only, since it imports jax."""
+        on = self.on()
+        if not on and not _ARM_OPEN_HOOK():
             return _NOOP_SPAN
-        return _Span(self, name, attrs)
+        return _Span(self, name, attrs,
+                     _annotation(name, step_num) if annotate and on
+                     else None)
 
     def now(self) -> Optional[Tuple[int, int]]:
         """(wall_ns, mono_ns) start token for :meth:`complete_span`, or
@@ -393,6 +547,30 @@ class Tracer:
             return
         self._push(("i", None, name, self._wall() // 1000, 0,
                     self._ident(), None, self._ctx.get(), attrs))
+
+    # -- the step account (live even when tracing is off) -----------------
+
+    def step_account(self) -> StepAccount:
+        """A writer of account rows for one ``fit`` call, numbered from 1
+        in this tracer's order of calls."""
+        with self._lock:
+            self._fits += 1
+            return StepAccount(self, self._fits)
+
+    def _push_step_row(self, row: tuple) -> None:
+        with self._lock:
+            self._step_rows.append(row)
+
+    def step_rows(self, fit: Optional[int] = None) -> List[tuple]:
+        """The retained account rows, oldest first (of one ``fit`` call
+        where ``fit`` is given; ``-1``: of the newest one)."""
+        with self._lock:
+            rows = list(self._step_rows)
+        if fit is None or not rows:
+            return rows
+        if fit < 0:
+            fit = rows[-1][0]
+        return [r for r in rows if r[0] == fit]
 
     # -- counters (live even when tracing is off) -------------------------
 

@@ -51,6 +51,9 @@ from dt_tpu.training.train_state import TrainState
 
 logger = logging.getLogger("dt_tpu")
 
+_ROW_DISPATCHED = obs_trace.STEP_ROW_FIELDS.index("dispatched")
+_ROW_TOTAL_NS = obs_trace.STEP_ROW_FIELDS.index("total_ns")
+
 
 def softmax_ce_loss(logits, labels):
     return losses_lib.softmax_cross_entropy(logits, labels)
@@ -310,14 +313,17 @@ class Module:
             if batch_stats:
                 variables["batch_stats"] = batch_stats
                 mutable.append("batch_stats")
-            out, mutated = model.apply(
-                variables, data, training=True,
-                rngs={"dropout": dropout_rng}, mutable=mutable)
-            new_stats = mutated.get("batch_stats", batch_stats)
-            aux = sum(jax.tree_util.tree_leaves(
-                mutated.get("aux_loss", {})), 0.0)
-            logits = out[0] if isinstance(out, tuple) else out
-            return loss_fn(logits, labels) + aux, (logits, new_stats)
+            # scopes are metadata: a device trace can split the step into
+            # forward, backward (transpose(jvp(forward))) and optimizer
+            with jax.named_scope("forward"):
+                out, mutated = model.apply(
+                    variables, data, training=True,
+                    rngs={"dropout": dropout_rng}, mutable=mutable)
+                new_stats = mutated.get("batch_stats", batch_stats)
+                aux = sum(jax.tree_util.tree_leaves(
+                    mutated.get("aux_loss", {})), 0.0)
+                logits = out[0] if isinstance(out, tuple) else out
+                return loss_fn(logits, labels) + aux, (logits, new_stats)
 
         if self.remat:
             forward_loss = jax.checkpoint(forward_loss,
@@ -370,13 +376,16 @@ class Module:
                 state.params, state.batch_stats, data, labels, dropout_rng)
 
             def apply(_):
-                return state.apply_gradients(grads).replace(
-                    batch_stats=new_stats)
+                with jax.named_scope("optimizer"):
+                    return state.apply_gradients(grads).replace(
+                        batch_stats=new_stats)
 
             if not sentinel:
                 return apply(None), loss, logits
-            health = health_vec(jax.flatten_util.ravel_pytree(grads)[0],
-                                state.params, loss)
+            with jax.named_scope("health"):
+                health = health_vec(
+                    jax.flatten_util.ravel_pytree(grads)[0], state.params,
+                    loss)
             if halt:
                 new_state = jax.lax.cond(health[0] > 0,
                                          lambda _: state, apply, None)
@@ -499,8 +508,9 @@ class Module:
                 else state.batch_stats
 
             def apply(_):
-                return state.apply_gradients(grads).replace(
-                    batch_stats=new_stats)
+                with jax.named_scope("optimizer"):
+                    return state.apply_gradients(grads).replace(
+                        batch_stats=new_stats)
 
             if not sentinel:
                 return apply(None)
@@ -508,7 +518,8 @@ class Module:
             # worker's poisoned contribution makes the average
             # non-finite on EVERY worker, so the whole fleet halts on
             # the same step with identical (pre-fault) params
-            health = health_vec(flat_g, state.params, jnp.float32(0.0))
+            with jax.named_scope("health"):
+                health = health_vec(flat_g, state.params, jnp.float32(0.0))
             if halt:
                 new_state = jax.lax.cond(health[0] > 0,
                                          lambda _: state, apply, None)
@@ -579,19 +590,26 @@ class Module:
             self._overlap = overlap_lib.GradSyncEngine()
         return self._overlap
 
-    def _prefetch_batch(self, train_data):
+    def _prefetch_batch(self, train_data, acct):
         """Double-buffered input: dispatch the NEXT batch's host->device
         placement right after the current step's compute is in flight, so
         its H2D copies overlap the current step's sync/metric phase
         instead of serializing in front of the next step (the input half
         of the overlap design; the reference's engine overlapped IO the
         same way, SURVEY §3.4).  Returns (batch, data_dev, labels_dev)
-        or None when the epoch's iterator is exhausted."""
+        or None when the epoch's iterator is exhausted.  ``acct`` is the
+        ``fit`` call's step account: the ``next()`` is its ``step.input``,
+        the placement its ``step.place``."""
+        acct.phase("step.input")
         try:
             batch = train_data.next()
         except StopIteration:
+            acct.phase("step.hooks")
             return None
-        return (batch, self._place(batch.data), self._place(batch.label))
+        acct.phase("step.place")
+        placed = (batch, self._place(batch.data), self._place(batch.label))
+        acct.phase("step.hooks")
+        return placed
 
     def _place(self, arr):
         if jax.process_count() > 1:
@@ -714,6 +732,11 @@ class Module:
         from dt_tpu.elastic import faults as faults_lib
         from dt_tpu.obs import blackbox as bb_lib
         _obs = obs_trace.tracer()  # epoch/step spans (off unless DT_OBS)
+        # the step account (obs/trace.py StepAccount): where every
+        # iteration of the step loop spent its wall time, live with
+        # tracing off; with DT_OBS=1 the same boundaries are the `step`
+        # span and its phase spans
+        acct = _obs.step_account()
         # r16 flight recorder: the per-worker hang watchdog (deadman on
         # step progress, DT_HANG_S) runs for the whole fit and is torn
         # down on EVERY exit path; no-op unless DT_BLACKBOX=1
@@ -758,6 +781,9 @@ class Module:
                 "cold-restart resume: step %d, epoch %d, %d batches "
                 "into the epoch", int(_mf["step"]), begin_epoch,
                 _resume_skip)
+        # state.step as the host counts it from here on: read once per fit
+        # call, never in the step loop
+        host_step = int(jax.device_get(self.state.step))
         try:
             for epoch in range(begin_epoch, num_epoch):
                 # named begin: an epoch the process dies inside shows in
@@ -816,14 +842,25 @@ class Module:
                             # rebuild the distributed world + mesh, reshard the
                             # live state, recompile the steps for the new mesh
                             self.mesh_rebuilds += 1
-                            self._mesh, self.state = self.mesh_manager.rebuild(
-                                self.state, num_workers, self.kv.rank)
-                            self._build_steps()
+                            with _obs.span("epoch.rebuild",
+                                           {"epoch": epoch,
+                                            "workers": num_workers},
+                                           annotate=True):
+                                self._mesh, self.state = \
+                                    self.mesh_manager.rebuild(
+                                        self.state, num_workers,
+                                        self.kv.rank)
+                                self._build_steps()
                             self._unravel = None
                             self._unravel_stats = None
                         if elastic_data_iterator is not None:
-                            train_data, new_eval = \
-                                elastic_data_iterator.get_data_iterator(self.kv)
+                            with _obs.span("epoch.data_reshard",
+                                           {"epoch": epoch,
+                                            "workers": num_workers},
+                                           annotate=True):
+                                train_data, new_eval = \
+                                    elastic_data_iterator.get_data_iterator(
+                                        self.kv)
                             if new_eval is not None:
                                 eval_data = new_eval
                         grad_scale = self._policy_grad_scale(
@@ -852,29 +889,25 @@ class Module:
                 # iterator exhausted, tuple = batch k+1 already placed on
                 # device while step k's sync phase ran (_prefetch_batch)
                 prefetched = ()
+                iteration = 0
                 while True:
-                    if prefetched:
-                        batch, data, labels = prefetched
-                    elif prefetched is None:
+                    # the account's row runs from here to here: whatever
+                    # is inside no named phase below is its `step.hooks`
+                    self._observe_step(acct.begin(epoch, iteration,
+                                                  host_step))
+                    iteration += 1
+                    if prefetched == ():  # the epoch's first iteration
+                        prefetched = self._prefetch_batch(train_data, acct)
+                    if prefetched is None:
                         break
-                    else:
-                        try:
-                            batch = train_data.next()
-                        except StopIteration:
-                            break
-                        data = self._place(batch.data)
-                        labels = self._place(batch.label)
+                    batch, data, labels = prefetched
                     prefetched = ()
                     # r16 chaos hook: a site-scoped stall rule blocks HERE
                     # forever (--plan hang) — the hang the watchdog below
                     # must catch; no-op without a matching fault rule
                     faults_lib.stall_point("worker.step", host=_bb_host)
-                    # step span: dispatch + host-side sync points of one
-                    # batch (device programs run async — this is the control
-                    # view, not a kernel timeline; jax.profiler has those)
-                    _obs_st_t0 = _obs.begin("step")
-                    _mt0 = time.monotonic() if obs_metrics.enabled() else None
                     health = None  # sentinel vector; None when not armed
+                    acct.dispatched = host_step
                     if is_async:
                         # dist_async step: local grad -> push -> adopt the
                         # post-update master weights.  No peer barrier; the
@@ -883,9 +916,11 @@ class Module:
                         # stay worker-local between epoch-end snapshot
                         # averages, as in the reference's aux-key flow.
                         self._ensure_unravel()  # None after elastic rebuilds
+                        acct.phase("step.dispatch")
                         flat_g, flat_s, loss, logits = self._grad_step(
                             self.state, data, labels, rng)
-                        prefetched = self._prefetch_batch(train_data)
+                        prefetched = self._prefetch_batch(train_data, acct)
+                        acct.phase("step.sync")
                         g_host = np.asarray(jax.device_get(flat_g))
                         if self._sentinel:
                             # no post-average apply step exists on this
@@ -927,6 +962,7 @@ class Module:
                                 if self._unravel_stats
                                 else self.state.batch_stats,
                                 step=self.state.step + 1)
+                        acct.phase("step.hooks")
                     elif self.sync_mode == "host" and self.kv.num_workers > 1:
                         ctrl = getattr(self.kv, "_controller", None)
                         if ctrl is None:
@@ -934,9 +970,11 @@ class Module:
                                 "sync_mode='host' needs an elastic controller "
                                 "(kv.set_controller) to carry the allreduce")
                         self._ensure_unravel()
+                        acct.phase("step.dispatch")
                         flat_g, flat_s, loss, logits = self._grad_step(
                             self.state, data, labels, rng)
-                        prefetched = self._prefetch_batch(train_data)
+                        prefetched = self._prefetch_batch(train_data, acct)
+                        acct.phase("step.sync")
                         if faults_lib.nan_point("worker.grad",
                                                 host=getattr(ctrl, "host",
                                                              None)):
@@ -975,14 +1013,12 @@ class Module:
                             # stats round rides concurrently.  Bit-identical
                             # to the serial branch below (overlap.py); the
                             # DT_AR_OVERLAP=0 escape hatch restores it.
-                            avg_g_dev, avg_s = self._overlap_engine().sync(
+                            avg_g, avg_s = self._overlap_engine().sync(
                                 ctrl, gc, flat_g,
                                 flat_s if self._unravel_stats is not None
                                 else None)
                             if avg_s is None:
                                 avg_s = np.zeros((0,), np.float32)
-                            health = self._apply_synced(avg_g_dev,
-                                                        jnp.asarray(avg_s))
                         else:
                             if gc is not None:
                                 # quantize ON DEVICE, fetch only the packed
@@ -1001,9 +1037,14 @@ class Module:
                                     "stats", np.asarray(jax.device_get(flat_s)))
                             else:
                                 avg_s = np.zeros((0,), np.float32)
-                            health = self._apply_synced(jnp.asarray(avg_g),
-                                                        jnp.asarray(avg_s))
+                        # the averaged gradient back on the device (already
+                        # there from the overlap engine) ends the sync
+                        avg_g, avg_s = jnp.asarray(avg_g), jnp.asarray(avg_s)
+                        acct.phase("step.dispatch")
+                        health = self._apply_synced(avg_g, avg_s)
+                        acct.phase("step.hooks")
                     else:
+                        acct.phase("step.dispatch")
                         if self._sentinel:
                             self.state, loss, logits, health = \
                                 self._train_step(self.state, data, labels,
@@ -1011,8 +1052,8 @@ class Module:
                         else:
                             self.state, loss, logits = self._train_step(
                                 self.state, data, labels, rng)
-                        prefetched = self._prefetch_batch(train_data)
-                    _obs.complete_span("step", _obs_st_t0, {"epoch": epoch})
+                        prefetched = self._prefetch_batch(train_data, acct)
+                    host_step += 1
                     if _bb_dog is not None:
                         # step progress reached the deadman; nbatch is
                         # the bundle's "last step seen alive" evidence
@@ -1021,9 +1062,6 @@ class Module:
                     # None-check per step unless a profile_capture
                     # command armed a bounded trace
                     obs_device.capture_tick()
-                    if _mt0 is not None:
-                        obs_metrics.registry().observe(
-                            "step.ms", (time.monotonic() - _mt0) * 1000.0)
                     if self.health_halted or (
                             health is not None
                             and self._health_step(health, loss, epoch)):
@@ -1056,13 +1094,19 @@ class Module:
                     # logits are ready by now; this step already runs on device)
                     if pending is not None:
                         nbatch = self._flush_metric(pending, eval_metric, epoch,
-                                                    nbatch, batch_end_callback)
+                                                    nbatch, batch_end_callback,
+                                                    acct)
                     # pad examples excluded (reference DataBatch.pad semantics)
                     pending = (np.asarray(batch.label),
                                batch.data.shape[0] - batch.pad, logits)
-                if pending is not None:  # final step's metric + callback
+                # the iteration that found the feed exhausted (or halted)
+                # stays open for the final step's metric + callback: its row
+                # dispatches nothing and flushes the last batch
+                if pending is not None:
                     nbatch = self._flush_metric(pending, eval_metric, epoch,
-                                                nbatch, batch_end_callback)
+                                                nbatch, batch_end_callback,
+                                                acct)
+                self._observe_step(acct.end())
 
                 if self.health_halted:
                     # the clean stop: the compiled step already SKIPPED the
@@ -1094,7 +1138,9 @@ class Module:
 
                 # --- epoch end: publish snapshot (store_aux_params analog,
                 # base_module.py:601-605) ---
-                self._publish_snapshot()
+                with _obs.span("epoch.snapshot", {"epoch": epoch},
+                               annotate=True):
+                    self._publish_snapshot()
                 if _fc is not None:
                     # a DRAINING scheduler flags ckpt_epoch_end on the
                     # heartbeat channel; the boundary is the free
@@ -1136,6 +1182,9 @@ class Module:
                 e, host=_bb_host)
             raise
         finally:
+            # every way out (a removal, a drain, an exception out of a
+            # callback) leaves the open iteration's row behind
+            self._observe_step(acct.end())
             if _bb_dog is not None:
                 _bb_dog.stop()
             # a profile_capture the loop couldn't finish (job end,
@@ -1232,19 +1281,42 @@ class Module:
             * float(getattr(ctrl, "policy_lr_scale", 1.0))
 
     def _flush_metric(self, pending, eval_metric, epoch, nbatch,
-                      batch_end_callback):
+                      batch_end_callback, acct):
         """Account one completed batch: metric update, then its batch-end
         callback — same ordering as the reference's synchronous loop, just
-        deferred one step so device dispatch never drains for metrics."""
+        deferred one step so device dispatch never drains for metrics.
+        In the step account (``acct``) the wait for the logits and their
+        copy to the host is ``step.fetch``, the softmax and the metric
+        ``step.metric``, the callbacks ``step.callback``."""
         lab, n_real, lg = pending
-        probs = _softmax_np(_local_np(lg))
+        acct.phase("step.fetch")
+        logits = _local_np(lg)
+        acct.phase("step.metric")
+        probs = _softmax_np(logits)
+        del logits  # as soon as a temporary would be: update() reuses it
         eval_metric.update(lab[:n_real], probs[:n_real])
+        # released inside the phase that made it (0.8 GB for a language
+        # model): its unmapping would otherwise read as hooks
+        del probs
         nbatch += 1
+        acct.flushed = nbatch
         if batch_end_callback is not None:
+            acct.phase("step.callback")
             p = callbacks_lib.BatchEndParam(epoch, nbatch, eval_metric)
             for cb in batch_end_callback:
                 cb(p)
+        acct.phase("step.hooks")
         return nbatch
+
+    @staticmethod
+    def _observe_step(row):
+        """``step.ms`` from the account's own clock reads: the length of a
+        closed iteration that dispatched a step (``row`` as
+        ``StepAccount.begin``/``end`` return it, or None)."""
+        if row is not None and obs_metrics.enabled() and \
+                row[_ROW_DISPATCHED] is not None:
+            obs_metrics.registry().observe("step.ms",
+                                           row[_ROW_TOTAL_NS] / 1e6)
 
     def _publish_snapshot(self):
         """Push the live TrainState to the elastic controller — the role the
@@ -1272,22 +1344,22 @@ class Module:
         """Reference ``BaseModule.score`` (``base_module.py:613-620``)."""
         if self._eval_step is None:
             self._build_steps()
-        _obs_t0 = obs_trace.tracer().now()
         eval_metric = metrics_lib.create(eval_metric)
-        eval_metric.reset()
-        eval_data.reset()
-        while True:
-            try:
-                batch = eval_data.next()
-            except StopIteration:
-                break
-            logits = self._eval_step(self.state, self._place(batch.data))
-            n_real = batch.data.shape[0] - batch.pad
-            # multi-host: local logits shard vs local labels (same rows)
-            probs = _softmax_np(_local_np(logits))
-            eval_metric.update(np.asarray(batch.label)[:n_real],
-                               probs[:n_real])
-        obs_trace.tracer().complete_span("eval", _obs_t0)
+        with obs_trace.tracer().span("eval", annotate=True):
+            eval_metric.reset()
+            eval_data.reset()
+            while True:
+                try:
+                    batch = eval_data.next()
+                except StopIteration:
+                    break
+                logits = self._eval_step(self.state,
+                                         self._place(batch.data))
+                n_real = batch.data.shape[0] - batch.pad
+                # multi-host: local logits shard vs local labels (same rows)
+                probs = _softmax_np(_local_np(logits))
+                eval_metric.update(np.asarray(batch.label)[:n_real],
+                                   probs[:n_real])
         return eval_metric.get_name_value()
 
     def predict(self, data) -> np.ndarray:

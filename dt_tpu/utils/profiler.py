@@ -94,9 +94,14 @@ class trace:
         set_state("stop")
 
 
-def annotate(name: str):
+def annotate(name: str, step_num: Optional[int] = None):
     """Named region in the trace (reference scoped ``ProfileTask``/
-    ``ProfileOperator``)."""
+    ``ProfileOperator``).  With ``step_num`` the region is one step of a
+    loop (``StepTraceAnnotation``): the profiler's tools group the device
+    operations under it.  The training loop's spans (``obs/trace.py``)
+    open their annotations through here."""
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num)
     return jax.profiler.TraceAnnotation(name)
 
 

@@ -927,7 +927,7 @@ def test_xla_pristine_module_copy_clean(tmp_path):
 
 def test_dt015_module_copy_detects_in_body_jit(tmp_path):
     rel = "dt_tpu/training/module.py"
-    anchor = '_obs.complete_span("step", _obs_st_t0, {"epoch": epoch})'
+    anchor = "host_step += 1"  # in the step loop, after the dispatch
     _, src = _copy_into(tmp_path, rel)
     assert anchor in src
     broken = src.replace(
@@ -943,7 +943,7 @@ def test_dt015_module_copy_detects_in_body_jit(tmp_path):
 
 def test_dt016_module_copy_detects_step_loop_sync(tmp_path):
     rel = "dt_tpu/training/module.py"
-    anchor = '_obs.complete_span("step", _obs_st_t0, {"epoch": epoch})'
+    anchor = "host_step += 1"  # in the step loop, after the dispatch
     _, src = _copy_into(tmp_path, rel)
     broken = src.replace(
         anchor,
