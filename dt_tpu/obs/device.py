@@ -34,9 +34,9 @@ planes; ``tests/test_device_obs.py`` holds the guards):
 - **Recompile-cause ledger** — a second compile of the same ``what``
   diffs the new abstract signature against the previous one and emits a
   ``compile.recompile`` event naming the delta (``shape`` / ``dtype`` /
-  ``mesh`` / ``donate`` / ``nargs``, or ``rebuild`` when the signature
-  is identical — a fresh ``jax.jit`` object after an elastic rebuild,
-  the case the persistent cache exists for).  The chaos straggler drill
+  ``mesh`` / ``donate`` / ``metric`` / ``nargs``, or ``rebuild`` when the
+  signature is identical — a fresh ``jax.jit`` object after an elastic
+  rebuild, the case the persistent cache exists for).  The chaos straggler drill
   gates ZERO recompiles across share-only policy rebalances on this.
 - **Memory plane** — :func:`sample_into` sets per-device
   ``device.hbm_*`` gauges from ``jax.Device.memory_stats()`` with an
@@ -187,6 +187,9 @@ def _sig_of(args: tuple, meta: Optional[dict],
         "dtype": hashlib.sha1(repr(dtypes).encode()).hexdigest()[:12],
         "mesh": str((meta or {}).get("mesh", "")),
         "donate": str((meta or {}).get("donate", "")),
+        # what the step returns for the fit call's metric (Module: the
+        # names of its per-row statistics, None for the logits)
+        "metric": str((meta or {}).get("metric", "")),
     }
     sig["digest"] = hashlib.sha1(
         repr(sorted(sig.items())).encode()).hexdigest()[:12]
@@ -197,7 +200,8 @@ def _sig_delta(prev: Dict[str, Any], new: Dict[str, Any]) -> List[str]:
     """The named recompile cause: which signature facets changed
     (``rebuild`` = none of them — a fresh jit object re-compiled the
     identical program, the persistent-cache-hit case)."""
-    changed = [k for k in ("shape", "dtype", "mesh", "donate", "nargs")
+    changed = [k for k in ("shape", "dtype", "mesh", "donate", "metric",
+                           "nargs")
                if prev.get(k) != new.get(k)]
     return changed or ["rebuild"]
 

@@ -45,9 +45,12 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "step.sync": ("span", "host-sync and async modes: gradient to the "
                           "host, allreduce or overlap engine or "
                           "push_flat, the average back to the device"),
-    "step.fetch": ("span", "the wait for the previous step's logits and "
-                           "their copy to the host"),
-    "step.metric": ("span", "softmax on the host and eval_metric.update"),
+    "step.fetch": ("span", "the wait for the previous step's per-row "
+                           "metric statistics (its logits, for a metric "
+                           "without a device form) and their copy to the "
+                           "host"),
+    "step.metric": ("span", "eval_metric.update_reduced (or the host's "
+                            "softmax and eval_metric.update)"),
     "step.callback": ("span", "the batch-end callbacks"),
     "step.hooks": ("span", "the rest of an iteration: fault hooks, "
                            "watchdog beat, capture tick, health "
@@ -137,6 +140,14 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "health.grad_norm": ("gauge", "last step's global gradient L2 norm "
                                   "(non-finite entries excluded)"),
     "health.param_norm": ("gauge", "last step's parameter L2 norm"),
+    "fit.metric_device_steps": ("gauge", "steps of the current fit call "
+                                         "whose metric was reduced to "
+                                         "per-row statistics on the "
+                                         "device"),
+    "fit.metric_host_steps": ("gauge", "steps of the current fit call "
+                                       "whose logits crossed to the host "
+                                       "for a metric without a device "
+                                       "form"),
     "worker.step_rate": ("gauge", "scheduler-derived per-worker step "
                                   "rate (steps/s) from the shipped "
                                   "train.steps series"),

@@ -4,19 +4,95 @@ Reference: ``python/mxnet/metric.py:1`` (1,424 LoC — EvalMetric base with
 update/reset/get, Accuracy, TopKAccuracy, F1, MAE/MSE/RMSE, CrossEntropy,
 NegativeLogLikelihood, Perplexity, CompositeEvalMetric, CustomMetric,
 ``metric.create``).  Updates take numpy/jax arrays; accumulation is
-host-side floats exactly like the reference (so metrics never force extra
-device sync beyond fetching the outputs).
+host-side floats exactly like the reference.
+
+**What a step hands the host.**  A metric with a *device form* names the
+per-row statistics it reads (``device_stats``: ``label_logp``, ``argmax``,
+``top<k>_hit``, each with the logits' shape less the class axis) and
+accumulates from them (``update_reduced``).  ``Module.fit`` and ``score``
+then reduce the logits over the class axis inside the compiled program and
+fetch those statistics, a few bytes a row, and the host never sees the
+logits.  ``Accuracy``, ``TopKAccuracy``, ``CrossEntropy``,
+``NegativeLogLikelihood``, ``Perplexity`` and a composite of such metrics
+have one.  Every other metric (``F1``, ``MAE``, ``MSE``, ``RMSE``, ``Loss``,
+``CustomMetric``, a user subclass that declares none) is handed the float32
+softmax of the whole logits on the host, as before: batch x classes (x
+sequence) values cross from the device every step, 823 MB at GPT-2's
+vocabulary (``docs/metrics.md``).
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 
 def _np(x) -> np.ndarray:
     return np.asarray(x)
+
+
+# ---------------------------------------------------------------------------
+# per-row statistics: pure functions of (logits, labels), traced into the
+# compiled step.  Each is a reduction over the class axis that XLA fuses with
+# its float32 cast, so no float32 copy of the logits is ever written.  A
+# statistic's NAME is its identity: the compiled steps are keyed by the names
+# a metric asks for.
+# ---------------------------------------------------------------------------
+
+def _label_logit(z, labels):
+    """The label's own logit, as a sum over a one-hot mask (a reduction like
+    the others: a gather would want the float32 logits written out)."""
+    classes = jnp.arange(z.shape[-1], dtype=jnp.int32)
+    hot = classes == labels.astype(jnp.int32)[..., None]
+    return jnp.sum(jnp.where(hot, z, 0.0), axis=-1)
+
+
+def label_logp(logits, labels):
+    """float32 ``log softmax(logits)[label]``; labels outside the classes
+    give ``-logsumexp`` (rows a metric ignores)."""
+    z = logits.astype(jnp.float32)
+    return _label_logit(z, labels) - jax.nn.logsumexp(z, axis=-1)
+
+
+def argmax(logits, labels):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def topk_hit(logits, labels, k):
+    """Whether fewer than ``k`` classes score above the label's."""
+    z = logits.astype(jnp.float32)
+    above = jnp.sum(z > _label_logit(z, labels)[..., None], axis=-1)
+    return above < k
+
+
+def device_form(metric) -> Optional[Dict[str, Callable]]:
+    """``metric.device_stats()``, or None (the host path) where a subclass
+    overrode ``update`` below the class whose ``update_reduced`` would stand
+    in for it: the two would no longer accumulate the same thing."""
+    def owner(name):
+        return next(c for c in type(metric).__mro__ if name in vars(c))
+    stats = metric.device_stats()
+    if stats and issubclass(owner("update_reduced"), owner("update")):
+        return stats
+    return None
+
+
+def stats_key(stats: Optional[Dict[str, Callable]]) -> Optional[Tuple[str, ...]]:
+    """What compiled programs are kept by: the statistics' names (None: the
+    logits themselves)."""
+    return tuple(sorted(stats)) if stats else None
+
+
+def device_reduce(stats: Dict[str, Callable], logits, labels):
+    """What a compiled step returns in the logits' place: each statistic of
+    ``stats`` by name, with the logits' shape less the class axis."""
+    labels = labels.reshape(logits.shape[:-1])
+    return {name: f(logits, labels) for name, f in stats.items()}
 
 
 class EvalMetric:
@@ -31,6 +107,18 @@ class EvalMetric:
         self.sum_metric = 0.0
 
     def update(self, labels, preds):
+        raise NotImplementedError
+
+    def device_stats(self) -> Optional[Dict[str, Callable]]:
+        """The device form: ``{name: f(logits, labels)}``, the per-row
+        statistics ``update_reduced`` reads, each a pure jax function that
+        returns an array with the logits' shape less the class axis.  None
+        (the default): the metric is handed probabilities on the host."""
+        return None
+
+    def update_reduced(self, labels, reduced: Dict[str, np.ndarray]):
+        """Accumulate from the statistics ``device_stats`` named what
+        ``update(labels, softmax(logits))`` would have."""
         raise NotImplementedError
 
     def get(self) -> Tuple[str, float]:
@@ -58,6 +146,12 @@ class Accuracy(EvalMetric):
         self.sum_metric += float((preds == labels).sum())
         self.num_inst += labels.size
 
+    def device_stats(self):
+        return {"argmax": argmax}
+
+    def update_reduced(self, labels, reduced):
+        self.update(labels, reduced["argmax"].reshape(np.shape(labels)))
+
 
 class TopKAccuracy(EvalMetric):
     """Reference: ``mx.metric.TopKAccuracy`` (top_k attr)."""
@@ -72,6 +166,15 @@ class TopKAccuracy(EvalMetric):
         topk = np.argpartition(preds, -self.top_k, axis=-1)[:, -self.top_k:]
         self.sum_metric += float((topk == labels[:, None]).any(-1).sum())
         self.num_inst += labels.size
+
+    def device_stats(self):
+        return {f"top{self.top_k}_hit":
+                functools.partial(topk_hit, k=self.top_k)}
+
+    def update_reduced(self, labels, reduced):
+        hit = reduced[f"top{self.top_k}_hit"]
+        self.sum_metric += float(hit.sum())
+        self.num_inst += hit.size
 
 
 class F1(EvalMetric):
@@ -147,6 +250,15 @@ class CrossEntropy(EvalMetric):
         self.sum_metric += float(-np.log(np.maximum(p, self.eps)).sum())
         self.num_inst += labels.size
 
+    def device_stats(self):
+        return {"label_logp": label_logp}
+
+    def update_reduced(self, labels, reduced):
+        logp = reduced["label_logp"].reshape(-1)
+        self.sum_metric += float(
+            -np.maximum(logp, math.log(self.eps)).sum())
+        self.num_inst += logp.size
+
 
 class NegativeLogLikelihood(CrossEntropy):
     def __init__(self, eps: float = 1e-12, name: str = "nll-loss"):
@@ -171,6 +283,12 @@ class Perplexity(CrossEntropy):
         p = preds[np.arange(labels.size), labels]
         self.sum_metric += float(-np.log(np.maximum(p, self.eps)).sum())
         self.num_inst += labels.size
+
+    def update_reduced(self, labels, reduced):
+        logp = reduced["label_logp"].reshape(-1)
+        if self.ignore_label is not None:
+            logp = logp[_np(labels).reshape(-1) != self.ignore_label]
+        super().update_reduced(None, {"label_logp": logp})
 
     def get(self):
         if self.num_inst == 0:
@@ -220,6 +338,19 @@ class CompositeEvalMetric(EvalMetric):
     def update(self, labels, preds):
         for m in self.metrics:
             m.update(labels, preds)
+        self.num_inst = 1
+
+    def device_stats(self):
+        """The union of the members' statistics, so that ``["acc", "ce"]``
+        is one pass over the logits; None if any member has none."""
+        forms = [device_form(m) for m in self.metrics]
+        if not forms or not all(forms):
+            return None
+        return {k: f for form in forms for k, f in form.items()}
+
+    def update_reduced(self, labels, reduced):
+        for m in self.metrics:
+            m.update_reduced(labels, reduced)
         self.num_inst = 1
 
     def get(self):

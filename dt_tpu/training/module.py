@@ -27,6 +27,7 @@ bootstrap state from the snapshot instead of fresh init.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
@@ -106,10 +107,14 @@ def _softmax_np(logits: np.ndarray) -> np.ndarray:
     """Metrics follow the reference convention that predictions are
     PROBABILITIES (SoftmaxOutput emitted probs); models here emit logits, so
     normalize before metric.update.  Monotonic — Accuracy unaffected,
-    CrossEntropy/Perplexity become meaningful."""
-    z = logits - logits.max(axis=-1, keepdims=True)
-    e = np.exp(z)
-    return e / e.sum(axis=-1, keepdims=True)
+    CrossEntropy/Perplexity become meaningful.  In float32 whatever the
+    logits' type, as the device form of a metric computes it: numpy would
+    sum a bfloat16 row's thousands of terms in bfloat16."""
+    z = logits.astype(np.float32, copy=False)
+    z = z - z.max(axis=-1, keepdims=True)
+    np.exp(z, out=z)
+    z /= z.sum(axis=-1, keepdims=True)
+    return z
 
 
 class Module:
@@ -196,6 +201,18 @@ class Module:
         self.sharding_report: Dict[str, tuple] = {}
         self._train_step = None
         self._eval_step = None
+        # What the compiled train/grad steps return beside the loss: the
+        # per-row statistics the fit call's metric reads
+        # (metrics.device_stats: {name: f(logits, labels)}), or the logits
+        # (None) for a metric without a device form.  The steps are keyed
+        # by the statistics' names, not by the metric object: fit makes a
+        # new metric on every call.
+        self._metric_stats = None
+        self._score_reduce = {}  # spec -> jitted reduce, for score()
+        # the last fit call's flushed steps by the path their metric took
+        # (the same two numbers are the fit.metric_*_steps gauges)
+        self.metric_flushes = {"device": 0, "host": 0}
+        self._fallback_said = set()  # metric names the fallback was logged for
         # Gradient sync across worker PROCESSES.  "mesh" = gradients ride the
         # XLA allreduce inside the jit step (TPU pod / single process — the
         # normal path).  "host" = two-phase step with an exact-average
@@ -232,6 +249,10 @@ class Module:
     # ------------------------------------------------------------------
     # Binding / init
     # ------------------------------------------------------------------
+
+    @property
+    def _metric_spec(self):
+        return metrics_lib.stats_key(self._metric_stats)
 
     @property
     def mesh(self):
@@ -300,8 +321,12 @@ class Module:
         self._halt = halt
         health_vec = sentinel_health_vec  # shared with Trainer._build
 
+        metric_stats = self._metric_stats
+
         def forward_loss(params, batch_stats, data, labels, dropout_rng):
-            """Shared by the mesh train step and the host-sync grad step.
+            """Shared by the mesh train step and the host-sync grad step:
+            the loss, and beside it what the step hands the host for its
+            metric and the new BN statistics.
 
             Layers may sow pre-weighted regularizers into the
             ``aux_loss`` collection (e.g. the MoE load-balancing term,
@@ -323,7 +348,17 @@ class Module:
                 aux = sum(jax.tree_util.tree_leaves(
                     mutated.get("aux_loss", {})), 0.0)
                 logits = out[0] if isinstance(out, tuple) else out
-                return loss_fn(logits, labels) + aux, (logits, new_stats)
+                # what the step hands the host for its metric: the logits,
+                # or (a metric with a device form) its per-row statistics,
+                # reduced over the class axis here, where the logits are,
+                # and sharded along the batch as they were
+                handed = logits
+                if metric_stats is not None:
+                    with jax.named_scope("metric"):
+                        handed = metrics_lib.device_reduce(
+                            metric_stats, jax.lax.stop_gradient(logits),
+                            labels)
+                return loss_fn(logits, labels) + aux, (handed, new_stats)
 
         if self.remat:
             forward_loss = jax.checkpoint(forward_loss,
@@ -332,7 +367,7 @@ class Module:
         accum = self.grad_accum
 
         def compute_grads(params, batch_stats, data, labels, dropout_rng):
-            """(loss, logits, new_stats, grads) — one shot, or ``accum``
+            """(loss, metric_out, new_stats, grads) — one shot, or ``accum``
             sequential microbatches under ``lax.scan`` (the reference's
             ``grad_req='add'`` accumulation, ``executor_group.py`` grad
             aggregation) with ONE weight update at the end.  Peak
@@ -341,20 +376,20 @@ class Module:
             through the microbatches exactly as they would through
             sequential steps."""
             if accum <= 1:
-                (loss, (logits, new_stats)), grads = jax.value_and_grad(
+                (loss, (out, new_stats)), grads = jax.value_and_grad(
                     forward_loss, has_aux=True)(params, batch_stats,
                                                 data, labels, dropout_rng)
-                return loss, logits, new_stats, grads
+                return loss, out, new_stats, grads
 
             def micro(carry, xs):
                 stats, gsum = carry
                 d, lb, i = xs
-                (loss, (logits, stats)), grads = jax.value_and_grad(
+                (loss, (out, stats)), grads = jax.value_and_grad(
                     forward_loss, has_aux=True)(
                     params, stats, d, lb,
                     jax.random.fold_in(dropout_rng, i))
                 gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-                return (stats, gsum), (loss, logits)
+                return (stats, gsum), (loss, out)
 
             if data.shape[0] % accum:
                 raise ValueError(
@@ -363,16 +398,17 @@ class Module:
             d_mb = data.reshape((accum, -1) + data.shape[1:])
             l_mb = labels.reshape((accum, -1) + labels.shape[1:])
             zero_g = jax.tree_util.tree_map(jnp.zeros_like, params)
-            (new_stats, gsum), (losses, logits_mb) = jax.lax.scan(
+            (new_stats, gsum), (losses, out_mb) = jax.lax.scan(
                 micro, (batch_stats, zero_g),
                 (d_mb, l_mb, jnp.arange(accum)))
             grads = jax.tree_util.tree_map(lambda g: g / accum, gsum)
-            logits = logits_mb.reshape((-1,) + logits_mb.shape[2:])
-            return losses.mean(), logits, new_stats, grads
+            out = jax.tree_util.tree_map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), out_mb)
+            return losses.mean(), out, new_stats, grads
 
         def train_step(state: TrainState, data, labels, rng):
             dropout_rng = jax.random.fold_in(rng, state.step)
-            loss, logits, new_stats, grads = compute_grads(
+            loss, out, new_stats, grads = compute_grads(
                 state.params, state.batch_stats, data, labels, dropout_rng)
 
             def apply(_):
@@ -381,7 +417,7 @@ class Module:
                         batch_stats=new_stats)
 
             if not sentinel:
-                return apply(None), loss, logits
+                return apply(None), loss, out
             with jax.named_scope("health"):
                 health = health_vec(
                     jax.flatten_util.ravel_pytree(grads)[0], state.params,
@@ -391,7 +427,7 @@ class Module:
                                          lambda _: state, apply, None)
             else:
                 new_state = apply(None)
-            return new_state, loss, logits, health
+            return new_state, loss, out, health
 
         def eval_step(state: TrainState, data):
             variables = {"params": state.params}
@@ -451,6 +487,9 @@ class Module:
                     "sharded (%.2f of %.2f MiB; rest replicated)",
                     name, mesh.shape["data"], 100 * frac, sh_b / 2**20,
                     tot_b / 2**20)
+        # the third output, the logits or the metric's per-row statistics
+        # (one sharding for every array of the dict), is sharded along the
+        # batch: each process fetches its own rows (_local_np)
         step_out_sh = (state_sharding, replicated,
                        mesh_lib.data_sharding(mesh))
         if sentinel:
@@ -459,7 +498,8 @@ class Module:
         # surface is wrapped so its XLA compiles run inside compile.*
         # spans with a recompile-cause ledger; with DT_DEVICE_OBS off
         # instrument() returns the jit fn UNCHANGED
-        _dev_meta = {"mesh": dict(mesh.shape), "donate": donate}
+        _dev_meta = {"mesh": dict(mesh.shape), "donate": donate,
+                     "metric": self._metric_spec}
         self._train_step = obs_device.instrument(
             "train_step", jax.jit(train_step, donate_argnums=donate,
                                   out_shardings=step_out_sh), _dev_meta)
@@ -494,13 +534,13 @@ class Module:
         # reference's epoch-end >= 10M-key averaging).
         def grad_step(state, data, labels, rng):
             dropout_rng = jax.random.fold_in(rng, state.step)
-            loss, logits, new_stats, grads = compute_grads(
+            loss, out, new_stats, grads = compute_grads(
                 state.params, state.batch_stats, data, labels, dropout_rng)
             # grads and BN stats travel separately: grads may be 2-bit
             # compressed on the wire, stats never are
             flat_g, _ = jax.flatten_util.ravel_pytree(grads)
             flat_s, _ = jax.flatten_util.ravel_pytree(new_stats)
-            return flat_g, flat_s, loss, logits
+            return flat_g, flat_s, loss, out
 
         def apply_step(state, flat_g, flat_s):
             grads = self._unravel(flat_g)
@@ -531,6 +571,19 @@ class Module:
             "grad_step", jax.jit(grad_step), _dev_meta)
         self._apply_step = obs_device.instrument(
             "apply_step", jax.jit(apply_step), _dev_meta)
+
+    def _use_metric(self, eval_metric):
+        """Have the compiled steps return what ``eval_metric`` reads: its
+        per-row statistics if it has a device form, the logits if not.
+        Steps built for the same statistics are kept (``fit`` makes a new
+        metric object on every call); other statistics rebuild them once,
+        and the recompile ledger (``obs/device.py``) names ``metric`` as
+        the cause."""
+        stats = metrics_lib.device_form(eval_metric)
+        if self._train_step is None or \
+                metrics_lib.stats_key(stats) != self._metric_spec:
+            self._metric_stats = stats
+            self._build_steps()
 
     @staticmethod
     def _coverage(tree, shardings, replicated):
@@ -678,8 +731,15 @@ class Module:
             first = _peek_batch(train_data)
             self.init_params(first.data,
                              initialize_from_kvstore=is_new_worker)
-        if self._train_step is None:
-            self._build_steps()
+        self._use_metric(eval_metric)
+        self.metric_flushes = {"device": 0, "host": 0}
+        if self._metric_spec is None and \
+                eval_metric.name not in self._fallback_said:
+            self._fallback_said.add(eval_metric.name)
+            logger.info(
+                "fit: metric %r has no device form (metrics.device_stats): "
+                "every step's logits cross to the host for it",
+                eval_metric.name)
 
         rng = jax.random.PRNGKey(self.seed + 17)
         num_workers = self.kv.num_workers
@@ -881,10 +941,11 @@ class Module:
                                                       _resume_skip)
                     _resume_skip = 0
                 # Metric updates run ONE STEP BEHIND: step N+1 is dispatched
-                # before step N's logits are fetched to host, so the device
-                # pipeline never drains for metrics (the async-dispatch analog
-                # of the reference engine's compute/update overlap, SURVEY §3.4).
-                pending = None  # (label_np, n_real, logits_device)
+                # before step N's statistics (or logits) are fetched to host,
+                # so the device pipeline never drains for metrics (the
+                # async-dispatch analog of the reference engine's
+                # compute/update overlap, SURVEY §3.4).
+                pending = None  # (label_np, n_real, the step's metric_out)
                 # double-buffered input: () = nothing prefetched yet, None =
                 # iterator exhausted, tuple = batch k+1 already placed on
                 # device while step k's sync phase ran (_prefetch_batch)
@@ -917,7 +978,7 @@ class Module:
                         # averages, as in the reference's aux-key flow.
                         self._ensure_unravel()  # None after elastic rebuilds
                         acct.phase("step.dispatch")
-                        flat_g, flat_s, loss, logits = self._grad_step(
+                        flat_g, flat_s, loss, out = self._grad_step(
                             self.state, data, labels, rng)
                         prefetched = self._prefetch_batch(train_data, acct)
                         acct.phase("step.sync")
@@ -971,7 +1032,7 @@ class Module:
                                 "(kv.set_controller) to carry the allreduce")
                         self._ensure_unravel()
                         acct.phase("step.dispatch")
-                        flat_g, flat_s, loss, logits = self._grad_step(
+                        flat_g, flat_s, loss, out = self._grad_step(
                             self.state, data, labels, rng)
                         prefetched = self._prefetch_batch(train_data, acct)
                         acct.phase("step.sync")
@@ -1046,11 +1107,11 @@ class Module:
                     else:
                         acct.phase("step.dispatch")
                         if self._sentinel:
-                            self.state, loss, logits, health = \
+                            self.state, loss, out, health = \
                                 self._train_step(self.state, data, labels,
                                                  rng)
                         else:
-                            self.state, loss, logits = self._train_step(
+                            self.state, loss, out = self._train_step(
                                 self.state, data, labels, rng)
                         prefetched = self._prefetch_batch(train_data, acct)
                     host_step += 1
@@ -1091,14 +1152,14 @@ class Module:
                             int(jax.device_get(self.state.step)))
                         return eval_metric
                     # flush the PREVIOUS step's metric + its callback (its
-                    # logits are ready by now; this step already runs on device)
+                    # outputs are ready by now; this step already runs on device)
                     if pending is not None:
                         nbatch = self._flush_metric(pending, eval_metric, epoch,
                                                     nbatch, batch_end_callback,
                                                     acct)
                     # pad examples excluded (reference DataBatch.pad semantics)
                     pending = (np.asarray(batch.label),
-                               batch.data.shape[0] - batch.pad, logits)
+                               batch.data.shape[0] - batch.pad, out)
                 # the iteration that found the feed exhausted (or halted)
                 # stays open for the final step's metric + callback: its row
                 # dispatches nothing and flushes the last batch
@@ -1285,19 +1346,17 @@ class Module:
         """Account one completed batch: metric update, then its batch-end
         callback — same ordering as the reference's synchronous loop, just
         deferred one step so device dispatch never drains for metrics.
-        In the step account (``acct``) the wait for the logits and their
-        copy to the host is ``step.fetch``, the softmax and the metric
+        In the step account (``acct``) the wait for the step's statistics
+        (or logits) and their copy to the host is ``step.fetch``, the metric
+        (after the host's softmax, on the path that needs one)
         ``step.metric``, the callbacks ``step.callback``."""
-        lab, n_real, lg = pending
-        acct.phase("step.fetch")
-        logits = _local_np(lg)
-        acct.phase("step.metric")
-        probs = _softmax_np(logits)
-        del logits  # as soon as a temporary would be: update() reuses it
-        eval_metric.update(lab[:n_real], probs[:n_real])
-        # released inside the phase that made it (0.8 GB for a language
-        # model): its unmapping would otherwise read as hooks
-        del probs
+        path = self._update_metric(eval_metric, *pending, acct)
+        self.metric_flushes[path] += 1
+        if obs_metrics.enabled():
+            reg = obs_metrics.registry()
+            reg.gauge("fit.metric_device_steps",
+                      self.metric_flushes["device"])
+            reg.gauge("fit.metric_host_steps", self.metric_flushes["host"])
         nbatch += 1
         acct.flushed = nbatch
         if batch_end_callback is not None:
@@ -1307,6 +1366,49 @@ class Module:
                 cb(p)
         acct.phase("step.hooks")
         return nbatch
+
+    @staticmethod
+    def _update_metric(eval_metric, lab, n_real, out, acct=None):
+        """One batch into ``eval_metric``, for ``fit`` and ``score`` alike.
+        ``out`` is what the compiled program left on the device: the
+        metric's per-row statistics (a dict, reduced over the class axis
+        there) or the logits.  Pad rows are cut off on the host (reference
+        DataBatch.pad semantics): no mask and no traced row count in the
+        program.  Returns the path taken, ``"device"`` or ``"host"``."""
+        if acct is not None:
+            acct.phase("step.fetch")
+        if isinstance(out, dict):
+            # multi-host: this process's rows of each statistic, against
+            # its local labels (same rows)
+            reduced = {k: _local_np(v)[:n_real] for k, v in out.items()}
+            if acct is not None:
+                acct.phase("step.metric")
+            eval_metric.update_reduced(lab[:n_real], reduced)
+            return "device"
+        logits = _local_np(out)
+        if acct is not None:
+            acct.phase("step.metric")
+        probs = _softmax_np(logits)
+        del logits  # as soon as a temporary would be: update() reuses it
+        eval_metric.update(lab[:n_real], probs[:n_real])
+        # released inside the phase that made it (0.8 GB for a language
+        # model): its unmapping would otherwise read as hooks
+        del probs
+        return "host"
+
+    def _reduce_for(self, eval_metric):
+        """The jitted ``metrics.device_reduce`` of ``eval_metric``'s device
+        form, kept by the statistics' names (None: the host path)."""
+        stats = metrics_lib.device_form(eval_metric)
+        spec = metrics_lib.stats_key(stats)
+        if spec is None:
+            return None
+        if spec not in self._score_reduce:
+            self._score_reduce[spec] = obs_device.instrument(
+                "metric_reduce", jax.jit(functools.partial(
+                    metrics_lib.device_reduce, stats)),
+                {"mesh": dict(self.mesh.shape), "metric": spec})
+        return self._score_reduce[spec]
 
     @staticmethod
     def _observe_step(row):
@@ -1345,6 +1447,7 @@ class Module:
         if self._eval_step is None:
             self._build_steps()
         eval_metric = metrics_lib.create(eval_metric)
+        reduce = self._reduce_for(eval_metric)
         with obs_trace.tracer().span("eval", annotate=True):
             eval_metric.reset()
             eval_data.reset()
@@ -1353,13 +1456,11 @@ class Module:
                     batch = eval_data.next()
                 except StopIteration:
                     break
-                logits = self._eval_step(self.state,
-                                         self._place(batch.data))
-                n_real = batch.data.shape[0] - batch.pad
-                # multi-host: local logits shard vs local labels (same rows)
-                probs = _softmax_np(_local_np(logits))
-                eval_metric.update(np.asarray(batch.label)[:n_real],
-                                   probs[:n_real])
+                out = self._eval_step(self.state, self._place(batch.data))
+                if reduce is not None:
+                    out = reduce(out, self._place(batch.label))
+                self._update_metric(eval_metric, np.asarray(batch.label),
+                                    batch.data.shape[0] - batch.pad, out)
         return eval_metric.get_name_value()
 
     def predict(self, data) -> np.ndarray:

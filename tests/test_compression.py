@@ -161,7 +161,7 @@ def test_module_host_sync_with_compression_end_to_end():
     from dt_tpu import data, models, parallel
     from dt_tpu.elastic import Scheduler, WorkerClient
     from dt_tpu.parallel import mesh as mesh_lib
-    from dt_tpu.training import Module
+    from dt_tpu.training import Module, metrics
 
     s = Scheduler(initial_workers=["w0", "w1"])
     rng = np.random.RandomState(5)
@@ -189,7 +189,7 @@ def test_module_host_sync_with_compression_end_to_end():
             # shapes via the iterator); outputs discarded, state untouched
             b = data.NDArrayIter(X, Y, batch_size=16).next()
             mod.init_params(b.data)
-            mod._build_steps()
+            mod._use_metric(metrics.create("acc"))  # fit's default
             mod._ensure_unravel()
             fg, fs, _, _ = mod._grad_step(
                 mod.state, mod._place(b.data), mod._place(b.label),

@@ -96,7 +96,7 @@ def _run_host_pair(overlap_on, compress, monkeypatch, bucket_bytes=256):
     import jax
     from dt_tpu import data, parallel
     from dt_tpu.parallel import mesh as mesh_lib
-    from dt_tpu.training import Module
+    from dt_tpu.training import Module, metrics
 
     monkeypatch.setenv("DT_AR_OVERLAP", "1" if overlap_on else "0")
     # tiny buckets: the ~300-param model must split into MANY buckets or
@@ -131,7 +131,7 @@ def _run_host_pair(overlap_on, compress, monkeypatch, bucket_bytes=256):
             it = data.NDArrayIter(X, Y, batch_size=8)
             b = it.next()
             mod.init_params(b.data)
-            mod._build_steps()
+            mod._use_metric(metrics.create("acc"))  # fit's default
             mod._ensure_unravel()
             fg, fs, _, _ = mod._grad_step(
                 mod.state, mod._place(b.data), mod._place(b.label),

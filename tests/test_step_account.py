@@ -128,6 +128,29 @@ def test_an_iteration_that_a_callback_leaves_still_writes_its_row(tracer):
     assert len(_rows(tracer, fit=1)) == 3
 
 
+@pytest.mark.parametrize("metric,path", [
+    (["acc", "ce"], "device"),
+    (lambda lb, p: float((p.argmax(-1) == lb).mean()), "host"),
+], ids=["device_path", "host_path"])
+def test_fetch_and_metric_stay_separate_phases_on_either_metric_path(
+        tracer, metric, path):
+    """On the device path the step hands the host per-row statistics, not
+    logits: the wait for them is still ``step.fetch``, ``update_reduced``
+    still ``step.metric``, and ``flushed`` counts as before."""
+    mod = _module()
+    mod.fit(_feed(4), eval_metric=metric,
+            batch_end_callback=lambda p: None)
+    assert mod.metric_flushes == {"device": 0, "host": 0, path: 4}
+    rows = _rows(tracer)
+    assert [r["flushed"] for r in rows] == [None, 1, 2, 3, 4]
+    assert [r["dispatched"] for r in rows] == [0, 1, 2, 3, None]
+    for r in rows[1:]:
+        assert r["step.fetch"] > 0 and r["step.metric"] > 0
+        assert r["step.callback"] > 0
+        assert sum(r[p] for p in PHASES) == r["total_ns"]
+    assert rows[0]["step.fetch"] == rows[0]["step.metric"] == 0
+
+
 def test_account_ring_is_bounded_and_drops_the_oldest():
     tr = obs_trace.Tracer(capacity=8, enabled=False)
     acct = tr.step_account()
