@@ -34,13 +34,15 @@ def _dtype(cfg):
 class WindowDone(Exception):
     """Raised by the clock out of ``fit`` when the window has closed: the
     steps the loop has already queued (two: one dispatched, one prefetched)
-    would otherwise run after it, 16 s of every LM run."""
+    would otherwise run after it, 0.6 s of every LM run."""
 
 
 class Clock:
     """The batch-end callback kept with the benchmark (the Speedometer's
-    pattern): ``fit`` calls it once ``_flush_metric`` has waited for that
-    step's logits, so the host clock read here is a completion time.  The
+    pattern): ``fit`` calls it once ``_flush_metric`` has fetched what that
+    step's program hands the host for the metric (its per-row statistics;
+    its logits for a metric without a device form), so the host clock read
+    here is a completion time.  The
     window starts at the ``warm_steps``-th completion (or at ``t_start``
     where there is none) and ends at the first completion at or after
     ``seconds``; every step between the two is in it.  In a traced run the
@@ -61,6 +63,7 @@ class Clock:
         self.seen = 0
         self.trace = trace          # None or dict(dir, last_s)
         self.trace_span = None      # [t_start, t_stop, first step, last step]
+        self.stop_trace_s = 0.0     # what stopping the profiler cost
         self.done = False
         self.gc_s, self._gc_t0 = 0.0, None
         gc.callbacks.append(self._gc)
@@ -130,7 +133,9 @@ class Clock:
         if self.trace_span is not None and self.trace_span[1] is None:
             self.trace_span[1] = self.times[-1]
             self.trace_span[3] = len(self.times) - 1
+            t0 = time.perf_counter()
             jax.profiler.stop_trace()
+            self.stop_trace_s = time.perf_counter() - t0
 
     def slow_steps(self, top=5, factor=1.5):
         """The slowest steps that took over ``factor`` times the median

@@ -49,14 +49,17 @@ def value(ctx, m):
     return out
 
 
-def host_ms_per_step(ctx, m):
-    """CPU milliseconds of the loop's thread per step: what the host
-    computes, not what it waits for."""
-    return 1e3 * ctx["host_cpu_s"] / ctx["steps"] if ctx["steps"] else None
-
-
-def input_wait_ms_per_step(ctx, m):
-    return 1e3 * ctx["feed_wait_s"] / ctx["steps"] if ctx["steps"] else None
+def counter_share_pct(ctx, m):
+    """The share (%) that ``args.key`` has of a counter the program keeps on
+    the job: ``args.counter`` is the dotted path from ``ctx["job"]`` to a
+    dict of counts (``mod.metric_flushes``: which way each step's metric
+    went in the window's ``fit`` call)."""
+    counts = ctx["job"]
+    for part in m["args"]["counter"].split("."):
+        counts = getattr(counts, part, None)
+    if not isinstance(counts, dict) or not sum(counts.values()):
+        return None
+    return 100.0 * counts.get(m["args"]["key"], 0) / sum(counts.values())
 
 
 def peak_hbm_gb(ctx, m):
@@ -103,6 +106,40 @@ def mfu_pct(ctx, m):
                                                         ctx["traffic"])
     return 100.0 * flops * ctx["items_per_step"] * tr["steps"] \
         / (tr["busy_s"] * ctx["chips"]) / peaks["bf16_flops_per_s"]
+
+
+def scope_ms_per_step(ctx, m):
+    """Device milliseconds a traced step in the operations whose scope path
+    holds every string of ``args.holds`` and none of ``args.lacks`` (scopes,
+    transforms and flax module names are all parts of the path); None where
+    the operations carry no scopes (``xplane.scopes_missing``)."""
+    import xplane
+    tr = ctx["trace"]
+    if not tr or not tr["steps"] or not tr["busy_s"] or \
+            xplane.scopes_missing(tr):
+        return None
+    args = m.get("args", {})
+    return 1e3 * xplane.scope_seconds(tr, args.get("holds", ()),
+                                      args.get("lacks", ())) \
+        / tr["steps"] / tr["devices"]
+
+
+def unscoped_pct(ctx, m):
+    """The share (%) of the traced busy time in operations that none of the
+    metrics ``args.none_of`` counts (their files say what each holds and
+    lacks): what carries another scope, or none."""
+    import xplane
+    tr = ctx["trace"]
+    if not tr or not tr["busy_s"]:
+        return None
+    splits = []
+    for name in m["args"]["none_of"]:
+        with open(metric_file(ctx["bench_dir"], name)) as f:
+            splits.append(json.load(f).get("args", {}))
+    rest = sum(v for k, v in tr["scope_seconds"].items() if not any(
+        xplane.scope_matches(k, a.get("holds", ()), a.get("lacks", ()))
+        for a in splits))
+    return 100.0 * rest / (tr["busy_s"] * tr["devices"])
 
 
 def _kernel(ctx, m):

@@ -260,6 +260,49 @@ def log_memory(job, when):
     return stats
 
 
+def traced(su, clock, trace):
+    """The traced steps' ``.xplane.pb`` -> (its reduction, its path); None
+    where the trace holds no device operation."""
+    import xplane
+    path = xplane.find(trace["dir"])
+    span = clock.trace_span
+    rows = xplane.load(path) if path else []
+    whole = xplane.whole_steps(rows)
+    reduced = xplane.reduce_rows(rows, window_ns=whole and whole[0])
+    if span is None or (reduced is None and not su.forced_cpu):
+        print("benchmark: the traced window holds no device operation "
+              "(it closed before the profiler started?)", file=sys.stderr)
+        return None
+    if reduced is None:  # the CPU rehearsal: its trace has no device
+        reduced = {"devices": 1, "busy_s": 0.0, "window_s": 0.0,
+                   "op_seconds": {}, "scope_seconds": {}, "device_ops": [],
+                   "idle_gaps": []}
+    elif xplane.scopes_missing(reduced):
+        log("scopes_missing: under half of the busy time carries a scope "
+            "(an executable cached before the program had scopes?); the "
+            "split by scope is left out")
+    if whole:   # the device's own step edges
+        reduced["steps"] = whole[1]
+        reduced["host_window_s"] = reduced["window_s"]
+    else:
+        # under three executions of the step's program in the trace: the
+        # host's count of steps, over its clock around them or the span of
+        # the device's own events where that is longer (the device finishes
+        # the step in flight after the host has asked the profiler to stop)
+        log("trace_edges: the trace is not cut to whole steps")
+        reduced["steps"] = span[3] - span[2]
+        reduced["host_window_s"] = max(span[1] - span[0],
+                                       reduced["window_s"])
+    reduced["idle_gaps"] = [["unannotated", reduced["host_window_s"]
+                             - reduced["busy_s"]]]
+    log(f"trace steps={reduced['steps']} busy_s={reduced['busy_s']:.6f} "
+        f"device_span_s={reduced['window_s']:.6f} "
+        f"host_window_s={reduced['host_window_s']:.6f} "
+        f"stop_trace_s={clock.stop_trace_s:.3f} file={path} "
+        f"bytes={os.path.getsize(path) if path else 0}")
+    return reduced, path
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -287,7 +330,6 @@ def main(argv=None):
     # -- the first steps: warm-up, and what `correct` compares ------------
     program = job.first_steps(feed, key, cfg["check"]["steps"])
     setup_compiles, setup_compile_s = counter.compiles, counter.compile_s
-    feed.wait_s.clear()
     log_memory(job, "first_steps_done")
 
     # -- the window --------------------------------------------------------
@@ -333,51 +375,50 @@ def main(argv=None):
         "median_reading_s": stats["median_s"], "window_s": stats["window_s"],
         "window_vs_median_pct": stats["window_vs_median_pct"],
         "steps": steps, "steps_per_reading": spr,
-        "host_cpu_s": clock.marks[-1][0] - clock.marks[0][0],
-        "feed_wait_s": sum(feed.wait_s[:steps]),
         "compiles_in_window": in_window, "peak_bytes": peak,
         "device_kind": su.dev["kind"], "bench_dir": su.bench_dir,
         "setup": {"import_s": t_import - T_PROCESS,
                   "init_s": t_init - t_import,
                   "compile_s": setup_compile_s,
                   "warm_s": t_window - t_init, "check_s": check_s},
-        "setup_s": setup_s, "trace": None, "rehearsal": su.forced_cpu,
+        "setup_s": setup_s, "rehearsal": su.forced_cpu,
+        # for a reader in a module a later PR adds: the job (the program's
+        # own counters hang on it), the traced steps' reduction, and the
+        # trace's file, kept until every reader has returned
+        "job": job, "trace": None, "trace_path": None,
     }
     device = {**su.dev, "memory_peak_bytes": int(peak)}
     result = {"correct": correct, "attempted": steps, "failed": 0}
-    if args.trace:
-        import xplane
-        path = xplane.find(trace["dir"])
-        span = clock.trace_span
-        reduced = xplane.reduce_rows(xplane.load(path)) if path else None
-        if span is None or (reduced is None and not su.forced_cpu):
-            print("benchmark: the traced window holds no device operation "
-                  "(it closed before the profiler started?)", file=sys.stderr)
-            return 1
-        if reduced is None:  # the CPU rehearsal: its trace has no device
-            reduced = {"busy_s": 0.0, "window_s": 0.0, "op_seconds": {},
-                       "device_ops": [], "idle_gaps": []}
-        reduced["steps"] = span[3] - span[2]
-        # the host's clock around the traced steps, or the span of the
-        # device's own events where that is longer (the device finishes the
-        # step in flight after the host has asked the profiler to stop)
-        reduced["host_window_s"] = max(span[1] - span[0], reduced["window_s"])
-        reduced["idle_gaps"] = [["unannotated", reduced["host_window_s"]
-                                 - reduced["busy_s"]]]
-        ctx["trace"] = reduced
-        device["busy_s"] = reduced["busy_s"]
-        device["window_s"] = reduced["host_window_s"]
-        log(f"trace steps={reduced['steps']} busy_s={reduced['busy_s']:.6f} "
-            f"device_span_s={reduced['window_s']:.6f} "
-            f"host_window_s={reduced['host_window_s']:.6f} file={path}")
-        shutil.rmtree(trace["dir"], ignore_errors=True)
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
-    result["metrics"] = readers.collect(su.manifest, su.cell, ctx,
-                                        bool(args.trace))
+    try:
+        if args.trace:
+            found = traced(su, clock, trace)
+            if found is None:
+                return 1
+            reduced, ctx["trace_path"] = found
+            ctx["trace"] = reduced
+            device["busy_s"] = reduced["busy_s"]
+            device["window_s"] = reduced["host_window_s"]
+            result["breakdown"] = {"device_ops": reduced["device_ops"],
+                                   "idle_gaps": reduced["idle_gaps"]}
+        result["metrics"] = readers.collect(su.manifest, su.cell, ctx,
+                                            bool(args.trace))
+    finally:
+        if trace is not None:
+            shutil.rmtree(trace["dir"], ignore_errors=True)
     result["device"] = device
     for name, m in result["metrics"].items():
         log(f"metric {name}={m['value']} {m['unit']}")
+    # each number compared beside its limit: last in the line, and the last
+    # lines on standard error
+    result["compared"] = {what: {"value": value, "limit": limit}
+                          for what, value, limit in rows}
+    result["compared"]["compiles_in_window"] = {"value": in_window,
+                                                "limit": 0}
+    sys.stdout.flush()
+    for what, row in result["compared"].items():
+        print(f"compared {what} value={row['value']:.6g} "
+              f"limit={row['limit']:.6g}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

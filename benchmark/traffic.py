@@ -3,13 +3,12 @@
 A traffic mix is a data file ``traffic/<name>.json``: its parameters and the
 function that draws one batch from them, named ``module:function`` (the two
 here, or one in a module a later PR adds beside this file).  ``generate``
-makes a cell's batches from the seed; every seed gives the same sizes.  ``Feed`` hands them to ``Module.fit`` as the
-iterator a user would pass, cycling, and keeps the host clock around each
-``next()`` (the time a step waited for input).
+makes a cell's batches from the seed; every seed gives the same sizes.
+``Feed`` hands them to ``Module.fit`` as the iterator a user would pass,
+cycling.
 """
 
 import importlib
-import time
 
 import numpy as np
 
@@ -49,7 +48,6 @@ class Feed:
                          for d, lb in batches]
         self._cursor = 0      # position in the cycle, kept across epochs
         self._left = 0
-        self.wait_s = []      # host seconds inside each next()
 
     def arm(self, limit=None):
         self._left = float("inf") if limit is None else limit
@@ -62,11 +60,9 @@ class Feed:
         pass
 
     def next(self):
-        t0 = time.perf_counter()
         if self._left <= 0:
             raise StopIteration
         self._left -= 1
         batch = self._batches[self._cursor % len(self._batches)]
         self._cursor += 1
-        self.wait_s.append(time.perf_counter() - t0)
         return batch

@@ -1,9 +1,11 @@
 """A temporary copy of the benchmark with toy-size cells added as files.
 
-What a later PR does, done in a scratch directory: a configuration, a
-traffic mix (with a generator module of its own), a per-layer metric (with a
-reader module of its own) and a cell are each new files plus one new entry;
-no file that is there is edited.  The
+What a later PR does, done in a scratch directory: a configuration (one of
+them cut: ``reduced``, ``published``, ``deployment``), a traffic mix (with a
+generator module of its own), per-layer metrics (with a reader module of
+their own, which reads the job and the kept trace through ``ctx``) and a
+cell are each new files plus one new entry; no file that is there is
+edited.  The
 toy cells are the CPU rehearsal's: ResNet-50's graph on 32x32 images and a
 two-layer GPT-2, float32, through the real runner.
 """
@@ -39,7 +41,11 @@ def dump(obj, path):
 
 
 def toy_config(base, limits=None, **changes):
+    """A toy configuration; with ``reduced`` among the ``changes`` a cut
+    one, whose ``published`` values are the base's."""
     cfg = load(os.path.join(BENCH, "configs", base + ".json"))
+    if changes.get("reduced"):
+        changes["published"] = {k: cfg[k] for k in changes["reduced"]}
     cfg.update(changes)
     cfg["source"] = f"toy-size copy of {base} for the CPU rehearsal"
     cfg["assumed"], cfg["departures"] = {}, ["toy size"]
@@ -53,9 +59,15 @@ TOY_CONFIGS = {
     "resnet50-toy": lambda: toy_config(
         "resnet50", name="resnet50-toy", image_shape=[32, 32, 3],
         num_classes=10),
+    # a cut configuration: what a model_config PR brings for a model that
+    # does not fit one chip whole (depth and vocabulary rows held here)
     "gpt2-toy": lambda: toy_config(
         "gpt2-medium", name="gpt2-toy", vocab_size=64, n_positions=128,
-        n_ctx=128, n_embd=128, n_layer=2, n_head=2, n_inner=512),
+        n_ctx=128, n_embd=128, n_layer=2, n_head=2, n_inner=512,
+        reduced=["n_layer", "vocab_size"],
+        deployment="toy: this chip holds 2 of 24 layers and 64 of 50,257 "
+                   "vocabulary rows, as if the others were other chips' of "
+                   "a pipeline and of a head shared by rows"),
 }
 for _broken in ("FrozenLMJob", "HalfBatchLMJob"):
     # the same toy LM with the timed path broken underneath (toy/)
@@ -96,27 +108,50 @@ def counting_tokens(rng, traffic, cfg):
     return toks, np.roll(toks, -1, axis=1)
 '''
 
-TOY_READER = '''"""A reader module a later PR adds beside the others."""
+TOY_READER = '''"""A reader module a later PR adds beside the others.  Given only ``ctx``
+it reads the runner's own numbers, a counter the program keeps on the job,
+and the traced steps' file (kept until every reader has returned)."""
+
+import os
 
 
 def steps_in_window(ctx, m):
     return float(ctx["steps"])
+
+
+def job_device_flushes(ctx, m):
+    return float(ctx["job"].mod.metric_flushes["device"])
+
+
+def trace_bytes(ctx, m):
+    path = ctx["trace_path"]
+    return float(os.path.getsize(path)) if path else None
 '''
+#: the per-layer metrics the copy adds, each a file naming a reader above
+TOY_METRICS = {
+    "loop.steps_in_window": ("steps_in_window", "count",
+                             "steps completed inside the window"),
+    "loop.job_device_flushes": ("job_device_flushes", "count",
+                                "Module.metric_flushes through ctx['job']"),
+    "loop.trace_bytes": ("trace_bytes", "bytes",
+                         "size of the traced steps' .xplane.pb"),
+}
 
 
 def make_copy(dest):
     """``dest``/BENCHMARK.json and ``dest``/benchmark with the toy cells,
-    a new per-layer metric and its reader added.  Returns the manifest's
+    new per-layer metrics and their readers added.  Returns the manifest's
     path."""
     shutil.copytree(BENCH, os.path.join(dest, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     bench = os.path.join(dest, "benchmark")
     manifest = load(os.path.join(REPO, "BENCHMARK.json"))
     for name, make in TOY_CONFIGS.items():
-        dump(make(), os.path.join(bench, "configs", name + ".json"))
+        cfg = make()
+        dump(cfg, os.path.join(bench, "configs", name + ".json"))
         manifest["configs"].append({
-            "name": name, "source": "toy", "reduced": [], "why": "toy",
-            "file": f"benchmark/configs/{name}.json"})
+            "name": name, "source": "toy", "reduced": cfg["reduced"],
+            "why": "toy", "file": f"benchmark/configs/{name}.json"})
     for name, traffic in TOY_TRAFFIC.items():
         dump(traffic, os.path.join(bench, "traffic", name + ".json"))
     for cell, (config, traffic, like) in TOY_CELLS.items():
@@ -130,14 +165,14 @@ def make_copy(dest):
         f.write(TOY_READER)
     with open(os.path.join(bench, "toy_traffic.py"), "w") as f:
         f.write(TOY_GENERATOR)
-    metric = {"name": "loop.steps_in_window", "unit": "count",
-              "better": "higher", "source": "program_counter",
-              "layer": "loop: training/module.py fit",
-              "moves": "tokens_per_s_per_chip", "workloads": ["toy-lm"]}
-    dump({"reader": "toy_readers:steps_in_window",
-          "what": "steps completed inside the window"},
-         os.path.join(bench, "metrics", "loop.steps_in_window.json"))
-    manifest["per_layer"].append(metric)
+    for name, (reader, unit, what) in TOY_METRICS.items():
+        dump({"reader": "toy_readers:" + reader, "what": what},
+             os.path.join(bench, "metrics", name + ".json"))
+        manifest["per_layer"].append({
+            "name": name, "unit": unit, "better": "higher",
+            "source": "program_counter",
+            "layer": "loop: training/module.py fit",
+            "moves": "tokens_per_s_per_chip", "workloads": ["toy-lm"]})
     for src in os.listdir(TOY):   # the broken drivers of the rehearsal
         if src.endswith(".py"):
             shutil.copy(os.path.join(TOY, src), bench)

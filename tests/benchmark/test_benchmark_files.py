@@ -9,6 +9,7 @@ import sys
 import pytest
 
 import bench_toy
+import contract
 from bench_toy import BENCH, REPO, load
 
 sys.path.insert(0, BENCH)
@@ -50,10 +51,7 @@ def test_cell_resolves_to_files_that_exist(cell):
     module, attr = traffic["generator"].split(":")
     assert callable(getattr(__import__(module), attr))
     entry = next(e for e in MANIFEST["configs"] if e["name"] == c["config"])
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert cfg["name"] == entry["name"] and cfg["source"]
-    assert cfg["reduced"] == entry["reduced"] == []
-    assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
+    contract.check_config(entry, cfg)
     module, attr = cfg["driver"].split(":")
     assert os.path.isfile(os.path.join(BENCH, module + ".py"))
     limits = cfg["check"]["limits"]
@@ -111,6 +109,67 @@ def test_every_name_is_made_of_the_allowed_characters():
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", f), f
 
 
+# -- a configuration's entry and its file; a cut one ------------------------
+
+@pytest.fixture(scope="module")
+def toy_manifest(tmp_path_factory):
+    path = bench_toy.make_copy(str(tmp_path_factory.mktemp("contract")))
+    return path, load(path)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in MANIFEST["configs"]]
+                         + list(bench_toy.TOY_CONFIGS))
+def test_configuration_meets_the_contract(toy_manifest, name):
+    """The two configurations that are here (nothing cut) and the toy
+    copy's, one of them cut, under the one function."""
+    path, manifest = toy_manifest
+    entry = next(e for e in manifest["configs"] if e["name"] == name)
+    cfg = load(os.path.join(os.path.dirname(path), entry["file"]))
+    contract.check_config(entry, cfg)
+    if name in ("resnet50", "gpt2-medium"):
+        assert entry["reduced"] == [] and "published" not in cfg
+    if name == "gpt2-toy":
+        assert entry["reduced"] == ["n_layer", "vocab_size"]
+        assert cfg["published"] == {"n_layer": 24, "vocab_size": 50257}
+        assert (cfg["n_layer"], cfg["vocab_size"]) == (2, 64)
+
+
+def _cut():
+    cfg = bench_toy.TOY_CONFIGS["gpt2-toy"]()
+    entry = {"name": cfg["name"], "source": "toy", "why": "toy",
+             "file": "benchmark/configs/gpt2-toy.json",
+             "reduced": list(cfg["reduced"])}
+    return entry, cfg
+
+
+MALFORMED = {
+    "a reduced key the file lacks": lambda e, c: (
+        e["reduced"].append("n_experts"), c["reduced"].append("n_experts"),
+        c["published"].update(n_experts=128)),
+    "a published value equal to the one held": lambda e, c:
+        c["published"].update(n_layer=c["n_layer"]),
+    "cut and no deployment": lambda e, c: c.pop("deployment"),
+    "the two lists differ": lambda e, c: e["reduced"].pop(),
+    "cut and no published": lambda e, c: c.pop("published"),
+    "a value larger than the source's": lambda e, c:
+        c["published"].update(n_layer=1),
+    "a width among the reduced": lambda e, c: (
+        e["reduced"].append("n_embd"), c["reduced"].append("n_embd"),
+        c["published"].update(n_embd=1024)),
+    "a deployment of two lines": lambda e, c:
+        c.update(deployment="8 chips\n16 experts here"),
+}
+
+
+@pytest.mark.parametrize("case", MALFORMED, ids=lambda c: c.replace(" ", "-"))
+def test_malformed_cut_configuration_fails_the_contract(case):
+    entry, cfg = _cut()
+    contract.check_config(entry, cfg)       # sound before it is broken
+    MALFORMED[case](entry, cfg)
+    with pytest.raises(contract.ContractError):
+        contract.check_config(entry, cfg)
+
+
 def test_a_later_pr_adds_one_of_each_as_new_files(tmp_path):
     """A configuration, a traffic mix with its generator, a per-layer
     metric with its reader and a cell, added to a copy without editing a
@@ -128,8 +187,11 @@ def test_a_later_pr_adds_one_of_each_as_new_files(tmp_path):
         manifest_path, "toy-lm")
     assert cfg["name"] == "gpt2-toy" and traffic["batch"] == 2
     assert bench_dir == copy and os.path.isfile(reference)
-    added = load(os.path.join(copy, "metrics", "loop.steps_in_window.json"))
-    assert added["reader"] == "toy_readers:steps_in_window"
+    for name, (reader, _, _) in bench_toy.TOY_METRICS.items():
+        added = load(os.path.join(copy, "metrics", name + ".json"))
+        assert added["reader"] == "toy_readers:" + reader
+    # the configuration added is a cut one
+    assert cfg["reduced"] and cfg["published"] and cfg["deployment"]
     # the mix's generator is a module the copy added beside traffic.py
     assert traffic["generator"] == "toy_traffic:counting_tokens"
     sys.path.insert(0, copy)
@@ -263,20 +325,175 @@ def test_worst_leaf_gap_is_against_the_larger_of_leaf_and_median():
 FIXTURE = os.path.join(BENCH, "fixtures", "small.xplane.pb")
 
 
+STEP = "jit(train_step)/jit(main)/"
+
+
 def test_reduction_of_made_up_rows():
-    rows = [("/device:TPU:0", "XLA Ops", [("fusion.1", 0.0, 4e9),
-                                          ("kern_fwd.2", 3e9, 2e9),
-                                          ("fusion.1", 8e9, 1e9)]),
-            ("/device:TPU:0", "Steps", [("0", 0.0, 9e9)]),
-            ("/host:CPU", "python", [("other", 0.0, 9e9)])]
+    rows = [("/device:TPU:0", "XLA Ops", [
+        ("fusion.1", 0.0, 4e9, STEP + "jvp(forward)/block0/dot_general"),
+        ("kern_fwd.2", 3e9, 2e9, STEP + "transpose(jvp(forward))/mul"),
+        ("fusion.1", 8e9, 1e9, STEP + "jvp(forward)/block0/dot_general"),
+        ("copy.3", 9e9, 0.5e9, "")]),
+            ("/device:TPU:0", "Steps", [("0", 0.0, 9e9, "")]),
+            ("/host:CPU", "python", [("other", 0.0, 9e9, "")])]
     red = xplane.reduce_rows(rows, window_ns=(0.0, 10e9))
-    # busy: [0,5) and [8,9) = 6 s; idle: [5,8) and [9,10) = 4 s
-    assert red["busy_s"] == pytest.approx(6.0) and red["devices"] == 1
-    assert red["op_seconds"] == {"fusion.1": 5.0, "kern_fwd.2": 2.0}
-    assert red["device_ops"][0] == ["fusion.1", 5.0]
-    assert red["idle_gaps"] == [["unannotated", pytest.approx(4.0)]]
+    # busy: [0,5) and [8,9.5) = 6.5 s; idle: [5,8) and [9.5,10) = 3.5 s
+    assert red["busy_s"] == pytest.approx(6.5) and red["devices"] == 1
+    # an operation's seconds stay under its bare name ...
+    assert red["op_seconds"] == {"fusion.1": 5.0, "kern_fwd.2": 2.0,
+                                 "copy.3": 0.5}
+    # ... and the busy time is kept by scope path, "" for none: the second
+    # [3,4) in which two operations ran goes to the one that started last
+    assert red["scope_seconds"] == {
+        STEP + "jvp(forward)/block0/dot_general": 4.0,
+        STEP + "transpose(jvp(forward))/mul": 2.0, "": 0.5}
+    assert sum(red["scope_seconds"].values()) == red["busy_s"]
+    # the breakdown names each operation with its phase before it
+    assert red["device_ops"] == [["jvp(forward)/fusion.1", 5.0],
+                                 ["transpose(jvp(forward))/kern_fwd.2", 2.0],
+                                 ["copy.3", 0.5]]
+    assert red["idle_gaps"] == [["unannotated", pytest.approx(3.5)]]
     assert xplane.op_seconds(red, "kern_fwd") == (2.0, 1)
-    assert xplane.reduce_rows([("/host:CPU", "t", [("x", 0.0, 1.0)])]) is None
+    assert xplane.scope_seconds(red, ["forward"], ["transpose("]) == 4.0
+    assert xplane.scope_seconds(red, ["block0"]) == 4.0
+    assert xplane.scope_seconds(red) == 6.5
+    assert not xplane.scopes_missing(red)
+    assert xplane.reduce_rows(
+        [("/host:CPU", "t", [("x", 0.0, 1.0, "")])]) is None
+
+
+def test_every_busy_instant_goes_to_the_innermost_operation():
+    # a conditional [0,10) holding two operations of its body, [1,4) and
+    # [5,9), the second holding a call of its own [6,7); then an operation
+    # that outlives the one it started in, [12,15) over [11,13); a gap
+    events = [("cond", 0.0, 10.0, ""), ("a", 1.0, 3.0, "x"),
+              ("b", 5.0, 4.0, "y"), ("c", 6.0, 1.0, "z"),
+              ("d", 11.0, 2.0, "x"), ("e", 12.0, 3.0, "y")]
+    own = xplane.self_ns(events)
+    assert own == [3.0, 3.0, 3.0, 1.0, 1.0, 3.0]
+    busy = sum(e - s for s, e in xplane.merge(
+        [s, s + d] for _, s, d, _ in events))
+    assert sum(own) == busy == 14.0
+    assert sum(d for _, _, d, _ in events) == 23.0   # durations do not
+    shuffled = [events[i] for i in (4, 2, 0, 5, 1, 3)]
+    assert xplane.self_ns(shuffled) == [own[i] for i in (4, 2, 0, 5, 1, 3)]
+    assert xplane.self_ns([]) == []
+
+
+@pytest.mark.parametrize("scope,want", [
+    (STEP + "optimizer/mul", "optimizer"),
+    (STEP + "transpose(jvp(forward))/block3/mlp_in/dot_general",
+     "transpose(jvp(forward))"),
+    ("jit(work)/kern_matmul/dot_general", "kern_matmul"),
+    ("jit(train_step)/mul", ""),         # the primitive is no phase
+    ("", "")])
+def test_phase_is_the_first_scope_that_is_no_jit(scope, want):
+    assert xplane.phase(scope) == want
+
+
+# -- the step split by scope, and the program's counter ---------------------
+
+SPLIT = ("model.forward_ms_per_step", "model.backward_ms_per_step",
+         "model.optimizer_ms_per_step", "model.unscoped_pct")
+
+
+def split_ctx(scope_seconds, busy_s, steps=2, devices=1):
+    return {"bench_dir": BENCH, "trace": {
+        "steps": steps, "devices": devices, "busy_s": busy_s,
+        "scope_seconds": scope_seconds}}
+
+
+def read(name, ctx):
+    m = load(readers.metric_file(BENCH, name))
+    return readers.resolve(m["reader"])(ctx, m)
+
+
+def test_every_operation_falls_in_exactly_one_of_the_four():
+    seconds = {
+        STEP + "jvp(forward)/block0/dot_general": 0.030,
+        STEP + "jvp(forward)/metric/reduce_max": 0.002,
+        STEP + "transpose(jvp(forward))/block0/dot_general": 0.050,
+        STEP + "optimizer/mul": 0.010,
+        STEP + "health/reduce_sum": 0.003,
+        STEP + "convert_element_type": 0.001,
+        "": 0.004}
+    ctx = split_ctx(seconds, busy_s=0.100)
+    forward, backward, optimizer, unscoped = (read(n, ctx) for n in SPLIT)
+    assert forward == pytest.approx(16.0)       # (30 + 2) ms over 2 steps
+    assert backward == pytest.approx(25.0)
+    assert optimizer == pytest.approx(5.0)
+    assert unscoped == pytest.approx(8.0)       # 3 + 1 + 4 ms of 100
+    # the four add up to the busy time: no operation is in two of them
+    assert forward + backward + optimizer + unscoped / 100 * 50.0 == \
+        pytest.approx(50.0)
+    # over two devices the seconds are summed and the busy time is a mean
+    both = split_ctx({k: 2 * v for k, v in seconds.items()}, 0.100,
+                     devices=2)
+    assert read(SPLIT[0], both) == pytest.approx(16.0)
+    assert read(SPLIT[3], both) == pytest.approx(8.0)
+
+
+def test_an_executable_without_scopes_reports_no_split():
+    # cached before the program had scopes: 4 of 100 ms carry one
+    ctx = split_ctx({"": 0.096, STEP + "optimizer/mul": 0.004}, 0.100)
+    assert xplane.scopes_missing(ctx["trace"] | {"devices": 1})
+    assert [read(n, ctx) for n in SPLIT[:3]] == [None, None, None]
+    assert read(SPLIT[3], ctx) == pytest.approx(96.0)
+    # nothing traced (the CPU rehearsal): nothing to read
+    nothing = split_ctx({}, 0.0)
+    assert [read(n, nothing) for n in SPLIT] == [None] * 4
+    assert read(SPLIT[0], {"bench_dir": BENCH, "trace": None}) is None
+
+
+def test_the_programs_counter_is_read_through_the_job():
+    class Mod:
+        metric_flushes = {"device": 170, "host": 0}
+
+    class Job:
+        mod = Mod()
+    name = "loop.metric_device_steps_pct"
+    assert read(name, {"job": Job()}) == 100.0
+    Mod.metric_flushes = {"device": 150, "host": 50}
+    assert read(name + ".lm", {"job": Job()}) == 75.0
+    Mod.metric_flushes = {"device": 0, "host": 0}    # no step flushed
+    assert read(name, {"job": Job()}) is None
+    assert read(name, {"job": object()}) is None     # a job without it
+
+
+def test_the_traced_window_is_cut_to_whole_steps():
+    # the profiler started 60 into a step of 100 and stopped 5 into
+    # another: of five executions of the step's program three are whole; a
+    # short program (a transfer) between them is not the step's
+    step = "jit_train_step(7)"
+    modules = [(step, 0.0, 40.0, ""), (step, 40.0, 98.0, ""),
+               ("jit_copy(3)", 139.0, 1.0, ""), (step, 140.0, 99.0, ""),
+               (step, 240.0, 97.0, ""), (step, 340.0, 5.0, "")]
+    ops = [("fusion.1", s, d, STEP + "jvp(forward)/mul")
+           for _, s, d, _ in modules]
+    rows = [("/device:TPU:0", xplane.MODULES_LINE, modules),
+            ("/device:TPU:0", xplane.OPS_LINE, ops)]
+    window, steps = xplane.whole_steps(rows)
+    assert (window, steps) == ((40.0, 340.0), 3)
+    red = xplane.reduce_rows(rows, window_ns=window)
+    # the cut executions' operations are outside; the gaps are inside
+    assert red["busy_s"] == pytest.approx((98 + 1 + 99 + 97) * 1e-9)
+    assert red["window_s"] == pytest.approx(300e-9)
+    # over the host's count (it saw four completions) the step would read
+    # (40 + 98 + 1 + 99 + 97 + 5) / 4 = 85, not 98.3
+    assert red["busy_s"] / steps == pytest.approx(98.33e-9, rel=1e-3)
+    # under three executions there is no whole step to cut to
+    assert xplane.whole_steps([rows[0][:2] + (modules[:2],)]) is None
+    assert xplane.whole_steps([rows[1]]) is None
+    # the recorded traces: three calls leave one whole step; two leave none
+    want = load(os.path.join(BENCH, "fixtures",
+                             "small.expected.json"))["whole_steps"]
+    small = xplane.load(FIXTURE)
+    window, steps = xplane.whole_steps(small)
+    assert (list(window), steps) == (want["window_ns"], want["steps"])
+    red = xplane.reduce_rows(small, window_ns=window)
+    assert red["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert red["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
+    assert xplane.whole_steps(xplane.load(SCOPED)) is None
 
 
 @pytest.mark.skipif(not os.path.isfile(FIXTURE), reason="no recorded trace")
@@ -291,3 +508,71 @@ def test_reduction_of_the_recorded_trace():
     assert names == want["kernel_names"]
     assert seconds == pytest.approx(want["kernel_s"], rel=1e-9)
     assert red["idle_gaps"][0][1] == pytest.approx(want["idle_s"], rel=1e-9)
+
+
+# -- the scoped step: a recorded trace with the program's scopes -----------
+
+SCOPED = os.path.join(BENCH, "fixtures", "scoped.xplane.pb")
+SCOPED_WANT = load(os.path.join(BENCH, "fixtures", "scoped.expected.json"))
+
+
+def test_load_keeps_each_operations_scope():
+    assert os.path.getsize(SCOPED) < 200 * 1024
+    (plane, _, events), = [r for r in xplane.load(SCOPED)
+                           if r[0].startswith("/device:")
+                           and r[1] == xplane.OPS_LINE]
+    assert len(events) == SCOPED_WANT["events"]
+    by_name = {}
+    for name, _, _, scope in events:
+        assert by_name.setdefault(name, scope) == scope
+    assert by_name == SCOPED_WANT["scope_of"]
+    # read raw from the plane's event metadata, keyed by the whole
+    # instruction as the profiler names the event
+    raw = xplane.event_scopes(SCOPED)[plane]
+    assert {xplane.short_name(k): v for k, v in raw.items()} == {
+        k: v for k, v in by_name.items() if v}
+    # the older fixture's one scoped operation
+    (_, _, small), = [r for r in xplane.load(FIXTURE)
+                      if r[0].startswith("/device:")
+                      and r[1] == xplane.OPS_LINE]
+    assert {e[3] for e in small} == {"", "jit(work)/kern_matmul/dot_general"}
+
+
+def test_reduction_of_the_scoped_trace_by_scope():
+    red = xplane.reduce_rows(xplane.load(SCOPED))
+    want = SCOPED_WANT
+    assert red["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert red["scope_seconds"] == pytest.approx(want["scope_seconds"],
+                                                 rel=1e-9)
+    assert sum(red["scope_seconds"].values()) == pytest.approx(
+        sum(red["op_seconds"].values()), rel=1e-12)
+    assert [n for n, _ in red["device_ops"][:len(want["top_ops"])]] == \
+        want["top_ops"]
+    assert not xplane.scopes_missing(red)
+
+
+def test_the_scoped_trace_splits_into_exactly_the_four():
+    red = xplane.reduce_rows(xplane.load(SCOPED))
+    red["steps"] = SCOPED_WANT["steps"]
+    ctx = {"bench_dir": BENCH, "trace": red}
+    got = {n: read(n, ctx) for n in SPLIT}
+    want = SCOPED_WANT["split"]
+    assert got == pytest.approx(want, rel=1e-9)
+    # no operation overlaps another here, so the four add up to the busy
+    # time of a step
+    per_step = 1e3 * red["busy_s"] / red["steps"]
+    assert sum(got[n] for n in SPLIT[:3]) + got[SPLIT[3]] / 100 * per_step \
+        == pytest.approx(per_step, rel=1e-9)
+    # a later PR's metric is a data file: any part of the path will do
+    sub = {"args": {"holds": ["optimizer/sub"], "lacks": ["forward"]}}
+    assert readers.scope_ms_per_step(ctx, sub) == pytest.approx(
+        SCOPED_WANT["sub_ms_per_step"], rel=1e-9)
+    # the same trace with its scopes gone (an executable from an old cache)
+    bare = xplane.reduce_rows([(p, ln, [e[:3] + ("",) for e in ev])
+                               for p, ln, ev in xplane.load(SCOPED)])
+    bare["steps"] = red["steps"]
+    assert xplane.scopes_missing(bare)
+    assert [read(n, {"bench_dir": BENCH, "trace": bare})
+            for n in SPLIT[:3]] == [None] * 3
+    assert read(SPLIT[3], {"bench_dir": BENCH, "trace": bare}) == \
+        pytest.approx(100.0)

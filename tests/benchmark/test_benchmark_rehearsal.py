@@ -12,7 +12,8 @@ import pytest
 import bench_toy
 from bench_toy import BENCH, load
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 @pytest.fixture(scope="module")
@@ -40,7 +41,30 @@ def test_rehearsal_prints_exactly_the_contracts_keys(manifest, cell, trace):
         assert {"busy_s", "window_s"} <= set(last["device"])
         assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
         assert last["metrics"]["compile.in_window.lm"]["value"] == 0
-        assert "loop.steps_in_window" in got        # the metric added
+        # the metrics the copy added: their reader, in a module of its
+        # own, read the job's counter and the kept trace through ctx
+        assert set(bench_toy.TOY_METRICS) <= got
+        values = {k: last["metrics"][k]["value"] for k in got}
+        assert values["loop.job_device_flushes"] >= last["attempted"]
+        assert values["loop.trace_bytes"] > 0
+        assert values["loop.metric_device_steps_pct.lm"] == 100.0
+        # the CPU's trace has no device plane: the split is left out
+        assert got.isdisjoint(
+            f"model.{part}.lm" for part in (
+                "forward_ms_per_step", "backward_ms_per_step",
+                "optimizer_ms_per_step", "unscoped_pct"))
+        assert "scopes_missing" not in out
+        # the trace is removed once the readers have returned
+        kept = [ln for ln in out.splitlines() if ln.startswith("# trace ")]
+        assert kept and not os.path.exists(
+            kept[0].split(" file=")[1].split(" ")[0])
+    # each number compared beside its limit: last in the line, and the
+    # last lines on standard error
+    assert list(last)[-1] == "compared"
+    assert len(last["compared"]) == 8
+    assert all(set(v) == {"value", "limit"} and v["value"] <= v["limit"]
+               for v in last["compared"].values())
+    assert sum(ln.startswith("compared ") for ln in out.splitlines()) == 8
     # the earlier lines say what ran and how the readings spread
     head = [ln for ln in out.splitlines() if ln.startswith("# ")]
     assert any("platform=cpu" in ln and "device_kind=" in ln and
