@@ -1,0 +1,265 @@
+"""A decoder whose layers are read from a pattern: state-space (Mamba-2)
+mixers among grouped-query attention layers, each followed by a gated
+feed-forward.
+
+Beyond the reference's RNN ceiling (the cuDNN fused LSTM,
+``src/operator/cudnn_rnn-inl.h:1``; SURVEY.md §5.7) and beside
+``TransformerLM``'s 2019 block: what the hybrid decoders of 2025 share.
+Pre-norm RMSNorm with a learned scale; ``silu(x Wg) * (x Wu)`` feed-forward
+without bias; no positional encoding (the recurrence orders the sequence);
+attention with fewer key-value heads than query heads and a given score
+scale; scalar multipliers on the embedding, every residual branch and the
+logits; a head tied to the embedding; per-block rematerialisation.
+
+    h = embedding_multiplier * E[token]
+    h = h + residual_multiplier * mixer_l(rms(h))     mixer: "mamba" | "attention"
+    h = h + residual_multiplier * mlp(rms(h))
+    logits = rms(h) E^T / logits_scaling              (float32)
+
+The Mamba-2 mixer (``ops/ssm.py`` has the recurrence)::
+
+    [z | xBC | dt] = x W_in                 widths d_inner, d_inner + 2 G N, H
+    xBC = silu(conv(xBC))                   causal depthwise, d_conv taps, bias
+    x, B, C = split(xBC)                    H heads of P; G groups of N
+    dt = softplus(dt + dt_bias); a = -exp(A_log)
+    y = ssd_scan(x, dt, a, B, C) + D x      chunked, chunk positions at a time
+    out = rms_g(y * silu(z)) W_out          the norm after the gate
+
+Module names tell the parts apart in an operation's scope path
+(``block3/mamba/in_proj``, ``block5/attn/q_proj``, ``block0/mlp/gate``), and
+``jax.named_scope``s ``conv1d``, ``ssd_scan`` and ``gated_norm`` mark the
+mixer's parts that are no module; ``embed`` and ``lm_head`` mark the ends.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Optional, Sequence
+
+import flax.linen as linen
+import jax
+import jax.numpy as jnp
+
+from dt_tpu.ops import ssm
+
+F32 = jnp.float32
+
+
+class RMSNorm(linen.Module):
+    eps: float = 1e-5
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x):
+        scale = self.param("scale", linen.initializers.ones,
+                           (x.shape[-1],), F32)
+        v = x.astype(F32)
+        v = v * jax.lax.rsqrt(jnp.mean(jnp.square(v), axis=-1,
+                                       keepdims=True) + self.eps)
+        return (v * scale).astype(self.dtype)
+
+
+class GatedMLP(linen.Module):
+    intermediate: int
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x):
+        dense = lambda n, name: linen.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        h = jax.nn.silu(dense(self.intermediate, "gate")(x)) \
+            * dense(self.intermediate, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
+class GroupedQueryAttention(linen.Module):
+    """``num_kv_heads`` key-value heads, each serving
+    ``num_heads // num_kv_heads`` consecutive query heads; no positions;
+    causal softmax of ``scale * q k^T``."""
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    scale: float
+    attention: Optional[str] = "flash"   # 'flash' (Pallas) | None (plain)
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        dense = lambda n, name: linen.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        q = dense(self.num_heads * self.head_dim, "q_proj")(x)
+        k = dense(self.num_kv_heads * self.head_dim, "k_proj")(x)
+        v = dense(self.num_kv_heads * self.head_dim, "v_proj")(x)
+        q = q.reshape(b, s, self.num_heads, self.head_dim)
+        rep = self.num_heads // self.num_kv_heads
+        # the kernel takes one head count: each key-value head is repeated
+        # for the query heads it serves (its gradient sums over them)
+        k, v = (jnp.repeat(t.reshape(b, s, self.num_kv_heads, self.head_dim),
+                           rep, axis=2) for t in (k, v))
+        if self.attention == "flash":
+            from dt_tpu.ops.pallas.attention import (flash_attention,
+                                                     DEFAULT_BLOCK)
+            pad = (-s) % DEFAULT_BLOCK
+            if pad:   # as TransformerLM: padded keys lie after every real query
+                q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                           for t in (q, k, v))
+            out = flash_attention(q, k, v, causal=True,
+                                  scale=self.scale)[:, :s]
+        else:
+            from dt_tpu.parallel.ring_attention import full_attention
+            out = full_attention(q, k, v, causal=True, scale=self.scale)
+        return dense(d, "o_proj")(out.reshape(b, s, -1))
+
+
+def _a_log_init(key, shape, dtype=F32):
+    """Mamba-2's: A uniform in 1..16."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=F32, lo=1e-3, hi=0.1):
+    """Mamba-2's: dt log-uniform in 0.001..0.1, through the inverse of the
+    softplus."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2Mixer(linen.Module):
+    n_heads: int
+    d_head: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk: int = 256
+    conv_bias: bool = True
+    eps: float = 1e-5
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x):
+        b, l, d = x.shape
+        h, p, g, n = self.n_heads, self.d_head, self.n_groups, self.d_state
+        d_inner, conv_dim = h * p, h * p + 2 * g * n
+        zxbcdt = linen.Dense(d_inner + conv_dim + h, use_bias=False,
+                             dtype=self.dtype, name="in_proj")(x)
+        z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
+        conv_w = self.param("conv_kernel",
+                            linen.initializers.lecun_normal(),
+                            (self.d_conv, conv_dim), F32)
+        conv_b = self.param("conv_bias", linen.initializers.zeros,
+                            (conv_dim,), F32) if self.conv_bias else None
+        dt_bias = self.param("dt_bias", _dt_bias_init, (h,), F32)
+        a_log = self.param("A_log", _a_log_init, (h,), F32)
+        skip = self.param("D", linen.initializers.ones, (h,), F32)
+        norm_scale = self.param("norm_scale", linen.initializers.ones,
+                                (d_inner,), F32)
+        with jax.named_scope("conv1d"):
+            xbc = jax.nn.silu(ssm.causal_conv1d(xbc, conv_w, conv_b))
+        with jax.named_scope("ssd_scan"):
+            xs, bm, cm = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+            xs = xs.reshape(b, l, h, p)
+            y = ssm.ssd_scan(
+                xs, jax.nn.softplus(dt.astype(F32) + dt_bias),
+                -jnp.exp(a_log), bm.reshape(b, l, g, n),
+                cm.reshape(b, l, g, n), chunk=self.chunk)
+            y = y + (skip[:, None] * xs.astype(F32)).astype(y.dtype)
+        with jax.named_scope("gated_norm"):
+            y = ssm.gated_rms_norm(y.reshape(b, l, d_inner), z, norm_scale,
+                                   self.eps)
+        return linen.Dense(d, use_bias=False, dtype=self.dtype,
+                           name="out_proj")(y)
+
+
+class HybridBlock(linen.Module):
+    """One layer: the mixer its ``kind`` names, then the feed-forward, each
+    on the RMSNorm of the stream and added back times
+    ``residual_multiplier``."""
+    kind: str                 # 'mamba' | 'attention'
+    intermediate: int
+    residual_multiplier: float
+    mixer: Any                # kwargs of the mixer's module
+    eps: float = 1e-5
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x):
+        h = RMSNorm(self.eps, self.dtype, name="input_norm")(x)
+        if self.kind == "mamba":
+            h = Mamba2Mixer(eps=self.eps, dtype=self.dtype, name="mamba",
+                            **dict(self.mixer))(h)
+        elif self.kind == "attention":
+            h = GroupedQueryAttention(dtype=self.dtype, name="attn",
+                                      **dict(self.mixer))(h)
+        else:
+            raise ValueError(f"unknown layer type {self.kind!r}")
+        x = x + (self.residual_multiplier * h).astype(x.dtype)
+        h = RMSNorm(self.eps, self.dtype, name="post_norm")(x)
+        h = GatedMLP(self.intermediate, self.dtype, name="mlp")(h)
+        return x + (self.residual_multiplier * h).astype(x.dtype)
+
+
+class HybridLM(linen.Module):
+    """``tokens`` (B, S) int32 -> float32 logits (B, S, V).  The defaults
+    are a small model; a published one passes its own ``config.json``'s
+    numbers (``benchmark/hybrid_drivers.py`` does)."""
+    vocab_size: int = 32000
+    embed_dim: int = 512
+    layer_types: Sequence[str] = ("mamba", "mamba", "attention", "mamba")
+    intermediate: int = 2048
+    # attention layers
+    num_heads: int = 8
+    num_kv_heads: int = 2
+    head_dim: Optional[int] = None        # embed_dim // num_heads
+    attention_multiplier: Optional[float] = None   # 1 / sqrt(head_dim)
+    attention: Optional[str] = "flash"
+    # state-space layers
+    ssm_heads: int = 16
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_conv_bias: bool = True
+    # the four scalars and the head
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = True
+    rms_norm_eps: float = 1e-5
+    dtype: Any = F32
+    # per-block rematerialisation, as TransformerLM's: a block's activations
+    # are recomputed in the backward pass, so one block's are live at a time
+    remat: bool = False
+
+    @linen.compact
+    def __call__(self, tokens, training: bool = True):
+        head_dim = self.head_dim or self.embed_dim // self.num_heads
+        scale = self.attention_multiplier
+        mixers = {
+            "attention": dict(
+                num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                head_dim=head_dim, attention=self.attention,
+                scale=head_dim ** -0.5 if scale is None else scale),
+            "mamba": dict(
+                n_heads=self.ssm_heads, d_head=self.ssm_head_dim,
+                d_state=self.ssm_state, n_groups=self.ssm_groups,
+                d_conv=self.ssm_conv, chunk=self.ssm_chunk,
+                conv_bias=self.ssm_conv_bias)}
+        table = self.param("embedding", linen.initializers.normal(0.02),
+                           (self.vocab_size, self.embed_dim), F32)
+        with jax.named_scope("embed"):
+            x = (jnp.take(table, tokens, axis=0)
+                 * self.embedding_multiplier).astype(self.dtype)
+        block_cls = linen.remat(HybridBlock) if self.remat else HybridBlock
+        for i, kind in enumerate(self.layer_types):
+            x = block_cls(kind, self.intermediate, self.residual_multiplier,
+                          tuple(sorted(mixers[kind].items())),
+                          self.rms_norm_eps, self.dtype, name=f"block{i}")(x)
+        x = RMSNorm(self.rms_norm_eps, self.dtype, name="final_norm")(x)
+        head = table if self.tie_word_embeddings else self.param(
+            "lm_head", linen.initializers.normal(0.02),
+            (self.vocab_size, self.embed_dim), F32)
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bsd,vd->bsv", x, head.astype(self.dtype),
+                                preferred_element_type=F32)
+            return logits / self.logits_scaling

@@ -148,6 +148,17 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                        "whose logits crossed to the host "
                                        "for a metric without a device "
                                        "form"),
+    # set when Module builds its steps, for a model whose layers are read
+    # from a pattern (models/hybrid_lm.py)
+    "model.layers_ssm": ("gauge", "state-space (Mamba-2) layers of the "
+                                  "model the compiled steps run"),
+    "model.layers_attention": ("gauge", "attention layers of the model the "
+                                        "compiled steps run"),
+    "model.ssm_chunk": ("gauge", "positions in one chunk of the "
+                                 "state-space scan (ops/ssm.py ssd_scan)"),
+    "model.remat_blocks": ("gauge", "1 where each block's activations are "
+                                    "recomputed in the backward pass "
+                                    "(linen.remat per block), else 0"),
     "worker.step_rate": ("gauge", "scheduler-derived per-worker step "
                                   "rate (steps/s) from the shipped "
                                   "train.steps series"),

@@ -571,6 +571,24 @@ class Module:
             "grad_step", jax.jit(grad_step), _dev_meta)
         self._apply_step = obs_device.instrument(
             "apply_step", jax.jit(apply_step), _dev_meta)
+        self._model_gauges()
+
+    def _model_gauges(self):
+        """What the steps just built compute, for a model whose layers are
+        read from a pattern (``models.HybridLM``): the count of layers of
+        each kind, the state-space scan's chunk, and whether each block is
+        rematerialised.  Gauges of the metrics plane; nothing where it is
+        off or the model has no pattern."""
+        kinds = getattr(self.model, "layer_types", None)
+        if kinds is None or not obs_metrics.enabled():
+            return
+        reg = obs_metrics.registry()
+        reg.gauge("model.layers_ssm", sum(k == "mamba" for k in kinds))
+        reg.gauge("model.layers_attention",
+                  sum(k == "attention" for k in kinds))
+        reg.gauge("model.ssm_chunk", getattr(self.model, "ssm_chunk", 0))
+        reg.gauge("model.remat_blocks",
+                  int(bool(getattr(self.model, "remat", False))))
 
     def _use_metric(self, eval_metric):
         """Have the compiled steps return what ``eval_metric`` reads: its
