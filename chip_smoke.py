@@ -196,7 +196,8 @@ def stage_a(size="full"):
 # ---------------------------------------------------------------------------
 
 _B_SIZES = {
-    # flash: bench.py's LM shape (8 heads of 64 at sequence 2048); BN:
+    # flash: bench.py's LM shape (8 heads of 64 at sequence 2048), at the
+    # forward's derived tiles (flash_case passes none); BN:
     # ResNet-50's first and last BN inputs at batch 128; LSTM: hidden 512
     # and one that is not a multiple of the 128 lanes; quantize: 16M
     "full": dict(flash=(8, 2048, 8, 64), bn=[(128, 112, 112, 64),
@@ -223,6 +224,7 @@ def stage_b(size="full", interpret=False):
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import pallas_drive as pd
     from dt_tpu import data, models
+    from dt_tpu.ops.pallas import attention
     from dt_tpu.parallel import mesh as mesh_lib
     from dt_tpu.training import Module
 
@@ -247,9 +249,13 @@ def stage_b(size="full", interpret=False):
     cases.append(("quantize_2bit", cfg["quant"], 1e-6,
                   lambda: pd.quantize_case(rng, cfg["quant"], interpret)))
 
+    # the flash gate runs at the tiles the models get: derived from the shape
+    _, seq, _, head = cfg["flash"]
+    extras = {"flash_attention_fwd_bwd": {"tiles": list(
+        attention.forward_tiles(seq, seq, head, jnp.dtype(dt).itemsize))}}
     kernels, failed = [], []
     for name, shape, bound, build in cases:
-        row = {"kernel": name, "shape": str(shape)}
+        row = {"kernel": name, "shape": str(shape), **extras.get(name, {})}
         try:
             oracle, pallas, a = build()
             want, got = oracle(*a), pallas(*a)

@@ -159,6 +159,13 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "model.remat_blocks": ("gauge", "1 where each block's activations are "
                                     "recomputed in the backward pass "
                                     "(linen.remat per block), else 0"),
+    # set at trace time, once per distinct (s, sk, d, dtype), label `shape`
+    # (ops/pallas/attention.py)
+    "flash.block_q": ("gauge", "query rows in one tile of the flash "
+                               "forward, derived from the shape "
+                               "(forward_tiles) or given"),
+    "flash.block_k": ("gauge", "key rows in one tile of the flash forward; "
+                               "the blockwise backward keeps 128"),
     "worker.step_rate": ("gauge", "scheduler-derived per-worker step "
                                   "rate (steps/s) from the shipped "
                                   "train.steps series"),

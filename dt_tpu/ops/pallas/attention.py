@@ -4,17 +4,37 @@ The reference's long-context ceiling is the cuDNN fused RNN
 (``src/operator/cudnn_rnn-inl.h:1`` — SURVEY §5.7: no attention anywhere in
 the 2018 tree); this framework makes long-context first-class, so the
 single-device attention hot path gets the same treatment the reference
-gave its RNN cells: a hand-fused kernel.  Forward is a Pallas kernel —
-grid (batch*heads, q_blocks, kv_blocks), online-softmax accumulation in
-VMEM scratch across the sequential kv axis, O(block²) VMEM instead of
-O(S²) HBM for the score matrix.  Backward is the standard flash backward
-(recompute per KV block from the saved logsumexp) expressed as a
-``lax.scan`` — O(S x block) memory, no materialized score matrix.
+gave its RNN cells: a hand-fused kernel.  Forward is a Pallas kernel:
+grid (batch*heads, q_tiles, k_tiles), online-softmax accumulation in VMEM
+scratch across the sequential key axis, O(tile²) VMEM instead of O(S²)
+HBM for the score matrix.
+
+The tiles are chosen from the shape (``forward_tiles``): for each side the
+largest of ``FORWARD_TILES`` that divides the (padded) length, as long as
+one grid step's blocks, scratch and float32 scores fit ``VMEM_BUDGET``.  A
+grid step costs about 0.4 us whatever it computes, so at 128 x 128 the
+8,192 and 65,536 steps a call of the benchmark's two cells were the
+kernel's whole time (PERF.md section 6, PR 29).  q, k and v go to the MXU
+in the type they are stored in, with float32 accumulation; the
+probabilities are cast to ``v``'s type for the second product; max, exp,
+the running sum and the accumulator are float32.  Under ``causal`` a tile
+above the diagonal computes nothing and fetches nothing (its index map
+repeats the last block needed), and only tiles the diagonal crosses are
+masked.  ``flash_attention``'s ``block_q`` / ``block_k`` override the
+choice.
+
+Backward is the standard flash backward (recompute per key block from the
+saved logsumexp) expressed as a ``lax.scan``: O(S x 128) memory, no
+materialized score matrix.  Its block is ``DEFAULT_BLOCK`` (128) whatever
+the forward's tiles are: its float32 temporaries are (BH, S, block).
+``DEFAULT_BLOCK`` is also what callers pad sequences to.
 
 Composes with the distributed layer: ``ring_attention`` shards the
-sequence over the mesh and runs blockwise attention per shard — this
-kernel is the per-shard fusion; ``DT_PALLAS_ATTN=1`` swaps it into
-``TransformerLM``'s local-attention path.
+sequence over the mesh and runs blockwise attention per shard; this
+kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``
+and ``GroupedQueryAttention(attention="flash")`` select it
+(``DT_PALLAS_ATTN=1`` does for a ``TransformerLM`` that names no
+``seq_parallel``).
 
 Parity: ``dt_tpu.parallel.ring_attention.full_attention`` is the oracle;
 tests cover fwd/bwd, causal and full, interpret (CPU) mode.
@@ -23,6 +43,7 @@ tests cover fwd/bwd, causal and full, interpret (CPU) mode.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -31,10 +52,56 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dt_tpu.obs import metrics as obs_metrics
 from dt_tpu.ops.pallas.kernels import _default_interpret
 
+logger = logging.getLogger("dt_tpu")
+
 NEG_INF = -1e30
-DEFAULT_BLOCK = 128  # callers that pad (TransformerLM) key off this
+# what callers pad to (TransformerLM, GroupedQueryAttention) and the
+# backward's block; the forward's tile is derived, see forward_tiles
+DEFAULT_BLOCK = 128
+_LANES = 128
+# the forward's candidate tiles, largest first (PERF.md section 6, PR 29:
+# the sweep over {256, 512, 1024} on either side at the cells' shapes)
+FORWARD_TILES = (1024, 512, 256, 128)
+# what one grid step may hold in VMEM by tile_vmem_bytes' reckoning; the
+# compiler is given twice that (v5e has 128 MiB of it, 16 MiB scoped by
+# default)
+VMEM_BUDGET = 24 << 20
+
+
+def tile_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """VMEM one grid step of the forward holds, reckoned from the shapes:
+    the double-buffered q, k, v and output blocks and log-sum-exp tile,
+    the float32 scratch (rows padded to a lane tile), and the tile's
+    float32 scores and probabilities with the probabilities' copy in the
+    operands' type."""
+    dl = -(-d // _LANES) * _LANES
+    blocks = 2 * (2 * block_q + 2 * block_k) * dl * itemsize \
+        + 2 * max(block_q // _LANES, 8) * _LANES * 4
+    scratch = block_q * (dl + 2 * _LANES) * 4
+    scores = block_q * block_k * (3 * 4 + itemsize)
+    return blocks + scratch + scores
+
+
+def forward_tiles(s: int, sk: int, d: int, itemsize: int):
+    """The forward's (block_q, block_k) for query length ``s``, key length
+    ``sk``, head size ``d`` and operands of ``itemsize`` bytes: of the
+    pairs of ``FORWARD_TILES`` that divide the lengths and keep
+    ``tile_vmem_bytes`` within ``VMEM_BUDGET``, the largest (by scores a
+    tile, then by query rows); a length below a tile is one tile of a
+    smaller one.  A length that no tile divides raises."""
+    def dividing(n):
+        got = [t for t in FORWARD_TILES if n % t == 0]
+        if not got:
+            raise ValueError(f"seq length {n} must be a multiple of "
+                             f"{FORWARD_TILES[-1]}")
+        return got
+    pairs = [(bq, bk) for bq in dividing(s) for bk in dividing(sk)]
+    fit = [p for p in pairs
+           if tile_vmem_bytes(*p, d, itemsize) <= VMEM_BUDGET]
+    return max(fit or pairs[-1:], key=lambda p: (p[0] * p[1], p[0]))
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -53,18 +120,19 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     qi = pl.program_id(1)
 
-    def _attend():
-        q = q_ref[0].astype(jnp.float32)              # (BQ, D)
-        k = k_ref[0].astype(jnp.float32)              # (BK, D)
-        v = v_ref[0].astype(jnp.float32)              # (BK, D)
+    def _attend(masked: bool):
+        # operands as stored: the MXU takes bfloat16 at full rate and
+        # accumulates float32; float32 inputs multiply as before
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]        # (BQ, D), (BK, D) x2
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + lax.broadcasted_iota(
+        if masked:
+            # q_pos >= k_pos, the tile's offsets moved to the scalar side
+            row_less_col = lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0) - lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = jnp.where(row_less_col >= ki * block_k - qi * block_q,
+                          s, NEG_INF)
 
         m_prev = m_ref[:]                             # (BQ, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -73,64 +141,113 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         correction = jnp.exp(m_prev - m_new)          # (BQ, 1)
         l_ref[:] = l_ref[:] * correction + p.sum(axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
     if causal:
-        # blocks whose first key position is beyond the last query
-        # position are fully masked — skip their matmuls entirely
-        # (~2x FLOPs saved on causal prefill)
-        pl.when(ki * block_k <= qi * block_q + block_q - 1)(_attend)
+        # tiles whose first key position is beyond the last query
+        # position are fully masked: no products (and, by the index maps,
+        # no fetch); only tiles the diagonal crosses pay for the mask
+        first_k, last_k = ki * block_k, ki * block_k + block_k - 1
+        first_q, last_q = qi * block_q, qi * block_q + block_q - 1
+        runs = first_k <= last_q
+        crossed = last_k > first_q
+        pl.when(runs & crossed)(functools.partial(_attend, True))
+        pl.when(runs & jnp.logical_not(crossed))(
+            functools.partial(_attend, False))
     else:
-        _attend()
+        _attend(False)
 
     @pl.when(ki == n_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        # lse is per-row but Mosaic requires the last two block dims to
-        # tile (8, 128) on real TPU (a (1, block_q) block does not), so
-        # the output carries a 128-lane axis with the value broadcast;
-        # the wrapper slices lane 0 (round-2 TPU-drive finding)
-        lse_ref[0] = jnp.broadcast_to(m_ref[:] + jnp.log(l),
-                                      (lse_ref.shape[1], 128))
+        # the log-sum-exp is a column (BQ, 1); Mosaic stores lane tiles,
+        # so row r goes to [r // 128, r % 128] of a (BQ/128, 128) tile:
+        # the column spread over the lanes, its diagonal kept, each 128
+        # rows summed into one
+        lse = m_ref[:] + jnp.log(l)
+        rows = lax.broadcasted_iota(jnp.int32, (block_q, _LANES), 0)
+        lanes = lax.broadcasted_iota(jnp.int32, (block_q, _LANES), 1)
+        spread = jnp.where(rows % _LANES == lanes, lse, 0.0)
+        lse_ref[0, 0] = spread.reshape(
+            block_q // _LANES, _LANES, _LANES).sum(axis=1)
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "interpret"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
                       interpret):
-    """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S))."""
+    """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S)).  ``block_q`` /
+    ``block_k`` of None are derived from the shapes (``forward_tiles``).
+
+    Jitted and inlined: a model's layers share one trace of the kernel's
+    body (Pallas traces it anew for every call otherwise, 24 times a
+    build of the gpt2-medium step), and the call keeps its caller's scope
+    and so its event's name."""
     bh, s, d = q3.shape
     sk = k3.shape[1]
-    n_q = -(-s // block_q)
-    n_k = -(-sk // block_k)
+    if block_q is None or block_k is None:
+        dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize)
+        block_q, block_k = block_q or dq, block_k or dk
+    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k)
+    n_q = s // block_q
+    n_k = sk // block_k
     kern = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k)
-    out, lse_lanes = pl.pallas_call(
+    if causal:
+        # a skipped step names the last block its query tile needs: the
+        # same block as the step before, so nothing is fetched for it
+        kv_map = lambda b, qi, ki: (
+            b, jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k), 0)
+    else:
+        kv_map = lambda b, qi, ki: (b, ki, 0)
+    sub = block_q // _LANES
+    out, lse = pl.pallas_call(
         kern,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, sub, _LANES),
+                         lambda b, qi, ki: (b, qi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, s, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n_q, sub, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
     )(q3, k3, v3)
-    return out, lse_lanes[:, :, 0]
+    return out, lse.reshape(bh, s)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_tiles(shape, block_q: int, block_k: int) -> None:
+    """Record, once per distinct shape and tile, what the forward was
+    traced with: a debug line and the metrics plane's gauges, so that a
+    shape that falls back to 128 is seen.  Trace time only."""
+    s, sk, d, dtype = shape
+    logger.debug("# flash_tiles s=%d sk=%d d=%d dtype=%s block_q=%d "
+                 "block_k=%d", s, sk, d, dtype, block_q, block_k)
+    if obs_metrics.enabled():
+        reg = obs_metrics.registry()
+        labels = {"shape": f"{s}x{sk}x{d}.{dtype}"}
+        reg.gauge("flash.block_q", block_q, labels)
+        reg.gauge("flash.block_k", block_k, labels)
 
 
 def _flash_bwd_blockwise(q3, k3, v3, o3, lse, do3, *, scale, causal,
@@ -202,9 +319,11 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do3):
+    # the forward's tile stops here: the backward's float32 temporaries
+    # are (BH, S, block), 268 MB each at 512 in the gpt2-medium cell
     q3, k3, v3, out, lse = res
     return _flash_bwd_blockwise(q3, k3, v3, out, lse, do3, scale=scale,
-                                causal=causal, block_k=block_k)
+                                causal=causal, block_k=DEFAULT_BLOCK)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -212,14 +331,17 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
-                    block_q: int = DEFAULT_BLOCK,
-                    block_k: int = DEFAULT_BLOCK,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused attention, (B, S, H, D) layout (``full_attention`` oracle).
 
-    Sequence lengths must be multiples of the block sizes (pad upstream;
-    ``TransformerLM`` shapes already are).  Differentiable via the
-    blockwise flash backward.
+    Sequence lengths must be multiples of ``DEFAULT_BLOCK`` (pad upstream;
+    ``TransformerLM`` does).  ``block_q`` / ``block_k`` override the
+    forward's tiles, which are otherwise derived from the shapes
+    (``forward_tiles``); they must be multiples of 128 that divide the
+    lengths.  Differentiable via the blockwise flash backward, whose block
+    is ``DEFAULT_BLOCK`` whatever the forward's tiles are.
     """
     if interpret is None:
         interpret = _default_interpret()
@@ -227,9 +349,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scale = 1.0 / (q.shape[-1] ** 0.5)
     b, s, h, d = q.shape
     sk = k.shape[1]
-    if s % block_q or sk % block_k:
-        raise ValueError(f"seq lengths ({s}, {sk}) must be multiples of "
-                         f"blocks ({block_q}, {block_k})")
+    for n, block in ((s, block_q), (sk, block_k)):
+        block = DEFAULT_BLOCK if block is None else block
+        if n % block or block % _LANES:
+            raise ValueError(f"seq lengths ({s}, {sk}) must be multiples "
+                             f"of blocks ({block_q}, {block_k}), and those "
+                             f"of {_LANES}")
     to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
                   interpret)

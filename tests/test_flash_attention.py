@@ -9,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from dt_tpu.ops.pallas import attention as attn
 from dt_tpu.ops.pallas.attention import flash_attention
 from dt_tpu.parallel.ring_attention import full_attention
 
@@ -79,3 +80,168 @@ def test_flash_rejects_nonmultiple_seq():
     q = jnp.zeros((1, 100, 1, 64))
     with pytest.raises(ValueError):
         flash_attention(q, q, q)
+
+
+# ---------------------------------------------------------------------------
+# PR 29: the forward's tiles come from the shape; operands go in as stored
+# ---------------------------------------------------------------------------
+
+TILES = [(128, 128), (256, 512), (512, 256), (512, 512), (None, None)]
+LENGTHS = [512, 640, 1024]
+# what the benchmark's two LM cells get (PERF.md section 6, PR 29)
+CELL_TILES = {1024: (1024, 1024), 4096: (1024, 1024)}
+
+
+def _tiles_for(s, tiles):
+    """An explicit pair where it divides ``s``; the derived default
+    (block None) where it does not, as for 640."""
+    return tiles if all(t is None or s % t == 0 for t in tiles) \
+        else (None, None)
+
+
+def _grads(f, q, k, v):
+    loss = lambda q, k, v: (f(q, k, v).astype(jnp.float32) ** 2).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("s", LENGTHS)
+@pytest.mark.parametrize("tiles", TILES)
+def test_flash_tiles_forward_and_gradients_match_oracle(tiles, s, causal):
+    """float32 inputs, today's tolerances, every tile pair (640 is a
+    multiple of 128 and of nothing larger: its pairs fall to the derived
+    default, which must still divide it)."""
+    bq, bk = _tiles_for(s, tiles)
+    rng = np.random.RandomState(s + 7 * causal)
+    q, k, v = _qkv(rng, b=1, s=s, h=1, d=64)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk)
+    ref = lambda q, k, v: full_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    for name, a, b in zip("qkv", _grads(flash, q, k, v),
+                          _grads(ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4,
+                                   err_msg=f"d{name} {bq}x{bk} s={s}")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("tiles", TILES)
+def test_flash_bfloat16_operands_match_float32_oracle(tiles, causal):
+    """bfloat16 inputs against the oracle run in float32 on the same
+    bfloat16 values: the products q k^T are exact, the probabilities and
+    the output round to bfloat16, so the gap is a bfloat16 step (2^-8 of
+    the largest value) and not a float32 one."""
+    rng = np.random.RandomState(11 + causal)
+    qkv = [t.astype(jnp.bfloat16) for t in _qkv(rng, b=1, s=512, h=2, d=64)]
+    qkv32 = [t.astype(jnp.float32) for t in qkv]
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            block_q=tiles[0],
+                                            block_k=tiles[1])
+    ref = lambda q, k, v: full_attention(q, k, v, causal=causal)
+    step = 2.0 ** -8
+    got, want = flash(*qkv), ref(*qkv32)
+    assert got.dtype == jnp.bfloat16
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=0, atol=2 * step * np.abs(want).max())
+    for name, a, b in zip("qkv", _grads(flash, *qkv), _grads(ref, *qkv32)):
+        assert a.dtype == jnp.bfloat16
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=0,
+                                   atol=4 * step * np.abs(b).max(),
+                                   err_msg=f"d{name} {tiles}")
+
+
+def test_flash_derived_tiles_over_several_tiles():
+    """2,048 positions at the derived 1,024 x 1,024: a tile above the
+    diagonal that is skipped (its index map repeats the block before),
+    one the diagonal crosses, one wholly below it."""
+    rng = np.random.RandomState(5)
+    q, k, v = _qkv(rng, b=1, s=2048, h=1, d=64)
+    assert attn.forward_tiles(2048, 2048, 64, 4) == (1024, 1024)
+    got = flash_attention(q, k, v, causal=True)
+    want = full_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
+def test_forward_tiles_rule_over_every_length():
+    """The rule alone: for every multiple of 128 up to 8,192 the tiles
+    divide the lengths and the reckoned VMEM is under the limit; the two
+    cells' shapes get the tiles PERF.md records (section 6, PR 29)."""
+    for d in (64, 128):
+        for itemsize in (2, 4):
+            for s in range(128, 8192 + 1, 128):
+                for sk in {s, 128, 8192}:
+                    bq, bk = attn.forward_tiles(s, sk, d, itemsize)
+                    assert s % bq == 0 and sk % bk == 0, (s, sk, bq, bk)
+                    assert bq in attn.FORWARD_TILES
+                    assert bk in attn.FORWARD_TILES
+                    assert attn.tile_vmem_bytes(bq, bk, d, itemsize) \
+                        <= attn.VMEM_BUDGET, (s, sk, d, itemsize)
+    # gpt2m-seq1024 and granite4hm-b2-seq4096, bfloat16
+    assert attn.forward_tiles(1024, 1024, 64, 2) == CELL_TILES[1024]
+    assert attn.forward_tiles(4096, 4096, 64, 2) == CELL_TILES[4096]
+    # a multiple of 128 and of nothing larger; a length below every tile
+    assert attn.forward_tiles(640, 1152, 64, 2) == (128, 128)
+    assert attn.forward_tiles(256, 256, 64, 4) == (256, 256)
+    with pytest.raises(ValueError):
+        attn.forward_tiles(100, 128, 64, 4)
+    assert attn.DEFAULT_BLOCK == 128
+
+
+
+def _scan_lengths(jaxpr):
+    """Trip counts of every scan in a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            found.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scan_lengths(sub)
+    return found
+
+
+@pytest.mark.parametrize("tiles", [(None, None), (512, 256)])
+def test_backward_block_is_128_whatever_the_forward_chose(tiles):
+    """The forward's tile stops at the backward's rule: the blockwise
+    backward scans S/128 key blocks, so its float32 temporaries stay
+    (BH, S, 128) (it guards device.peak_hbm_gb.lm)."""
+    s = 1024
+    q = jnp.zeros((1, s, 1, 64), jnp.bfloat16)
+    assert attn.forward_tiles(s, s, 64, 2)[1] > attn.DEFAULT_BLOCK
+    f = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=tiles[0],
+        block_k=tiles[1]).astype(jnp.float32).sum()
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, q, q)
+    assert _scan_lengths(jaxpr.jaxpr) == [s // attn.DEFAULT_BLOCK]
+
+
+def test_tiles_are_recorded_once_per_shape(caplog):
+    """The tile chosen at trace time: one ``# flash_tiles`` debug line and
+    the gauge pair a distinct shape, so a fall back to 128 is seen."""
+    import logging
+    from dt_tpu.obs import metrics as obs_metrics
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()
+        attn._flash_fwd_pallas.clear_cache()   # an earlier test's trace
+        q = jnp.zeros((1, 640, 1, 64), jnp.float32)
+        with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
+            flash_attention(q, q, q, causal=True)
+            flash_attention(q, q, q, causal=True)
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("# flash_tiles")]
+        assert lines == ["# flash_tiles s=640 sk=640 d=64 dtype=float32 "
+                         "block_q=128 block_k=128"]
+        labels = {"shape": "640x640x64.float32"}
+        assert obs_metrics.registry().gauges_export() == [
+            ["flash.block_k", labels, 128.0],
+            ["flash.block_q", labels, 128.0]]
+    finally:
+        obs_metrics.set_enabled(None)
+        obs_metrics.registry().clear()

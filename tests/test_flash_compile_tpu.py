@@ -1,0 +1,42 @@
+"""The flash forward compiled by the TPU's own compiler for a described
+v5e, no chip attached: Mosaic refuses what the Pallas interpreter takes (a
+tile that does not fit VMEM, a store that is not lane-aligned), and a
+refusal here costs no chip time.  Nothing runs; no time is read."""
+
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from dt_tpu.ops.pallas import attention as attn
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# the benchmark's two cells, head size 128 in float32, and a length that
+# only 128 divides
+@pytest.mark.parametrize("bh,s,d,dtype", [
+    (8 * 16, 1024, 64, jnp.bfloat16),
+    (2 * 32, 4096, 64, jnp.bfloat16),
+    (8, 2048, 128, jnp.float32),
+    (4, 640, 64, jnp.bfloat16),
+])
+def test_mosaic_takes_the_derived_tiles(one_chip, bh, s, d, dtype):
+    x = jax.ShapeDtypeStruct((bh, s, d), dtype, sharding=one_chip)
+    fwd = jax.jit(lambda q, k, v: attn._flash_fwd_pallas(
+        q, k, v, scale=d ** -0.5, causal=True, block_q=None, block_k=None,
+        interpret=False))
+    compiled = fwd.lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()

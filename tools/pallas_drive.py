@@ -14,6 +14,7 @@ feed the kernels, so the oracles live in one place.
 
 Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only quantize_2bit  # one kernel
+        python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -197,11 +198,54 @@ def flash_case(rng, B, S, H, D, dt, interpret=None):
             return jnp.sum(f(q, k, v).astype(jnp.float32) ** 2)
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
 
-    oracle_fn = (_chunked_full_attention if S >= 16384
+    # the naive oracle's float32 scores and what autodiff keeps of them
+    # pass the device's memory from 2**30 scores on
+    oracle_fn = (_chunked_full_attention if B * H * S * S >= 1 << 30
                  else lambda q, k, v: full_attention(q, k, v, causal=True))
     return (attn_loss(oracle_fn),
             attn_loss(lambda q, k, v: attn.flash_attention(
                 q, k, v, causal=True, interpret=interpret)), qkv)
+
+
+# the benchmark's two language-model cells (gpt2m-seq1024,
+# granite4hm-b2-seq4096) and one shape at head size 128
+FLASH_CELL_SHAPES = [(8, 1024, 16, 64), (2, 4096, 32, 64), (4, 2048, 8, 128)]
+FLASH_SWEEP_TILES = (256, 512, 1024)
+
+
+def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
+    """The causal forward alone at every tile pair of FLASH_SWEEP_TILES,
+    at the backward's 128 x 128 and at the derived default (block None):
+    one record a pair, with its gap from the derived default's output."""
+    import jax
+    import jax.numpy as jnp
+    from dt_tpu.ops.pallas import attention as attn
+    # the kernel's own (BH, S, D) layout: the models' transposes around
+    # it are XLA's and not what the tiles move
+    qkv = tuple(jnp.asarray(rng.randn(B * H, S, D) * 0.3, dt)
+                for _ in range(3))
+
+    if interpret is None:
+        interpret = attn._default_interpret()
+    derived = attn.forward_tiles(S, S, D, jnp.dtype(dt).itemsize)
+    pairs = [(None, None), (attn.DEFAULT_BLOCK,) * 2] + [
+        (bq, bk) for bq in FLASH_SWEEP_TILES for bk in FLASH_SWEEP_TILES
+        if S % bq == 0 and S % bk == 0]
+    want = None
+    for bq, bk in pairs:
+        fn = jax.jit(lambda q, k, v, bq=bq, bk=bk: attn._flash_fwd_pallas(
+            q, k, v, scale=D ** -0.5, causal=True, block_q=bq, block_k=bk,
+            interpret=interpret))
+        got = fn(*qkv)
+        want = got if want is None else want   # the derived pair runs first
+        tq, tk = bq or derived[0], bk or derived[1]
+        yield {"kernel": "flash_fwd_tiles",
+               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}",
+               "block_q": tq, "block_k": tk, "derived": bq is None,
+               "grid_steps": B * H * (S // tq) * (S // tk),
+               "vs_derived_max_abs_err": _err(got, want),
+               "fwd_ms": round(_timeit(fn, *qkv, iters=iters), 4),
+               "backend": jax.default_backend()}
 
 
 def main():
@@ -272,6 +316,7 @@ def main():
     # ---- flash attention fwd+bwd vs full-attention oracle ---------------
     if wanted("flash_attention_fwd_bwd"):
         for B, S, H, D in ([(1, 256, 2, 64)] if args.small else
+                           FLASH_CELL_SHAPES[:2] +
                            [(4, 2048, 8, 128),   # round-2 point
                             (8, 1024, 8, 128),   # shorter seq, bigger batch
                             (1, 8192, 8, 128),   # long-context: O(S^2) oracle
@@ -279,6 +324,14 @@ def main():
             emit("flash_attention_fwd_bwd",
                  f"B{B}xS{S}xH{H}xD{D} {dt.__name__}",
                  flash_case(rng, B, S, H, D, dt))
+
+    # ---- the flash forward alone, by tile pair (PERF.md, PR 29) ----------
+    if wanted("flash_fwd_tiles"):
+        for B, S, H, D in ([(1, 512, 2, 64)] if args.small else
+                           FLASH_CELL_SHAPES):
+            for rec in flash_tiles_sweep(rng, B, S, H, D, dt,
+                                         iters=args.iters):
+                print(json.dumps(rec), flush=True)
 
 
 if __name__ == "__main__":
