@@ -196,18 +196,15 @@ def stage_a(size="full"):
 # ---------------------------------------------------------------------------
 
 _B_SIZES = {
-    # flash: bench.py's LM shape (8 heads of 64 at sequence 2048), at the
-    # forward's derived tiles (flash_case passes none); BN:
-    # ResNet-50's first and last BN inputs at batch 128; LSTM: hidden 512
-    # and one that is not a multiple of the 128 lanes; quantize: 16M
+    # flash: the stage's own LM shape below (8 heads of 64 at sequence
+    # 2048), at the forward's derived tiles (flash_case passes none); BN:
+    # ResNet-50's first and last BN inputs at batch 128
     "full": dict(flash=(8, 2048, 8, 64), bn=[(128, 112, 112, 64),
                                              (128, 7, 7, 2048)],
-                 lstm=[(4, 64, 512, 512), (4, 64, 200, 200)],
-                 quant=1 << 24, dtype="bfloat16",
+                 dtype="bfloat16",
                  lm=dict(vocab=8192, embed=512, layers=6, heads=8, seq=2048,
                          batch=8, steps=10)),
     "toy": dict(flash=(1, 256, 2, 64), bn=[(2, 8, 8, 64)],
-                lstm=[(2, 8, 32, 32), (2, 8, 24, 24)], quant=1 << 12,
                 dtype="float32",
                 lm=dict(vocab=64, embed=32, layers=1, heads=2, seq=128,
                         batch=2, steps=2)),
@@ -243,11 +240,6 @@ def stage_b(size="full", interpret=False):
                    lambda s=shape: pd.bn_inference_case(rng, s, dt, interpret)),
                   ("fused_bn_train_fwd_bwd", shape, tol,
                    lambda s=shape: pd.bn_train_case(rng, s, dt, interpret))]
-    for shape in cfg["lstm"]:
-        cases.append(("lstm_cell_fwd_bwd", shape, tol,
-                      lambda s=shape: pd.lstm_case(rng, *s, dt, interpret)))
-    cases.append(("quantize_2bit", cfg["quant"], 1e-6,
-                  lambda: pd.quantize_case(rng, cfg["quant"], interpret)))
 
     # the flash gate runs at the tiles the models get: derived from the shape
     _, seq, _, head = cfg["flash"]

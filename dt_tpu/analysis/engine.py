@@ -145,8 +145,7 @@ def _collect_suppressions(source: str) -> Dict[int, Set[str]]:
 #: default lint scope, relative to the repo root.  tests/ is excluded on
 #: purpose: fixtures under tests/dtlint_fixtures/ violate rules by design,
 #: and test code freely pokes private state the rules guard.
-DEFAULT_PATHS = ("dt_tpu", "tools", "examples", "bench.py",
-                 "__graft_entry__.py")
+DEFAULT_PATHS = ("dt_tpu", "tools", "examples", "__graft_entry__.py")
 
 _SKIP_DIRS = {"__pycache__", ".git", ".dtlint_cache", "node_modules"}
 

@@ -70,7 +70,7 @@ class PallasTiling(Rule):
     name = "pallas-tiling"
     hint = ("make the last two block dims multiples of (8, 128) or equal "
             "to the array dims; pack unsigned reductions via int32 + "
-            "bitcast (see ops/pallas/kernels.py _quant2_kernel)")
+            "bitcast")
 
     def applies_to(self, relpath: str) -> bool:
         return relpath.endswith(".py")
@@ -206,8 +206,7 @@ class PartialBlock(Rule):
     id = "DT004"
     name = "partial-block"
     hint = ("block on the full step output, e.g. "
-            "jax.block_until_ready((state, loss)) — bench.py's "
-            "queued-drain discipline")
+            "jax.block_until_ready((state, loss))")
 
     #: lines of separation within which a time.* call makes a block
     #: "timing-adjacent"

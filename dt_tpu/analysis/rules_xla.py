@@ -5,7 +5,7 @@ the two invariants the ROADMAP's perf arc depends on AT RUNTIME:
 program signatures stay stable (no recompile storms — cf. *Automatic
 Cross-Replica Sharding of Weight Update Computation*, arXiv:2004.13336)
 and the hot path never round-trips through the host (the failure that
-makes the host-packed 2-bit wire path lose, WIRE_BENCH_r06; cf.
+makes the host-packed 2-bit wire path lose; cf.
 *EQuARX*, arXiv:2506.17615, which wins by keeping quantization in XLA).
 These rules move both to lint time, on the :mod:`dt_tpu.analysis.flow`
 jax-dataflow substrate (reference gap: the reference's executor rebinds
@@ -342,7 +342,7 @@ class TransferDiscipline(Rule):
     or truthiness/comparison test on a value the dataflow types as a
     jax device array blocks the dispatch queue mid-step — the exact
     host round-trip that generalizes DT004's bench-local check to the
-    fleet (and that makes host-packed wire paths lose, WIRE_BENCH_r06).
+    fleet (and that makes host-packed wire paths lose).
     Explicit ``jax.device_get`` is the sanctioned spelling: it
     documents the transfer and the StagingPool D2H sites build on it.
 

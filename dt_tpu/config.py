@@ -48,11 +48,8 @@ ENV_SCHEDULER_PORT = "DMLC_PS_ROOT_PORT"
 ENV_REGISTRY: Mapping[str, Tuple[str, str]] = {
     # runtime / backend
     "DT_FORCE_CPU": ("", "1 = flip jax to the CPU backend before init (tests/CI)"),
-    # Pallas kernel opt-ins (model zoo / op surface swaps)
+    # Pallas kernel opt-in (model zoo)
     "DT_PALLAS_BN": ("", "1 = model zoo uses the Pallas fused BN (models/common.py)"),
-    "DT_PALLAS_ATTN": ("", "1 = TransformerLM local attention uses the Pallas flash kernel"),
-    "DT_PALLAS_RNN": ("", "1 = lstm() runs the Pallas fused cell in the scan"),
-    "DT_PALLAS_QUANT": ("", "1 = 2-bit gradient compression uses the Pallas kernels"),
     # elastic control plane / wire
     "DT_ELASTIC_SECRET": ("", "HMAC secret authenticating control frames (launcher generates per-job)"),
     "DT_ELASTIC_INSECURE": ("", "1 = explicit opt-out of frame authentication (trusted single host)"),
@@ -129,15 +126,6 @@ ENV_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "DT_DROP_MSG": ("", "percent of received control messages to drop (ps-lite PS_DROP_MSG fuzz)"),
     # data pipeline
     "DT_DECODE_THREADS": ("", "recordio decode pool size (default min(cpus, 16))"),
-    # bench.py harness
-    "DT_BENCH_MODEL": ("", "run only this tier (default: headline ladder)"),
-    "DT_BENCH_BATCH": ("32", "CNN tier batch size"),
-    "DT_BENCH_IMAGE": ("224", "CNN tier image size"),
-    "DT_BENCH_ITERS": ("20", "measured steps per tier"),
-    "DT_BENCH_LM_BATCH": ("8", "transformer_lm tier batch"),
-    "DT_BENCH_LM_SEQ": ("2048", "transformer_lm tier sequence length"),
-    "DT_BENCH_LM_VOCAB": ("8192", "transformer_lm tier vocab"),
-    "DT_BENCH_LM_ATTN": ("", "override transformer_lm attention path (e.g. pallas)"),
     # tools/convergence_run.py
     "DT_CONV_EPOCHS": ("40", "convergence-run epoch budget"),
     "DT_CONV_SKIP_ELASTIC": ("", "1 = skip the elastic leg of the convergence run"),

@@ -221,10 +221,6 @@ PROTOCOL_REGISTRY: Mapping[str, Tuple[str, str, str, str]] = {
     "ping": (
         "range_server", "read_only", "exempt|external",
         "shard liveness probe; sent by tests and operator tooling"),
-    "stats": (
-        "range_server", "read_only", "exempt",
-        "per-shard load/staleness introspection (tools/wire_bench.py "
-        "load-balance evidence)"),
 }
 
 _ROLES = frozenset({"scheduler", "range_server", "replica"})

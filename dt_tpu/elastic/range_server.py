@@ -66,8 +66,7 @@ class RangeServer:
         self._ttl = membership_ttl_s
         # observability (dt_tpu/obs): per-instance tracer; the old ad-hoc
         # _bytes_in/_rounds ints (load-balance evidence: with R servers
-        # each should carry ~1/R of the bytes) are obs counters now, and
-        # the "stats" command is a thin view over them
+        # each should carry ~1/R of the bytes) are obs counters now
         self._obs = obs_trace.Tracer(name=f"range-server-{self.index}")
         # confirm_fn forces a synchronous scheduler read right before a
         # round completes, closing the stale-cache join race (one extra
@@ -161,8 +160,8 @@ class RangeServer:
         context gets an ``rpc.<cmd>`` handler span on THIS shard's
         tracer, linked to the client's wire.request span.  Range-server
         tracers are per-instance and not merged into the scheduler's
-        job dump (separate processes) — the spans serve the ``stats``
-        introspection path and in-process tests."""
+        job dump (separate processes) — the spans serve in-process
+        tests."""
         return protocol.traced_handle(self._obs, msg, self._handle_inner)
 
     def _handle_inner(self, msg: dict) -> Optional[dict]:
@@ -228,23 +227,6 @@ class RangeServer:
                 return out
         if cmd == "ping":
             return {"index": self.index}
-        if cmd == "stats":
-            with self._dp._async_lock:
-                keys = len(self._dp._async_store)
-                bytes_stored = sum(int(v.nbytes)
-                                   for v in self._dp._async_store.values())
-            return {"index": self.index, "async_keys": keys,
-                    "async_bytes": bytes_stored,
-                    "data_bytes_in": self._obs.get_counter("data.bytes_in"),
-                    "data_requests": self._obs.get_counter("data.requests"),
-                    # overlap-pipeline rounds served by THIS shard (the
-                    # per-bucket accounting of the r10 streaming step)
-                    "bucket_rounds": self._obs.get_counter(
-                        "dataplane.bucket_rounds"),
-                    # this shard's round-lag EWMA view (r13): each shard
-                    # sees the same workers, so per-shard scores agree
-                    # up to per-round noise
-                    "straggler": self._dp.straggler_scores()}
         if cmd == "shutdown":
             self.close()
             return {}

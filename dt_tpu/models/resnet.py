@@ -141,7 +141,7 @@ class ResNet(linen.Module):
     # memory is ~one block deep instead of the whole network.  Wrapping
     # the WHOLE forward in jax.checkpoint would NOT save memory (the
     # rematerialized forward is all live at once) — block granularity is
-    # what makes it real; verified by tools/memcost.py.
+    # what makes it real.
     remat: bool = False
 
     @linen.compact

@@ -23,11 +23,6 @@ import jax.numpy as jnp
 from dt_tpu.ops import nn as ops
 
 
-def _use_pallas_attn() -> bool:
-    import os
-    return os.environ.get("DT_PALLAS_ATTN", "") == "1"
-
-
 class MultiHeadAttention(linen.Module):
     num_heads: int
     seq_parallel: Optional[str] = None  # None|'ring'|'ulysses'|'flash'
@@ -53,8 +48,7 @@ class MultiHeadAttention(linen.Module):
             from dt_tpu.parallel.ulysses import ulysses_attention
             out = ulysses_attention(q, k, v, self.mesh,
                                     axis_name=self.axis_name, causal=True)
-        elif self.seq_parallel == "flash" or (
-                self.seq_parallel is None and _use_pallas_attn()):
+        elif self.seq_parallel == "flash":
             from dt_tpu.ops.pallas.attention import (flash_attention,
                                                      DEFAULT_BLOCK)
             pad = (-s) % DEFAULT_BLOCK
@@ -284,8 +278,8 @@ class TransformerLM(linen.Module):
     # the difference between O(layers * S * d) and O(S * d) live
     # activation HBM (the reference's memory mirror; composes with
     # ring/ulysses sequence parallelism and grad_accum).  Stable
-    # `block{i}` names keep checkpoints interchangeable.  Memory effect
-    # is TPU-real; XLA CPU folds recompute away (tools/memcost.py).
+    # `block{i}` names keep checkpoints interchangeable.  The memory
+    # effect needs the chip's compiler: XLA CPU folds recompute away.
     remat: bool = False
 
     @linen.compact

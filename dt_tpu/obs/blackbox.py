@@ -614,10 +614,9 @@ _FATAL_BUNDLED = False
 
 def install(host: Optional[str] = None) -> bool:
     """Arm the process-wide crash hooks (idempotent; no-op unless the
-    plane is enabled).  Call sites: ``WorkerClient.__init__``,
-    ``scheduler_main``, ``bench.py``, ``tools/profile_step.py`` —
-    anything whose death should leave a bundle instead of a bare exit
-    code."""
+    plane is enabled).  Call sites: ``WorkerClient.__init__`` and
+    ``scheduler_main`` — anything whose death should leave a bundle
+    instead of a bare exit code."""
     global _INSTALLED
     if not enabled():
         return False

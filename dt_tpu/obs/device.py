@@ -29,8 +29,8 @@ planes; ``tests/test_device_obs.py`` holds the guards):
   compile in progress), timing exactly the compile, counting
   persistent-cache hits/misses (new cache files
   after the compile = miss), and capturing XLA's own
-  ``memory_analysis()`` (the ``tools/memcost.py`` static estimate, now
-  live).  Off, :func:`instrument` returns the function UNCHANGED.
+  ``memory_analysis()`` (the static estimate, taken at every real
+  compile).  Off, :func:`instrument` returns the function UNCHANGED.
 - **Recompile-cause ledger** — a second compile of the same ``what``
   diffs the new abstract signature against the previous one and emits a
   ``compile.recompile`` event naming the delta (``shape`` / ``dtype`` /
@@ -236,8 +236,7 @@ class _CacheProbe:
 
 
 def cache_probe() -> _CacheProbe:
-    """Start a persistent-cache probe around a compile (``bench.py``
-    uses this directly, ungated — its rows carry the outcome)."""
+    """Start a persistent-cache probe around a compile."""
     return _CacheProbe()
 
 
@@ -325,10 +324,9 @@ def compiling() -> Optional[str]:
 
 
 def memory_analysis_row(m) -> Dict[str, float]:
-    """XLA buffer-assignment bytes as the canonical MiB row — shared by
-    the compile observatory and ``tools/memcost.py`` (the offline
-    ``example/memcost`` analog; this module is its live counterpart on
-    the dtop device board, estimated next to measured HBM).  Field
+    """XLA buffer-assignment bytes as the canonical MiB row of the
+    compile observatory (the reference's ``example/memcost`` analog, live
+    on the dtop device board: estimated next to measured HBM).  Field
     availability varies by jax version — ``peak_memory_in_bytes`` is
     absent on some ``CompiledMemoryStats`` builds, where
     temp+args+output is the buffer-assignment upper bound XLA would

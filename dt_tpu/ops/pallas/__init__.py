@@ -1,19 +1,9 @@
-"""Pallas TPU kernels for the paths the reference hand-wrote CUDA for.
-
-Planned contents (SURVEY.md §7 translation table):
-- fused batch-norm variants (reference ``src/operator/nn/batch_norm.cu``)
-- 2-bit stochastic gradient quantize/dequantize with error-feedback residual
-  (reference ``src/kvstore/gradient_compression.cu``)
-- fused LSTM/GRU cell (reference ``cudnn_rnn-inl.h``)
-
-Kernels land incrementally; each has an interpreter-mode test against the
-jnp oracle in ``dt_tpu.ops``.
+"""Pallas TPU kernels: fused batch-norm (``kernels.py``; reference
+``src/operator/nn/batch_norm.cu``) and the flash attention forward
+(``attention.py``).  Each has an interpreter-mode test against its jnp
+oracle in ``dt_tpu.ops``.
 """
 
 from dt_tpu.ops.pallas.kernels import (
     fused_bn_inference as fused_bn_inference,
-    quantize_2bit as quantize_2bit,
-    dequantize_2bit as dequantize_2bit,
-    lstm_pointwise as lstm_pointwise,
-    lstm_cell_fused as lstm_cell_fused,
 )

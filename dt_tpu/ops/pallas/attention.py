@@ -32,9 +32,7 @@ the forward's tiles are: its float32 temporaries are (BH, S, block).
 Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
 kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``
-and ``GroupedQueryAttention(attention="flash")`` select it
-(``DT_PALLAS_ATTN=1`` does for a ``TransformerLM`` that names no
-``seq_parallel``).
+and ``GroupedQueryAttention(attention="flash")`` select it.
 
 Parity: ``dt_tpu.parallel.ring_attention.full_attention`` is the oracle;
 tests cover fwd/bwd, causal and full, interpret (CPU) mode.

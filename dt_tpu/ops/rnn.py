@@ -11,23 +11,13 @@ cuDNN convention: i, f, g(c~), o for LSTM; r, z, n for GRU.
 
 from __future__ import annotations
 
-import os
-from typing import NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 Array = jax.Array
-
-
-def _use_fused(fused: Optional[bool]) -> bool:
-    """Pallas fused-cell gate: explicit arg wins; else ``DT_PALLAS_RNN=1``
-    (the cuDNN-fused-kernel switch the reference flips with MXNET_USE_CUDNN,
-    ``cudnn_rnn-inl.h``)."""
-    if fused is not None:
-        return fused
-    return os.environ.get("DT_PALLAS_RNN") == "1"
 
 
 class LSTMWeights(NamedTuple):
@@ -82,29 +72,19 @@ def vanilla_cell(x: Array, h: Array, wx: Array, wh: Array, b: Array,
 
 
 def lstm(x: Array, h0: Array, c0: Array, weights: Sequence[LSTMWeights],
-         reverse: bool = False,
-         fused: Optional[bool] = None) -> Tuple[Array, Array, Array]:
+         reverse: bool = False) -> Tuple[Array, Array, Array]:
     """Multi-layer unidirectional LSTM over a sequence.
 
     ``x``: (T, B, I); ``h0``/``c0``: (L, B, H).  Returns (outputs (T,B,H),
     hT (L,B,H), cT (L,B,H)).  Equivalent capability to the reference fused RNN
     op (``src/operator/rnn.cc``) in lstm mode.
-
-    ``fused`` (default: env ``DT_PALLAS_RNN=1``): run the post-matmul
-    pointwise stage as the Pallas fused kernel
-    (:func:`dt_tpu.ops.pallas.kernels.lstm_cell_fused` — trainable via its
-    custom VJP), the cuDNN-fused-cell analog.
     """
-    if _use_fused(fused):
-        from dt_tpu.ops.pallas.kernels import lstm_cell_fused as cell
-    else:
-        cell = lstm_cell
     outs = x
     hs, cs = [], []
     for layer, w in enumerate(weights):
         def step(carry, xt):
             h, c = carry
-            h, c = cell(xt, h, c, w)
+            h, c = lstm_cell(xt, h, c, w)
             return (h, c), h
         seq = jnp.flip(outs, 0) if reverse else outs
         (hT, cT), ys = lax.scan(step, (h0[layer], c0[layer]), seq)

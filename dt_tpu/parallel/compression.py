@@ -119,37 +119,6 @@ def np_dequantize_2bit(packed: np.ndarray, n: int, threshold: float = 0.5,
     return vals.ravel()[:n]
 
 
-def quantize_2bit_best(grad: jax.Array, residual: jax.Array,
-                       threshold: float = 0.5
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """The production in-graph quantizer: the fused jnp/XLA path.
-
-    Round-2 TPU drive measured the Pallas kernel at 0.625x the oracle on
-    16M f32 (PALLAS_TPU_r02.jsonl): the 2-bit wire format forces a
-    16-element minor dimension, which occupies 16 of a TPU vector's 128
-    lanes — Mosaic pads the other 112, wasting ~7/8 of the load/store
-    bandwidth on this HBM-bound op, while XLA fuses the whole oracle
-    (threshold + decode + residual + pack) into one pass at full lane
-    width.  The reference shipped CUDA kernels because its naive path was
-    slow (``gradient_compression.cu``); here the naive path IS the fast
-    path, so the Pallas kernel is retired behind ``DT_PALLAS_QUANT=1``
-    (kept for drive comparisons on future hardware).
-
-    NOTE: callers that jit this must read the env var OUTSIDE the traced
-    function (``_use_pallas_quant()``) — a read inside the trace is baked
-    in at compile time and later toggles would silently no-op
-    (ADVICE r3)."""
-    if _use_pallas_quant():
-        from dt_tpu.ops.pallas import kernels
-        return kernels.quantize_2bit(grad, residual, threshold)
-    return quantize_2bit(grad, residual, threshold)
-
-
-def _use_pallas_quant() -> bool:
-    from dt_tpu import config
-    return config.env("DT_PALLAS_QUANT") in ("1", "true")
-
-
 class GradientCompression:
     """Stateful wrapper holding the error-feedback residual
     (reference ``GradientCompression`` + per-key residual buffers)."""
@@ -161,7 +130,6 @@ class GradientCompression:
         self._residual: np.ndarray = None
         self._residual_dev = None
         self._jit_compress = None
-        self._jit_uses_pallas = False
 
     def compress(self, grad: np.ndarray) -> np.ndarray:
         if self._residual is None or self._residual.shape != grad.shape:
@@ -175,26 +143,16 @@ class GradientCompression:
         the production entry for the host-sync plane (``Module.fit``):
         only the packed words (16x fewer bytes) cross the device-host
         boundary, and the error-feedback residual never leaves HBM.
-        Routes through :func:`quantize_2bit_best` (fused jnp by default;
-        Pallas behind ``DT_PALLAS_QUANT=1``)."""
-        use_pallas = _use_pallas_quant()  # read OUTSIDE jit: a read under
-        # trace is baked in for the cached program (ADVICE r3)
+        :func:`quantize_2bit` is the quantizer: XLA fuses threshold,
+        decode, residual and pack into one pass at full lane width,
+        where a Pallas kernel over the wire format's 16-code rows fills
+        16 of a vector's 128 lanes (0.625x on the chip,
+        ``PALLAS_TPU_r02.jsonl``)."""
         if self._residual_dev is None or \
-                self._residual_dev.shape != grad.shape or \
-                use_pallas != self._jit_uses_pallas:
-            self._residual_dev = (
-                jnp.zeros(grad.shape, jnp.float32)
-                if self._residual_dev is None
-                or self._residual_dev.shape != grad.shape
-                else self._residual_dev)
-            if use_pallas:
-                from dt_tpu.ops.pallas import kernels
-                impl = kernels.quantize_2bit
-            else:
-                impl = quantize_2bit
+                self._residual_dev.shape != grad.shape:
+            self._residual_dev = jnp.zeros(grad.shape, jnp.float32)
             self._jit_compress = jax.jit(
-                lambda g, r: impl(g, r, self.threshold))
-            self._jit_uses_pallas = use_pallas
+                lambda g, r: quantize_2bit(g, r, self.threshold))
         packed, self._residual_dev = self._jit_compress(
             grad.astype(jnp.float32), self._residual_dev)
         return packed

@@ -155,7 +155,7 @@ class Module:
         # cached programs instead of paying full recompiles (SURVEY §7
         # mesh-resize mitigation).
         config_lib.enable_compilation_cache()
-        # Whole-loss jax.checkpoint.  NOTE (r4, tools/memcost.py): a
+        # Whole-loss jax.checkpoint.  NOTE (r4): a
         # SINGLE checkpoint segment is memory-neutral — the recomputed
         # forward is all live at once — so the real memory mirror
         # (MXNET_BACKWARD_DO_MIRROR, SURVEY §5.6) is the PER-BLOCK remat

@@ -6,8 +6,8 @@ SORTED sequence — a pure sequence-to-sequence transduction that needs
 both directions of context (position k of the sorted output depends on
 the whole input), which is why the reference uses a bidirectional LSTM.
 TPU-first shape: the framework's fused-scan
-:func:`dt_tpu.ops.rnn.bidirectional_lstm` (Pallas fused cell on TPU,
-lax.scan elsewhere) runs under ONE jit step; tokens embed, the bi-LSTM
+:func:`dt_tpu.ops.rnn.bidirectional_lstm` (one ``lax.scan`` a
+direction) runs under ONE jit step; tokens embed, the bi-LSTM
 encodes, a shared dense head scores every position.
 
     python examples/train_bilstm_sort.py --epochs 12

@@ -95,29 +95,6 @@ def test_kvstore_set_gradient_compression():
         kv.set_gradient_compression({"type": "1bit"})
 
 
-def test_quantize_2bit_best_defaults_to_oracle(monkeypatch):
-    """Round-2 judge item 3: the slower-than-oracle Pallas kernel is
-    retired — the production selector uses the fused jnp path unless
-    DT_PALLAS_QUANT=1 explicitly opts in."""
-    import jax.numpy as jnp
-    import numpy as np
-    from dt_tpu.parallel import compression as C
-
-    monkeypatch.delenv("DT_PALLAS_QUANT", raising=False)
-    g = jnp.asarray(np.linspace(-2, 2, 64), jnp.float32)
-    r = jnp.zeros((64,), jnp.float32)
-    pk_best, res_best = C.quantize_2bit_best(g, r, 0.5)
-    pk_ref, res_ref = C.quantize_2bit(g, r, 0.5)
-    np.testing.assert_array_equal(np.asarray(pk_best), np.asarray(pk_ref))
-    np.testing.assert_allclose(np.asarray(res_best), np.asarray(res_ref))
-
-    monkeypatch.setenv("DT_PALLAS_QUANT", "1")
-    pk_p, res_p = C.quantize_2bit_best(g, r, 0.5)  # interpret on CPU
-    np.testing.assert_array_equal(np.asarray(pk_p), np.asarray(pk_ref))
-    np.testing.assert_allclose(np.asarray(res_p), np.asarray(res_ref),
-                               atol=1e-6)
-
-
 def test_compress_on_device_matches_np_sequence():
     """The device-side production path (Module.fit host-sync: quantize in
     HBM, fetch packed words) must track the np host path bit-for-bit,

@@ -143,8 +143,7 @@ def test_resnet_per_block_remat_equivalence():
     """models.create(..., remat=True) (per-block memory mirror,
     MXNET_BACKWARD_DO_MIRROR analog) must be a numerical no-op: same
     outputs AND same grads, only the backward's memory schedule differs
-    (memory effect is TPU-only; XLA CPU folds the recompute away —
-    tools/memcost.py documents this)."""
+    (memory effect is TPU-only; XLA CPU folds the recompute away)."""
     import jax
     import jax.flatten_util
     import jax.numpy as jnp

@@ -13,9 +13,9 @@ the same boundary twice.  It takes ``--steps`` steps of a small model through
 ``Module.fit`` inside such a session, reads each annotation's start from the
 ``.xplane.pb`` (``profile_start_time`` of the ``Task Environment`` plane plus
 the event's offset) and the same span's start from the ring, and prints the
-differences.  It also says where in the trace a device operation's scope
-(``forward``, ``optimizer``: the step program's ``named_scope``s) can be
-found, which ``benchmark/xplane.py`` drops today.
+differences.  It also counts the device operations that carry each of the
+step program's ``named_scope``s (``forward``, ``optimizer``), as
+``benchmark/xplane.py`` ``event_scopes`` reads them.
 
 It runs on whatever device JAX finds and names it: a time from a CPU run is
 a rehearsal of the script, not a reading of the chip.  Reference analog: the
@@ -66,89 +66,19 @@ def annotation_starts(path, names):
     return {k: sorted(v) for k, v in starts.items()}, device_ops
 
 
-def _varint(buf, i):
-    """The varint at ``buf[i:]`` -> (value, index after it)."""
-    val = shift = 0
-    while True:
-        b = buf[i]
-        i += 1
-        val |= (b & 0x7F) << shift
-        shift += 7
-        if b < 0x80:
-            return val, i
-
-
-def _fields(buf):
-    """A protobuf message's fields as (number, value): a varint as int, a
-    length-delimited field as bytes; fixed-width fields are skipped."""
-    i = 0
-    while i < len(buf):
-        key, i = _varint(buf, i)
-        number, wire = key >> 3, key & 7
-        if wire == 0:
-            val, i = _varint(buf, i)
-            yield number, val
-        elif wire == 2:
-            size, i = _varint(buf, i)
-            yield number, buf[i:i + size]
-            i += size
-        else:
-            i += 8 if wire == 1 else 4
-
-
-def scopes_in_event_metadata(path):
-    """Where a device operation's scope lives in the ``.xplane.pb``: the
-    profiler keeps an operation's name and statistics once, in the plane's
-    event metadata (which ``jax.profiler.ProfileData`` does not show), and
-    an event only points there.  Read raw (``xplane.proto``: XSpace.planes
-    = 1; XPlane.name = 2, .event_metadata = 4, .stat_metadata = 5;
-    XEventMetadata.name = 2, .display_name = 4, .stats = 5; XStat
-    .metadata_id = 1, .str_value = 5, .ref_value = 7; XStatMetadata.name =
-    2) -> ({scope: {where: operations}}, one scoped operation as a
-    sample), over the ``/device:`` planes."""
-    with open(path, "rb") as f:
-        space = f.read()
-    found, sample = {s: {} for s in SCOPES}, None
-    for number, plane in _fields(space):
-        if number != 1:
-            continue
-        name, events, stat_names = b"", [], {}
-        for num, val in _fields(plane):
-            if num == 2:
-                name = val
-            elif num in (4, 5):     # map entries: key = 1, value = 2
-                entry = dict(_fields(val))
-                if num == 4:
-                    events.append(entry.get(2, b""))
-                else:
-                    stat_names[entry.get(1, 0)] = dict(_fields(
-                        entry.get(2, b""))).get(2, b"").decode(
-                            "utf-8", "replace")
-        if not name.startswith(b"/device:"):
-            continue
-        for meta in events:
-            texts = {}
-            for num, val in _fields(meta):
-                if num == 2:
-                    texts["name"] = val
-                elif num == 4:
-                    texts["display_name"] = val
-                elif num == 5:
-                    stat = dict(_fields(val))
-                    key = "stat:" + stat_names.get(stat.get(1), "?")
-                    if isinstance(stat.get(5), bytes):
-                        texts[key] = stat[5]
-                    elif 7 in stat:     # a string kept as a stat's name
-                        texts[key] = stat_names.get(stat[7], "").encode()
-            hit = False
-            for where, text in texts.items():
-                for scope in SCOPES:
-                    if scope.encode() in text:
-                        found[scope][where] = found[scope].get(where, 0) + 1
-                        hit = True
-            if hit and sample is None:
-                sample = {k: v.decode("utf-8", "replace")[:300]
-                          for k, v in texts.items()}
+def scoped_operations(path):
+    """({scope: device operations traced under it}, one scoped operation as
+    a sample): ``benchmark/xplane.py`` ``event_scopes`` reads an
+    operation's scope path from the plane's event metadata."""
+    from benchmark.xplane import event_scopes
+    found, sample = {s: 0 for s in SCOPES}, None
+    for plane, scopes in sorted(event_scopes(path).items()):
+        for op, scope in sorted(scopes.items()):
+            hits = [s for s in SCOPES if s in scope]
+            for s in hits:
+                found[s] += 1
+            if hits and sample is None:
+                sample = {"plane": plane, "name": op, "scope": scope[:300]}
     return found, sample
 
 
@@ -203,7 +133,7 @@ def main(argv=None):
     path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                          recursive=True), key=os.path.getmtime)
     starts, device_ops = annotation_starts(path, set(spans))
-    scoped, sample = scopes_in_event_metadata(path)
+    scoped, sample = scoped_operations(path)
 
     per_name, offsets_us = {}, []
     for name, ring in sorted(spans.items()):

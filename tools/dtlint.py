@@ -28,7 +28,8 @@ The whole-tree result cache (``.dtlint_cache.json``) keys scanned files
 by (size, mtime) and the rule engine's own sources by CONTENT digest —
 editing a rule in ``dt_tpu/analysis/`` invalidates the cache even when
 size and mtime are preserved (r12).  ``--json`` appends one
-``{"rule_timings_ms": ...}`` summary object after the findings.
+``{"rule_timings_ms": ..., "from_cache": ...}`` summary object after the
+findings (``from_cache``: the verdict was read back, no rule ran).
 """
 
 import argparse
@@ -321,7 +322,7 @@ def main(argv=None):
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to lint (default: dt_tpu tools "
-                         "examples bench.py __graft_entry__.py)")
+                         "examples __graft_entry__.py)")
     ap.add_argument("--root", default=_ROOT)
     ap.add_argument("--baseline", default=None,
                     help="baseline file (default: <root>/dtlint_baseline"
@@ -402,7 +403,8 @@ def main(argv=None):
     if cacheable:
         findings, sig, timings = _cached_findings(analysis, root,
                                                   eff_paths, select)
-    if findings is None:
+    from_cache = findings is not None
+    if not from_cache:
         timings = {}
         findings = analysis.run(root, paths=eff_paths, select=select,
                                 timings=timings)
@@ -445,7 +447,8 @@ def main(argv=None):
     if args.json:
         print(json.dumps({"rule_timings_ms":
                           {k: round(v, 2)
-                           for k, v in sorted(timings.items())}}))
+                           for k, v in sorted(timings.items())},
+                          "from_cache": from_cache}))
     for key in stale:
         print(f"{baseline_path}: stale baseline entry (fixed or moved — "
               f"delete it): {' | '.join(key)}")

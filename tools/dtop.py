@@ -251,7 +251,7 @@ def render(summary) -> str:
                     line += f"  limit={d['bytes_limit'] / 2**20:.0f}MiB"
                 if est.get("peak_mb"):
                     # estimated-vs-measured: XLA's buffer-assignment
-                    # peak (the memcost static estimate) vs live HBM
+                    # peak (the static estimate) vs live HBM
                     delta = d.get("peak_bytes_in_use", 0) / 2**20 \
                         - est["peak_mb"]
                     line += (f"  est_peak={est['peak_mb']:.1f}MiB"

@@ -1,10 +1,12 @@
 """Compiled parity + timing drive for the Pallas kernels vs their XLA/jnp
 oracles — run on a real TPU (also runs on CPU in interpret mode, slowly).
 
-Round-1 VERDICT item 5: prove the kernels help compiled, or delete them.
+Round-1 VERDICT item 5: prove the kernels help compiled, or delete them
+(the LSTM cell and the 2-bit quantizer went that way, on
+``PALLAS_TPU_r02.jsonl``).
 Round-2 VERDICT items 3/9: sweep >= 3 shapes per kernel (batch/seq/
-channels; 1M/16M/64M for the 2-bit quantizer) so "wired into hot paths"
-never rests on one point.  Each line of output is a JSON record:
+channels) so "wired into hot paths" never rests on one point.  Each line
+of output is a JSON record:
 {kernel, shape, parity_max_abs_err, oracle_ms, pallas_ms, speedup}.
 
 Each kernel has a ``*_case`` builder returning ``(oracle, pallas, args)``
@@ -13,13 +15,12 @@ calls the same builders with ``interpret=False`` at the shapes the models
 feed the kernels, so the oracles live in one place.
 
 Usage:  python tools/pallas_drive.py                       # full sweep
-        python tools/pallas_drive.py --only quantize_2bit  # one kernel
+        python tools/pallas_drive.py --only fused_bn_inference  # one kernel
         python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -66,34 +67,6 @@ def rel_err(got, want):
 # ---------------------------------------------------------------------------
 
 
-def lstm_case(rng, T, B, I, H, dt, interpret=None):
-    """T-step LSTM fwd+bwd: the oracle cell vs the fused cell in one scan."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from dt_tpu.ops import rnn
-    from dt_tpu.ops.pallas import kernels
-    w = rnn.LSTMWeights(
-        jnp.asarray(rng.randn(I, 4 * H) * 0.05, dt),
-        jnp.asarray(rng.randn(H, 4 * H) * 0.05, dt),
-        jnp.asarray(np.zeros(4 * H), jnp.float32))
-    x = jnp.asarray(rng.randn(T, B, I), dt)
-    h0 = jnp.zeros((B, H), dt)
-
-    def make(cell):
-        def loss(w, x):
-            def step(carry, xt):
-                h, c = cell(xt, *carry, w)
-                return (h, c), h
-            _, outs = jax.lax.scan(step, (h0, h0), x)
-            return jnp.sum(outs.astype(jnp.float32) ** 2)
-        return jax.jit(jax.value_and_grad(loss))
-
-    return (make(rnn.lstm_cell),
-            make(functools.partial(kernels.lstm_cell_fused,
-                                   interpret=interpret)), (w, x))
-
-
 def _bn_inputs(rng, shape, dt):
     import jax.numpy as jnp
     c = shape[-1]
@@ -138,20 +111,6 @@ def bn_train_case(rng, shape, dt, interpret=None):
     pallas = train_loss(lambda x, g, b: kernels.fused_bn_train(
         x, g, b, mean, var, 0.9, 1e-5, 256, interpret))
     return oracle, pallas, (x, gamma, beta)
-
-
-def quantize_case(rng, n, interpret=None):
-    """2-bit quantize of ``n`` f32 gradients (+ zero residual)."""
-    import jax
-    import jax.numpy as jnp
-    from dt_tpu.ops.pallas import kernels
-    from dt_tpu.parallel import compression
-    g = jnp.asarray(rng.randn(n), jnp.float32)
-    r = jnp.zeros((n,), jnp.float32)
-    oracle = jax.jit(lambda g, r: compression.quantize_2bit(g, r, 0.5))
-    pallas = jax.jit(lambda g, r: kernels.quantize_2bit(
-        g, r, 0.5, interpret=interpret))
-    return oracle, pallas, (g, r)
 
 
 def _chunked_full_attention(q, k, v, chunk=1024):
@@ -273,8 +232,7 @@ def main():
 
     def emit(kernel, shape, case):
         # print per-record, flushed: a crash in a later kernel must not
-        # lose earlier evidence (round-2 lesson: the uint32-reduction crash
-        # in quantize_2bit ate the LSTM/BN records)
+        # lose earlier evidence (a round-2 lesson)
         oracle, pallas, a = case
         rec = {"kernel": kernel, "shape": shape,
                "parity_max_abs_err": _err(oracle(*a), pallas(*a)),
@@ -287,15 +245,6 @@ def main():
 
     dt = jnp.float32 if args.small else jnp.bfloat16
 
-    # ---- LSTM: full sequence fwd+bwd, oracle cell vs fused cell ---------
-    if wanted("lstm_seq_fwd_bwd"):
-        for T, B, I, H in ([(8, 8, 32, 32)] if args.small else
-                           [(64, 64, 512, 512),    # round-2 point
-                            (128, 32, 256, 256),   # long seq, small model
-                            (32, 128, 1024, 1024)]):  # big batch, wide
-            emit("lstm_seq_fwd_bwd", f"T{T}xB{B}xI{I}xH{H} {dt.__name__}",
-                 lstm_case(rng, T, B, I, H, dt))
-
     # ---- BN inference epilogue + train-mode fused BN ---------------------
     if wanted("fused_bn_inference"):
         for N, HW, C in ([(4, 8, 64)] if args.small else
@@ -307,11 +256,6 @@ def main():
                  bn_inference_case(rng, (N, HW, HW, C), dt))
             emit("fused_bn_train_fwd_bwd", shape,
                  bn_train_case(rng, (N, HW, HW, C), dt))
-
-    # ---- 2-bit gradient quantize (1M/16M/64M sweep) ---------------------
-    if wanted("quantize_2bit"):
-        for n in ([1 << 14] if args.small else [1 << 20, 1 << 24, 1 << 26]):
-            emit("quantize_2bit", f"{n} f32", quantize_case(rng, n))
 
     # ---- flash attention fwd+bwd vs full-attention oracle ---------------
     if wanted("flash_attention_fwd_bwd"):

@@ -1,10 +1,9 @@
 #!/usr/bin/env python
 """Serving-plane bench: sustained QPS / latency / loss under faults.
 
-``tools/step_bench.py`` measures the training step; this bench measures
-the r21 serving plane (``dt_tpu/serve/``, docs/serving.md) end to end —
-REAL replica subprocesses (``python -m dt_tpu.serve.replica``, each a
-jax Predictor behind a Gateway) against a real Scheduler, driven by an
+Measures the r21 serving plane (``dt_tpu/serve/``, docs/serving.md) end
+to end — REAL replica subprocesses (``python -m dt_tpu.serve.replica``,
+each a jax Predictor behind a Gateway) against a real Scheduler, driven by an
 open-loop load generator that verifies EVERY answer against the
 deterministic toy-model oracle.  Four scenarios:
 
@@ -33,7 +32,7 @@ Loss accounting is strict: every submitted request must end ``ok``
 explicit bounded-admission answer).  ``lost`` (retries exhausted) or
 ``bad`` (wrong bytes) fail the run.
 
-jax-optional in THIS process (the dtop/step_bench path shim): the
+jax-optional in THIS process (the dtop path shim): the
 parent imports only the jax-free elastic + serve.client layers; jax
 lives in the replica subprocesses (CPU-forced via ``DT_FORCE_CPU``).
 
@@ -58,7 +57,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 # import dt_tpu.elastic / dt_tpu.serve.client WITHOUT dt_tpu/__init__
-# (which pulls the ops surface and therefore jax) — the dtop/step_bench
+# (which pulls the ops surface and therefore jax) — the dtop
 # shim; dt_tpu.serve.replica is jax-free too (Predictor imports lazily)
 if "dt_tpu" not in sys.modules:
     import types
