@@ -1,0 +1,34 @@
+"""The flash attention's backward kernel (PR 31): what the mathematics needs
+of it, from shapes alone, and its roofline share in either language-model
+cell (the kernel's events are named ``flash_bwd`` in both)."""
+
+import readers
+
+
+def flash_backward_ops_bytes(batch, heads, seq, head_dim, itemsize):
+    """Causal attention backward for (batch, seq, heads, head_dim): the five
+    products over the lower triangle (the scores again, dv, dp, dk, dq),
+    counted once however many passes a kernel makes; q, k, v, the output and
+    its gradient read and dq, dk, dv written once, plus one float32
+    log-sum-exp per row."""
+    ops = batch * heads * 5 * (2 * seq * seq * head_dim) // 2
+    nbytes = batch * heads * seq * (8 * head_dim * itemsize + 4)
+    return ops, nbytes
+
+
+def roofline_pct(ctx, m):
+    """``readers:kernel_roofline_pct`` for a kernel that two configurations
+    with different keys call: the heads are the first of ``args.heads`` the
+    configuration has, and the calls a step are how often
+    ``args.calls.counted.value`` stands in its list ``args.calls.counted.in``
+    (the attention layers among ``layer_types``) or, where it has no such
+    list, its ``args.calls.else`` (every layer)."""
+    args, cfg = m["args"], ctx["cfg"]
+    heads = next(cfg[k] for k in args["heads"] if k in cfg)
+    counted = args["calls"]["counted"]
+    calls = cfg[counted["in"]].count(counted["value"]) \
+        if counted["in"] in cfg else cfg[args["calls"]["else"]]
+    return readers.kernel_roofline_pct(
+        {**ctx, "cfg": {**cfg, "flash_bwd_calls_per_step": calls}},
+        {**m, "args": {**args, "shape": {**args["shape"], "heads": heads},
+                       "calls_per_step": "flash_bwd_calls_per_step"}})

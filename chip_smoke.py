@@ -197,7 +197,7 @@ def stage_a(size="full"):
 
 _B_SIZES = {
     # flash: the stage's own LM shape below (8 heads of 64 at sequence
-    # 2048), at the forward's derived tiles (flash_case passes none); BN:
+    # 2048), at the derived tiles of either pass (flash_case passes none); BN:
     # ResNet-50's first and last BN inputs at batch 128
     "full": dict(flash=(8, 2048, 8, 64), bn=[(128, 112, 112, 64),
                                              (128, 7, 7, 2048)],
@@ -243,8 +243,10 @@ def stage_b(size="full", interpret=False):
 
     # the flash gate runs at the tiles the models get: derived from the shape
     _, seq, _, head = cfg["flash"]
-    extras = {"flash_attention_fwd_bwd": {"tiles": list(
-        attention.forward_tiles(seq, seq, head, jnp.dtype(dt).itemsize))}}
+    lengths = (seq, seq, head, jnp.dtype(dt).itemsize)
+    extras = {"flash_attention_fwd_bwd": {
+        "tiles": list(attention.forward_tiles(*lengths)),
+        "bwd_tiles": list(attention.backward_tiles(*lengths))}}
     kernels, failed = [], []
     for name, shape, bound, build in cases:
         row = {"kernel": name, "shape": str(shape), **extras.get(name, {})}

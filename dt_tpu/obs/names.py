@@ -164,8 +164,13 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "flash.block_q": ("gauge", "query rows in one tile of the flash "
                                "forward, derived from the shape "
                                "(forward_tiles) or given"),
-    "flash.block_k": ("gauge", "key rows in one tile of the flash forward; "
-                               "the blockwise backward keeps 128"),
+    "flash.block_k": ("gauge", "key rows in one tile of the flash forward, "
+                               "derived or given"),
+    "flash.bwd_block_q": ("gauge", "query rows in one tile of the flash "
+                                   "backward, derived from the shape "
+                                   "(backward_tiles)"),
+    "flash.bwd_block_k": ("gauge", "key rows in one tile of the flash "
+                                   "backward (backward_tiles)"),
     "worker.step_rate": ("gauge", "scheduler-derived per-worker step "
                                   "rate (steps/s) from the shipped "
                                   "train.steps series"),

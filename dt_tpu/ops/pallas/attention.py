@@ -23,11 +23,23 @@ repeats the last block needed), and only tiles the diagonal crosses are
 masked.  ``flash_attention``'s ``block_q`` / ``block_k`` override the
 choice.
 
-Backward is the standard flash backward (recompute per key block from the
-saved logsumexp) expressed as a ``lax.scan``: O(S x 128) memory, no
-materialized score matrix.  Its block is ``DEFAULT_BLOCK`` (128) whatever
-the forward's tiles are: its float32 temporaries are (BH, S, block).
-``DEFAULT_BLOCK`` is also what callers pad sequences to.
+Backward is the standard flash backward from the saved log-sum-exp, one
+Pallas call named ``flash_bwd`` (its events carry that name in a trace,
+where the forward's carry its caller's): grid (batch*heads, k_tiles,
+q_tiles), the tile held keys by queries (``s^T = k q^T``) so that the
+log-sum-exp and ``delta = rowsum(do * out)`` (float32, from XLA) are lane
+rows and dv, dk are plain products; dk, dv accumulate in float32 VMEM
+scratch over the query axis, dq over both axes in a scratch for the whole
+sequence.  Five products and one exponential a tile pair.  Operands as
+stored and float32 accumulation, as the forward; ``p`` and ``ds`` are cast
+to the operands' type for the products they enter.  Pruned under ``causal``
+as the forward is.  Its tiles are its own (``backward_tiles``: at most 512
+a side), whatever the forward's are; nothing chooses another backward.  The
+whole sequence's dq in VMEM bounds the length one device takes: in
+bfloat16 the tiles fall to 128 x 128 from about 23,000 positions and the
+compiler (given twice ``VMEM_BUDGET``) refuses from about 48,000
+(``ring_attention`` is the path beyond one device).  ``DEFAULT_BLOCK``
+(128) is what callers pad sequences to.
 
 Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
@@ -56,13 +68,17 @@ from dt_tpu.ops.pallas.kernels import _default_interpret
 logger = logging.getLogger("dt_tpu")
 
 NEG_INF = -1e30
-# what callers pad to (TransformerLM, GroupedQueryAttention) and the
-# backward's block; the forward's tile is derived, see forward_tiles
+# what callers pad to (TransformerLM, GroupedQueryAttention); the tiles of
+# either pass are derived from the shape (forward_tiles, backward_tiles)
 DEFAULT_BLOCK = 128
 _LANES = 128
 # the forward's candidate tiles, largest first (PERF.md section 6, PR 29:
 # the sweep over {256, 512, 1024} on either side at the cells' shapes)
 FORWARD_TILES = (1024, 512, 256, 128)
+# the backward's: at 1,024 positions three 512 x 512 tiles of four beat the
+# whole square, and 1,024 x 1,024 is over VMEM_BUDGET by backward_vmem_bytes
+# (PERF.md section 6, PR 31: the sweep)
+BACKWARD_TILES = (512, 256, 128)
 # what one grid step may hold in VMEM by tile_vmem_bytes' reckoning; the
 # compiler is given twice that (v5e has 128 MiB of it, 16 MiB scoped by
 # default)
@@ -83,23 +99,43 @@ def tile_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
     return blocks + scratch + scores
 
 
-def forward_tiles(s: int, sk: int, d: int, itemsize: int):
-    """The forward's (block_q, block_k) for query length ``s``, key length
-    ``sk``, head size ``d`` and operands of ``itemsize`` bytes: of the
-    pairs of ``FORWARD_TILES`` that divide the lengths and keep
-    ``tile_vmem_bytes`` within ``VMEM_BUDGET``, the largest (by scores a
-    tile, then by query rows); a length below a tile is one tile of a
-    smaller one.  A length that no tile divides raises."""
+def _largest_tiles(candidates, s: int, sk: int, fits):
+    """Of the pairs of ``candidates`` that divide the lengths and that
+    ``fits`` admits, the largest (by scores a tile, then by query rows); a
+    length below a tile is one tile of a smaller one.  A length that no
+    candidate divides raises."""
     def dividing(n):
-        got = [t for t in FORWARD_TILES if n % t == 0]
+        got = [t for t in candidates if n % t == 0]
         if not got:
             raise ValueError(f"seq length {n} must be a multiple of "
-                             f"{FORWARD_TILES[-1]}")
+                             f"{candidates[-1]}")
         return got
     pairs = [(bq, bk) for bq in dividing(s) for bk in dividing(sk)]
-    fit = [p for p in pairs
-           if tile_vmem_bytes(*p, d, itemsize) <= VMEM_BUDGET]
+    fit = [p for p in pairs if fits(*p)]
     return max(fit or pairs[-1:], key=lambda p: (p[0] * p[1], p[0]))
+
+
+def forward_tiles(s: int, sk: int, d: int, itemsize: int):
+    """The forward's (block_q, block_k) for query length ``s``, key length
+    ``sk``, head size ``d`` and operands of ``itemsize`` bytes: the largest
+    pair of ``FORWARD_TILES`` (``_largest_tiles``) that keeps
+    ``tile_vmem_bytes`` within ``VMEM_BUDGET``."""
+    return _largest_tiles(
+        FORWARD_TILES, s, sk,
+        lambda bq, bk: tile_vmem_bytes(bq, bk, d, itemsize) <= VMEM_BUDGET)
+
+
+def _when_causal(tile, qi, ki, block_q: int, block_k: int):
+    """Run ``tile(masked)`` for the (qi, ki) tile under the causal mask: not
+    at all where the tile's first key position is beyond its last query
+    position (no products and, by the index maps, no fetch), masked where
+    the diagonal crosses it, unmasked below."""
+    first_k, last_k = ki * block_k, ki * block_k + block_k - 1
+    first_q, last_q = qi * block_q, qi * block_q + block_q - 1
+    runs = first_k <= last_q
+    crossed = last_k > first_q
+    pl.when(runs & crossed)(functools.partial(tile, True))
+    pl.when(runs & jnp.logical_not(crossed))(functools.partial(tile, False))
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -144,16 +180,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = m_new
 
     if causal:
-        # tiles whose first key position is beyond the last query
-        # position are fully masked: no products (and, by the index maps,
-        # no fetch); only tiles the diagonal crosses pay for the mask
-        first_k, last_k = ki * block_k, ki * block_k + block_k - 1
-        first_q, last_q = qi * block_q, qi * block_q + block_q - 1
-        runs = first_k <= last_q
-        crossed = last_k > first_q
-        pl.when(runs & crossed)(functools.partial(_attend, True))
-        pl.when(runs & jnp.logical_not(crossed))(
-            functools.partial(_attend, False))
+        _when_causal(_attend, qi, ki, block_q, block_k)
     else:
         _attend(False)
 
@@ -234,71 +261,175 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
 
 
 @functools.lru_cache(maxsize=None)
-def _note_tiles(shape, block_q: int, block_k: int) -> None:
-    """Record, once per distinct shape and tile, what the forward was
-    traced with: a debug line and the metrics plane's gauges, so that a
+def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False) -> None:
+    """Record, once per distinct shape and tile, what the forward (or with
+    ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
+    flash_bwd_tiles``) debug line and the metrics plane's gauges, so that a
     shape that falls back to 128 is seen.  Trace time only."""
     s, sk, d, dtype = shape
-    logger.debug("# flash_tiles s=%d sk=%d d=%d dtype=%s block_q=%d "
-                 "block_k=%d", s, sk, d, dtype, block_q, block_k)
+    logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
+                 "block_k=%d", "bwd_" if bwd else "", s, sk, d, dtype,
+                 block_q, block_k)
     if obs_metrics.enabled():
         reg = obs_metrics.registry()
         labels = {"shape": f"{s}x{sk}x{d}.{dtype}"}
-        reg.gauge("flash.block_q", block_q, labels)
-        reg.gauge("flash.block_k", block_k, labels)
+        if bwd:
+            reg.gauge("flash.bwd_block_q", block_q, labels)
+            reg.gauge("flash.bwd_block_k", block_k, labels)
+        else:
+            reg.gauge("flash.block_q", block_q, labels)
+            reg.gauge("flash.block_k", block_k, labels)
 
 
-def _flash_bwd_blockwise(q3, k3, v3, o3, lse, do3, *, scale, causal,
-                         block_k):
-    """Standard flash backward from the saved logsumexp, scanned over KV
-    blocks: never materializes the (S, S) score matrix.
+def backward_vmem_bytes(block_q: int, block_k: int, s: int, d: int,
+                        itemsize: int) -> int:
+    """VMEM one grid step of the backward holds, reckoned from the shapes:
+    the double-buffered q, do, k, v blocks, the dk, dv blocks and the whole
+    sequence's dq block going out, the log-sum-exp and delta rows (eight
+    sublanes each); the float32 accumulators (dq's for the whole sequence);
+    and the tile's four float32 arrays (scores, probabilities, dp, ds)
+    with the copies of p and ds, and of ds turned, in the operands' type."""
+    dl = -(-d // _LANES) * _LANES
+    blocks = 2 * ((2 * block_q + 4 * block_k + s) * dl * itemsize
+                  + 2 * 8 * block_q * 4)
+    scratch = (2 * block_k + s) * dl * 4
+    scores = block_q * block_k * (4 * 4 + 3 * itemsize)
+    return blocks + scratch + scores
 
-    Unlike the forward kernel, the causal triangle is NOT pruned here —
-    each KV block attends the full Q range with masking (pruning would
-    need q-blocking with dynamic trip counts; the memory win is what
-    this pass is for)."""
+
+def backward_tiles(s: int, sk: int, d: int, itemsize: int):
+    """The backward's (block_q, block_k): the largest pair of
+    ``BACKWARD_TILES`` (``_largest_tiles``) that keeps the backward's own
+    reckoning, ``backward_vmem_bytes``, within ``VMEM_BUDGET``."""
+    return _largest_tiles(
+        BACKWARD_TILES, s, sk, lambda bq, bk: backward_vmem_bytes(
+            bq, bk, s, d, itemsize) <= VMEM_BUDGET)
+
+
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      scale: float, causal: bool, block_q: int, block_k: int,
+                      n_q: int, n_k: int):
+    """One (bh, k_block, q_block) grid step of the backward.  The tile is
+    held keys by queries (``s^T = k q^T``): the log-sum-exp and delta of the
+    query rows are then lane rows that broadcast down the sublanes, and
+    dv, dk are plain products.  dk, dv accumulate over the query axis (the
+    innermost), dq over both in a scratch for the whole sequence."""
+    ki, qi = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((ki == 0) & (qi == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _tile(masked: bool):
+        # operands as stored, float32 accumulation: the forward's rule
+        q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
+        nt = (((1,), (1,)), ((), ()))
+        st = jax.lax.dot_general(k, q, nt,
+                                 preferred_element_type=jnp.float32) * scale
+        if masked:
+            # q_pos >= k_pos, the tile's offsets moved to the scalar side
+            col_less_row = lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1) - lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            st = jnp.where(col_less_row >= ki * block_k - qi * block_q,
+                           st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[0])                 # (BK, BQ)
+        dv_acc[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, nt,
+                                  preferred_element_type=jnp.float32)
+        # scale is applied to the sums, once a row and not once a score
+        dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+        dk_acc[:] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[rows, :] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if causal:
+        _when_causal(_tile, qi, ki, block_q, block_k)    # as the forward
+    else:
+        _tile(False)
+
+    @pl.when(qi == n_q - 1)
+    def _finish_dkv():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((ki == n_k - 1) & (qi == n_q - 1))
+    def _finish_dq():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
+                      block_q=None, block_k=None):
+    """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
+    (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
+    ``flash_bwd``.  Its tiles come from the shapes (``backward_tiles``).
+
+    Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
+    kernel's body for all of a model's layers."""
     bh, s, d = q3.shape
     sk = k3.shape[1]
-    n_k = -(-sk // block_k)
-    pad = n_k * block_k - sk
-    kp = jnp.pad(k3, ((0, 0), (0, pad), (0, 0)))
-    vp = jnp.pad(v3, ((0, 0), (0, pad), (0, 0)))
-    kb = kp.reshape(bh, n_k, block_k, d)
-    vb = vp.reshape(bh, n_k, block_k, d)
-
-    qf = q3.astype(jnp.float32)
-    dof = do3.astype(jnp.float32)
-    delta = (dof * o3.astype(jnp.float32)).sum(-1)    # (BH, S)
-    q_pos = jnp.arange(s)
-
-    def per_block(j, kj, vj):
-        kjf = kj.astype(jnp.float32)
-        vjf = vj.astype(jnp.float32)
-        k_pos = j * block_k + jnp.arange(block_k)
-        sij = jnp.einsum("bqd,bkd->bqk", qf, kjf) * scale
-        valid = k_pos < sk
-        mask = valid[None, :]
-        if causal:
-            mask = mask & (q_pos[:, None] >= k_pos[None, :])
-        p = jnp.where(mask[None], jnp.exp(sij - lse[:, :, None]), 0.0)
-        dv = jnp.einsum("bqk,bqd->bkd", p, dof)
-        dp = jnp.einsum("bqd,bkd->bqk", dof, vjf)
-        ds = p * (dp - delta[:, :, None]) * scale
-        dq_part = jnp.einsum("bqk,bkd->bqd", ds, kjf)
-        dk = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        return dq_part, dk, dv
-
-    def step(dq, j_kv):
-        j, kj, vj = j_kv
-        dq_part, dk, dv = per_block(j, kj, vj)
-        return dq + dq_part, (dk, dv)
-
-    dq, (dkb, dvb) = lax.scan(
-        step, jnp.zeros_like(qf),
-        (jnp.arange(n_k), jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0)))
-    dk = jnp.moveaxis(dkb, 0, 1).reshape(bh, n_k * block_k, d)[:, :sk]
-    dv = jnp.moveaxis(dvb, 0, 1).reshape(bh, n_k * block_k, d)[:, :sk]
-    return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
+    if block_q is None or block_k is None:
+        tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize)
+        block_q, block_k = block_q or tq, block_k or tk
+    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True)
+    n_q, n_k = s // block_q, sk // block_k
+    # delta = rowsum(do * out), float32, in XLA: one pass over two arrays
+    # the step already holds; a row vector per head, as the log-sum-exp
+    delta = (do3.astype(jnp.float32) * o3.astype(jnp.float32)).sum(-1)
+    kern = functools.partial(
+        _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, n_q=n_q, n_k=n_k)
+    if causal:
+        # a skipped step names the first query block its key tile needs:
+        # the block the first step that runs will want, fetched once
+        first = lambda ki, qi: jnp.minimum(
+            jnp.maximum(qi, ki * block_k // block_q), n_q - 1)
+    else:
+        first = lambda ki, qi: qi
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda b, ki, qi: (b, first(ki, qi), 0))
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda b, ki, qi: (b, 0, first(ki, qi)))
+    k_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
+    dq, dk, dv = pl.pallas_call(
+        kern,
+        name="flash_bwd",
+        grid=(bh, n_k, n_q),
+        in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
+        out_specs=[
+            pl.BlockSpec((1, s, d), lambda b, ki, qi: (b, 0, 0)),
+            k_spec, k_spec,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
+            jax.ShapeDtypeStruct((bh, sk, d), v3.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * VMEM_BUDGET),
+        interpret=interpret,
+    )(q3, do3, lse.reshape(bh, 1, s), delta.reshape(bh, 1, s), k3, v3)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -317,11 +448,11 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do3):
-    # the forward's tile stops here: the backward's float32 temporaries
-    # are (BH, S, block), 268 MB each at 512 in the gpt2-medium cell
+    # the forward's tiles stop here: the backward derives its own from the
+    # shapes (backward_tiles), whatever the forward was given
     q3, k3, v3, out, lse = res
-    return _flash_bwd_blockwise(q3, k3, v3, out, lse, do3, scale=scale,
-                                causal=causal, block_k=DEFAULT_BLOCK)
+    return _flash_bwd_pallas(q3, k3, v3, out, lse, do3, scale=scale,
+                             causal=causal, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -338,8 +469,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``TransformerLM`` does).  ``block_q`` / ``block_k`` override the
     forward's tiles, which are otherwise derived from the shapes
     (``forward_tiles``); they must be multiples of 128 that divide the
-    lengths.  Differentiable via the blockwise flash backward, whose block
-    is ``DEFAULT_BLOCK`` whatever the forward's tiles are.
+    lengths.  Differentiable via the Pallas flash backward
+    (``_flash_bwd_pallas``), whose tiles are derived from the shapes
+    (``backward_tiles``) whatever the forward's are.
     """
     if interpret is None:
         interpret = _default_interpret()

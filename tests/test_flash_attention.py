@@ -90,6 +90,8 @@ TILES = [(128, 128), (256, 512), (512, 256), (512, 512), (None, None)]
 LENGTHS = [512, 640, 1024]
 # what the benchmark's two LM cells get (PERF.md section 6, PR 29)
 CELL_TILES = {1024: (1024, 1024), 4096: (1024, 1024)}
+# and the backward's (PERF.md section 6, PR 31)
+CELL_BWD_TILES = {1024: (512, 512), 4096: (512, 512)}
 
 
 def _tiles_for(s, tiles):
@@ -194,54 +196,119 @@ def test_forward_tiles_rule_over_every_length():
 
 
 
-def _scan_lengths(jaxpr):
-    """Trip counts of every scan in a jaxpr, nested ones included."""
+def _primitives(jaxpr):
+    """Every equation of a jaxpr, nested ones included, as (primitive
+    name, the ``name`` a pallas_call was given)."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            found.append(eqn.params["length"])
+        meta = eqn.params.get("metadata") or {}
+        found.append((eqn.primitive.name, eqn.params.get("name")
+                      or meta.get("name")))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _scan_lengths(sub)
+            found += _primitives(sub)
     return found
 
 
 @pytest.mark.parametrize("tiles", [(None, None), (512, 256)])
-def test_backward_block_is_128_whatever_the_forward_chose(tiles):
-    """The forward's tile stops at the backward's rule: the blockwise
-    backward scans S/128 key blocks, so its float32 temporaries stay
-    (BH, S, 128) (it guards device.peak_hbm_gb.lm)."""
+def test_backward_is_one_pallas_call_whatever_the_forward_chose(tiles):
+    """The forward's tiles stop at the backward's rule: the gradient's
+    jaxpr holds the forward's call and one call named ``flash_bwd``, no
+    scan and no while."""
     s = 1024
     q = jnp.zeros((1, s, 1, 64), jnp.bfloat16)
-    assert attn.forward_tiles(s, s, 64, 2)[1] > attn.DEFAULT_BLOCK
     f = lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=tiles[0],
         block_k=tiles[1]).astype(jnp.float32).sum()
-    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, q, q)
-    assert _scan_lengths(jaxpr.jaxpr) == [s // attn.DEFAULT_BLOCK]
+    prims = _primitives(
+        jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, q, q).jaxpr)
+    names = [p for p, _ in prims]
+    assert "scan" not in names and "while" not in names
+    calls = [n for p, n in prims if p == "pallas_call"]
+    assert len(calls) == 2 and sum("flash_bwd" in (n or "")
+                                   for n in calls) == 1, calls
 
 
-def test_tiles_are_recorded_once_per_shape(caplog):
-    """The tile chosen at trace time: one ``# flash_tiles`` debug line and
-    the gauge pair a distinct shape, so a fall back to 128 is seen."""
+@pytest.mark.parametrize("d,itemsize", [(64, 2), (64, 4), (128, 2),
+                                        (128, 4)])
+def test_backward_tiles_rule_over_every_length(d, itemsize):
+    """The backward's rule alone, over the lengths the forward's rule test
+    walks: the tiles divide the lengths and the backward's own reckoning
+    of VMEM is under the limit."""
+    for s in range(128, 8192 + 1, 128):
+        for sk in {s, 128, 8192}:
+            bq, bk = attn.backward_tiles(s, sk, d, itemsize)
+            assert s % bq == 0 and sk % bk == 0, (s, sk, bq, bk)
+            assert bq in attn.BACKWARD_TILES and bk in attn.BACKWARD_TILES
+            assert attn.backward_vmem_bytes(bq, bk, s, d, itemsize) \
+                <= attn.VMEM_BUDGET, (s, sk, d, itemsize)
+    # a multiple of 128 and of nothing larger
+    assert attn.backward_tiles(640, 1152, d, itemsize) == (128, 128)
+    with pytest.raises(ValueError):
+        attn.backward_tiles(100, 128, d, itemsize)
+
+
+def test_backward_tiles_of_the_two_cells():
+    """gpt2m-seq1024 and granite4hm-b2-seq4096, bfloat16 (PERF.md section
+    6, PR 31); the forward's 1,024 x 1,024 is over the backward's VMEM."""
+    assert attn.backward_tiles(1024, 1024, 64, 2) == CELL_BWD_TILES[1024]
+    assert attn.backward_tiles(4096, 4096, 64, 2) == CELL_BWD_TILES[4096]
+    assert attn.backward_vmem_bytes(1024, 1024, 1024, 64, 2) \
+        > attn.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("s,sk,causal", [
+    (256, 384, False),     # more keys than queries, no mask
+    (384, 128, False),     # fewer
+    (256, 512, True),      # keys no query sees: their dk, dv are zero
+    (1536, 1536, True),    # 512 x 512: a tile skipped, crossed, below
+])
+def test_flash_backward_tiles_match_oracle(s, sk, causal):
+    """Gradients at the backward's derived tiles against the oracle where
+    the lengths differ and where the causal square is several tiles."""
+    rng = np.random.RandomState(s + sk)
+    mk = lambda n: jnp.asarray(rng.randn(1, n, 1, 64).astype(np.float32)
+                               * 0.5)
+    q, k, v = mk(s), mk(sk), mk(sk)
+    if s == 1536:
+        assert attn.backward_tiles(s, sk, 64, 4) == (512, 512)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal)
+    ref = lambda q, k, v: full_attention(q, k, v, causal=causal)
+    for name, a, b in zip("qkv", _grads(flash, q, k, v),
+                          _grads(ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4,
+                                   err_msg=f"d{name} s={s} sk={sk}")
+
+
+@pytest.mark.parametrize("prefix", ["", "bwd_"], ids=["forward", "backward"])
+def test_tiles_are_recorded_once_per_shape(caplog, prefix):
+    """The tiles chosen at either pass's trace: one ``# flash_tiles`` (``#
+    flash_bwd_tiles``) debug line and the gauge pair a distinct shape, so a
+    fall back to 128 is seen."""
     import logging
     from dt_tpu.obs import metrics as obs_metrics
     obs_metrics.set_enabled(True)
     try:
         obs_metrics.registry().clear()
         attn._note_tiles.cache_clear()
-        attn._flash_fwd_pallas.clear_cache()   # an earlier test's trace
+        attn._flash_fwd_pallas.clear_cache()   # an earlier test's traces
+        attn._flash_bwd_pallas.clear_cache()
         q = jnp.zeros((1, 640, 1, 64), jnp.float32)
+        run = lambda q: flash_attention(q, q, q, causal=True).sum()
+        if prefix:
+            run = jax.grad(run)
         with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
-            flash_attention(q, q, q, causal=True)
-            flash_attention(q, q, q, causal=True)
+            run(q)
+            run(q)
         lines = [r.getMessage() for r in caplog.records
-                 if r.getMessage().startswith("# flash_tiles")]
-        assert lines == ["# flash_tiles s=640 sk=640 d=64 dtype=float32 "
-                         "block_q=128 block_k=128"]
+                 if r.getMessage().startswith(f"# flash_{prefix}tiles")]
+        assert lines == [f"# flash_{prefix}tiles s=640 sk=640 d=64 "
+                         "dtype=float32 block_q=128 block_k=128"]
         labels = {"shape": "640x640x64.float32"}
-        assert obs_metrics.registry().gauges_export() == [
-            ["flash.block_k", labels, 128.0],
-            ["flash.block_q", labels, 128.0]]
+        gauges = [g for g in obs_metrics.registry().gauges_export()
+                  if g[0].startswith(f"flash.{prefix}block_")]
+        assert gauges == [[f"flash.{prefix}block_k", labels, 128.0],
+                          [f"flash.{prefix}block_q", labels, 128.0]]
     finally:
         obs_metrics.set_enabled(None)
         obs_metrics.registry().clear()

@@ -1,4 +1,4 @@
-"""The flash forward compiled by the TPU's own compiler for a described
+"""The flash forward and backward compiled by the TPU's own compiler for a described
 v5e, no chip attached: Mosaic refuses what the Pallas interpreter takes (a
 tile that does not fit VMEM, a store that is not lane-aligned), and a
 refusal here costs no chip time.  Nothing runs; no time is read."""
@@ -27,12 +27,15 @@ def one_chip():
 
 # the benchmark's two cells, head size 128 in float32, and a length that
 # only 128 divides
-@pytest.mark.parametrize("bh,s,d,dtype", [
+SHAPES = [
     (8 * 16, 1024, 64, jnp.bfloat16),
     (2 * 32, 4096, 64, jnp.bfloat16),
     (8, 2048, 128, jnp.float32),
     (4, 640, 64, jnp.bfloat16),
-])
+]
+
+
+@pytest.mark.parametrize("bh,s,d,dtype", SHAPES)
 def test_mosaic_takes_the_derived_tiles(one_chip, bh, s, d, dtype):
     x = jax.ShapeDtypeStruct((bh, s, d), dtype, sharding=one_chip)
     fwd = jax.jit(lambda q, k, v: attn._flash_fwd_pallas(
@@ -40,3 +43,18 @@ def test_mosaic_takes_the_derived_tiles(one_chip, bh, s, d, dtype):
         interpret=False))
     compiled = fwd.lower(x, x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("bh,s,d,dtype", SHAPES)
+def test_mosaic_takes_the_backward(one_chip, bh, s, d, dtype):
+    """The backward at its own derived tiles, and under the name the
+    benchmark's ``kernel.flash_bwd_*`` find it by."""
+    x = jax.ShapeDtypeStruct((bh, s, d), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, s), jnp.float32, sharding=one_chip)
+    bwd = jax.jit(lambda q, k, v, o, lse, do: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, scale=d ** -0.5, causal=True, interpret=False))
+    text = bwd.lower(x, x, x, x, lse, x).compile().as_text()
+    calls = [ln.split("=")[0] for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert calls and all("flash_bwd" in c and "attn" not in c
+                         for c in calls), calls

@@ -17,6 +17,7 @@ feed the kernels, so the oracles live in one place.
 Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only fused_bn_inference  # one kernel
         python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
+        python tools/pallas_drive.py --only flash_bwd_tiles  # the backward's
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -172,9 +173,17 @@ FLASH_CELL_SHAPES = [(8, 1024, 16, 64), (2, 4096, 32, 64), (4, 2048, 8, 128)]
 FLASH_SWEEP_TILES = (256, 512, 1024)
 
 
+def _sweep_pairs(S):
+    """The derived default (block None) first, 128 x 128, then every pair
+    of FLASH_SWEEP_TILES that divides ``S``."""
+    return [(None, None), (128, 128)] + [
+        (bq, bk) for bq in FLASH_SWEEP_TILES for bk in FLASH_SWEEP_TILES
+        if S % bq == 0 and S % bk == 0]
+
+
 def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
     """The causal forward alone at every tile pair of FLASH_SWEEP_TILES,
-    at the backward's 128 x 128 and at the derived default (block None):
+    at 128 x 128 and at the derived default (block None):
     one record a pair, with its gap from the derived default's output."""
     import jax
     import jax.numpy as jnp
@@ -187,9 +196,7 @@ def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
     if interpret is None:
         interpret = attn._default_interpret()
     derived = attn.forward_tiles(S, S, D, jnp.dtype(dt).itemsize)
-    pairs = [(None, None), (attn.DEFAULT_BLOCK,) * 2] + [
-        (bq, bk) for bq in FLASH_SWEEP_TILES for bk in FLASH_SWEEP_TILES
-        if S % bq == 0 and S % bk == 0]
+    pairs = _sweep_pairs(S)
     want = None
     for bq, bk in pairs:
         fn = jax.jit(lambda q, k, v, bq=bq, bk=bk: attn._flash_fwd_pallas(
@@ -205,6 +212,49 @@ def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
                "vs_derived_max_abs_err": _err(got, want),
                "fwd_ms": round(_timeit(fn, *qkv, iters=iters), 4),
                "backend": jax.default_backend()}
+
+
+def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
+    """The causal backward alone (delta in XLA and the one ``flash_bwd``
+    call) at the derived default (block None), at 128 x 128 and at every
+    tile pair of FLASH_SWEEP_TILES the compiler takes: one record a pair,
+    with its gap from the derived default's gradients and the VMEM
+    ``backward_vmem_bytes`` reckons for it."""
+    import jax
+    import jax.numpy as jnp
+    from dt_tpu.ops.pallas import attention as attn
+    q, k, v, do = (jnp.asarray(rng.randn(B * H, S, D) * 0.3, dt)
+                   for _ in range(4))
+    if interpret is None:
+        interpret = attn._default_interpret()
+    itemsize = jnp.dtype(dt).itemsize
+    out, lse = attn._flash_fwd_pallas(        # jitted where it is defined
+        q, k, v, scale=D ** -0.5, causal=True, block_q=None, block_k=None,
+        interpret=interpret)
+    derived = attn.backward_tiles(S, S, D, itemsize)
+    pairs = _sweep_pairs(S)
+    want = None
+    for bq, bk in pairs:
+        fn = jax.jit(lambda *a, bq=bq, bk=bk: attn._flash_bwd_pallas(
+            *a, scale=D ** -0.5, causal=True, block_q=bq, block_k=bk,
+            interpret=interpret))
+        tq, tk = bq or derived[0], bk or derived[1]
+        rec = {"kernel": "flash_bwd_tiles",
+               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}",
+               "block_q": tq, "block_k": tk, "derived": bq is None,
+               "grid_steps": B * H * (S // tq) * (S // tk),
+               "vmem_mb": round(attn.backward_vmem_bytes(
+                   tq, tk, S, D, itemsize) / 2 ** 20, 1),
+               "backend": jax.default_backend()}
+        try:
+            got = fn(q, k, v, out, lse, do)
+            want = got if want is None else want  # the derived pair is first
+            rec["vs_derived_max_abs_err"] = _err(got, want)
+            rec["bwd_ms"] = round(_timeit(fn, q, k, v, out, lse, do,
+                                          iters=iters), 4)
+        except Exception as e:  # noqa: BLE001 — a pair Mosaic refuses
+            rec["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+        yield rec
 
 
 def main():
@@ -275,6 +325,14 @@ def main():
                            FLASH_CELL_SHAPES):
             for rec in flash_tiles_sweep(rng, B, S, H, D, dt,
                                          iters=args.iters):
+                print(json.dumps(rec), flush=True)
+
+    # ---- the flash backward alone, by tile pair (PERF.md, PR 31) ---------
+    if wanted("flash_bwd_tiles"):
+        for B, S, H, D in ([(1, 512, 2, 64)] if args.small else
+                           FLASH_CELL_SHAPES):
+            for rec in flash_bwd_tiles_sweep(rng, B, S, H, D, dt,
+                                             iters=args.iters):
                 print(json.dumps(rec), flush=True)
 
 
