@@ -32,6 +32,10 @@ from dt_tpu.data.dataset import (
     SequentialSampler as SequentialSampler,
 )
 from dt_tpu.data.bucket_io import BucketSentenceIter as BucketSentenceIter
+from dt_tpu.data.block_diffusion import (
+    BlockDiffusionIter as BlockDiffusionIter,
+    block_diffusion_noise as block_diffusion_noise,
+)
 from dt_tpu.data.recordio import (
     RecordIOReader as RecordIOReader,
     RecordIOWriter as RecordIOWriter,

@@ -33,6 +33,7 @@ from dt_tpu.models.transformer import TransformerLM as TransformerLM
 from dt_tpu.models.transformer import (
     PipelinedTransformerLM as PipelinedTransformerLM)
 from dt_tpu.models.hybrid_lm import HybridLM as HybridLM
+from dt_tpu.models.routed_lm import RoutedLM as RoutedLM
 from dt_tpu.models.ssd import (SSD as SSD, ssd_loss as ssd_loss,
                                ssd_detect as ssd_detect)
 from dt_tpu.models.rcnn import (FasterRCNNMini as FasterRCNNMini,
@@ -53,7 +54,8 @@ def create(name: str, **kwargs):
     inception-v3, inception-bn, inception-v4, inception-resnet-v2, googlenet,
     resnext50/101/152, mobilenet[_v2], densenet121/161/169/201, squeezenet,
     lstm_lm, transformer_lm, hybrid_lm (state-space and attention layers by
-    a pattern)."""
+    a pattern), routed_lm (rotary attention and routed experts, trained
+    by diffusion over blocks)."""
     key = name.lower().replace("-", "_")
     if key in _REGISTRY:
         return _REGISTRY[key](**kwargs)
@@ -90,6 +92,7 @@ def _setup_registry():
     register("transformer_lm_pipelined",
              lambda **kw: PipelinedTransformerLM(**kw))
     register("hybrid_lm", lambda **kw: HybridLM(**kw))
+    register("routed_lm", lambda **kw: RoutedLM(**kw))
     register("ssd", lambda **kw: SSD(**kw))
     register("faster_rcnn", lambda **kw: FasterRCNNMini(**kw))
 

@@ -159,8 +159,25 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "model.remat_blocks": ("gauge", "1 where each block's activations are "
                                     "recomputed in the backward pass "
                                     "(linen.remat per block), else 0"),
-    # set at trace time, once per distinct (s, sk, d, dtype), label `shape`
-    # (ops/pallas/attention.py)
+    # set at each flushed step of fit from what a routed expert layer
+    # (parallel/moe.py RoutedExperts) counted in it, label `layer`
+    # (training/module.py _count_step)
+    "moe.held_load_share_pct": ("gauge", "the step's assignments to the "
+                                         "experts this chip holds, over "
+                                         "all the layer made (T x k); "
+                                         "100 x held / total is even"),
+    "moe.fullest_over_mean_load": ("gauge", "the fullest held expert's "
+                                            "assignments over the held "
+                                            "experts' mean"),
+    "moe.buffer_fill_pct": ("gauge", "rows of the layer's static buffer "
+                                     "that held an assignment, over "
+                                     "buffer_rows"),
+    "moe.overflow_assignments": ("gauge", "assignments to held experts "
+                                          "that found no room in the "
+                                          "buffer and were dropped from "
+                                          "the step's result"),
+    # set at trace time, once per distinct (s, sk, d, dtype), label `shape`,
+    # and under a mask rule label `mask` (ops/pallas/attention.py)
     "flash.block_q": ("gauge", "query rows in one tile of the flash "
                                "forward, derived from the shape "
                                "(forward_tiles) or given"),

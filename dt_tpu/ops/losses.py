@@ -36,6 +36,22 @@ def softmax_cross_entropy(logits: Array, labels: Array,
     return jnp.mean(nll)
 
 
+def weighted_masked_cross_entropy(logits: Array, labels: Array) -> Array:
+    """The loss of training by diffusion over blocks: ``labels`` (..., 2)
+    float32 hold a target id and a weight for every row of ``logits``
+    (..., V) (``data.block_diffusion_noise``: ``1 / t_b`` where the position
+    was masked, 0 where not); the weighted ``-log p(target)`` summed and
+    divided by the number of rows, masked or not."""
+    z = logits.astype(jnp.float32)
+    # the target's logit as a reduction over a one-hot mask, beside the
+    # log-sum-exp: no float32 log-softmax of the logits is written out
+    hot = jnp.arange(z.shape[-1], dtype=jnp.int32) \
+        == labels[..., 0].astype(jnp.int32)[..., None]
+    nll = jax.nn.logsumexp(z, axis=-1) - jnp.sum(jnp.where(hot, z, 0.0),
+                                                 axis=-1)
+    return jnp.sum(labels[..., 1] * nll) / nll.size
+
+
 def l2_loss(pred: Array, label: Array) -> Array:
     """Reference: LinearRegressionOutput (0.5*(p-y)^2 mean)."""
     return 0.5 * jnp.mean(jnp.square(pred.astype(jnp.float32) - label))

@@ -41,10 +41,19 @@ compiler (given twice ``VMEM_BUDGET``) refuses from about 48,000
 (``ring_attention`` is the path beyond one device).  ``DEFAULT_BLOCK``
 (128) is what callers pad sequences to.
 
+Beside ``causal`` both kernels take a mask rule, ``BlockDiffusionMask``
+(training by diffusion over blocks: ``[noisy ; clean]`` halves, a block
+diagonal, two block-causal triangles and an empty quadrant, about a quarter
+of the square): a tile's fate (skip, run unmasked, run masked) and what a
+skipped step fetches follow from its offsets, as under ``causal``; the
+kernels are then named ``flash_fwd_bd`` and ``flash_bwd_bd``.  The rule is
+static: without it the programs are what they were.
+
 Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
-kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``
-and ``GroupedQueryAttention(attention="flash")`` select it.
+kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``,
+``GroupedQueryAttention(attention="flash")`` and ``RotaryAttention(attention=
+"flash")`` (``models/routed_lm.py``, under the mask rule) select it.
 
 Parity: ``dt_tpu.parallel.ring_attention.full_attention`` is the oracle;
 tests cover fwd/bwd, causal and full, interpret (CPU) mode.
@@ -52,6 +61,7 @@ tests cover fwd/bwd, causal and full, interpret (CPU) mode.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 from typing import Optional
@@ -115,14 +125,163 @@ def _largest_tiles(candidates, s: int, sk: int, fits):
     return max(fit or pairs[-1:], key=lambda p: (p[0] * p[1], p[0]))
 
 
-def forward_tiles(s: int, sk: int, d: int, itemsize: int):
+def forward_tiles(s: int, sk: int, d: int, itemsize: int, mask=None):
     """The forward's (block_q, block_k) for query length ``s``, key length
     ``sk``, head size ``d`` and operands of ``itemsize`` bytes: the largest
     pair of ``FORWARD_TILES`` (``_largest_tiles``) that keeps
-    ``tile_vmem_bytes`` within ``VMEM_BUDGET``."""
+    ``tile_vmem_bytes`` within ``VMEM_BUDGET``.  Under a
+    ``BlockDiffusionMask`` a tile lies in one half, so it divides
+    ``mask.half``: at 4,096 positions a half 1,024 x 1,024, which runs 1.5
+    times the rule's area where 512 x 512 runs 1.25 times and takes 1.6
+    times as long (9.36 against 14.95 ms a call, PERF.md section 6, PR
+    34)."""
+    if mask is not None:
+        s = sk = mask.half
     return _largest_tiles(
         FORWARD_TILES, s, sk,
         lambda bq, bk: tile_vmem_bytes(bq, bk, d, itemsize) <= VMEM_BUDGET)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The mask of training by diffusion over blocks (BD3-LM, SDAR), a rule
+    the kernels take beside ``causal``.  The sequence is two halves of
+    ``half`` positions each, ``[noisy ; clean]``, both cut into blocks of
+    ``block`` positions; with ``qb``, ``kb`` the blocks of a query and a key
+    inside their halves,
+
+        noisy query, noisy key:  qb == kb      (a block diagonal)
+        noisy query, clean key:  kb <  qb      (a block-causal triangle)
+        clean query, clean key:  kb <= qb      (the other)
+        clean query, noisy key:  never         (the empty quadrant)
+
+    so about ``half^2`` of the ``4 half^2`` square is needed.  Static: a
+    tile's fate (skip, run unmasked, run masked) follows from its offsets,
+    and a skipped step's index map names a tile its neighbour fetches
+    anyway.  ``half`` is a multiple of ``block`` and of the tiles (a caller
+    pads each half: a padded key lies in a block after every real query's,
+    so the rule itself hides it).  The methods take a tile's grid indices
+    as Python or traced integers."""
+    half: int
+    block: int
+
+    _FAR = 1 << 30
+
+    def __post_init__(self):
+        if self.block < 1 or self.half % self.block:
+            raise ValueError(f"a half of {self.half} positions is not whole "
+                             f"blocks of {self.block}")
+
+    def allowed(self, q_pos, k_pos):
+        """The rule, element by element, for positions in ``[0, 2 half)``:
+        what a dense masked softmax applies (the kernels' oracle)."""
+        q_noisy, k_noisy = q_pos < self.half, k_pos < self.half
+        qb, kb = (q_pos % self.half) // self.block, \
+            (k_pos % self.half) // self.block
+        return jnp.where(k_noisy, q_noisy & (qb == kb),
+                         jnp.where(q_noisy, kb < qb, kb <= qb))
+
+    def _blocks(self, first, n):
+        """First and last block of ``n`` positions from ``first`` (inside a
+        half)."""
+        return first // self.block, (first + n - 1) // self.block
+
+    def _band(self, q_noisy, k_noisy):
+        """``(c1, c2)``: a pair is allowed where ``qb - c2 <= kb <= qb -
+        c1``; the empty quadrant's band is out of reach."""
+        c1 = jnp.where(k_noisy, jnp.where(q_noisy, 0, self._FAR),
+                       jnp.where(q_noisy, 1, 0))
+        c2 = jnp.where(k_noisy, 0, self._FAR)
+        return c1, c2
+
+    def tile(self, qi, ki, block_q: int, block_k: int):
+        """The (qi, ki) tile -> (runs, unmasked, q0, k0, c1, c2): whether
+        any pair of it is allowed, whether all are, its first query and key
+        position inside their halves, and the band its pairs are held to."""
+        nq, nk = self.half // block_q, self.half // block_k
+        q_noisy, k_noisy = qi < nq, ki < nk
+        q0 = (qi - jnp.where(q_noisy, 0, nq)) * block_q
+        k0 = (ki - jnp.where(k_noisy, 0, nk)) * block_k
+        (qb_lo, qb_hi), (kb_lo, kb_hi) = self._blocks(q0, block_q), \
+            self._blocks(k0, block_k)
+        c1, c2 = self._band(q_noisy, k_noisy)
+        # kb - qb takes every value between its least and its largest
+        runs = (kb_lo - qb_hi <= -c1) & (kb_hi - qb_lo >= -c2)
+        unmasked = (kb_hi - qb_lo <= -c1) & (kb_lo - qb_hi >= -c2)
+        return runs, unmasked, q0, k0, c1, c2
+
+    def key_tile(self, qi, ki, block_q: int, block_k: int):
+        """The key tile the forward's step (qi, ki) fetches: ``ki`` where
+        the tile runs, else a tile the query tile needs anyway (the nearest
+        before it, or the first), so that a skipped step fetches nothing."""
+        nq, nk = self.half // block_q, self.half // block_k
+        q_noisy = qi < nq
+        q0 = (qi - jnp.where(q_noisy, 0, nq)) * block_q
+        qb_lo, qb_hi = self._blocks(q0, block_q)
+        # noisy keys: the tiles that hold the query tile's own blocks
+        a = qb_lo * self.block // block_k
+        b = jnp.minimum(((qb_hi + 1) * self.block - 1) // block_k, nk - 1)
+        # clean keys: the tiles up to the last block seen (before it, for a
+        # noisy query); -1 where there is none
+        last = jnp.where(q_noisy, qb_hi * self.block,
+                         (qb_hi + 1) * self.block) - 1
+        c = jnp.minimum(last // block_k, nk - 1)
+        in_noisy = jnp.where(q_noisy, jnp.clip(ki, a, b), nk)
+        in_clean = jnp.where(c >= 0, nk + jnp.clip(ki - nk, 0, c), b)
+        return jnp.where(ki < nk, in_noisy, in_clean)
+
+    def query_tile(self, ki, qi, block_q: int, block_k: int):
+        """The query tile the backward's step (ki, qi) fetches, as
+        ``key_tile``: ``qi`` where the tile runs, else one the key tile
+        needs anyway."""
+        nq, nk = self.half // block_q, self.half // block_k
+        k_noisy = ki < nk
+        k0 = (ki - jnp.where(k_noisy, 0, nk)) * block_k
+        kb_lo, kb_hi = self._blocks(k0, block_k)
+        # a noisy key: the noisy queries of its own blocks, no clean one
+        a = kb_lo * self.block // block_q
+        b = jnp.minimum(((kb_hi + 1) * self.block - 1) // block_q, nq - 1)
+        # a clean key: noisy queries from the first tile with a block after
+        # its first (none: nq), clean queries from its first block's tile
+        s1 = (kb_lo + 1) * self.block // block_q
+        s2 = kb_lo * self.block // block_q
+        clean_from = nq + jnp.maximum(qi - nq, s2)
+        for_clean = jnp.where((qi < nq) & (s1 < nq), jnp.maximum(qi, s1),
+                              clean_from)
+        return jnp.where(k_noisy, jnp.clip(qi, a, b), for_clean)
+
+    def tiles_run(self, block_q: int, block_k: int) -> int:
+        """How many of the grid's tiles run (of ``4 half^2 / (block_q
+        block_k)``): what pruning leaves."""
+        nq, nk = 2 * self.half // block_q, 2 * self.half // block_k
+        with jax.ensure_compile_time_eval():    # asked while tracing
+            return sum(bool(self.tile(qi, ki, block_q, block_k)[0])
+                       for qi in range(nq) for ki in range(nk))
+
+
+def _when_block_diffusion(tile, rule: BlockDiffusionMask, qi, ki,
+                          block_q: int, block_k: int):
+    """Run ``tile(band)`` for the (qi, ki) tile under ``rule``: not at all
+    where no pair of it is allowed, with ``band`` None where all are, else
+    with ``band = (q0, k0, c1, c2)`` for ``_band_mask``."""
+    runs, unmasked, q0, k0, c1, c2 = rule.tile(qi, ki, block_q, block_k)
+    pl.when(runs & jnp.logical_not(unmasked))(
+        functools.partial(tile, (q0, k0, c1, c2)))
+    pl.when(runs & unmasked)(functools.partial(tile, None))
+
+
+def _band_mask(rule: BlockDiffusionMask, band, q_axis: int, shape):
+    """The allowed pairs of a masked tile of ``shape`` whose queries lie
+    along ``q_axis``: the block of each query (a column or a row vector),
+    of each key (the other), and the band between them."""
+    q0, k0, c1, c2 = band
+    def blocks(first, axis):
+        vec = tuple(n if a == axis else 1 for a, n in enumerate(shape))
+        pos = first + lax.broadcasted_iota(jnp.int32, vec, axis)
+        shift = rule.block.bit_length() - 1
+        return pos >> shift if rule.block == 1 << shift else pos // rule.block
+    gap = blocks(k0, 1 - q_axis) - blocks(q0, q_axis)       # kb - qb
+    return (gap <= -c1) & (gap >= -c2)
 
 
 def _when_causal(tile, qi, ki, block_q: int, block_k: int):
@@ -141,7 +300,7 @@ def _when_causal(tile, qi, ki, block_q: int, block_k: int):
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                  acc_ref, m_ref, l_ref, *,
                  scale: float, causal: bool, block_q: int, block_k: int,
-                 n_k: int):
+                 n_k: int, mask: Optional[BlockDiffusionMask] = None):
     """One (bh, q_block, k_block) grid step; kv axis is sequential, so the
     VMEM scratch (acc, m, l) carries the online softmax across it."""
     ki = pl.program_id(2)
@@ -154,13 +313,18 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     qi = pl.program_id(1)
 
-    def _attend(masked: bool):
-        # operands as stored: the MXU takes bfloat16 at full rate and
-        # accumulates float32; float32 inputs multiply as before
+    def _attend(masked):
+        # ``masked``: False, True (the causal diagonal) or a band of
+        # ``mask``.  Operands as stored: the MXU takes bfloat16 at full rate
+        # and accumulates float32; float32 inputs multiply as before
         q, k, v = q_ref[0], k_ref[0], v_ref[0]        # (BQ, D), (BK, D) x2
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if masked:
+        if mask is not None:
+            if masked is not None:
+                s = jnp.where(_band_mask(mask, masked, 0, s.shape), s,
+                              NEG_INF)
+        elif masked:
             # q_pos >= k_pos, the tile's offsets moved to the scalar side
             row_less_col = lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0) - lax.broadcasted_iota(
@@ -179,7 +343,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    if causal:
+    if mask is not None:
+        _when_block_diffusion(_attend, mask, qi, ki, block_q, block_k)
+    elif causal:
         _when_causal(_attend, qi, ki, block_q, block_k)
     else:
         _attend(False)
@@ -201,9 +367,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
-                      interpret):
+                      interpret, mask=None):
     """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S)).  ``block_q`` /
     ``block_k`` of None are derived from the shapes (``forward_tiles``).
 
@@ -214,15 +380,18 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     bh, s, d = q3.shape
     sk = k3.shape[1]
     if block_q is None or block_k is None:
-        dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize)
+        dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize, mask)
         block_q, block_k = block_q or dq, block_k or dk
-    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k)
+    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask)
     n_q = s // block_q
     n_k = sk // block_k
     kern = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k)
-    if causal:
+        block_k=block_k, n_k=n_k, mask=mask)
+    if mask is not None:
+        kv_map = lambda b, qi, ki: (
+            b, mask.key_tile(qi, ki, block_q, block_k), 0)
+    elif causal:
         # a skipped step names the last block its query tile needs: the
         # same block as the step before, so nothing is fetched for it
         kv_map = lambda b, qi, ki: (
@@ -232,6 +401,9 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     sub = block_q // _LANES
     out, lse = pl.pallas_call(
         kern,
+        # under a mask rule the forward has a name of its own in a trace;
+        # otherwise its events carry its caller's, as they always have
+        **({} if mask is None else {"name": "flash_fwd_bd"}),
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
@@ -261,18 +433,27 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
 
 
 @functools.lru_cache(maxsize=None)
-def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False) -> None:
-    """Record, once per distinct shape and tile, what the forward (or with
-    ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
+def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
+                mask=None) -> None:
+    """Record, once per distinct shape, tile and mask rule, what the forward
+    (or with ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
     flash_bwd_tiles``) debug line and the metrics plane's gauges, so that a
-    shape that falls back to 128 is seen.  Trace time only."""
+    shape that falls back to 128 is seen.  Under a mask rule the line and
+    the gauges' label ``mask`` name it, with the tiles that run of the
+    grid's.  Trace time only."""
     s, sk, d, dtype = shape
+    rule = "" if mask is None else (
+        f"block_diffusion.half{mask.half}.block{mask.block}.run"
+        f"{mask.tiles_run(block_q, block_k)}of"
+        f"{(s // block_q) * (sk // block_k)}")
     logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
-                 "block_k=%d", "bwd_" if bwd else "", s, sk, d, dtype,
-                 block_q, block_k)
+                 "block_k=%d%s", "bwd_" if bwd else "", s, sk, d, dtype,
+                 block_q, block_k, " mask=" + rule if rule else "")
     if obs_metrics.enabled():
         reg = obs_metrics.registry()
         labels = {"shape": f"{s}x{sk}x{d}.{dtype}"}
+        if rule:
+            labels["mask"] = rule
         if bwd:
             reg.gauge("flash.bwd_block_q", block_q, labels)
             reg.gauge("flash.bwd_block_k", block_k, labels)
@@ -297,19 +478,25 @@ def backward_vmem_bytes(block_q: int, block_k: int, s: int, d: int,
     return blocks + scratch + scores
 
 
-def backward_tiles(s: int, sk: int, d: int, itemsize: int):
+def backward_tiles(s: int, sk: int, d: int, itemsize: int, mask=None):
     """The backward's (block_q, block_k): the largest pair of
     ``BACKWARD_TILES`` (``_largest_tiles``) that keeps the backward's own
-    reckoning, ``backward_vmem_bytes``, within ``VMEM_BUDGET``."""
+    reckoning, ``backward_vmem_bytes`` (dq's scratch is of the whole
+    sequence ``s``), within ``VMEM_BUDGET``; under a ``BlockDiffusionMask``
+    a tile divides its half."""
+    whole = s
+    if mask is not None:
+        s = sk = mask.half
     return _largest_tiles(
         BACKWARD_TILES, s, sk, lambda bq, bk: backward_vmem_bytes(
-            bq, bk, s, d, itemsize) <= VMEM_BUDGET)
+            bq, bk, whole, d, itemsize) <= VMEM_BUDGET)
 
 
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       scale: float, causal: bool, block_q: int, block_k: int,
-                      n_q: int, n_k: int):
+                      n_q: int, n_k: int,
+                      mask: Optional[BlockDiffusionMask] = None):
     """One (bh, k_block, q_block) grid step of the backward.  The tile is
     held keys by queries (``s^T = k q^T``): the log-sum-exp and delta of the
     query rows are then lane rows that broadcast down the sublanes, and
@@ -326,13 +513,18 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _tile(masked: bool):
-        # operands as stored, float32 accumulation: the forward's rule
+    def _tile(masked):
+        # ``masked`` as the forward's.  Operands as stored, float32
+        # accumulation: the forward's rule
         q, do, k, v = q_ref[0], do_ref[0], k_ref[0], v_ref[0]
         nt = (((1,), (1,)), ((), ()))
         st = jax.lax.dot_general(k, q, nt,
                                  preferred_element_type=jnp.float32) * scale
-        if masked:
+        if mask is not None:
+            if masked is not None:
+                st = jnp.where(_band_mask(mask, masked, 1, st.shape), st,
+                               NEG_INF)
+        elif masked:
             # q_pos >= k_pos, the tile's offsets moved to the scalar side
             col_less_row = lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1) - lax.broadcasted_iota(
@@ -355,7 +547,9 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if mask is not None:
+        _when_block_diffusion(_tile, mask, qi, ki, block_q, block_k)
+    elif causal:
         _when_causal(_tile, qi, ki, block_q, block_k)    # as the forward
     else:
         _tile(False)
@@ -371,9 +565,9 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask"))
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
-                      block_q=None, block_k=None):
+                      block_q=None, block_k=None, mask=None):
     """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
     (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
     ``flash_bwd``.  Its tiles come from the shapes (``backward_tiles``).
@@ -383,17 +577,20 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     bh, s, d = q3.shape
     sk = k3.shape[1]
     if block_q is None or block_k is None:
-        tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize)
+        tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize, mask)
         block_q, block_k = block_q or tq, block_k or tk
-    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True)
+    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
+                mask=mask)
     n_q, n_k = s // block_q, sk // block_k
     # delta = rowsum(do * out), float32, in XLA: one pass over two arrays
     # the step already holds; a row vector per head, as the log-sum-exp
     delta = (do3.astype(jnp.float32) * o3.astype(jnp.float32)).sum(-1)
     kern = functools.partial(
         _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_q=n_q, n_k=n_k)
-    if causal:
+        block_k=block_k, n_q=n_q, n_k=n_k, mask=mask)
+    if mask is not None:
+        first = lambda ki, qi: mask.query_tile(ki, qi, block_q, block_k)
+    elif causal:
         # a skipped step names the first query block its key tile needs:
         # the block the first step that runs will want, fetched once
         first = lambda ki, qi: jnp.minimum(
@@ -407,7 +604,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
     dq, dk, dv = pl.pallas_call(
         kern,
-        name="flash_bwd",
+        name="flash_bwd" if mask is None else "flash_bwd_bd",
         grid=(bh, n_k, n_q),
         in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
         out_specs=[
@@ -432,27 +629,29 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, mask):
     out, _ = _flash_fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               interpret=interpret)
+                               interpret=interpret, mask=mask)
     return out
 
 
-def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
+                    mask):
     out, lse = _flash_fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
                                  block_q=block_q, block_k=block_k,
-                                 interpret=interpret)
+                                 interpret=interpret, mask=mask)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do3):
+def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, mask, res,
+                    do3):
     # the forward's tiles stop here: the backward derives its own from the
     # shapes (backward_tiles), whatever the forward was given
     q3, k3, v3, out, lse = res
     return _flash_bwd_pallas(q3, k3, v3, out, lse, do3, scale=scale,
-                             causal=causal, interpret=interpret)
+                             causal=causal, interpret=interpret, mask=mask)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -462,7 +661,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    mask: Optional[BlockDiffusionMask] = None) -> jax.Array:
     """Fused attention, (B, S, H, D) layout (``full_attention`` oracle).
 
     Sequence lengths must be multiples of ``DEFAULT_BLOCK`` (pad upstream;
@@ -472,6 +672,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     lengths.  Differentiable via the Pallas flash backward
     (``_flash_bwd_pallas``), whose tiles are derived from the shapes
     (``backward_tiles``) whatever the forward's are.
+
+    ``mask`` is a rule beside ``causal`` (and instead of it): a
+    ``BlockDiffusionMask(half, block)`` over ``2 half`` positions, queries
+    and keys alike, each half a multiple of ``DEFAULT_BLOCK``.  Both kernels
+    prune, mask and fetch tile by tile by the rule.
     """
     if interpret is None:
         interpret = _default_interpret()
@@ -479,7 +684,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scale = 1.0 / (q.shape[-1] ** 0.5)
     b, s, h, d = q.shape
     sk = k.shape[1]
-    for n, block in ((s, block_q), (sk, block_k)):
+    if mask is not None and (causal or s != sk or s != 2 * mask.half):
+        raise ValueError(f"{mask} is a rule over {2 * mask.half} positions, "
+                         f"queries and keys alike, and not beside causal: "
+                         f"got ({s}, {sk}), causal={causal}")
+    for n, block in ((s if mask is None else mask.half, block_q),
+                     (sk if mask is None else mask.half, block_k)):
         block = DEFAULT_BLOCK if block is None else block
         if n % block or block % _LANES:
             raise ValueError(f"seq lengths ({s}, {sk}) must be multiples "
@@ -487,5 +697,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              f"of {_LANES}")
     to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
-                  interpret)
+                  interpret, mask)
     return jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2)

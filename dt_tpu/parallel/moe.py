@@ -116,3 +116,153 @@ class MoEMLP(linen.Module):
         xout = ep(xout, (self.axis, None, None))
         out = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), xout)
         return out.reshape(b, s, d)
+
+
+# ---------------------------------------------------------------------------
+# k > 1 routing over a share of the experts: what expert parallelism asks of
+# one chip.  The layer is told which experts it holds, routes over all of
+# them, and computes the part of the result that its own give.
+# ---------------------------------------------------------------------------
+
+#: the columns of the ``counters`` a ``RoutedExperts`` layer sows for each
+#: row of the batch: that row's assignments to each expert held, in order,
+#: then their sum, those among them that found no room in the buffer, and
+#: all the row made (``S x k``)
+COUNTER_TAIL = ("held", "overflow", "assignments")
+
+
+def route_top_k(logits: Array, k: int):
+    """``logits`` (T, E) float32 -> (experts (T, k) int32, weights (T, k),
+    probs (T, E)): the softmax over all ``E``, its ``k`` largest, and their
+    weights renormalised to sum to one a token."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights, probs
+
+
+def load_balancing_term(experts: Array, probs: Array) -> Array:
+    """Switch eq. 4 for ``k`` experts a token: ``E * sum_e f_e P_e`` with
+    ``f_e`` the assignments to expert ``e`` over the tokens (they sum to
+    ``k``; a count, so no gradient) and ``P_e`` the mean router
+    probability; ``k`` where the load is even."""
+    t, e = probs.shape
+    f = jnp.zeros((e,), jnp.float32).at[experts.reshape(-1)].add(1.0) / t
+    return e * jnp.sum(jax.lax.stop_gradient(f) * jnp.mean(probs, axis=0))
+
+
+def sort_held(experts: Array, first: int, count: int, rows: int):
+    """The assignments ``experts`` (T, k) to the ``count`` experts from
+    ``first``, sorted by expert into a buffer of ``rows`` rows ->
+    (assignment (rows,), sizes (count,), held (count,)): the flat index
+    ``token * k + slot`` each row holds, how many rows each expert has in
+    the buffer, and how many assignments it had (``held - sizes`` found no
+    room: the buffer fills in expert order).  Rows past the load hold
+    assignments to experts that are not here, in order; their weight is
+    zero (``RoutedExperts``)."""
+    local = experts.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(local, stable=True)[:rows].astype(jnp.int32)
+    held = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)[:count]
+    ends = jnp.minimum(jnp.cumsum(held), rows)
+    sizes = jnp.diff(ends, prepend=0)
+    return order, sizes, held
+
+
+class RoutedExperts(linen.Module):
+    """A layer of ``num_experts`` gated-SiLU experts, ``top_k`` a token, of
+    which this chip holds ``held = (first, count)`` (all of them where
+    None)::
+
+        p = softmax_f32(x Wr) over num_experts ;  S = top_k(p)
+        w_e = p_e / sum_S p
+        y = sum_{e in S, e held} w_e * Wdown_e(silu(x Wgate_e) * (x Wup_e))
+
+    What the experts that are not held would have added is left out: on one
+    chip the layer runs without its exchange, and no code stands in for the
+    other chips.  The router is float32 at its full width (its product at
+    ``highest`` precision).  The assignments to held experts are sorted by
+    expert into a buffer of ``buffer_rows`` rows, the receive side of an
+    expert-parallel exchange, and three grouped products
+    (``jax.lax.ragged_dot``: on the TPU one grouped kernel each) run over
+    the whole buffer: rows past the load are padding the products still
+    multiply (they go through the last expert with weight zero), so a step
+    costs the same whatever the router decides.  ``buffer_rows=None`` is
+    the exact worst case ``T x top_k``.  An assignment that finds no room
+    is dropped from the result and counted, never silently lost.
+
+    Sows ``aux_weight`` times the Switch load-balancing term over all
+    ``num_experts`` router outputs under ``("aux_loss", "load_balance")``
+    (``Module`` adds the collection to the objective), and under
+    ``("counters", "moe")`` for each row of the batch its assignments to
+    each held expert, their sum, those dropped, and all it made
+    (``COUNTER_TAIL``):
+    ``Module`` hands them to the host with the metric's statistics.
+    ``jax.named_scope``s ``route``, ``dispatch``, ``experts`` and
+    ``combine`` tell the parts apart in an operation's scope path."""
+    num_experts: int
+    top_k: int
+    intermediate: int
+    held: Optional[tuple] = None          # (first, count)
+    buffer_rows: Optional[int] = None     # None: T x top_k
+    aux_weight: float = 0.0
+    dtype: Any = jnp.float32
+
+    @linen.compact
+    def __call__(self, x: Array) -> Array:
+        b, s, d = x.shape
+        k = self.top_k
+        first, count = self.held or (0, self.num_experts)
+        tokens = x.reshape(b * s, d)
+        t = b * s
+        rows = t * k if self.buffer_rows is None else int(self.buffer_rows)
+        init = linen.initializers.normal(0.02)
+        router = self.param("router", init, (d, self.num_experts),
+                            jnp.float32)
+        w_gate, w_up = (self.param(n, init, (count, d, self.intermediate),
+                                   jnp.float32) for n in ("gate", "up"))
+        w_down = self.param("down", init, (count, self.intermediate, d),
+                            jnp.float32)
+
+        with jax.named_scope("route"):
+            logits = jnp.dot(tokens.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            experts, weights, probs = route_top_k(logits, k)
+            if self.aux_weight:
+                self.sow("aux_loss", "load_balance", self.aux_weight
+                         * load_balancing_term(experts, probs))
+            order, sizes, held = sort_held(experts, first, count, rows)
+            placed = jnp.arange(rows) < jnp.sum(sizes)
+            # the padding goes through the last expert: every row of the
+            # buffer is in a group, and the products' cost is the buffer's
+            groups = sizes.at[count - 1].add(rows - jnp.sum(sizes))
+            row_weight = jnp.where(placed, weights.reshape(-1)[order], 0.0)
+            self._count(experts, first, count, order, placed, b, s * k)
+        with jax.named_scope("dispatch"):
+            buf = jnp.take(tokens, order // k, axis=0).astype(self.dtype)
+        with jax.named_scope("experts"):
+            grouped = lambda lhs, w: jax.lax.ragged_dot(  # noqa: E731
+                lhs, w.astype(self.dtype), groups,
+                preferred_element_type=jnp.float32)
+            hidden = jax.nn.silu(grouped(buf, w_gate)) * grouped(buf, w_up)
+            out = grouped(hidden.astype(self.dtype), w_down)
+        with jax.named_scope("combine"):
+            out = (out * row_weight[:, None]).astype(self.dtype)
+            y = jnp.zeros((t, d), self.dtype).at[order // k].add(out)
+        return y.reshape(b, s, d)
+
+    def _count(self, experts, first, count, order, placed, b, per_row):
+        """Sow the layer's counters: per row of the batch, its assignments
+        to each held expert, their sum, those among them that found no room
+        in the buffer, and all it made."""
+        local = (experts - first).reshape(b, per_row)
+        here = (local >= 0) & (local < count)
+        to_each = jnp.sum(jax.nn.one_hot(jnp.where(here, local, count),
+                                         count + 1, dtype=jnp.int32),
+                          axis=1)[:, :count]
+        placed_of = jnp.zeros((b,), jnp.int32).at[order // per_row].add(
+            placed.astype(jnp.int32))
+        held = jnp.sum(to_each, axis=1)
+        self.sow("counters", "moe", jnp.concatenate(
+            [to_each, held[:, None], (held - placed_of)[:, None],
+             jnp.full((b, 1), per_row, jnp.int32)], axis=1))

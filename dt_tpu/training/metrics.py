@@ -70,6 +70,13 @@ def topk_hit(logits, labels, k):
     return above < k
 
 
+def weighted_label_logp(logits, labels):
+    """``labels`` (..., 2) float32 ``[target id, weight]``
+    (``data.block_diffusion_noise``) -> float32 ``weight * log
+    softmax(logits)[target]``."""
+    return labels[..., 1] * label_logp(logits, labels[..., 0])
+
+
 def device_form(metric) -> Optional[Dict[str, Callable]]:
     """``metric.device_stats()``, or None (the host path) where a subclass
     overrode ``update`` below the class whose ``update_reduced`` would stand
@@ -91,7 +98,10 @@ def stats_key(stats: Optional[Dict[str, Callable]]) -> Optional[Tuple[str, ...]]
 def device_reduce(stats: Dict[str, Callable], logits, labels):
     """What a compiled step returns in the logits' place: each statistic of
     ``stats`` by name, with the logits' shape less the class axis."""
-    labels = labels.reshape(logits.shape[:-1])
+    rows = logits.shape[:-1]
+    if labels.size == math.prod(rows):
+        labels = labels.reshape(rows)
+    # else several numbers a row (a target and a weight): as they are
     return {name: f(logits, labels) for name, f in stats.items()}
 
 
@@ -296,6 +306,33 @@ class Perplexity(CrossEntropy):
         return self.name, float(np.exp(self.sum_metric / self.num_inst))
 
 
+class WeightedCrossEntropy(EvalMetric):
+    """The objective of training by diffusion over blocks
+    (``ops.losses.weighted_masked_cross_entropy``) as a metric: ``labels``
+    (..., 2) hold a target id and a weight a row; the mean over all rows of
+    ``-weight * log p(target)``.  ``preds`` are probabilities."""
+
+    def __init__(self, eps: float = 1e-12, name: str = "weighted-ce"):
+        self.eps = eps
+        super().__init__(name)
+
+    def update(self, labels, preds):
+        labels = _np(labels).reshape(-1, 2)
+        preds = _np(preds).reshape(labels.shape[0], -1)
+        p = preds[np.arange(labels.shape[0]), labels[:, 0].astype(int)]
+        self.sum_metric += float(
+            -(labels[:, 1] * np.log(np.maximum(p, self.eps))).sum())
+        self.num_inst += labels.shape[0]
+
+    def device_stats(self):
+        return {"weighted_label_logp": weighted_label_logp}
+
+    def update_reduced(self, labels, reduced):
+        weighted = reduced["weighted_label_logp"].reshape(-1)
+        self.sum_metric += float(-weighted.sum())
+        self.num_inst += weighted.size
+
+
 class Loss(EvalMetric):
     """Running mean of a scalar loss (reference ``mx.metric.Loss``)."""
 
@@ -377,6 +414,7 @@ _REGISTRY: Dict[str, Callable[..., EvalMetric]] = {
     "cross-entropy": CrossEntropy,
     "nll_loss": NegativeLogLikelihood,
     "perplexity": Perplexity,
+    "weighted-ce": WeightedCrossEntropy,
     "loss": Loss,
 }
 

@@ -56,6 +56,11 @@ _ROW_DISPATCHED = obs_trace.STEP_ROW_FIELDS.index("dispatched")
 _ROW_TOTAL_NS = obs_trace.STEP_ROW_FIELDS.index("total_ns")
 
 
+#: the key, in what a step hands the host, of what a layer sowed into the
+#: ``counters`` collection, before the layer's path
+_COUNTERS = "counters/"
+
+
 def softmax_ce_loss(logits, labels):
     return losses_lib.softmax_cross_entropy(logits, labels)
 
@@ -212,6 +217,12 @@ class Module:
         # the last fit call's flushed steps by the path their metric took
         # (the same two numbers are the fit.metric_*_steps gauges)
         self.metric_flushes = {"device": 0, "host": 0}
+        # what the model's layers counted for each row of the batch in the
+        # last fit call (the ``counters`` collection: parallel/moe.py
+        # RoutedExperts), by the layer's path: over the steps flushed the
+        # sum and the largest step's value of each column, and the steps.
+        # They reach the host with the metric's statistics (device form)
+        self.step_counters: Dict[str, dict] = {}
         self._fallback_said = set()  # metric names the fallback was logged for
         # Gradient sync across worker PROCESSES.  "mesh" = gradients ride the
         # XLA allreduce inside the jit step (TPU pod / single process — the
@@ -332,9 +343,11 @@ class Module:
             ``aux_loss`` collection (e.g. the MoE load-balancing term,
             ``parallel/moe.py``); they are added to the objective here —
             without the collection in ``mutable`` flax drops sows
-            silently."""
+            silently.  What layers sow into ``counters`` (integers for each
+            row of the batch) goes to the host with the metric's per-row
+            statistics, under ``counters/<the layer's path>``."""
             variables = {"params": params}
-            mutable = ["aux_loss"]
+            mutable = ["aux_loss", "counters"]
             if batch_stats:
                 variables["batch_stats"] = batch_stats
                 mutable.append("batch_stats")
@@ -358,6 +371,11 @@ class Module:
                         handed = metrics_lib.device_reduce(
                             metric_stats, jax.lax.stop_gradient(logits),
                             labels)
+                    for path, leaf in jax.tree_util.tree_flatten_with_path(
+                            mutated.get("counters", {}))[0]:
+                        handed[_COUNTERS + "/".join(
+                            str(getattr(k, "key", k)) for k in path
+                            if not hasattr(k, "idx"))] = leaf
                 return loss_fn(logits, labels) + aux, (handed, new_stats)
 
         if self.remat:
@@ -751,6 +769,7 @@ class Module:
                              initialize_from_kvstore=is_new_worker)
         self._use_metric(eval_metric)
         self.metric_flushes = {"device": 0, "host": 0}
+        self.step_counters = {}
         if self._metric_spec is None and \
                 eval_metric.name not in self._fallback_said:
             self._fallback_said.add(eval_metric.name)
@@ -1368,8 +1387,11 @@ class Module:
         (or logits) and their copy to the host is ``step.fetch``, the metric
         (after the host's softmax, on the path that needs one)
         ``step.metric``, the callbacks ``step.callback``."""
-        path = self._update_metric(eval_metric, *pending, acct)
+        counted = []
+        path = self._update_metric(eval_metric, *pending, acct, counted)
         self.metric_flushes[path] += 1
+        for name, rows in counted:
+            self._count_step(name, rows)
         if obs_metrics.enabled():
             reg = obs_metrics.registry()
             reg.gauge("fit.metric_device_steps",
@@ -1385,8 +1407,33 @@ class Module:
         acct.phase("step.hooks")
         return nbatch
 
+    def _count_step(self, name, rows):
+        """One flushed step's ``counters`` of one layer (``rows``: a row of
+        the batch each) into ``step_counters`` and, where the metrics plane
+        is on, the routed layers' gauges."""
+        step = rows.reshape(rows.shape[0], -1).sum(axis=0).astype(np.int64)
+        kept = self.step_counters.setdefault(
+            name, {"sum": np.zeros_like(step), "max": np.zeros_like(step),
+                   "steps": 0})
+        kept["sum"] += step
+        kept["max"] = np.maximum(kept["max"], step)
+        kept["steps"] += 1
+        if obs_metrics.enabled() and name.endswith("/moe"):
+            reg = obs_metrics.registry()
+            held, overflow, made = step[:-3], step[-2], step[-1]
+            labels = {"layer": name}
+            reg.gauge("moe.held_load_share_pct",
+                      100.0 * held.sum() / max(made, 1), labels)
+            reg.gauge("moe.fullest_over_mean_load",
+                      held.max() / max(held.mean(), 1e-9), labels)
+            reg.gauge("moe.overflow_assignments", overflow, labels)
+            buffer = getattr(self.model, "buffer_rows", None) or made
+            reg.gauge("moe.buffer_fill_pct",
+                      100.0 * (held.sum() - overflow) / buffer, labels)
+
     @staticmethod
-    def _update_metric(eval_metric, lab, n_real, out, acct=None):
+    def _update_metric(eval_metric, lab, n_real, out, acct=None,
+                       counted=None):
         """One batch into ``eval_metric``, for ``fit`` and ``score`` alike.
         ``out`` is what the compiled program left on the device: the
         metric's per-row statistics (a dict, reduced over the class axis
@@ -1401,6 +1448,10 @@ class Module:
             reduced = {k: _local_np(v)[:n_real] for k, v in out.items()}
             if acct is not None:
                 acct.phase("step.metric")
+            for k in [k for k in reduced if k.startswith(_COUNTERS)]:
+                rows = reduced.pop(k)      # the layers', not the metric's
+                if counted is not None:
+                    counted.append((k[len(_COUNTERS):], rows))
             eval_metric.update_reduced(lab[:n_real], reduced)
             return "device"
         logits = _local_np(out)

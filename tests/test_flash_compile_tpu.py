@@ -58,3 +58,36 @@ def test_mosaic_takes_the_backward(one_chip, bh, s, d, dtype):
              if "tpu_custom_call" in ln and "custom-call(" in ln]
     assert calls and all("flash_bwd" in c and "attn" not in c
                          for c in calls), calls
+
+
+# under the block-diffusion mask rule: the new cell's shape (32 heads of 128
+# over [xt ; x0] of 8,192 positions, blocks of 4) and a half that only 128
+# divides, with a block length that is no power of two
+MASKED = [
+    (2 * 32, 4096, 4, 128, jnp.bfloat16),
+    (4, 640, 5, 64, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("bh,half,block,d,dtype", MASKED)
+def test_mosaic_takes_both_kernels_under_the_mask_rule(one_chip, bh, half,
+                                                       block, d, dtype):
+    """Forward and backward pruned, masked and fetched by the rule, under
+    the names the benchmark's ``kernel.flash_*.bd`` find them by."""
+    rule = attn.BlockDiffusionMask(half, block)
+    x = jax.ShapeDtypeStruct((bh, 2 * half, d), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, 2 * half), jnp.float32,
+                               sharding=one_chip)
+    fwd = jax.jit(lambda q, k, v: attn._flash_fwd_pallas(
+        q, k, v, scale=d ** -0.5, causal=False, block_q=None, block_k=None,
+        interpret=False, mask=rule))
+    bwd = jax.jit(lambda q, k, v, o, lse, do: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, scale=d ** -0.5, causal=False, interpret=False,
+        mask=rule))
+    for text, name in ((fwd.lower(x, x, x).compile().as_text(),
+                        "flash_fwd_bd"),
+                       (bwd.lower(x, x, x, x, lse, x).compile().as_text(),
+                        "flash_bwd_bd")):
+        calls = [ln.split("=")[0] for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "custom-call(" in ln]
+        assert calls and all(name in c for c in calls), calls

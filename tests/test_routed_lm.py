@@ -1,0 +1,476 @@
+"""The routed expert layer (``parallel/moe.py`` ``RoutedExperts``), the
+block-diffusion mask rule of the flash kernels, ``models/routed_lm.py``, the
+weighted masked cross-entropy with its metric, and the noising transform, at
+a small size on the CPU: against a dense masked softmax, and against the
+benchmark's plain reference
+(``benchmark/configs/sdar-30b-a3b-chat_reference.py``) on seeded weights.
+Widths in the tens; the real widths run on the chip."""
+
+import importlib.util
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from dt_tpu import data as dt_data, models
+from dt_tpu.models import routed_lm
+from dt_tpu.ops import losses
+from dt_tpu.ops.pallas import attention as attn
+from dt_tpu.ops.pallas.attention import BlockDiffusionMask, flash_attention
+from dt_tpu.parallel import moe
+from dt_tpu.training import Module, metrics as metrics_lib
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+sys.path.insert(0, BENCH)
+import sdar_drivers  # noqa: E402
+
+
+def _load_reference():
+    path = os.path.join(BENCH, "configs", "sdar-30b-a3b-chat_reference.py")
+    spec = importlib.util.spec_from_file_location("sdar_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _load_reference()
+with open(os.path.join(BENCH, "configs", "sdar-30b-a3b-chat.json")) as f:
+    PUBLISHED = json.load(f)
+#: the published configuration at widths in the tens: 16 experts of which
+#: this chip holds 4 from the fifth, 2 a token, four query heads over two
+BATCH, SEQ, BLOCK = 2, 24, 4
+SMALL = {**PUBLISHED, "hidden_size": 32, "head_dim": 8,
+         "num_attention_heads": 4, "num_key_value_heads": 2,
+         "moe_intermediate_size": 24, "num_experts_per_tok": 2,
+         "num_experts": 4, "held_experts_first": 4,
+         "published": {**PUBLISHED["published"], "num_experts": 16},
+         "num_hidden_layers": 2, "vocab_size": 40, "mask_token_id": 39,
+         "buffer_rows": None, "block_length": BLOCK, "attention": None,
+         "dtype": "float32", "remat_blocks": False}
+TRAFFIC = {"batch": BATCH, "seq_len": SEQ, "block_length": BLOCK}
+
+
+def _dense_attention(q, k, v, rule):
+    pos = jnp.arange(q.shape[1])
+    seen = rule.allowed(pos[:, None], pos[None, :])
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+# -- the mask rule, tile by tile ---------------------------------------------
+
+@pytest.mark.parametrize("half,block,bq,bk", [
+    (256, 4, 128, 128), (512, 4, 128, 256), (512, 8, 256, 128),
+    (384, 6, 128, 128), (256, 256, 128, 128), (512, 1, 256, 256)])
+def test_a_tiles_fate_and_fetch_follow_the_rule(half, block, bq, bk):
+    """Against the rule applied element by element: a tile runs where any
+    pair of it is allowed and is unmasked where all are, its band is the
+    rule inside it, and a skipped step fetches a tile that runs."""
+    rule = BlockDiffusionMask(half, block)
+    pos = np.arange(2 * half)
+    dense = np.asarray(rule.allowed(pos[:, None], pos[None, :]))
+    # the rule as the issue words it
+    noisy, blk = pos < half, (pos % half) // block
+    q, k = np.ix_(pos, pos)
+    assert (dense == np.where(noisy[k], noisy[q] & (blk[q] == blk[k]),
+                              np.where(noisy[q], blk[k] < blk[q],
+                                       blk[k] <= blk[q]))).all()
+    ran = 0
+    for qi in range(2 * half // bq):
+        for ki in range(2 * half // bk):
+            t = dense[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            runs, unmasked, *band = rule.tile(qi, ki, bq, bk)
+            assert bool(runs) == t.any() and bool(unmasked) == t.all()
+            ran += bool(runs)
+            kt = int(rule.key_tile(qi, ki, bq, bk))
+            qt = int(rule.query_tile(ki, qi, bq, bk))
+            if t.any():
+                assert (kt, qt) == (ki, qi)
+                assert (np.asarray(attn._band_mask(rule, band, 0,
+                                                   (bq, bk))) == t).all()
+                assert (np.asarray(attn._band_mask(rule, band, 1,
+                                                   (bk, bq))) == t.T).all()
+            assert dense[qi * bq:(qi + 1) * bq, kt * bk:(kt + 1) * bk].any()
+            assert dense[qt * bq:(qt + 1) * bq, ki * bk:(ki + 1) * bk].any()
+    assert ran == rule.tiles_run(bq, bk)
+
+
+def test_the_rule_prunes_to_about_a_quarter_at_the_cells_shape():
+    rule = BlockDiffusionMask(4096, 4)
+    assert rule.tiles_run(512, 512) == 80        # of 256: 1.25 x the area
+    assert rule.tiles_run(1024, 1024) == 24      # of 64: 1.5 x the area
+    assert attn.forward_tiles(8192, 8192, 128, 2, rule) == (1024, 1024)
+    assert attn.backward_tiles(8192, 8192, 128, 2, rule) == (512, 512)
+    # without the rule the cells' tiles are what they were
+    assert attn.forward_tiles(4096, 4096, 64, 2) == (1024, 1024)
+    assert attn.backward_tiles(1024, 1024, 64, 2) == (512, 512)
+    with pytest.raises(ValueError):
+        BlockDiffusionMask(100, 8)
+    q = jnp.zeros((1, 256, 1, 8))
+    with pytest.raises(ValueError):
+        flash_attention(q, q, q, causal=True, mask=BlockDiffusionMask(128, 4))
+    with pytest.raises(ValueError):
+        flash_attention(q, q, q, mask=BlockDiffusionMask(256, 4))
+
+
+# -- the kernels under the rule, in interpret mode ---------------------------
+
+@pytest.mark.parametrize("half,block", [(256, 4), (384, 16), (256, 1)])
+def test_kernels_under_the_block_mask_match_a_dense_masked_softmax(half,
+                                                                   block):
+    rule = BlockDiffusionMask(half, block)
+    keys = jax.random.split(jax.random.PRNGKey(half + block), 4)
+    q, k, v, g = (jax.random.normal(kk, (1, 2 * half, 2, 32), jnp.float32)
+                  for kk in keys)
+    np.testing.assert_allclose(flash_attention(q, k, v, mask=rule),
+                               _dense_attention(q, k, v, rule),
+                               rtol=2e-5, atol=2e-5)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", grads(
+            lambda q, k, v: flash_attention(q, k, v, mask=rule)),
+            grads(lambda q, k, v: _dense_attention(q, k, v, rule))):
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4,
+                                   err_msg=f"d{name} half={half}")
+
+
+def test_a_length_that_needs_padding_to_the_tile():
+    """The attention module pads each half to the tile: 72 positions a
+    half become 128, and a padded key lies in a block after every real
+    query's, so nothing of it shows; forward and backward against the
+    module's own dense path."""
+    half, x = 72, jax.random.normal(jax.random.PRNGKey(0), (2, 144, 32))
+    kw = dict(num_heads=4, num_kv_heads=2, head_dim=8,
+              mask=BlockDiffusionMask(half, 4))
+    flash = routed_lm.RotaryAttention(attention="flash", **kw)
+    plain = routed_lm.RotaryAttention(attention=None, **kw)
+    variables = plain.init(jax.random.PRNGKey(1), x)
+    np.testing.assert_allclose(flash.apply(variables, x),
+                               plain.apply(variables, x), atol=2e-5)
+    grad = lambda m: jax.grad(lambda v, x: jnp.sum(  # noqa: E731
+        jnp.sin(m.apply(v, x))), argnums=(0, 1))(variables, x)
+    for a, b in zip(jax.tree_util.tree_leaves(grad(flash)),
+                    jax.tree_util.tree_leaves(grad(plain))):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_tile_notes_name_the_mask_rule(caplog):
+    import logging
+    from dt_tpu.obs import metrics as obs_metrics
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()
+        attn._flash_fwd_pallas.clear_cache()
+        attn._flash_bwd_pallas.clear_cache()
+        q = jnp.zeros((1, 512, 1, 64), jnp.float32)
+        rule = BlockDiffusionMask(256, 4)
+        with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
+            jax.grad(lambda q: flash_attention(q, q, q, mask=rule).sum())(q)
+        lines = [r.getMessage() for r in caplog.records
+                 if "tiles" in r.getMessage()]
+        assert len(lines) == 2 and all(
+            "mask=block_diffusion.half256.block4.run" in ln for ln in lines)
+        gauges = {(n, dict(lk).get("mask", "")) for n, lk, _ in
+                  obs_metrics.registry().gauges_export()}
+        assert any(n == "flash.block_q" and m.startswith("block_diffusion")
+                   for n, m in gauges)
+        assert any(n == "flash.bwd_block_k" and m.startswith(
+            "block_diffusion") for n, m in gauges)
+    finally:
+        obs_metrics.set_enabled(None)
+        attn._note_tiles.cache_clear()
+
+
+# -- the routed layer --------------------------------------------------------
+
+#: for a layer alone: its output matrix at the others' range, so that a
+#: result is of the inputs' size
+LAYER = {**SMALL, "residual_out_initializer_range": 0.02}
+
+
+def _layer_inputs(seed=0):
+    x = jax.random.normal(jax.random.PRNGKey(seed), (BATCH, 2 * SEQ, 32))
+    blk = REF.init(jax.random.PRNGKey(seed + 1), LAYER)["blocks"][0]
+    return x, blk
+
+
+def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0):
+    layer = moe.RoutedExperts(num_experts=16, top_k=2, intermediate=24,
+                              held=held, buffer_rows=buffer_rows,
+                              aux_weight=aux_weight)
+    first, count = held
+    params = {"router": blk["router"]}
+    for name in ("gate", "up", "down"):     # a whole layer's: this share's
+        params[name] = blk[name] if len(blk[name]) == count else \
+            blk[name][first:first + count]
+    return layer.apply({"params": params}, x,
+                       mutable=["aux_loss", "counters"])
+
+
+def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """16 experts over 8 chips, 2 each: each share computed by the routed
+    layer, with the router whole; together the uncut reference's layer."""
+    whole = {**LAYER, "num_experts": 16, "held_experts_first": 0}
+    x = jax.random.normal(jax.random.PRNGKey(0), (BATCH, 2 * SEQ, 32))
+    blk = REF.init(jax.random.PRNGKey(1), whole)["blocks"][0]
+    want, _ = REF.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
+    shares = [_routed(x, blk, (2 * i, 2)) for i in range(8)]
+    total = sum(out for out, _ in shares)
+    np.testing.assert_allclose(total.reshape(-1, 32), want, atol=2e-6)
+    # no share is the whole, and every assignment is in exactly one share
+    assert float(jnp.max(jnp.abs(shares[0][0] - total))) > 1e-4
+    counted = sum(np.asarray(m["counters"]["moe"][0]) for _, m in shares)
+    assert (counted[:, -3] == counted[:, -1] // 8).all()      # held: S x k
+    assert (counted[:, -2] == 0).all()
+
+
+def test_a_share_matches_the_references_share_and_its_auxiliary_term():
+    x, blk = _layer_inputs()
+    out, mutated = _routed(x, blk, (4, 4), aux_weight=0.5)
+    want, aux = REF.experts(x.reshape(-1, 32), blk, SMALL, lambda a: a)
+    np.testing.assert_allclose(out.reshape(-1, 32), want, atol=2e-6)
+    np.testing.assert_allclose(mutated["aux_loss"]["load_balance"][0],
+                               0.5 * aux, rtol=1e-6)
+    # the term is E sum f P over all 16 outputs; 2 (= k) where even
+    assert 2.0 <= float(aux) < 4.0
+    counters = np.asarray(mutated["counters"]["moe"][0])
+    assert counters.shape == (BATCH, 4 + len(moe.COUNTER_TAIL))
+    assert (counters[:, :4].sum(1) == counters[:, -3]).all()
+    assert (counters[:, -1] == 2 * SEQ * 2).all()
+
+
+def test_an_overflow_is_counted_and_shows_in_the_result():
+    x, blk = _layer_inputs()
+    full, counted = _routed(x, blk, (4, 4))
+    load = int(np.asarray(counted["counters"]["moe"][0])[:, -3].sum())
+    assert load > 16
+    # a buffer with room, padded: the same result, whatever the padding
+    roomy, m = _routed(x, blk, (4, 4), buffer_rows=load + 7)
+    np.testing.assert_allclose(roomy, full, atol=1e-6)
+    assert np.asarray(m["counters"]["moe"][0])[:, -2].sum() == 0
+    # eight rows too few: eight assignments dropped, counted, and missing
+    tight, m = _routed(x, blk, (4, 4), buffer_rows=load - 8)
+    assert np.asarray(m["counters"]["moe"][0])[:, -2].sum() == 8
+    assert float(jnp.max(jnp.abs(tight - full))) > 1e-5
+
+
+def test_sort_held_groups_and_clips_in_expert_order():
+    experts = jnp.asarray([[0, 5], [5, 6], [6, 9], [5, 1], [7, 5]])
+    order, sizes, held = moe.sort_held(experts, first=5, count=3, rows=6)
+    assert held.tolist() == [4, 2, 1] and sizes.tolist() == [4, 2, 0]
+    flat = np.asarray(experts).reshape(-1)
+    assert flat[np.asarray(order)].tolist() == [5, 5, 5, 5, 6, 6]
+    order, sizes, _ = moe.sort_held(experts, first=5, count=3, rows=9)
+    assert sizes.tolist() == [4, 2, 1]
+    assert flat[np.asarray(order)][:7].tolist() == [5, 5, 5, 5, 6, 6, 7]
+
+
+# -- the model against the reference -----------------------------------------
+
+def _job(cfg):
+    return sdar_drivers.BlockDiffusionMoEJob(cfg, TRAFFIC, 1, 0)
+
+
+def _batch(seed=1):
+    rng = np.random.default_rng(seed)
+    x0 = rng.integers(0, SMALL["mask_token_id"], (BATCH, SEQ))
+    return dt_data.block_diffusion_noise(x0, BLOCK, SMALL["mask_token_id"],
+                                         rng)
+
+
+def _gap(got, want):
+    return max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))
+                           / (jnp.max(jnp.abs(b)) + 1e-12)), got, want)))
+
+
+@pytest.mark.parametrize("attention,remat", [(None, False), ("flash", True)])
+def test_objective_and_gradient_match_the_reference(attention, remat):
+    cfg = {**SMALL, "attention": attention, "remat_blocks": remat}
+    params = REF.init(jax.random.PRNGKey(3), cfg)
+    data, labels = _batch()
+    with jax.default_matmul_precision("highest"):
+        (want, loss), grads = jax.jit(jax.value_and_grad(
+            lambda p: REF.loss_fn(p, data, labels, cfg), has_aux=True))(
+                params)
+    job = _job(cfg)
+
+    def objective(tree):
+        logits, mutated = job.mod.model.apply(
+            {"params": tree}, data, mutable=["aux_loss", "counters"])
+        assert logits.shape == (BATCH, SEQ, cfg["vocab_size"])
+        return losses.weighted_masked_cross_entropy(logits, labels) + sum(
+            jax.tree_util.tree_leaves(mutated["aux_loss"]))
+
+    got, got_grads = jax.jit(jax.value_and_grad(objective))(
+        job.program_tree(params))
+    assert float(want) > float(loss) > 0       # the auxiliary term is in it
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert _gap(got_grads, job.program_tree(grads)) < 2e-3
+    # the routers (through the weights and the auxiliary term) and both
+    # norms on the heads have a gradient in every layer; an expert has one
+    # where a position chose it
+    for i in range(cfg["num_hidden_layers"]):
+        blk = got_grads[f"block{i}"]
+        for leaf in (blk["moe"]["router"], blk["attn"]["q_norm"]["scale"],
+                     blk["attn"]["k_norm"]["scale"]):
+            assert float(jnp.max(jnp.abs(leaf))) > 0
+    assert float(jnp.max(jnp.abs(got_grads["block0"]["moe"]["down"]))) > 0
+
+
+def test_the_model_is_made_by_name_and_takes_only_the_pair_of_halves():
+    model = models.create("routed_lm", vocab_size=40, embed_dim=32,
+                          num_layers=1, num_heads=4, num_kv_heads=2,
+                          head_dim=8, num_experts=4, moe_intermediate=24,
+                          attention=None)
+    toks = jnp.arange(48).reshape(2, 24) % 40
+    variables = model.init(jax.random.PRNGKey(0), toks)
+    logits = model.apply(variables, toks, mutable=["counters"])[0]
+    assert logits.shape == (2, 12, 40) and logits.dtype == jnp.float32
+    # a noisy position sees no later block, noisy or clean
+    moved = model.apply(variables, toks.at[:, 11].set(7).at[:, 23].set(9),
+                        mutable=["counters"])[0]
+    np.testing.assert_allclose(moved[:, :8], logits[:, :8], atol=1e-6)
+    assert float(jnp.max(jnp.abs(moved[:, 8:] - logits[:, 8:]))) > 1e-6
+    with pytest.raises(ValueError):
+        model.init(jax.random.PRNGKey(0), toks[:, :23])
+
+
+def test_rope_turns_pairs_by_position():
+    x = jnp.ones((1, 3, 1, 4))
+    out = routed_lm.rope(x, jnp.arange(3), theta=100.0)
+    np.testing.assert_allclose(out[0, 0, 0], [1, 1, 1, 1], atol=1e-6)
+    # position 1: the pairs (x0, x2) by 1 radian, (x1, x3) by 0.1
+    want = [np.cos(1) - np.sin(1), np.cos(.1) - np.sin(.1),
+            np.cos(1) + np.sin(1), np.cos(.1) + np.sin(.1)]
+    np.testing.assert_allclose(out[0, 1, 0], want, atol=1e-6)
+    # a rotation: norms are kept
+    np.testing.assert_allclose(jnp.sum(out ** 2, -1), 4.0, atol=1e-5)
+
+
+# -- the loss, its metric, the transform -------------------------------------
+
+def test_weighted_masked_cross_entropy_and_its_metric_agree():
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.normal(size=(2, 6, 11)), jnp.float32)
+    labels = np.stack([rng.integers(0, 11, (2, 6)).astype(np.float32),
+                       rng.choice([0.0, 1.5, 40.0], (2, 6))], -1)
+    logp = np.asarray(jax.nn.log_softmax(logits))
+    want = -sum(labels[b, i, 1] * logp[b, i, int(labels[b, i, 0])]
+                for b in range(2) for i in range(6)) / 12
+    np.testing.assert_allclose(
+        losses.weighted_masked_cross_entropy(logits, jnp.asarray(labels)),
+        want, rtol=1e-6)
+    host, device = (metrics_lib.create("weighted-ce") for _ in range(2))
+    host.update(labels, np.asarray(jax.nn.softmax(logits)))
+    stats = metrics_lib.device_form(device)
+    assert metrics_lib.stats_key(stats) == ("weighted_label_logp",)
+    reduced = metrics_lib.device_reduce(stats, logits, jnp.asarray(labels))
+    assert reduced["weighted_label_logp"].shape == (2, 6)
+    device.update_reduced(labels, {k: np.asarray(v)
+                                   for k, v in reduced.items()})
+    np.testing.assert_allclose(host.get()[1], want, rtol=1e-6)
+    np.testing.assert_allclose(device.get()[1], want, rtol=1e-6)
+    # one number a row: reshaped as ever
+    ce = metrics_lib.device_reduce(
+        metrics_lib.device_form(metrics_lib.create("ce")), logits,
+        jnp.asarray(labels[..., 0].reshape(-1)))
+    assert ce["label_logp"].shape == (2, 6)
+
+
+def test_the_noising_transform_masks_at_its_rate_with_its_weights():
+    rng = np.random.default_rng(7)
+    x0 = rng.integers(0, 99, (8, 4096))
+    data, labels = dt_data.block_diffusion_noise(x0, 4, 99, rng)
+    assert data.shape == (8, 8192) and data.dtype == np.int32
+    assert labels.shape == (8, 4096, 2) and labels.dtype == np.float32
+    xt, clean = data[:, :4096], data[:, 4096:]
+    masked = xt == 99
+    assert (clean == x0).all() and (xt[~masked] == x0[~masked]).all()
+    assert (labels[..., 0] == x0).all()
+    weight = labels[..., 1]
+    assert ((weight > 0) == masked).all()
+    # t uniform in [0.001, 1]: half the positions masked, weights 1/t >= 1
+    assert abs(masked.mean() - 0.5005) < 0.01
+    assert weight[masked].min() >= 1.0 and weight.max() <= 1000.0
+    # one level a block: the masked positions of a block share a weight
+    by_block = weight.reshape(8, 1024, 4)
+    top = by_block.max(-1, keepdims=True)
+    assert ((by_block == 0) | (by_block == top)).all()
+    # E[1/t . 1(masked)] = 1 a position: the weights sum to about L
+    assert abs(weight.mean() - 1.0) < 0.02
+    with pytest.raises(ValueError):
+        dt_data.block_diffusion_noise(x0[:, :4095], 4, 99, rng)
+
+
+def test_the_iterator_noises_each_batch_anew_from_its_seed():
+    x0 = np.arange(4 * 16).reshape(4, 16) % 30
+    make = lambda seed: dt_data.BlockDiffusionIter(  # noqa: E731
+        dt_data.NDArrayIter(x0, np.zeros(4), batch_size=2), 4, 30, seed=seed)
+    a, b = list(make(5)), list(make(5))
+    assert len(a) == 2 and a[0].data.shape == (2, 32)
+    assert a[0].label.shape == (2, 16, 2)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(x.data, y.data)
+        np.testing.assert_array_equal(x.label, y.label)
+    assert any((x.data != y.data).any() for x, y in zip(a, list(make(6))))
+    np.testing.assert_array_equal(a[1].data[:, 16:], x0[2:])
+
+
+# -- through fit --------------------------------------------------------------
+
+def test_fit_reports_the_loss_and_the_layers_counters():
+    """``Module.fit`` with the loss and the metric's device form: the
+    metric is the reference's loss, the steps' counters reach the host with
+    its statistics, and the gauges name each layer."""
+    from dt_tpu.obs import metrics as obs_metrics
+    cfg = {**SMALL, "buffer_rows": 2 * SEQ * BATCH}     # T x k / 2
+    params = REF.init(jax.random.PRNGKey(3), cfg)
+    data, labels = _batch()
+    job = _job(cfg)
+    job.make_state(REF.init, jax.random.PRNGKey(3))
+
+    class Feed:
+        batch_size = BATCH
+
+        def reset(self):
+            self.left = 2
+
+        def next(self):
+            if not self.left:
+                raise StopIteration
+            self.left -= 1
+            return dt_data.DataBatch(data, labels, 0)
+
+    seen = []
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        job.fit(Feed(), [lambda p: seen.append(
+            dict(p.eval_metric.get_name_value())["weighted-ce"])])
+        gauges = {(n, dict(lk).get("layer")): v for n, lk, v in
+                  obs_metrics.registry().gauges_export()}
+    finally:
+        obs_metrics.set_enabled(None)
+    with jax.default_matmul_precision("highest"):
+        _, want = REF.loss_fn(params, data, labels, cfg)
+    np.testing.assert_allclose(seen[0], want, rtol=1e-5)
+    assert job.mod.metric_flushes == {"device": 2, "host": 0}
+    counted = job.mod.step_counters
+    assert sorted(counted) == ["block0/moe/moe", "block1/moe/moe"]
+    for name, c in counted.items():
+        assert c["steps"] == 2 and c["sum"].shape == (4 + 3,)
+        assert c["sum"][-1] == 2 * BATCH * 2 * SEQ * 2       # steps x T x k
+        assert c["sum"][:4].sum() == c["sum"][-3] and c["sum"][-2] == 0
+        assert (c["max"] <= c["sum"]).all() and c["max"][-3] > 0
+        assert gauges[("moe.overflow_assignments", name)] == 0
+        assert 0 < gauges[("moe.held_load_share_pct", name)] < 100
+        assert gauges[("moe.fullest_over_mean_load", name)] >= 1
+        assert 0 < gauges[("moe.buffer_fill_pct", name)] <= 100
