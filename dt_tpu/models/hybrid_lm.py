@@ -9,7 +9,9 @@ Pre-norm RMSNorm with a learned scale; ``silu(x Wg) * (x Wu)`` feed-forward
 without bias; no positional encoding (the recurrence orders the sequence);
 attention with fewer key-value heads than query heads and a given score
 scale; scalar multipliers on the embedding, every residual branch and the
-logits; a head tied to the embedding; per-block rematerialisation.
+logits; a head tied to the embedding; per-block rematerialisation that
+keeps the values named in ``SAVED`` (a few projections' outputs) and computes
+the rest of a block again in the backward pass.
 
     h = embedding_multiplier * E[token]
     h = h + residual_multiplier * mixer_l(rms(h))     mixer: "mamba" | "attention"
@@ -38,6 +40,7 @@ from typing import Any, Optional, Sequence
 import flax.linen as linen
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dt_tpu.ops import ssm
 
@@ -66,8 +69,9 @@ class GatedMLP(linen.Module):
     def __call__(self, x):
         dense = lambda n, name: linen.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        h = jax.nn.silu(dense(self.intermediate, "gate")(x)) \
-            * dense(self.intermediate, "up")(x)
+        h = jax.nn.silu(
+            checkpoint_name(dense(self.intermediate, "gate")(x), "mlp_gate")) \
+            * checkpoint_name(dense(self.intermediate, "up")(x), "mlp_up")
         return dense(x.shape[-1], "down")(h)
 
 
@@ -108,7 +112,8 @@ class GroupedQueryAttention(linen.Module):
         else:
             from dt_tpu.parallel.ring_attention import full_attention
             out = full_attention(q, k, v, causal=True, scale=self.scale)
-        return dense(d, "o_proj")(out.reshape(b, s, -1))
+        return checkpoint_name(
+            dense(d, "o_proj")(out.reshape(b, s, -1)), "mixer_out")
 
 
 def _a_log_init(key, shape, dtype=F32):
@@ -140,8 +145,9 @@ class Mamba2Mixer(linen.Module):
         b, l, d = x.shape
         h, p, g, n = self.n_heads, self.d_head, self.n_groups, self.d_state
         d_inner, conv_dim = h * p, h * p + 2 * g * n
-        zxbcdt = linen.Dense(d_inner + conv_dim + h, use_bias=False,
-                             dtype=self.dtype, name="in_proj")(x)
+        zxbcdt = checkpoint_name(
+            linen.Dense(d_inner + conv_dim + h, use_bias=False,
+                        dtype=self.dtype, name="in_proj")(x), "ssm_in_proj")
         z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
         conv_w = self.param("conv_kernel",
                             linen.initializers.lecun_normal(),
@@ -166,8 +172,23 @@ class Mamba2Mixer(linen.Module):
         with jax.named_scope("gated_norm"):
             y = ssm.gated_rms_norm(y.reshape(b, l, d_inner), z, norm_scale,
                                    self.eps)
-        return linen.Dense(d, use_bias=False, dtype=self.dtype,
-                           name="out_proj")(y)
+        return checkpoint_name(
+            linen.Dense(d, use_bias=False, dtype=self.dtype,
+                        name="out_proj")(y), "mixer_out")
+
+
+#: what a rematerialised ``HybridBlock`` keeps from its forward pass, by
+#: ``checkpoint_name``; the backward pass computes the rest again from the
+#: block's input.  Bytes a layer, for T positions (B x S) of width d in the
+#: compute dtype of c bytes:
+#:   ssm_in_proj  T x (2 d_inner + 2 G N + H) x c   a Mamba-2 layer's in_proj
+#:   mixer_out    T x d x c       the mixer's or the attention's last product
+#: Named and not kept: mlp_gate and mlp_up (T x intermediate x c each: the
+#: chip has no room for them beside these; PERF.md section 6, PR 35, has
+#: each name's measured milliseconds a gigabyte).  The flash kernel's
+#: ``flash_out`` is not here: one attention layer in ten, and the benchmark
+#: counts its calls from a file.
+SAVED = ("ssm_in_proj", "mixer_out")
 
 
 class HybridBlock(linen.Module):
@@ -227,9 +248,10 @@ class HybridLM(linen.Module):
     tie_word_embeddings: bool = True
     rms_norm_eps: float = 1e-5
     dtype: Any = F32
-    # per-block rematerialisation, as TransformerLM's: a block's activations
-    # are recomputed in the backward pass, so one block's are live at a time
+    # per-block rematerialisation: a block keeps its input and the values
+    # named in SAVED, and the backward pass computes the rest again
     remat: bool = False
+    saved_names = SAVED     # no field: the policy's list, and the gauge's
 
     @linen.compact
     def __call__(self, tokens, training: bool = True):
@@ -250,7 +272,9 @@ class HybridLM(linen.Module):
         with jax.named_scope("embed"):
             x = (jnp.take(table, tokens, axis=0)
                  * self.embedding_multiplier).astype(self.dtype)
-        block_cls = linen.remat(HybridBlock) if self.remat else HybridBlock
+        block_cls = linen.remat(
+            HybridBlock, policy=jax.checkpoint_policies.save_only_these_names(
+                *self.saved_names)) if self.remat else HybridBlock
         for i, kind in enumerate(self.layer_types):
             x = block_cls(kind, self.intermediate, self.residual_multiplier,
                           tuple(sorted(mixers[kind].items())),

@@ -31,6 +31,10 @@ Module names and ``jax.named_scope``s tell the parts apart in an operation's
 scope path: ``block3/attn/q_proj``, ``block3/attn/rope``,
 ``block3/moe/route`` (``dispatch``, ``experts``, ``combine``), ``embed``,
 ``lm_head``.
+
+With ``remat`` each block is rematerialised: it keeps its input and the
+values named in ``SAVED`` (the flash kernel's output among them, so the
+kernel runs once a layer) and computes the rest again in the backward pass.
 """
 
 from __future__ import annotations
@@ -40,6 +44,7 @@ from typing import Any, Optional
 import flax.linen as linen
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dt_tpu.models.hybrid_lm import RMSNorm
 from dt_tpu.ops.pallas.attention import (BlockDiffusionMask, DEFAULT_BLOCK,
@@ -83,9 +88,11 @@ class RotaryAttention(linen.Module):
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         dense = lambda n, name: linen.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        q = dense(h * hd, "q_proj")(x).reshape(b, s, h, hd)
-        k = dense(kv * hd, "k_proj")(x).reshape(b, s, kv, hd)
-        v = dense(kv * hd, "v_proj")(x).reshape(b, s, kv, hd)
+        q, k, v = checkpoint_name(
+            (dense(h * hd, "q_proj")(x), dense(kv * hd, "k_proj")(x),
+             dense(kv * hd, "v_proj")(x)), "attn_qkv")
+        q = q.reshape(b, s, h, hd)
+        k, v = k.reshape(b, s, kv, hd), v.reshape(b, s, kv, hd)
         q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
         k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
         mask = self.mask
@@ -100,7 +107,8 @@ class RotaryAttention(linen.Module):
             out = self._flash(q, k, v)
         else:
             out = self._plain(q, k, v)
-        return dense(d, "o_proj")(out.reshape(b, s, h * hd))
+        return checkpoint_name(
+            dense(d, "o_proj")(out.reshape(b, s, h * hd)), "attn_out")
 
     def _flash(self, q, k, v):
         s, mask = q.shape[1], self.mask
@@ -131,6 +139,26 @@ class RotaryAttention(linen.Module):
         probs = jax.nn.softmax(jnp.where(allowed, scores, NEG_INF), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", probs,
                           v.astype(F32)).astype(q.dtype)
+
+
+#: what a rematerialised ``RoutedBlock`` keeps from its forward pass, by
+#: ``checkpoint_name``; the backward pass computes the rest again from the
+#: block's input.  Bytes a layer, for T positions (B x 2 L) of width d, H
+#: heads of D, k experts a token and a buffer of R rows of expert width I,
+#: ``held`` experts, in the compute dtype of c bytes:
+#:   flash_out   T x H x D x c      the flash kernel's output
+#:   flash_lse   T x H x 4          its log-sum-exp, float32
+#:   attn_out    T x d x c          o_proj's output
+#:   moe_route   T x k x 4 + 2 x R x 4 + held x 4   weights; order and the
+#:               token each row holds; sizes (and 2 x R x 4 more: the
+#:               indices jax derives from those two for the two gathers)
+#:   moe_up      R x I x 4          float32, as ragged_dot returns it
+#: Named and not kept: moe_gate (as moe_up: the pair fits the chip with
+#: under half a gigabyte to spare) and attn_qkv (T x (H + 2 KV) x D x c,
+#: the three projections' outputs: fewest milliseconds a gigabyte).
+#: PERF.md section 6, PR 35, has each name's measured milliseconds and
+#: bytes, and what the chip has room for.
+SAVED = ("flash_out", "flash_lse", "attn_out", "moe_route", "moe_up")
 
 
 class RoutedBlock(linen.Module):
@@ -175,8 +203,10 @@ class RoutedLM(linen.Module):
     attention: Optional[str] = "flash"
     rms_norm_eps: float = 1e-6
     dtype: Any = F32
-    # per-block rematerialisation, as HybridLM's
+    # per-block rematerialisation: a block keeps its input and the values
+    # named in SAVED, and the backward pass computes the rest again
     remat: bool = False
+    saved_names = SAVED     # no field: the policy's list, and the gauge's
 
     @linen.compact
     def __call__(self, tokens, training: bool = True):
@@ -197,7 +227,9 @@ class RoutedLM(linen.Module):
                            (self.vocab_size, self.embed_dim), F32)
         with jax.named_scope("embed"):
             x = jnp.take(table, tokens, axis=0).astype(self.dtype)
-        block_cls = linen.remat(RoutedBlock) if self.remat else RoutedBlock
+        block_cls = linen.remat(
+            RoutedBlock, policy=jax.checkpoint_policies.save_only_these_names(
+                *self.saved_names)) if self.remat else RoutedBlock
         for i in range(self.num_layers):
             x = block_cls(tuple(sorted(attn.items())),
                           tuple(sorted(moe.items())), self.rms_norm_eps,

@@ -148,8 +148,9 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                        "whose logits crossed to the host "
                                        "for a metric without a device "
                                        "form"),
-    # set when Module builds its steps, for a model whose layers are read
-    # from a pattern (models/hybrid_lm.py)
+    # set when Module builds its steps: the first three for a model whose
+    # layers are read from a pattern (models/hybrid_lm.py), the two remat
+    # ones also for models/routed_lm.py
     "model.layers_ssm": ("gauge", "state-space (Mamba-2) layers of the "
                                   "model the compiled steps run"),
     "model.layers_attention": ("gauge", "attention layers of the model the "
@@ -159,6 +160,10 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "model.remat_blocks": ("gauge", "1 where each block's activations are "
                                     "recomputed in the backward pass "
                                     "(linen.remat per block), else 0"),
+    "model.remat_saved_names": ("gauge", "names on the list a rematerialised "
+                                         "block keeps from its forward pass "
+                                         "(the model file's SAVED; the rest "
+                                         "is recomputed); 0 with remat off"),
     # set at each flushed step of fit from what a routed expert layer
     # (parallel/moe.py RoutedExperts) counted in it, label `layer`
     # (training/module.py _count_step)

@@ -69,6 +69,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -642,6 +643,12 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     out, lse = _flash_fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
                                  block_q=block_q, block_k=block_k,
                                  interpret=interpret, mask=mask)
+    # names a block's remat policy can keep (models/routed_lm.py SAVED):
+    # on the values themselves, before they go into the result and the
+    # residuals, so that a policy which saves them needs no second call.
+    # q3, k3, v3 carry none: they are rebuilt from the projections
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q3, k3, v3, out, lse)
 
 

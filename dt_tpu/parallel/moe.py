@@ -27,6 +27,7 @@ from typing import Any, Optional
 import flax.linen as linen
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 Array = jax.Array
 
@@ -228,10 +229,17 @@ class RoutedExperts(linen.Module):
             logits = jnp.dot(tokens.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
             experts, weights, probs = route_top_k(logits, k)
+            order, sizes, _ = sort_held(experts, first, count, rows)
+            # what route hands on, under one name a block's remat policy
+            # can keep (models/routed_lm.py SAVED): with these held the
+            # backward pass does not sort again.  probs carries no name:
+            # nothing in the backward pass reads it (the softmax's own
+            # derivative keeps its own copy)
+            experts, weights, order, source, sizes = checkpoint_name(
+                (experts, weights, order, order // k, sizes), "moe_route")
             if self.aux_weight:
                 self.sow("aux_loss", "load_balance", self.aux_weight
                          * load_balancing_term(experts, probs))
-            order, sizes, held = sort_held(experts, first, count, rows)
             placed = jnp.arange(rows) < jnp.sum(sizes)
             # the padding goes through the last expert: every row of the
             # buffer is in a group, and the products' cost is the buffer's
@@ -239,16 +247,19 @@ class RoutedExperts(linen.Module):
             row_weight = jnp.where(placed, weights.reshape(-1)[order], 0.0)
             self._count(experts, first, count, order, placed, b, s * k)
         with jax.named_scope("dispatch"):
-            buf = jnp.take(tokens, order // k, axis=0).astype(self.dtype)
+            buf = jnp.take(tokens, source, axis=0).astype(self.dtype)
         with jax.named_scope("experts"):
             grouped = lambda lhs, w: jax.lax.ragged_dot(  # noqa: E731
                 lhs, w.astype(self.dtype), groups,
                 preferred_element_type=jnp.float32)
-            hidden = jax.nn.silu(grouped(buf, w_gate)) * grouped(buf, w_up)
+            # the two products in the float32 they are made in, by name
+            hidden = jax.nn.silu(
+                checkpoint_name(grouped(buf, w_gate), "moe_gate")) \
+                * checkpoint_name(grouped(buf, w_up), "moe_up")
             out = grouped(hidden.astype(self.dtype), w_down)
         with jax.named_scope("combine"):
             out = (out * row_weight[:, None]).astype(self.dtype)
-            y = jnp.zeros((t, d), self.dtype).at[order // k].add(out)
+            y = jnp.zeros((t, d), self.dtype).at[source].add(out)
         return y.reshape(b, s, d)
 
     def _count(self, experts, first, count, order, placed, b, per_row):

@@ -592,21 +592,27 @@ class Module:
         self._model_gauges()
 
     def _model_gauges(self):
-        """What the steps just built compute, for a model whose layers are
-        read from a pattern (``models.HybridLM``): the count of layers of
-        each kind, the state-space scan's chunk, and whether each block is
-        rematerialised.  Gauges of the metrics plane; nothing where it is
-        off or the model has no pattern."""
-        kinds = getattr(self.model, "layer_types", None)
-        if kinds is None or not obs_metrics.enabled():
+        """What the steps just built compute.  For a model whose blocks
+        keep named values when they are rematerialised (``saved_names``:
+        ``models.HybridLM``, ``models.RoutedLM``): whether each block is
+        rematerialised and how many names it then keeps.  For one whose
+        layers are read from a pattern (``models.HybridLM``) also the count
+        of layers of each kind and the state-space scan's chunk.  Gauges of
+        the metrics plane; nothing where it is off."""
+        saved = getattr(self.model, "saved_names", None)
+        if saved is None or not obs_metrics.enabled():
             return
         reg = obs_metrics.registry()
+        remat = bool(self.model.remat)
+        reg.gauge("model.remat_blocks", int(remat))
+        reg.gauge("model.remat_saved_names", len(saved) if remat else 0)
+        kinds = getattr(self.model, "layer_types", None)
+        if kinds is None:
+            return
         reg.gauge("model.layers_ssm", sum(k == "mamba" for k in kinds))
         reg.gauge("model.layers_attention",
                   sum(k == "attention" for k in kinds))
         reg.gauge("model.ssm_chunk", getattr(self.model, "ssm_chunk", 0))
-        reg.gauge("model.remat_blocks",
-                  int(bool(getattr(self.model, "remat", False))))
 
     def _use_metric(self, eval_metric):
         """Have the compiled steps return what ``eval_metric`` reads: its

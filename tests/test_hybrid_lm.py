@@ -4,6 +4,8 @@ against the one-position recurrence and the benchmark's plain reference
 Widths in the tens, chunks of 4 or 8; the real widths run on the chip.
 """
 
+from collections import Counter
+
 import numpy as np
 import pytest
 import jax
@@ -13,6 +15,7 @@ from dt_tpu import models
 from dt_tpu.models import hybrid_lm
 
 from hybrid_small import BATCH, REF, SEQ, SMALL, hybrid_drivers
+import remat_held
 import traffic  # benchmark/traffic.py, on the path since hybrid_small
 
 
@@ -149,6 +152,121 @@ def test_rematerialised_blocks_change_no_number_and_no_name():
     grads = [jax.jit(jax.grad(lambda p, m=m: losses.softmax_cross_entropy(
         m.apply(p, tokens), labels)))(params) for m in (plain, remat)]
     assert _gap(*grads) < 1e-5
+
+
+
+# -- what a rematerialised block keeps ----------------------------------------
+
+#: a block of each kind as ``HybridLM`` wraps it: width 32, feed-forward 48;
+#: four state-space heads of 16 over a state of 8 (in_proj 2 x 64 + 2 x 8 + 4
+#: wide); four query heads of 8 over two
+MIXERS = {"mamba": dict(n_heads=4, d_head=16, d_state=8, n_groups=1,
+                        d_conv=4, chunk=4, conv_bias=True),
+          "attention": dict(num_heads=4, num_kv_heads=2, head_dim=8,
+                            attention="flash", scale=8 ** -0.5)}
+
+
+def _block(kind, policy_names, length):
+    import flax.linen as linen
+    make = lambda cls: cls(kind, 48, 0.22,  # noqa: E731
+                           tuple(sorted(MIXERS[kind].items())), 1e-5,
+                           jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (BATCH, length, 32))
+    variables = jax.jit(make(hybrid_lm.HybridBlock).init)(
+        jax.random.PRNGKey(1), x)
+    return make(linen.remat(
+        hybrid_lm.HybridBlock,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *policy_names))), variables, x
+
+
+def _remat_changes_no_number():
+    cfg = {**SMALL, "attention": "flash"}
+    tokens, labels = _tokens(cfg)
+    tree = None
+    got = []
+    for remat in (False, True):
+        job = _job({**cfg, "remat_blocks": remat})
+        tree = tree or job.program_tree(REF.init(jax.random.PRNGKey(3), cfg))
+        got.append(_program_loss_and_grad(job, tree, tokens, labels))
+    (loss, grads), (loss_r, grads_r) = got
+    np.testing.assert_allclose(loss_r, loss, rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(grads_r)):
+        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-8,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def _a_block_keeps_its_list(kind):
+    """Beyond its input and its parameters a block keeps the values on
+    ``SAVED``, at the sizes the list's comment gives: the state-space one
+    its in_proj, both kinds the mixer's last product and the feed-forward's
+    two."""
+    length, d, inter, f32 = 16, 32, 48, "float32"
+    wide = 2 * 64 + 2 * 8 + 4
+    # each name's width and how many of it a block of this kind makes
+    by_name = {"mixer_out": (d, 1), "mlp_gate": (inter, 1),
+               "mlp_up": (inter, 1),
+               "ssm_in_proj": (wide, int(kind == "mamba"))}
+    assert set(hybrid_lm.SAVED) <= set(by_name)
+    for names in (hybrid_lm.SAVED, tuple(by_name)):   # the list; every name
+        blk, variables, x = _block(kind, names, length)
+        kept, args = remat_held.held(blk, variables, x)
+        assert sum(args.values()) == 1 + len(
+            jax.tree_util.tree_leaves(variables))
+        want = Counter()
+        for name in names:
+            width, count = by_name[name]
+            want[((BATCH, length, width), f32)] += count
+        assert kept == +want
+        # the formula in the list's comment at c = 4 bytes
+        assert remat_held.held_bytes(kept) == BATCH * length * 4 * sum(
+            by_name[n][0] * by_name[n][1] for n in names)
+    blk, variables, x = _block(kind, (), length)
+    assert not remat_held.held(blk, variables, x)[0]
+
+
+def _the_gradient_calls_the_forward_kernel_twice():
+    """``flash_out`` is not on this model's list (the benchmark counts the
+    forward kernel's calls from a file): the backward pass runs the forward
+    kernel again.  With it on the list, once."""
+    for names, forward in ((hybrid_lm.SAVED, 2),
+                           (hybrid_lm.SAVED + ("flash_out", "flash_lse"), 1)):
+        blk, variables, x = _block("attention", names, 128)
+        calls, total = remat_held.kernel_calls(blk, variables, x)
+        # the causal forward has no name of its own
+        assert calls == {"flash_bwd": 1} and total == forward + 1, names
+
+
+def _the_gauge_counts_the_list(remat):
+    from dt_tpu.obs import metrics as obs_metrics
+    from dt_tpu.training import metrics as metrics_lib
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        job = _job({**SMALL, "remat_blocks": remat})
+        job.mod._metric_stats = metrics_lib.device_form(
+            metrics_lib.create("ce"))
+        job.mod._build_steps()
+        gauges = {name: value for name, _, value in
+                  obs_metrics.registry().gauges_export()}
+    finally:
+        obs_metrics.set_enabled(None)
+    assert gauges["model.remat_blocks"] == int(remat)
+    assert gauges["model.remat_saved_names"] == (
+        len(hybrid_lm.SAVED) if remat else 0)
+
+
+@pytest.mark.parametrize("check", [
+    _remat_changes_no_number, lambda: _a_block_keeps_its_list("mamba"),
+    lambda: _a_block_keeps_its_list("attention"),
+    _the_gradient_calls_the_forward_kernel_twice,
+    lambda: _the_gauge_counts_the_list(True),
+    lambda: _the_gauge_counts_the_list(False)],
+    ids=["numbers", "held-mamba", "held-attention", "kernels", "gauge-on",
+         "gauge-off"])
+def test_rematerialised_blocks_keep_the_named_values(check):
+    check()
 
 
 def test_module_sets_the_models_gauges_when_it_builds_its_steps():

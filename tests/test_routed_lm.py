@@ -10,6 +10,7 @@ import importlib.util
 import json
 import os
 import sys
+from collections import Counter
 
 import numpy as np
 import pytest
@@ -28,6 +29,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 sys.path.insert(0, BENCH)
 import sdar_drivers  # noqa: E402
+import remat_held  # noqa: E402  (tests/)
 
 
 def _load_reference():
@@ -422,6 +424,131 @@ def test_the_iterator_noises_each_batch_anew_from_its_seed():
         np.testing.assert_array_equal(x.label, y.label)
     assert any((x.data != y.data).any() for x, y in zip(a, list(make(6))))
     np.testing.assert_array_equal(a[1].data[:, 16:], x0[2:])
+
+
+# -- what a rematerialised block keeps ----------------------------------------
+
+def _block(policy_names, attention="flash"):
+    """One ``RoutedBlock`` as ``RoutedLM`` wraps it, with its variables and
+    an input: 2 x 256 positions of width 32, four heads of 8 over two, 16
+    experts of width 24 of which 4 are held, 2 a token, the worst-case
+    buffer."""
+    import flax.linen as linen
+    attn_kw = dict(num_heads=4, num_kv_heads=2, head_dim=8, rope_theta=1e6,
+                   mask=BlockDiffusionMask(128, 4), attention=attention)
+    moe_kw = dict(num_experts=16, top_k=2, intermediate=24, held=(4, 4),
+                  buffer_rows=None, aux_weight=0.001)
+    cls = routed_lm.RoutedBlock if policy_names is None else linen.remat(
+        routed_lm.RoutedBlock,
+        policy=jax.checkpoint_policies.save_only_these_names(*policy_names))
+    blk = cls(tuple(sorted(attn_kw.items())), tuple(sorted(moe_kw.items())),
+              1e-6, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 256, 32))
+    params = jax.jit(routed_lm.RoutedBlock(
+        tuple(sorted(attn_kw.items())), tuple(sorted(moe_kw.items())),
+        1e-6, jnp.float32).init)(jax.random.PRNGKey(1), x)["params"]
+    return blk, {"params": params}, x
+
+
+def _remat_changes_no_number():
+    cfg = {**SMALL, "attention": "flash"}
+    data, labels = _batch()
+    params = REF.init(jax.random.PRNGKey(3), cfg)
+    got = []
+    for remat in (False, True):
+        job = _job({**cfg, "remat_blocks": remat})
+
+        def objective(tree, model=job.mod.model):
+            logits, mutated = model.apply(
+                {"params": tree}, data, mutable=["aux_loss", "counters"])
+            return losses.weighted_masked_cross_entropy(logits, labels) + sum(
+                jax.tree_util.tree_leaves(mutated["aux_loss"]))
+        got.append(jax.jit(jax.value_and_grad(objective))(
+            job.program_tree(params)))
+    (loss, grads), (loss_r, grads_r) = got
+    np.testing.assert_allclose(loss_r, loss, rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(grads_r)):
+        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-8,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def _a_block_keeps_its_list():
+    """Beyond its input and its parameters the block keeps the values on
+    ``SAVED``, at the sizes the list's comment gives, and the integer
+    indices jax derives from ``order`` for the two gathers."""
+    b, s, d, h, hd, k, i, count = 2, 256, 32, 4, 8, 2, 24, 4
+    t, r, f32 = b * s, b * s * k, "float32"
+    # each name's values, (shape, dtype) -> count; the formula in the
+    # list's comment at c = 4 bytes
+    by_name = {
+        "flash_out": {((b * h, s, hd), f32): 1},
+        "flash_lse": {((b * h, s), f32): 1},
+        "attn_out": {((b, s, d), f32): 1},
+        "attn_qkv": {((b, s, h * hd), f32): 1, ((b, s, 2 * hd), f32): 2},
+        # weights, sizes; order and each row's token, and beside those two
+        # the indices jax derives from them for the two gathers
+        "moe_route": {((t, k), f32): 1, ((count,), "int32"): 1,
+                      ((r,), "int32"): 4},
+        "moe_gate": {((r, i), f32): 1}, "moe_up": {((r, i), f32): 1}}
+    formula = {"flash_out": t * h * hd * 4, "flash_lse": t * h * 4,
+               "attn_out": t * d * 4, "attn_qkv": t * (h + 2 * 2) * hd * 4,
+               "moe_route": t * k * 4 + 2 * r * 4 + count * 4 + 2 * r * 4,
+               "moe_gate": r * i * 4, "moe_up": r * i * 4}
+    assert set(routed_lm.SAVED) <= set(by_name)
+    for names in (routed_lm.SAVED, tuple(by_name)):   # the list; every name
+        blk, variables, x = _block(names)
+        kept, args = remat_held.held(blk, variables, x,
+                                     mutable=["aux_loss", "counters"])
+        assert sum(args.values()) == 1 + len(
+            jax.tree_util.tree_leaves(variables))
+        assert kept == sum((Counter(by_name[n]) for n in names), Counter())
+        assert remat_held.held_bytes(kept) == sum(formula[n] for n in names)
+    # and a policy that names nothing keeps nothing
+    blk, variables, x = _block(())
+    assert not remat_held.held(blk, variables, x,
+                               mutable=["aux_loss", "counters"])[0]
+
+
+def _the_gradient_calls_each_kernel_once():
+    """``flash_out`` and ``flash_lse`` are on the list, so the backward pass
+    does not run the forward kernel again; a block that keeps nothing runs
+    it twice."""
+    for names, forward in ((routed_lm.SAVED, 1), ((), 2)):
+        blk, variables, x = _block(names)
+        calls, total = remat_held.kernel_calls(
+            blk, variables, x, mutable=["aux_loss", "counters"])
+        assert calls == {"flash_fwd_bd": forward, "flash_bwd_bd": 1}
+        assert total == forward + 1
+
+
+def _the_gauge_counts_the_list(remat):
+    from dt_tpu.obs import metrics as obs_metrics
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        job = _job({**SMALL, "remat_blocks": remat})
+        job.mod._metric_stats = metrics_lib.device_form(
+            metrics_lib.create("weighted-ce"))
+        job.mod._build_steps()
+        gauges = {name: value for name, _, value in
+                  obs_metrics.registry().gauges_export()}
+    finally:
+        obs_metrics.set_enabled(None)
+    assert gauges["model.remat_blocks"] == int(remat)
+    assert gauges["model.remat_saved_names"] == (
+        len(routed_lm.SAVED) if remat else 0)
+    assert "model.layers_ssm" not in gauges
+
+
+@pytest.mark.parametrize("check", [
+    _remat_changes_no_number, _a_block_keeps_its_list,
+    _the_gradient_calls_each_kernel_once,
+    lambda: _the_gauge_counts_the_list(True),
+    lambda: _the_gauge_counts_the_list(False)],
+    ids=["numbers", "held", "kernels", "gauge-on", "gauge-off"])
+def test_rematerialised_blocks_keep_the_named_values(check):
+    check()
 
 
 # -- through fit --------------------------------------------------------------
