@@ -19,16 +19,9 @@ def flash_backward_ops_bytes(batch, heads, seq, head_dim, itemsize):
 def roofline_pct(ctx, m):
     """``readers:kernel_roofline_pct`` for a kernel that two configurations
     with different keys call: the heads are the first of ``args.heads`` the
-    configuration has, and the calls a step are how often
-    ``args.calls.counted.value`` stands in its list ``args.calls.counted.in``
-    (the attention layers among ``layer_types``) or, where it has no such
-    list, its ``args.calls.else`` (every layer)."""
+    configuration has."""
     args, cfg = m["args"], ctx["cfg"]
     heads = next(cfg[k] for k in args["heads"] if k in cfg)
-    counted = args["calls"]["counted"]
-    calls = cfg[counted["in"]].count(counted["value"]) \
-        if counted["in"] in cfg else cfg[args["calls"]["else"]]
     return readers.kernel_roofline_pct(
-        {**ctx, "cfg": {**cfg, "flash_bwd_calls_per_step": calls}},
-        {**m, "args": {**args, "shape": {**args["shape"], "heads": heads},
-                       "calls_per_step": "flash_bwd_calls_per_step"}})
+        ctx, {**m, "args": {**args, "shape": {**args["shape"],
+                                              "heads": heads}}})

@@ -143,37 +143,45 @@ def unscoped_pct(ctx, m):
 
 
 def _kernel(ctx, m):
+    """Seconds and events a traced step of the operations whose name holds
+    ``args.op_name_holds``; None where the trace holds none."""
     import xplane
     tr = ctx["trace"]
     if not tr or not tr["steps"]:
         return None
-    seconds, names = xplane.op_seconds(tr, m["args"]["op_name_holds"])
-    return seconds / tr["steps"] if names else None
+    needle = m["args"]["op_name_holds"]
+    seconds, names = xplane.op_seconds(tr, needle)
+    if not names:
+        return None
+    return seconds / tr["steps"], xplane.op_events(tr, needle) / tr["steps"]
 
 
 def kernel_ms_per_step(ctx, m):
-    per_step = _kernel(ctx, m)
-    return None if per_step is None else 1e3 * per_step
+    found = _kernel(ctx, m)
+    return None if found is None else 1e3 * found[0]
 
 
 def kernel_roofline_pct(ctx, m):
     """The least time the chip could take for the kernel's calls of one step
-    (the larger of operations over peak and bytes over peak) over the time
-    they took."""
-    per_step = _kernel(ctx, m)
-    if not per_step:
+    (the larger of operations over peak and bytes over peak, for each event
+    of the kernel the traced steps hold: what a block's recomputation
+    repeats or a kept value spares is counted as it ran) over the time they
+    took."""
+    found = _kernel(ctx, m)
+    peaks = _peaks(ctx)
+    if not found or not found[0] or peaks is None:
         return None
+    per_step, calls = found
     shape = {k: (ctx["traffic"].get(v, ctx["cfg"].get(v))
                  if isinstance(v, str) else v)
              for k, v in m["args"]["shape"].items()}
     ops, nbytes = resolve(m["args"]["ops_bytes"])(**shape)
-    calls = ctx["cfg"][m["args"]["calls_per_step"]]
-    peaks = _peaks(ctx)
-    if peaks is None:
-        return None
     least = max(ops / peaks["bf16_flops_per_s"],
-                nbytes / peaks["hbm_bytes_per_s"]) * calls
-    return 100.0 * least / per_step
+                nbytes / peaks["hbm_bytes_per_s"])
+    print(f"# kernel {m['args']['op_name_holds']} events_per_step={calls:g} "
+          f"least_ms_per_call={1e3 * least:.4f} "
+          f"ms_per_call={1e3 * per_step / calls:.4f}", flush=True)
+    return 100.0 * least * calls / per_step
 
 
 def metric_file(bench_dir, name):

@@ -275,8 +275,8 @@ def traced(su, clock, trace):
         return None
     if reduced is None:  # the CPU rehearsal: its trace has no device
         reduced = {"devices": 1, "busy_s": 0.0, "window_s": 0.0,
-                   "op_seconds": {}, "scope_seconds": {}, "device_ops": [],
-                   "idle_gaps": []}
+                   "op_seconds": {}, "op_events": {}, "scope_seconds": {},
+                   "device_ops": [], "idle_gaps": []}
     elif xplane.scopes_missing(reduced):
         log("scopes_missing: under half of the busy time carries a scope "
             "(an executable cached before the program had scopes?); the "

@@ -211,9 +211,11 @@ def self_ns(events):
 
 def reduce_rows(rows, window_ns=None, top=10):
     """``rows`` as ``load`` gives them -> busy seconds (mean over devices),
-    seconds by operation name (its events' durations, summed over devices),
-    seconds by scope path (``self_ns``, summed over devices: they add up to
-    the busy time; "" holds the operations that carry no scope), the idle
+    seconds and events by operation name (its events' durations and their
+    number, summed over devices: an operation that a ``while`` runs several
+    times a step has that many events), seconds by scope path (``self_ns``,
+    summed over devices: they add up to the busy time; "" holds the
+    operations that carry no scope), the idle
     seconds (mean over devices) and the window.  ``device_ops`` names the
     operations that took most time, each with its phase before it
     (``optimizer/fusion.7``).  ``window_ns`` (start, end) defaults to the
@@ -228,7 +230,7 @@ def reduce_rows(rows, window_ns=None, top=10):
         window_ns = (min(e[1] for ev in device.values() for e in ev),
                      max(e[1] + e[2] for ev in device.values() for e in ev))
     w0, w1 = window_ns
-    busy, ops, scopes, phases = 0.0, {}, {}, {}
+    busy, ops, counts, scopes, phases = 0.0, {}, {}, {}, {}
     for events in device.values():
         inside = [e for e in events if e[1] + e[2] > w0 and e[1] < w1]
         clipped = [(n, max(s, w0), min(s + d, w1) - max(s, w0), scope)
@@ -237,13 +239,14 @@ def reduce_rows(rows, window_ns=None, top=10):
             [s, s + d] for _, s, d, _ in clipped))
         for (n, _, d, scope), own in zip(inside, self_ns(clipped)):
             ops[n] = ops.get(n, 0.0) + d
+            counts[n] = counts.get(n, 0) + 1
             scopes[scope] = scopes.get(scope, 0.0) + own
             phases.setdefault(n, phase(scope))
     n_dev = len(device)
     busy_s, window_s = busy * 1e-9 / n_dev, (w1 - w0) * 1e-9
     op_seconds = {k: v * 1e-9 for k, v in ops.items()}
     return {"devices": n_dev, "busy_s": busy_s, "window_s": window_s,
-            "op_seconds": op_seconds,
+            "op_seconds": op_seconds, "op_events": counts,
             "scope_seconds": {k: v * 1e-9 for k, v in scopes.items()},
             "device_ops": [[(phases[k] + "/" if phases[k] else "") + k, v]
                            for k, v in sorted(op_seconds.items(),
@@ -256,6 +259,14 @@ def op_seconds(reduced, needle):
     names matched."""
     hits = {k: v for k, v in reduced["op_seconds"].items() if needle in k}
     return sum(hits.values()), len(hits)
+
+
+def op_events(reduced, needle):
+    """Events of the operations whose name holds ``needle``: how often they
+    ran in the window, whatever their names (``flash_bwd.29``,
+    ``flash_bwd.35``: one a layer; one name under a ``while``: one event an
+    iteration)."""
+    return sum(v for k, v in reduced["op_events"].items() if needle in k)
 
 
 def scope_matches(scope, holds=(), lacks=()):

@@ -181,6 +181,47 @@ def make_copy(dest):
     return path
 
 
+#: what a model_config PR appends after ``make_copy``: a cell of its own on
+#: ``tokens_per_s_per_chip`` and two per-layer entries that list it alone
+LATER_CELL = "later-lm"
+LATER_METRICS = {"later.steps_in_window": "steps_in_window",
+                 "later.trace_bytes": "trace_bytes"}
+#: an accepted cell's own metric that the later cell reports too (its blocks
+#: are rematerialised as well): its name goes to the end of that list
+LATER_SHARED = ("model.remat_ms_per_step",)
+
+
+def later_pr_appends(path):
+    """To the copy's manifest at ``path``, what ``benchmark/README.md`` says
+    a later PR does for a language-model cell: a traffic file and a cell at
+    the end of ``workloads``, the cell's name at the end of
+    ``tokens_per_s_per_chip``'s list, of every ``.lm`` list and of the
+    lists of ``LATER_SHARED``, two per-layer entries of its own (each with
+    its file) at the end of ``per_layer``.  Nothing that is there is edited
+    or moved."""
+    bench = os.path.join(os.path.dirname(path), "benchmark")
+    manifest = load(path)
+    dump(TOY_TRAFFIC["tokens_b2_s128"],
+         os.path.join(bench, "traffic", "tokens_b2_s128_later.json"))
+    manifest["workloads"].append({
+        "name": LATER_CELL, "config": "gpt2-toy",
+        "traffic": "tokens_b2_s128_later", "chips": 1, "why": "toy"})
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if m["name"] == "tokens_per_s_per_chip" or \
+                m["name"].endswith(".lm") or m["name"] in LATER_SHARED:
+            m["workloads"].append(LATER_CELL)
+    for name, reader in LATER_METRICS.items():
+        dump({"reader": "toy_readers:" + reader, "what": "a later cell's"},
+             os.path.join(bench, "metrics", name + ".json"))
+        manifest["per_layer"].append({
+            "name": name, "unit": "count", "better": "higher",
+            "source": "program_counter",
+            "layer": "loop: training/module.py fit",
+            "moves": "tokens_per_s_per_chip", "workloads": [LATER_CELL]})
+    dump(manifest, path)
+    return manifest
+
+
 def rehearsal_env():
     """The CPU rehearsal's environment: one CPU device, as a one-chip cell
     has, and one thread for the arithmetic."""

@@ -1,7 +1,9 @@
 """What a configuration's entry in ``BENCHMARK.json`` and its file owe each
 other: one function, applied to the real manifest and to ``bench_toy``'s
 copy, so that a cut configuration a later PR adds as new files is held to
-the same words as the two that are here (``benchmark/README.md``)."""
+the same words as the two that are here (``benchmark/README.md``); and the
+two things a cell's test file may say of the order of a list in the
+manifest, which later PRs append to."""
 
 import re
 
@@ -60,3 +62,17 @@ def check_config(entry, cfg):
     _need(isinstance(deployment, str) and deployment.strip()
           and "\n" not in deployment,
           f"{name}: a cut configuration says its deployment in one line")
+
+
+def stands_once_after(names, name, earlier):
+    """``name`` is in the list once, after every one of ``earlier`` that
+    the list holds: what a cell's test may say of a list's order.  A later
+    PR appends its cell to the list, so nothing is said of what follows."""
+    return names.count(name) == 1 and all(
+        names.index(e) < names.index(name) for e in earlier if e in names)
+
+
+def in_this_order(names, wanted):
+    """The ``wanted`` names stand in ``names`` once each, in that order,
+    wherever: other names may stand before, between and after them."""
+    return [n for n in names if n in wanted] == list(wanted)

@@ -25,8 +25,8 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 METRICS = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
 
 
-def cells_of(metric):
-    return metric.get("workloads", [w["name"] for w in MANIFEST["workloads"]])
+def cells_of(metric, manifest=MANIFEST):
+    return metric.get("workloads", [w["name"] for w in manifest["workloads"]])
 
 
 def test_manifest_has_exactly_the_contracts_keys():
@@ -64,9 +64,11 @@ def test_cell_resolves_to_files_that_exist(cell):
     assert any(cell["name"] in cells_of(m) for m in MANIFEST["per_layer"])
 
 
-@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
-def test_metric_entry_is_the_contracts_and_its_file_names_a_reader(metric):
-    end_to_end = metric in MANIFEST["end_to_end"]
+def check_metric_entry(metric, manifest=MANIFEST, bench=BENCH):
+    """One entry of ``end_to_end`` or ``per_layer`` against the contract,
+    and its file under ``bench``/metrics: of the manifest here, or of a copy
+    that a later PR has appended to."""
+    end_to_end = metric in manifest["end_to_end"]
     keys = {"name", "unit", "better", "source"} | (
         {"bound"} if end_to_end else {"layer", "moves"})
     assert set(metric) - {"workloads"} == keys
@@ -78,22 +80,55 @@ def test_metric_entry_is_the_contracts_and_its_file_names_a_reader(metric):
         assert 0.01 <= metric["bound"] <= 0.1
     # what the manifest says the file does not say again; a split name
     # (``.lm``) shares the file of the name before its last dot
-    path = readers.metric_file(BENCH, metric["name"])
+    path = readers.metric_file(bench, metric["name"])
     assert path is not None
     assert os.path.basename(path)[:-len(".json")] in (
         metric["name"], metric["name"].rpartition(".")[0])
     on_file = load(path)
     assert set(on_file) <= {"reader", "args", "what"} and on_file["what"]
-    assert callable(readers.resolve(on_file["reader"]))
+    sys.path.insert(0, bench)       # a copy's readers stand beside its files
+    try:
+        assert callable(readers.resolve(on_file["reader"]))
+    finally:
+        sys.path.remove(bench)
+
+
+def check_per_layer_moves(metric, manifest=MANIFEST):
+    """A per-layer entry moves an end-to-end metric that each of its cells
+    reports, and every cell it names exists."""
+    moved = next(m for m in manifest["end_to_end"]
+                 if m["name"] == metric["moves"])
+    assert set(cells_of(metric, manifest)) <= set(cells_of(moved, manifest))
+    assert set(cells_of(metric, manifest)) <= {
+        w["name"] for w in manifest["workloads"]}
+    assert "\n" not in metric["layer"] and len(metric["layer"]) <= 200
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_entry_is_the_contracts_and_its_file_names_a_reader(metric):
+    check_metric_entry(metric)
 
 
 @pytest.mark.parametrize("metric", MANIFEST["per_layer"],
                          ids=lambda m: m["name"])
 def test_per_layer_metric_moves_a_metric_each_of_its_cells_reports(metric):
-    moved = next(m for m in MANIFEST["end_to_end"]
-                 if m["name"] == metric["moves"])
-    assert set(cells_of(metric)) <= set(cells_of(moved))
-    assert "\n" not in metric["layer"] and len(metric["layer"]) <= 200
+    check_per_layer_moves(metric)
+
+
+def test_no_metric_file_waits_without_an_entry():
+    """Every file under metrics/ is read by some entry (a retired metric's
+    file goes with its entry; a metric that waits beside the manifest is
+    one the driver cannot see), and no configuration's file carries how
+    often a kernel runs in a step: the readers count that in the trace."""
+    read = {os.path.basename(readers.metric_file(BENCH, m["name"]))
+            for m in METRICS}
+    assert set(os.listdir(os.path.join(BENCH, "metrics"))) == read
+    assert not os.path.exists(os.path.join(BENCH, "sdar_per_layer.json"))
+    for root, _, names in os.walk(BENCH):
+        for f in names:
+            if f.endswith(".json"):
+                with open(os.path.join(root, f)) as fh:
+                    assert "calls_per_step" not in fh.read(), f
 
 
 def test_every_name_is_made_of_the_allowed_characters():
@@ -170,10 +205,31 @@ def test_malformed_cut_configuration_fails_the_contract(case):
         contract.check_config(entry, cfg)
 
 
+def cell_files_with_manifest_assertions():
+    """{test file's module name: its ``manifest_assertions``} for every
+    ``test_*.py`` beside this one that has the function: what a cell's test
+    file says of ``BENCHMARK.json`` (its cell's entry, the lists that hold
+    its name, its own per-layer entries) as a function of a manifest."""
+    import importlib
+    here = os.path.dirname(os.path.abspath(__file__))
+    found = {}
+    for f in sorted(os.listdir(here)):
+        if f.startswith("test_") and f.endswith(".py"):
+            module = importlib.import_module(f[:-len(".py")])
+            if hasattr(module, "manifest_assertions"):
+                found[module.__name__] = module.manifest_assertions
+    return found
+
+
 def test_a_later_pr_adds_one_of_each_as_new_files(tmp_path):
     """A configuration, a traffic mix with its generator, a per-layer
     metric with its reader and a cell, added to a copy without editing a
-    file that is there."""
+    file that is there; then what a model_config PR appends (a cell on
+    ``tokens_per_s_per_chip``, its name at the end of that list and of
+    every ``.lm`` list, two per-layer entries of its own at the end of
+    ``per_layer``), and every cell file's manifest assertions hold on the
+    result: a test that pins the order or the length of a list fails here,
+    in the PR that writes it, and not in the next configuration's."""
     manifest_path = bench_toy.make_copy(str(tmp_path))
     before = {f: open(os.path.join(root, f), "rb").read()
               for root, _, files in os.walk(BENCH) for f in files
@@ -204,6 +260,44 @@ def test_a_later_pr_adds_one_of_each_as_new_files(tmp_path):
     assert len(a) == traffic["distinct_batches"]
     assert a[0][0].shape == (2, 128) and (a[0][0] == b[0][0]).all()
     assert (a[0][1][:, :-1] == a[0][0][:, 1:]).all()
+    # -- the next configuration's cell and entries, appended ---------------
+    accepted = load(os.path.join(REPO, "BENCHMARK.json"))
+    grown = bench_toy.later_pr_appends(manifest_path)
+    assert grown["workloads"][-1]["name"] == bench_toy.LATER_CELL
+    assert [m["name"] for m in grown["per_layer"][-2:]] == list(
+        bench_toy.LATER_METRICS)
+    # every accepted entry is where it was, and its list starts as it did
+    for kind in ("end_to_end", "per_layer"):
+        for was, now in zip(accepted[kind], grown[kind]):
+            assert {**now, "workloads": None} == {**was, "workloads": None}
+            assert cells_of(now, grown)[:len(cells_of(was, accepted))] == \
+                cells_of(was, accepted)
+    rate = next(m for m in grown["end_to_end"]
+                if m["name"] == "tokens_per_s_per_chip")
+    assert rate["workloads"][-1] == bench_toy.LATER_CELL
+    for m in grown["per_layer"]:
+        assert (m.get("workloads", [None])[-1] == bench_toy.LATER_CELL) == (
+            m["name"].endswith(".lm") or m["name"] in bench_toy.LATER_METRICS
+            or m["name"] in bench_toy.LATER_SHARED)
+    # the contract holds for every entry of the grown manifest, the runner
+    # finds the new cell's files, and its line would hold its own metrics
+    for m in grown["end_to_end"] + grown["per_layer"]:
+        check_metric_entry(m, grown, copy)
+    for m in grown["per_layer"]:
+        check_per_layer_moves(m, grown)
+    _, cell, cfg, traffic, _, _ = bench_run.load_cell(
+        manifest_path, bench_toy.LATER_CELL)
+    assert (cell["config"], traffic["seq_len"]) == ("gpt2-toy", 128)
+    mine = {m["name"] for m in grown["per_layer"]
+            if bench_toy.LATER_CELL in cells_of(m, grown)}
+    assert set(bench_toy.LATER_METRICS) | {"model.mfu_pct.lm"} <= mine
+    assert not {n for n in mine if n.startswith("kernel.")}
+    # what each cell's test file says of the manifest holds on the grown one
+    said = cell_files_with_manifest_assertions()
+    assert {"test_hybrid_cell", "test_sdar_cell",
+            "test_flash_bwd_metrics"} <= set(said)
+    for module, assertions in said.items():
+        assertions(grown)
 
 
 # -- operations and bytes, against hand-worked values ---------------------
@@ -342,6 +436,10 @@ def test_reduction_of_made_up_rows():
     # an operation's seconds stay under its bare name ...
     assert red["op_seconds"] == {"fusion.1": 5.0, "kern_fwd.2": 2.0,
                                  "copy.3": 0.5}
+    # ... and so do its events: how often it ran in the window
+    assert red["op_events"] == {"fusion.1": 2, "kern_fwd.2": 1, "copy.3": 1}
+    assert xplane.op_events(red, "fusion") == 2
+    assert xplane.op_events(red, "no such") == 0
     # ... and the busy time is kept by scope path, "" for none: the second
     # [3,4) in which two operations ran goes to the one that started last
     assert red["scope_seconds"] == {
@@ -493,7 +591,74 @@ def test_the_traced_window_is_cut_to_whole_steps():
     red = xplane.reduce_rows(small, window_ns=window)
     assert red["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
     assert red["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
+    assert xplane.op_events(red, "fusion") == want["kernel_events"]
     assert xplane.whole_steps(xplane.load(SCOPED)) is None
+
+
+def _kernel_step(step_start, kernel_events, other=("fusion.9", 50.0)):
+    """One execution of the step's program from ``step_start`` (ns), 1,000
+    long: the kernel's events (name, offset, duration) and one other
+    operation."""
+    ops = [(n, step_start + at, d, STEP + "jvp(forward)/attn/custom_call")
+           for n, at, d in kernel_events]
+    ops.append((other[0], step_start + 900.0, other[1], STEP + "mul"))
+    return ("jit_train_step(7)", step_start, 1000.0, ""), ops
+
+
+KERNEL_STEPS = {
+    # one call a layer, each under a name of its own: two layers
+    "a name a layer": ([("kern_bd.1", 0.0, 100.0),
+                        ("kern_bd.2", 200.0, 100.0)], 2, 200.0),
+    # the block's recomputation runs the forward again: four events
+    "recomputed": ([("kern_bd.1", 0.0, 100.0), ("kern_bd.2", 200.0, 100.0),
+                    ("kern_bd.3", 400.0, 100.0), ("kern_bd.4", 600.0, 100.0)],
+                   4, 400.0),
+    # one name under a ``while`` that runs three times a step
+    "under a while": ([("kern_bd.1", 0.0, 100.0), ("kern_bd.1", 200.0, 100.0),
+                       ("kern_bd.1", 400.0, 100.0)], 3, 300.0),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_STEPS,
+                         ids=lambda c: c.replace(" ", "-"))
+def test_a_kernels_calls_a_step_are_its_events_in_the_traced_steps(case,
+                                                                   capsys):
+    """The roofline share multiplies one call's least time by the events a
+    whole step holds, whatever a configuration's file used to say: a list
+    of kept values that halves the calls leaves the share where it was."""
+    events, calls, ns_per_step = KERNEL_STEPS[case]
+    modules, ops = [], []
+    for i in range(5):      # the first and the last execution are cut off
+        module, step_ops = _kernel_step(2000.0 * i, events)
+        modules.append(module)
+        ops += step_ops
+    rows = [("/device:TPU:0", xplane.MODULES_LINE, modules),
+            ("/device:TPU:0", xplane.OPS_LINE, ops)]
+    window, steps = xplane.whole_steps(rows)
+    red = xplane.reduce_rows(rows, window_ns=window)
+    red["steps"] = steps
+    assert steps == 3 and xplane.op_events(red, "kern_bd") == 3 * calls
+    m = {"args": {"op_name_holds": "kern_bd",
+                  "ops_bytes": "opcount:flash_forward_ops_bytes",
+                  "shape": {"batch": "batch", "heads": "n_head",
+                            "seq": "seq_len", "head_dim": 64, "itemsize": 2}}}
+    ctx = {"trace": red, "rehearsal": False, "bench_dir": BENCH,
+           "device_kind": "TPU v5 lite", "cfg": {"n_head": 16},
+           "traffic": {"batch": 8, "seq_len": 1024}}
+    assert readers.kernel_ms_per_step(ctx, m) == pytest.approx(
+        ns_per_step * 1e-6)
+    ops_one, _ = opcount.flash_forward_ops_bytes(8, 16, 1024, 64, 2)
+    # every event took 100 ns: the share is one call's, however many ran
+    assert readers.kernel_roofline_pct(ctx, m) == pytest.approx(
+        100 * (ops_one / 197e12) / 100e-9)
+    assert f"events_per_step={calls} " in capsys.readouterr().out
+    # no event of that name: nothing to read, and nothing printed
+    none = {**m, "args": {**m["args"], "op_name_holds": "kern_other"}}
+    assert readers.kernel_ms_per_step(ctx, none) is None
+    assert readers.kernel_roofline_pct(ctx, none) is None
+    assert readers.kernel_roofline_pct({**ctx, "trace": None}, m) is None
+    assert readers.kernel_roofline_pct({**ctx, "rehearsal": True}, m) is None
+    assert capsys.readouterr().out == ""
 
 
 @pytest.mark.skipif(not os.path.isfile(FIXTURE), reason="no recorded trace")
@@ -507,6 +672,7 @@ def test_reduction_of_the_recorded_trace():
     seconds, names = xplane.op_seconds(red, want["kernel"])
     assert names == want["kernel_names"]
     assert seconds == pytest.approx(want["kernel_s"], rel=1e-9)
+    assert xplane.op_events(red, want["kernel"]) == want["kernel_events"]
     assert red["idle_gaps"][0][1] == pytest.approx(want["idle_s"], rel=1e-9)
 
 
@@ -548,6 +714,10 @@ def test_reduction_of_the_scoped_trace_by_scope():
         sum(red["op_seconds"].values()), rel=1e-12)
     assert [n for n, _ in red["device_ops"][:len(want["top_ops"])]] == \
         want["top_ops"]
+    assert set(red["op_events"].values()) == {want["events_of_each_name"]}
+    assert sum(red["op_events"].values()) == want["events"]
+    assert {k: xplane.op_events(red, k) for k in want["events_holding"]} \
+        == want["events_holding"]
     assert not xplane.scopes_missing(red)
 
 

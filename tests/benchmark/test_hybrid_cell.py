@@ -24,8 +24,14 @@ MANIFEST = load(os.path.join(REPO, "BENCHMARK.json"))
 CELL = "granite4hm-b2-seq4096"
 CONFIG = "granite-4.0-h-micro"
 CFG = load(os.path.join(BENCH, "configs", CONFIG + ".json"))
-NEW_METRICS = [m["name"] for m in MANIFEST["per_layer"]
-               if m.get("workloads") == [CELL]]
+#: the cell's own per-layer metrics (PR 28), by name: each lists the cell,
+#: and a later cell may list itself too (``model.remat_ms_per_step``)
+NEW_METRICS = [
+    "model.ssm_mixer_ms_per_step", "model.ssm_scan_ms_per_step",
+    "model.ssm_scan_bwd_ms_per_step", "model.ssm_conv_ms_per_step",
+    "model.gqa_attn_ms_per_step", "model.mlp_ms_per_step",
+    "model.remat_ms_per_step", "kernel.flash_fwd_ms_per_step.gqa",
+    "kernel.flash_fwd_roofline.gqa"]
 
 #: the published configuration at widths in the tens, through the same job
 TOY = {"name": "granite-toy", "hidden_size": 32,
@@ -76,22 +82,31 @@ def test_entry_and_file_meet_the_contract_and_no_width_differs():
     assert not any(contract.WIDTH.search(k) for k in CFG["reduced"])
 
 
-def test_the_cell_reports_what_the_lm_cell_reports_and_its_own():
-    cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
+def manifest_assertions(manifest):
+    """What this file says of ``BENCHMARK.json``, of the one here or of a
+    copy that later PRs have appended to."""
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "tokens_b2_s4096", 1)
-    traffic = load(os.path.join(BENCH, "traffic", "tokens_b2_s4096.json"))
-    assert (traffic["batch"], traffic["seq_len"]) == (2, 4096)
-    assert traffic["generator"] == "traffic:uniform_tokens"
-    mine = {m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+    mine = {m["name"] for m in manifest["end_to_end"] + manifest["per_layer"]
             if CELL in m.get("workloads", [CELL])}
     assert {"tokens_per_s_per_chip", "setup_s", "model.mfu_pct.lm",
             "model.unscoped_pct.lm", "loop.metric_device_steps_pct.lm",
             "compile.in_window.lm"} <= mine
+    # each of the nine is there once and lists the cell
     assert len(NEW_METRICS) == 9 and set(NEW_METRICS) <= mine
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert all(names.count(n) == 1 for n in NEW_METRICS)
     # the names the first LM cell's kernel readers hold are not this model's
-    assert not {"kernel.flash_fwd_ms_per_step", "kernel.flash_fwd_roofline",
-                "model.attn_bwd_loop_ms_per_step"} & mine
+    assert not {"kernel.flash_fwd_ms_per_step",
+                "kernel.flash_fwd_roofline"} & mine
+
+
+def test_the_cell_reports_what_the_lm_cell_reports_and_its_own():
+    manifest_assertions(MANIFEST)
+    traffic = load(os.path.join(BENCH, "traffic", "tokens_b2_s4096.json"))
+    assert (traffic["batch"], traffic["seq_len"]) == (2, 4096)
+    assert traffic["generator"] == "traffic:uniform_tokens"
 
 
 def test_operations_per_token_by_hand():
@@ -109,7 +124,6 @@ def test_operations_per_token_by_hand():
     ops, nbytes = opcount.flash_forward_ops_bytes(2, 32, 4096, 64, 2)
     assert ops == 2 * 32 * 2 * (2 * 4096 * 4096 * 64) // 2
     assert nbytes == 2 * 32 * 4096 * (4 * 64 * 2 + 4)
-    assert CFG["flash_fwd_calls_per_step"] == 2   # forward, and the remat
 
 
 # -- the rehearsal: a copy with the toy cells added as files ----------------
@@ -242,10 +256,14 @@ def test_new_metric_resolves_its_reader_and_finds_its_scope(step_scopes,
     reader = readers.resolve(on_file["reader"])
     if name.startswith("kernel."):
         # the flash forward's events carry the attention module's name
-        # (on the chip: ``attn.N``); read here from made-up operations
-        ctx = {"trace": {"steps": 2, "op_seconds": {"attn.2": 0.004,
-                                                    "attn.3": 0.004,
-                                                    "fusion.1": 1.0}},
+        # (on the chip: ``attn.N``); read here from made-up operations:
+        # two steps, in each one event in the forward pass and one in the
+        # block's recomputation
+        ctx = {"trace": {"steps": 2,
+                         "op_seconds": {"attn.2": 0.004, "attn.3": 0.004,
+                                        "fusion.1": 1.0},
+                         "op_events": {"attn.2": 2, "attn.3": 2,
+                                       "fusion.1": 9}},
                "traffic": {"batch": 2, "seq_len": 4096}, "cfg": CFG,
                "rehearsal": False, "device_kind": "TPU v5 lite",
                "bench_dir": BENCH}

@@ -4,13 +4,7 @@ traffic against the program's own noising transform, a toy-size rehearsal of
 the cell's job on the CPU (``DT_FORCE_CPU=1``) through the real runner with
 the float8 control, an overflowing buffer coming out not correct, and every
 new metric file against the scope paths of the job's own step.  The numbers
-a rehearsal prints are written nowhere.
-
-The cell's own per-layer metrics are in ``benchmark/sdar_per_layer.json``
-and not yet in ``BENCHMARK.json``: two of the benchmark's tests pin its
-``per_layer`` list as PR 31 left it (that file's ``what`` says which), and
-they are not this PR's to edit.  The rehearsal's copy of the manifest holds
-them, so every file and reader is run here."""
+a rehearsal prints are written nowhere."""
 
 import json
 import os
@@ -36,8 +30,20 @@ CELL = "sdar30b-ep8share-bd4-seq4096"
 CONFIG = "sdar-30b-a3b-chat"
 TRAFFIC = "bdtokens_b2_s4096_blk4"
 CFG = load(os.path.join(BENCH, "configs", CONFIG + ".json"))
-PENDING = load(os.path.join(BENCH, "sdar_per_layer.json"))
-NEW_METRICS = [m["name"] for m in PENDING["entries"]]
+#: the cell's own per-layer metrics, by name (they waited in a file beside
+#: the manifest from PR 34 to PR 36)
+NEW_METRICS = [
+    "model.bd_attn_ms_per_step", "model.moe_ms_per_step",
+    "model.moe_route_ms_per_step", "model.moe_dispatch_ms_per_step",
+    "model.moe_experts_ms_per_step", "kernel.flash_fwd_ms_per_step.bd",
+    "kernel.flash_fwd_roofline.bd", "kernel.flash_bwd_ms_per_step.bd",
+    "kernel.flash_bwd_roofline.bd", "kernel.gmm_ms_per_step",
+    "kernel.gmm_roofline", "moe.held_load_share_pct",
+    "moe.fullest_over_mean_load", "moe.buffer_fill_pct",
+    "moe.overflow_assignments"]
+#: the cells accepted before this one: in a list that holds this cell's
+#: name they stand before it
+EARLIER = ["resnet50-synth", "gpt2m-seq1024", "granite4hm-b2-seq4096"]
 #: the catalog row's config (guide, architectures.jsonl), key for key
 PUBLISHED = {
     "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
@@ -93,10 +99,57 @@ def test_entry_and_file_meet_the_contract_and_no_width_differs():
     assert CFG["check"]["limits_set_from"]
 
 
-def test_the_cell_reports_what_the_lm_cells_report_and_its_own():
-    cell = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
+def manifest_assertions(manifest):
+    """What this file says of ``BENCHMARK.json``, of the one here or of a
+    copy that later PRs have appended to."""
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, TRAFFIC, 1)
+    metrics = manifest["end_to_end"] + manifest["per_layer"]
+    mine = {m["name"] for m in metrics if CELL in m.get("workloads", [CELL])}
+    assert {"tokens_per_s_per_chip", "setup_s", "model.mfu_pct.lm",
+            "model.device_ms_per_step.lm", "model.unscoped_pct.lm",
+            "model.forward_ms_per_step.lm", "model.backward_ms_per_step.lm",
+            "loop.metric_device_steps_pct.lm", "compile.in_window.lm",
+            "device.idle_pct.lm", "device.peak_hbm_gb.lm",
+            "model.remat_ms_per_step"} <= mine
+    # the other cells' kernel names are not this one's
+    assert not {"kernel.flash_fwd_ms_per_step", "kernel.flash_bwd_roofline",
+                "kernel.flash_fwd_roofline.gqa"} & mine
+    # in every list the cell's name stands once, after the cells accepted
+    # before it; what a later PR appends after it is that PR's
+    for m in metrics:
+        if CELL in m.get("workloads", []):
+            assert contract.stands_once_after(m["workloads"], CELL, EARLIER), m
+    # the fifteen of its own: each there once, in the manifest's form, the
+    # cell first in its list (no accepted cell reads them)
+    names = [m["name"] for m in manifest["per_layer"]]
+    by_name = dict(zip(names, manifest["per_layer"]))
+    assert len(NEW_METRICS) == 15 and set(NEW_METRICS) <= mine
+    # the metric shared with the hybrid cell lists that cell, then this one
+    assert by_name["model.remat_ms_per_step"]["workloads"][:2] == [
+        "granite4hm-b2-seq4096", CELL]
+    for name in NEW_METRICS:
+        m = by_name[name]
+        assert names.count(name) == 1
+        assert set(m) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["workloads"][0] == CELL
+        assert m["moves"] == "tokens_per_s_per_chip"
+        assert ("roofline" in name) == (m["unit"] == "%"
+                                        and name[:6] == "kernel")
+        assert m["layer"] == {
+            "model.": "model step: dt_tpu/models, optim",
+            "kernel": "kernels: ops/pallas",
+            "moe.": "expert routing: parallel/moe.py"}[
+                next(k for k in ("model.", "kernel", "moe.")
+                     if name.startswith(k))]
+        assert m["source"] == ("program_counter" if name.startswith("moe.")
+                               else "device_trace")
+
+
+def test_the_cell_reports_what_the_lm_cells_report_and_its_own():
+    manifest_assertions(MANIFEST)
     traffic = load(os.path.join(BENCH, "traffic", TRAFFIC + ".json"))
     assert (traffic["batch"], traffic["seq_len"], traffic["block_length"],
             traffic["t_min"], traffic["distinct_batches"],
@@ -104,43 +157,21 @@ def test_the_cell_reports_what_the_lm_cells_report_and_its_own():
         2, 4096, 4, 0.001, 3, 1, 0)
     assert traffic["block_length"] == CFG["block_length"]
     assert traffic["generator"] == "sdar_traffic:block_diffusion_tokens"
-    mine = {m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]
-            if CELL in m.get("workloads", [CELL])}
-    assert {"tokens_per_s_per_chip", "setup_s", "model.mfu_pct.lm",
-            "model.device_ms_per_step.lm", "model.unscoped_pct.lm",
-            "model.forward_ms_per_step.lm", "model.backward_ms_per_step.lm",
-            "loop.metric_device_steps_pct.lm", "compile.in_window.lm",
-            "device.idle_pct.lm", "device.peak_hbm_gb.lm"} <= mine
-    # the other cells' kernel names are not this one's
-    assert not {"kernel.flash_fwd_ms_per_step", "kernel.flash_bwd_roofline",
-                "kernel.flash_fwd_roofline.gqa"} & mine
-    # every accepted entry is as it was but for this cell's name at the end
-    # of a list, and no entry is this cell's alone
-    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL and len(m["workloads"]) > 1
 
 
-def test_the_cells_own_metrics_wait_in_a_file_beside_the_manifest():
-    """Fifteen entries, each in the manifest's form, none yet in it; the
-    metric the cell shares with the hybrid cell's named."""
-    names = {m["name"] for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]}
-    assert len(NEW_METRICS) == 15 and not set(NEW_METRICS) & names
-    assert PENDING["cell"] == CELL
-    assert PENDING["append_cell_to"] == ["model.remat_ms_per_step"]
-    layers = {m["layer"] for m in MANIFEST["per_layer"]}
-    for m in PENDING["entries"]:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["workloads"] == [CELL]
-        assert m["moves"] == "tokens_per_s_per_chip"
-        assert m["better"] in ("lower", "higher")
-        assert m["layer"] in layers | {"expert routing: parallel/moe.py"}
-        assert re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}", m["name"])
-        assert ("roofline" in m["name"]) == (m["unit"] == "%"
-                                             and m["name"][:6] == "kernel")
-        path = readers.metric_file(BENCH, m["name"])
-        assert os.path.basename(path) == m["name"] + ".json"
+def test_the_cells_own_metrics_are_in_the_manifest():
+    """Fifteen entries, each with a file of its own name and the cell first
+    in its list (a later routed cell may follow it there: "alone" would be a
+    pin of the list's length), and the metric shared with the hybrid cell:
+    the line a traced run prints holds what the LM cells report and these
+    sixteen."""
+    for name in NEW_METRICS:
+        path = readers.metric_file(BENCH, name)
+        assert os.path.basename(path) == name + ".json"
+    line = [m["name"] for m in MANIFEST["per_layer"]
+            if CELL in m.get("workloads", [CELL])]
+    assert len(line) >= 23 + 15 + 1
+    assert set(NEW_METRICS) | {"model.remat_ms_per_step"} <= set(line)
 
 
 def test_operations_per_token_by_hand():
@@ -177,8 +208,6 @@ def test_operations_per_token_by_hand():
         2, 32, 4096, 4, 128, 2)
     assert ops_b == 5 * ops // 2
     assert nbytes_b == 2 * 32 * 8192 * (8 * 128 * 2 + 4)
-    assert (CFG["flash_fwd_calls_per_step"],
-            CFG["flash_bwd_calls_per_step"]) == (12, 6)
 
 
 def test_the_traffic_is_the_programs_own_noising_transform():
@@ -224,12 +253,6 @@ def manifest(tmp_path_factory):
             "why": "toy", "file": f"benchmark/configs/{name}.json"})
     bench_toy.dump(TOY_TRAFFIC,
                    os.path.join(bench, "traffic", "bdtokens_b2_s128.json"))
-    # what the benchmark PR that takes the waiting entries in will do
-    man["per_layer"] += [dict(m, workloads=list(m["workloads"]))
-                         for m in PENDING["entries"]]
-    for m in man["per_layer"]:
-        if m["name"] in PENDING["append_cell_to"]:
-            m["workloads"].append(CELL)
     for cell, config in (("toy-sdar", "sdar-toy"),
                          ("toy-sdar-overflow", "sdar-toy-overflow")):
         man["workloads"].append({"name": cell, "config": config,
@@ -380,9 +403,12 @@ def test_new_metric_resolves_its_reader_and_finds_its_scope(step_scopes,
     reader = readers.resolve(on_file["reader"])
     if name.startswith("kernel.gmm"):
         # XLA's pass names each grouped product's event ragged-dot-none.N
+        # (66 a step since PR 35, under names of their own or not)
         ctx = {"trace": {"steps": 2, "op_seconds": {
             "ragged-dot-none.70": 0.03, "ragged-dot-none.7": 0.05,
-            "ragged-dot-metadata.1": 1.0, "fusion.1": 1.0}},
+            "ragged-dot-metadata.1": 1.0, "fusion.1": 1.0}, "op_events": {
+            "ragged-dot-none.70": 60, "ragged-dot-none.7": 72,
+            "ragged-dot-metadata.1": 12, "fusion.1": 2}},
             "traffic": {"batch": 2, "seq_len": 4096}, "cfg": CFG,
             "rehearsal": False, "device_kind": "TPU v5 lite",
             "bench_dir": BENCH}
@@ -395,9 +421,8 @@ def test_new_metric_resolves_its_reader_and_finds_its_scope(step_scopes,
             assert nbytes == rows * 2048 * 2 + 16 * 2048 * 768 * 2 \
                 + rows * 768 * 4
             peak = load(os.path.join(BENCH, "peaks.json"))["TPU v5 lite"]
-            assert CFG["grouped_calls_per_step"] == 72
             assert value == pytest.approx(
-                100 * 72 * ops / peak["bf16_flops_per_s"] / 0.04)
+                100 * 66 * ops / peak["bf16_flops_per_s"] / 0.04)
         else:
             assert value == pytest.approx(40.0)
         return
@@ -405,10 +430,14 @@ def test_new_metric_resolves_its_reader_and_finds_its_scope(step_scopes,
         # the kernels' events carry the names their pallas_calls give
         # them under the mask rule; read here from made-up operations
         bwd = "flash_bwd" in name
+        # six calls a step of each: the forward under two names, the
+        # backward under one (as under a ``while``)
         ctx = {"trace": {"steps": 2, "op_seconds": {
             "flash_fwd_bd.2": 0.004, "flash_fwd_bd.3": 0.006,
             "flash_bwd_bd.1": 0.02, "flash_bwd.7": 5.0, "attn.1": 3.0,
-            "fusion.1": 1.0}},
+            "fusion.1": 1.0}, "op_events": {
+            "flash_fwd_bd.2": 6, "flash_fwd_bd.3": 6, "flash_bwd_bd.1": 12,
+            "flash_bwd.7": 48, "attn.1": 4, "fusion.1": 2}},
             "traffic": {"batch": 2, "seq_len": 4096, "block_length": 4},
             "cfg": CFG, "rehearsal": False, "device_kind": "TPU v5 lite",
             "bench_dir": BENCH}
@@ -419,7 +448,7 @@ def test_new_metric_resolves_its_reader_and_finds_its_scope(step_scopes,
                       sdar_opcount.bd_flash_forward_ops_bytes)(
                 2, 32, 4096, 4, 128, 2)
             peak = load(os.path.join(BENCH, "peaks.json"))["TPU v5 lite"]
-            calls = 6 if bwd else 12
+            calls = 6
             assert value == pytest.approx(
                 100 * calls * ops / peak["bf16_flops_per_s"] / per_step)
         else:
