@@ -55,7 +55,8 @@ def create(name: str, **kwargs):
     resnext50/101/152, mobilenet[_v2], densenet121/161/169/201, squeezenet,
     lstm_lm, transformer_lm, hybrid_lm (state-space and attention layers by
     a pattern), routed_lm (rotary attention and routed experts, trained
-    by diffusion over blocks)."""
+    by diffusion over blocks or next-token, there also over the keys a
+    learned index picks)."""
     key = name.lower().replace("-", "_")
     if key in _REGISTRY:
         return _REGISTRY[key](**kwargs)

@@ -1,14 +1,15 @@
 """A decoder of rotary grouped-query attention and routed experts, trained by
-diffusion over blocks.
+diffusion over blocks or as a causal next-token model whose attention reads
+only the keys a learned indexer picks.
 
 Beyond the reference's RNN ceiling (the cuDNN fused LSTM,
 ``src/operator/cudnn_rnn-inl.h:1``; SURVEY.md §5.7) and beside ``HybridLM``
 (``hybrid_lm.py``, whose ``RMSNorm`` this reuses): what the sparse-expert
 decoders of 2025 share and that one lacks.  Rotary
-positions (``rope``), a learned RMSNorm on each head's query and key before
-them, a ``head_dim`` that is not ``embed_dim / num_heads``, an expert layer
-(``parallel/moe.py`` ``RoutedExperts``) where the gated feed-forward stands,
-an untied head::
+positions (``rope``; ``mrope`` where a position has three parts), a learned
+RMSNorm on each head's query and key before them, a ``head_dim`` that is not
+``embed_dim / num_heads``, an expert layer (``parallel/moe.py``
+``RoutedExperts``) where the gated feed-forward stands, an untied head::
 
     a = rms(x) ;  q = rms_h(a Wq) , k = rms_h(a Wk) , v = a Wv
     q, k = rope(q, pos), rope(k, pos)
@@ -16,7 +17,10 @@ an untied head::
     x' = h + experts(rms(h))
     logits = rms(x_last) Whead                          (float32)
 
-**Training by diffusion over blocks** (BD3-LM, SDAR): the model is handed ``[xt ; x0]``, ``2 L`` positions a sequence: ``x0`` the
+Two objectives (``RoutedLM.objective``).
+
+**Training by diffusion over blocks** (``"block_diffusion"``; BD3-LM, SDAR):
+the model is handed ``[xt ; x0]``, ``2 L`` positions a sequence: ``x0`` the
 ``L`` tokens of data, ``xt`` the same with each block's tokens replaced by
 the mask id at that block's noise level (``data.block_diffusion_noise``
 makes both, with the targets and weights).  Both halves sit at rotary
@@ -27,8 +31,38 @@ logits are of the noisy half only, ``(B, L, V)``; the loss is
 ``ops.losses.weighted_masked_cross_entropy``.  (Generation denoises one block
 at a time over the clean blocks before it: the serving path's, not here.)
 
+**Causal next-token training** (``"causal"``): ``T`` tokens a sequence,
+``M`` the causal mask, logits over all ``T`` positions, the loss
+``ops.losses.softmax_cross_entropy`` against the next token.  With
+``indexer`` every layer carries a learned index (``ops/sparse_index.py``;
+DeepSeek-V3.2-Exp's sparse attention) and attends only to the ``k`` keys it
+picks; ``a~`` is the stop-gradient of ``a``, ``H_I`` index heads of ``D_I``::
+
+    q, k = mrope(q, pos[3, T]), mrope(k, pos[3, T])     sections of the D/2 frequency pairs
+    qI = (a~ WqI) as [T, H_I, D_I] ;  kI = layer_norm(a~ WkI) as [T, D_I]
+    w = a~ Ww * H_I^-0.5 as [T, H_I]
+    I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) / sqrt(D_I)        s <= t
+    S_t = the k keys s <= t of largest I[t, s]          (every s <= t while t < k)
+    h = x + [softmax over s in S_t of q_t . k_s / sqrt(D)] v Wo
+    loss = CE(next token, all T positions) + aux_loss_coef load_balance
+           + kl_weight sum_layers mean_t KL(pbar_t || softmax over S_t of I[t, .])
+    pbar_t[s] = stop_gradient(sum_h p_h[t, s] / H) ,  s in S_t
+
+``p_h`` are the main attention's own probabilities.  Under grouped-query
+attention one selection serves all the heads of a position.  The index
+learns from the KL term alone (its inputs are ``a~``, and the term reads the
+main attention as constants) and the rest of the model from the
+cross-entropy alone (the selection passes no gradient).  The selection
+reaches the flash kernels as data, a packed bitmap
+(``ops/pallas/attention.py`` ``SelectedKeysMask``); the KL term is sown
+under ``("aux_loss", "indexer_kl")`` and the layer's counts under
+``("counters", "dsa")`` (``DSA_COUNTERS``).
+
 Module names and ``jax.named_scope``s tell the parts apart in an operation's
 scope path: ``block3/attn/q_proj``, ``block3/attn/rope``,
+``block3/attn/indexer`` (the three projections, the index key's norm and the
+chunked scores), ``block3/attn/select`` (each row's k-th largest score and
+the bitmaps), ``block3/attn/indexer_kl``,
 ``block3/moe/route`` (``dispatch``, ``experts``, ``combine``), ``embed``,
 ``lm_head``.
 
@@ -44,14 +78,29 @@ from typing import Any, Optional
 import flax.linen as linen
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dt_tpu.models.hybrid_lm import RMSNorm
+from dt_tpu.ops import sparse_index
 from dt_tpu.ops.pallas.attention import (BlockDiffusionMask, DEFAULT_BLOCK,
-                                         NEG_INF, flash_attention)
+                                         NEG_INF, SelectedKeysMask,
+                                         backward_tiles, flash_attention,
+                                         forward_tiles, unpack_selection)
 from dt_tpu.parallel.moe import RoutedExperts
 
 F32 = jnp.float32
+
+
+def _turn(x, angle):
+    """``x`` (B, S, H, D) with the pair ``(x_i, x_{i + D/2})`` turned by
+    ``angle`` (S, D/2), in float32."""
+    half = x.shape[-1] // 2
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    v = x.astype(F32)
+    a, b = v[..., :half], v[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def rope(x, positions, theta: float):
@@ -60,30 +109,56 @@ def rope(x, positions, theta: float):
     float32."""
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=F32) / half)
-    angle = positions.astype(F32)[:, None] * freq[None, :]      # (S, D/2)
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
-    v = x.astype(F32)
-    a, b = v[..., :half], v[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    return _turn(x, positions.astype(F32)[:, None] * freq[None, :])
+
+
+def mrope(x, positions, theta: float, sections):
+    """Three-part rotary positions on ``x`` (B, S, H, D) at ``positions``
+    (3, S): the ``D/2`` frequency pairs are cut into ``sections`` (their
+    sum), and pair ``i`` of section ``r`` turns by ``positions[r] *
+    theta^(-2i/D)``: ``rope`` where the three rows are equal."""
+    half = x.shape[-1] // 2
+    if len(sections) != 3 or sum(sections) != half:
+        raise ValueError(f"sections {sections} do not cut {half} pairs in "
+                         f"three")
+    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    row = np.repeat(np.arange(3), sections)               # (D/2,)
+    return _turn(x, positions.astype(F32)[row, :].T * freq[None, :])
+
+
+#: the columns of the ``counters`` an attention layer with an index sows for
+#: each row of the batch: the pairs selected and the causal pairs (``sum
+#: min(t + 1, k)`` of ``sum (t + 1)``), the tiles that ran and the tiles
+#: ``causal`` alone would run in the forward kernel and in the backward, and
+#: the layer's KL term in millionths
+DSA_COUNTERS = ("selected_pairs", "causal_pairs", "fwd_tiles_run",
+                "fwd_tiles_causal", "bwd_tiles_run", "bwd_tiles_causal",
+                "kl_millionths")
 
 
 class RotaryAttention(linen.Module):
     """Grouped-query attention with a learned RMSNorm on each head's query
-    and key, then rotary positions, under ``mask``: a
-    ``BlockDiffusionMask`` over ``[noisy ; clean]``, both halves at
-    positions ``0 .. half-1``."""
+    and key, then rotary positions.  Under ``mask``, a
+    ``BlockDiffusionMask`` over ``[noisy ; clean]``, both halves sit at
+    positions ``0 .. half-1`` unless ``positions`` says otherwise.  Without
+    one it is causal attention (``flash_attention(causal=True)``) at
+    ``positions`` (S,) or (3, S) (``mrope_section`` cuts the pairs), default
+    ``0 .. S-1``; with ``indexer`` (``heads``, ``head_dim``, ``top_k``,
+    ``q_chunk``, ``kv_chunk``, ``kl_weight``) each query reads only the keys
+    its learned index picks (the module's docstring has the equations)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    mask: BlockDiffusionMask
+    mask: Optional[BlockDiffusionMask] = None
     rope_theta: float = 1e6
     eps: float = 1e-6
     attention: Optional[str] = "flash"   # 'flash' (Pallas) | None (plain)
     dtype: Any = F32
+    indexer: Any = None                  # a dict, or its items
+    mrope_section: Optional[tuple] = None
 
     @linen.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         b, s, d = x.shape
         h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
         dense = lambda n, name: linen.Dense(  # noqa: E731
@@ -96,19 +171,86 @@ class RotaryAttention(linen.Module):
         q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
         k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
         mask = self.mask
+        if positions is None:
+            positions = jnp.arange(s) % (s if mask is None else mask.half)
         with jax.named_scope("rope"):
-            pos = jnp.arange(s) % mask.half
-            q, k = rope(q, pos, self.rope_theta), rope(k, pos,
-                                                       self.rope_theta)
+            if positions.ndim == 2:
+                q, k = (mrope(t, positions, self.rope_theta,
+                              self.mrope_section) for t in (q, k))
+            else:
+                q, k = (rope(t, positions, self.rope_theta) for t in (q, k))
         # the kernel takes one head count: each key-value head is repeated
         # for the query heads it serves (its gradient sums over them)
-        k, v = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
-        if self.attention == "flash":
-            out = self._flash(q, k, v)
+        k_all, v_all = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+        if self.indexer is not None:
+            out = self._sparse(x, q, k, k_all, v_all)
+        elif mask is None:
+            out = self._causal(q, k_all, v_all)
+        elif self.attention == "flash":
+            out = self._flash(q, k_all, v_all)
         else:
-            out = self._plain(q, k, v)
+            out = self._plain(q, k_all, v_all)
         return checkpoint_name(
             dense(d, "o_proj")(out.reshape(b, s, h * hd)), "attn_out")
+
+    def _sparse(self, x, q, k, k_all, v_all):
+        """Attention over the keys the index picks, and the index's own
+        term and counts."""
+        b, s = x.shape[:2]
+        if s % DEFAULT_BLOCK:
+            raise ValueError(f"{s} positions are not whole tiles of "
+                             f"{DEFAULT_BLOCK}")
+        ix = dict(self.indexer)
+        hi, di = ix["heads"], ix["head_dim"]
+        scale = self.head_dim ** -0.5
+        with jax.named_scope("indexer"):
+            a = jax.lax.stop_gradient(x)
+            dense = lambda n, name: linen.Dense(  # noqa: E731
+                n, use_bias=False, dtype=self.dtype, name=name)
+            q_i = dense(hi * di, "index_q")(a).reshape(b, s, hi, di)
+            k_i = linen.LayerNorm(epsilon=self.eps, dtype=self.dtype,
+                                  name="index_k_norm")(dense(di, "index_k")(a))
+            w = dense(hi, "index_w")(a).astype(F32) * hi ** -0.5
+        # its own scopes inside: ``indexer`` (the scores), ``select``
+        selection, index_lse, picked = checkpoint_name(
+            sparse_index.select_keys(
+                q_i, k_i, w, ix["top_k"], q_chunk=ix.get("q_chunk", 512),
+                kv_chunk=ix.get("kv_chunk", 512)), "dsa_selection")
+        if self.attention == "flash":
+            out, lse = flash_attention(
+                q, k_all, v_all, causal=True, mask=SelectedKeysMask(),
+                selection=selection, return_lse=True)
+        else:
+            out, lse = self._plain(
+                q, k_all, v_all, unpack_selection(selection.by_query, s)
+                & jnp.tril(jnp.ones((s, s), bool)), with_lse=True)
+        with jax.named_scope("indexer_kl"):
+            kl = sparse_index.indexer_kl(
+                q_i, k_i, w, q, k, lse, index_lse, selection, scale=scale,
+                chunk=ix.get("q_chunk", 512))
+            self.sow("aux_loss", "indexer_kl",
+                     ix.get("kl_weight", 1.0) * jnp.mean(kl))
+        with jax.named_scope("select"):
+            itemsize = jnp.dtype(self.dtype).itemsize
+            tiles = [n for tile in (forward_tiles, backward_tiles)
+                     for n in SelectedKeysMask.tiles(
+                         selection.blocks, *tile(s, s, self.head_dim,
+                                                 itemsize), causal=True)]
+            self.sow("counters", "dsa", jnp.stack(
+                [picked, jnp.full((b,), s * (s + 1) // 2, jnp.int32), *tiles,
+                 jnp.round(jax.lax.stop_gradient(kl) * 1e6).astype(
+                     jnp.int32)], axis=1))
+        return out
+
+    def _causal(self, q, k, v):
+        if self.attention != "flash":
+            s = q.shape[1]
+            return self._plain(q, k, v, jnp.tril(jnp.ones((s, s), bool)))
+        # padded to the tile: a padded key lies after every real query
+        s, pad = q.shape[1], (-q.shape[1]) % DEFAULT_BLOCK
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+        return flash_attention(q, k, v, causal=True)[:, :s]
 
     def _flash(self, q, k, v):
         s, mask = q.shape[1], self.mask
@@ -129,16 +271,22 @@ class RotaryAttention(linen.Module):
                                   (out.shape[0], s) + out.shape[2:])
         return out
 
-    def _plain(self, q, k, v):
-        """A dense masked softmax in float32: the kernels' oracle."""
+    def _plain(self, q, k, v, allowed=None, with_lse=False):
+        """A dense masked softmax in float32: the kernels' oracle.
+        ``allowed`` (S, S) or (B, S, S), the block-diffusion rule's where
+        None."""
         s = q.shape[1]
-        pos = jnp.arange(s)
-        allowed = self.mask.allowed(pos[:, None], pos[None, :])
+        if allowed is None:
+            pos = jnp.arange(s)
+            allowed = self.mask.allowed(pos[:, None], pos[None, :])
+        if allowed.ndim == 3:
+            allowed = allowed[:, None]
         scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(F32),
                             k.astype(F32)) * self.head_dim ** -0.5
-        probs = jax.nn.softmax(jnp.where(allowed, scores, NEG_INF), axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs,
-                          v.astype(F32)).astype(q.dtype)
+        scores = jnp.where(allowed, scores, NEG_INF)
+        out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
+                         v.astype(F32)).astype(q.dtype)
+        return (out, jax.nn.logsumexp(scores, axis=-1)) if with_lse else out
 
 
 #: what a rematerialised ``RoutedBlock`` keeps from its forward pass, by
@@ -153,12 +301,21 @@ class RotaryAttention(linen.Module):
 #:               token each row holds; sizes (and 2 x R x 4 more: the
 #:               indices jax derives from those two for the two gathers)
 #:   moe_up      R x I x 4          float32, as ragged_dot returns it
+#: and under an index (``RotaryAttention(indexer=...)``), for H_I index heads
+#: of D_I:
+#:   dsa_selection     2 x T x T / 8 + (T / 128)^2 + T x 4   the two bitmaps
+#:               (33.5 MB each at 16,384 positions), the 128 x 128 blocks
+#:               that hold a pair, each row's index log-sum-exp
+#:   indexer_kl_grads  T x (H_I x D_I + D_I + H_I) x 4   what the KL term's
+#:               forward pass computed for its backward (72 MB): with it
+#:               held, the probabilities are made once a step
 #: Named and not kept: moe_gate (as moe_up: the pair fits the chip with
 #: under half a gigabyte to spare) and attn_qkv (T x (H + 2 KV) x D x c,
 #: the three projections' outputs: fewest milliseconds a gigabyte).
 #: PERF.md section 6, PR 35, has each name's measured milliseconds and
 #: bytes, and what the chip has room for.
-SAVED = ("flash_out", "flash_lse", "attn_out", "moe_route", "moe_up")
+SAVED = ("flash_out", "flash_lse", "attn_out", "moe_route", "moe_up",
+         "dsa_selection", "indexer_kl_grads")
 
 
 class RoutedBlock(linen.Module):
@@ -170,10 +327,10 @@ class RoutedBlock(linen.Module):
     dtype: Any = F32
 
     @linen.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         h = RMSNorm(self.eps, self.dtype, name="input_norm")(x)
         h = RotaryAttention(eps=self.eps, dtype=self.dtype, name="attn",
-                            **dict(self.attn))(h)
+                            **dict(self.attn))(h, positions)
         x = x + h.astype(x.dtype)
         h = RMSNorm(self.eps, self.dtype, name="post_norm")(x)
         h = RoutedExperts(dtype=self.dtype, name="moe", **dict(self.moe))(h)
@@ -181,11 +338,17 @@ class RoutedBlock(linen.Module):
 
 
 class RoutedLM(linen.Module):
-    """``tokens`` ``[xt ; x0]`` (B, 2 L) -> float32 logits (B, L, V) of the
-    noisy half, under the mask of blocks of ``block_length``.  The defaults
+    """Under ``objective="block_diffusion"``: ``tokens`` ``[xt ; x0]`` (B,
+    2 L) -> float32 logits (B, L, V) of the noisy half, under the mask of
+    blocks of ``block_length``.  Under ``"causal"``: ``tokens`` (B, T) ->
+    float32 logits (B, T, V) of every position, under the causal mask, at
+    ``positions`` (T,) or (3, T) (default ``0 .. T-1``, three equal rows
+    where ``mrope_section`` is set); with ``indexer`` (``RotaryAttention``'s)
+    each layer attends to the keys its index picks.  The defaults
     are a small model; a published one passes its own ``config.json``'s
-    numbers (``benchmark/sdar_drivers.py`` does).  ``held_experts`` and
-    ``buffer_rows`` are ``RoutedExperts``' ``held`` and ``buffer_rows``."""
+    numbers (``benchmark/sdar_drivers.py``, ``benchmark/keye_drivers.py``).
+    ``held_experts`` and ``buffer_rows`` are ``RoutedExperts``' ``held`` and
+    ``buffer_rows``."""
     vocab_size: int = 32000
     embed_dim: int = 256
     num_layers: int = 2
@@ -206,17 +369,32 @@ class RoutedLM(linen.Module):
     # per-block rematerialisation: a block keeps its input and the values
     # named in SAVED, and the backward pass computes the rest again
     remat: bool = False
+    objective: str = "block_diffusion"      # or 'causal'
+    indexer: Any = None                     # RotaryAttention's, a dict
+    mrope_section: Optional[tuple] = None
     saved_names = SAVED     # no field: the policy's list, and the gauge's
 
     @linen.compact
-    def __call__(self, tokens, training: bool = True):
+    def __call__(self, tokens, training: bool = True, positions=None):
         b, s = tokens.shape
-        if s % 2:
-            raise ValueError(f"[xt ; x0] has an even length, not {s}")
-        mask = BlockDiffusionMask(s // 2, self.block_length)
+        causal = self.objective == "causal"
+        if not causal and self.objective != "block_diffusion":
+            raise ValueError(f"no objective {self.objective!r}")
+        if not causal and (s % 2 or self.indexer is not None):
+            raise ValueError(f"[xt ; x0] has an even length, not {s}, and "
+                             f"no index")
+        mask = None if causal else BlockDiffusionMask(s // 2,
+                                                      self.block_length)
+        if causal and positions is None and self.mrope_section is not None:
+            positions = jnp.broadcast_to(jnp.arange(s), (3, s))   # text
         attn = dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim, rope_theta=self.rope_theta,
                     mask=mask, attention=self.attention)
+        if causal:
+            attn.update(
+                mrope_section=self.mrope_section and tuple(self.mrope_section),
+                indexer=self.indexer and tuple(sorted(
+                    dict(self.indexer).items())))
         moe = dict(num_experts=self.num_experts,
                    top_k=self.num_experts_per_tok,
                    intermediate=self.moe_intermediate,
@@ -233,8 +411,9 @@ class RoutedLM(linen.Module):
         for i in range(self.num_layers):
             x = block_cls(tuple(sorted(attn.items())),
                           tuple(sorted(moe.items())), self.rms_norm_eps,
-                          self.dtype, name=f"block{i}")(x)
-        x = x[:, :mask.half]            # the head over the noisy half only
+                          self.dtype, name=f"block{i}")(x, positions)
+        if not causal:
+            x = x[:, :mask.half]        # the head over the noisy half only
         x = RMSNorm(self.rms_norm_eps, self.dtype, name="final_norm")(x)
         head = self.param("lm_head", init,
                           (self.vocab_size, self.embed_dim), F32)

@@ -49,11 +49,33 @@ skipped step fetches follow from its offsets, as under ``causal``; the
 kernels are then named ``flash_fwd_bd`` and ``flash_bwd_bd``.  The rule is
 static: without it the programs are what they were.
 
+A second rule is *data*: ``SelectedKeysMask`` (attention over the keys a
+learned indexer picked for each query, ``ops/sparse_index.py``).  Which keys
+a query may see comes from an array, the ``Selection``, made once a layer
+outside the kernels and read a tile at a time by both: a bitmap packed
+``SEL_GROUP`` = 32 x 128 keys to a row of 128 int32 words, bit ``b`` of lane
+``l`` of group ``g`` standing for key ``g * 4096 + b * 128 + l``, so that a
+tile's 128-key column blocks are ``(words >> b) & 1``: a shift of the block
+as it lies, no gather and no turn.  The forward reads ``by_query``
+``(B, T / 4096, T, 128)`` (rows are queries, bits keys), one ``(block_q,
+128)`` block a grid step, fetched once for the key tiles of one group; the
+backward, whose tile lies keys by queries, reads ``by_key`` (rows are keys,
+bits queries).  A tile's fate is data too: ``blocks`` says which 128 x 128
+blocks hold a selected pair, each kernel is handed the any-reduction over
+its own tiles as a scalar-prefetch table, and a tile whose entry is 0
+computes nothing.  The rule composes with ``causal``: tiles above the
+diagonal neither run nor fetch, whatever the table says, and a tile the
+diagonal crosses is held to both.  33.5 MB a bitmap at 16,384 positions (an
+int8 square would be 268 MB).  The kernels are then named ``flash_fwd_sel``
+and ``flash_bwd_sel``; they compute every pair of a tile that runs, so the
+result is exact and the work is the tiles', not the selection's.
+
 Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
 kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``,
 ``GroupedQueryAttention(attention="flash")`` and ``RotaryAttention(attention=
-"flash")`` (``models/routed_lm.py``, under the mask rule) select it.
+"flash")`` (``models/routed_lm.py``, under either mask rule or plain
+``causal``) select it.
 
 Parity: ``dt_tpu.parallel.ring_attention.full_attention`` is the oracle;
 tests cover fwd/bwd, causal and full, interpret (CPU) mode.
@@ -64,7 +86,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -298,12 +320,172 @@ def _when_causal(tile, qi, ki, block_q: int, block_k: int):
     pl.when(runs & jnp.logical_not(crossed))(functools.partial(tile, False))
 
 
+# ---------------------------------------------------------------------------
+# the rule that is data: a selection of keys for each query
+# ---------------------------------------------------------------------------
+
+#: keys one row of 128 int32 words stands for: 32 bits x 128 lanes
+SEL_GROUP = 32 * _LANES
+
+
+class Selection(NamedTuple):
+    """Which keys each query may see, as the kernels read it (``pack_rows``
+    and ``pack_columns`` make the bitmaps chunk by chunk, ``pack_selection``
+    from a whole boolean array):
+
+    ``by_query`` (B, G, T, 128) int32, ``G = ceil(T / 4096)``: bit ``b`` of
+    ``[n, g, t, l]`` is set where query ``t`` sees key ``g * 4096 + b * 128 +
+    l``; ``by_key`` (B, G, T, 128): bit ``b`` of ``[n, g, s, l]`` where key
+    ``s`` is seen by query ``g * 4096 + b * 128 + l`` (the backward's tile
+    lies keys by queries); ``blocks`` (B, T / 128, T / 128) bool: which
+    128-query by 128-key blocks hold a selected pair."""
+    by_query: jax.Array
+    by_key: jax.Array
+    blocks: jax.Array
+
+
+def pack_rows(allowed):
+    """``allowed`` (..., R, T) bool, ``R`` rows against all ``T`` positions
+    of the other side -> (..., G, R, 128) int32 in the ``Selection``'s
+    layout: the rows are queries and the packed side keys for ``by_query``,
+    the other way round for ``by_key``."""
+    *lead, r, t = allowed.shape
+    g = -(-t // SEL_GROUP)
+    a = jnp.pad(allowed, [(0, 0)] * (len(lead) + 1)
+                + [(0, g * SEL_GROUP - t)])
+    a = a.reshape(*lead, r, g, 32, _LANES).astype(jnp.uint32)
+    words = jnp.sum(a << jnp.arange(32, dtype=jnp.uint32)[:, None], axis=-2,
+                    dtype=jnp.uint32)
+    return jnp.moveaxis(lax.bitcast_convert_type(words, jnp.int32), -2, -3)
+
+
+def pack_columns(allowed, first: int):
+    """``allowed`` (..., R, C) bool, the ``C`` positions from ``first`` of
+    the packed side (whole 128s, inside one group) -> (..., R, 128) int32:
+    their bits of the group's words, the other bits 0, to be OR-ed into the
+    group ``first // SEL_GROUP``."""
+    *lead, r, c = allowed.shape
+    if c % _LANES or first % _LANES or \
+            first // SEL_GROUP != (first + c - 1) // SEL_GROUP:
+        raise ValueError(f"{c} positions from {first} are not whole 128s "
+                         f"inside one group of {SEL_GROUP}")
+    a = allowed.reshape(*lead, r, c // _LANES, _LANES).astype(jnp.uint32)
+    bit = (first % SEL_GROUP) // _LANES \
+        + jnp.arange(c // _LANES, dtype=jnp.uint32)
+    return lax.bitcast_convert_type(
+        jnp.sum(a << bit[:, None], axis=-2, dtype=jnp.uint32), jnp.int32)
+
+
+def _bits(words):
+    """(..., 128) int32 words -> (..., 32, 128) int32: bit ``b`` of each."""
+    return (words[..., None, :] >> jnp.arange(32)[:, None]) & 1
+
+
+def selected_blocks(by_query):
+    """``by_query`` (B, G, T, 128) -> (B, T / 128, T / 128) bool: the 128 x
+    128 blocks that hold a selected pair (a block's words OR-ed over its
+    128 queries and 128 lanes first, then one word's 32 bits)."""
+    b, g, t, _ = by_query.shape
+    any_of = lax.reduce(by_query.reshape(b, g, t // _LANES, _LANES * _LANES),
+                        jnp.int32(0), lax.bitwise_or, (3,))
+    cols = (any_of[..., None] >> jnp.arange(32)) & 1    # (B, G, T / 128, 32)
+    return jnp.moveaxis(cols, 1, 2).reshape(
+        b, t // _LANES, g * 32)[..., :t // _LANES] != 0
+
+
+def pack_selection(allowed) -> Selection:
+    """``allowed`` (B, T, T) bool (query, key) -> the ``Selection``; ``T`` a
+    multiple of 128.  For tests and small sizes: the indexer packs chunk by
+    chunk and never holds the square (``ops/sparse_index.py``)."""
+    by_query = pack_rows(allowed)
+    return Selection(by_query, pack_rows(jnp.swapaxes(allowed, -1, -2)),
+                     selected_blocks(by_query))
+
+
+def unpack_selection(by_query, t: int):
+    """``by_query`` (B, G, T, 128) -> (B, T, T) bool (query, key): the dense
+    oracle's mask.  Small sizes only."""
+    b, g = by_query.shape[:2]
+    return jnp.moveaxis(_bits(by_query), 1, 2).reshape(
+        b, t, g * SEL_GROUP)[..., :t] != 0
+
+
+def unpack_tile(words, first, n: int):
+    """``words`` (R, 128) int32 of one group -> (R, n) int32, 1 where the
+    position ``first + c`` of the packed side is selected (``first`` inside
+    the group, a traced or Python integer; ``n`` whole 128s)."""
+    bit0 = (first % SEL_GROUP) // _LANES
+    return jnp.concatenate([(words >> (bit0 + m)) & 1
+                            for m in range(n // _LANES)], axis=1)
+
+
+@dataclasses.dataclass(frozen=True)
+class SelectedKeysMask:
+    """The rule that is data: query ``t`` sees the keys a ``Selection``
+    names for it (``flash_attention(..., mask=SelectedKeysMask(),
+    selection=...)``), beside ``causal`` or without it.  Static in kind
+    only: the kernels it selects take the bitmaps and a table of tile fates
+    as operands.  Every query must see at least one key (under ``causal``
+    an indexer's selection holds the query's own position or an earlier
+    one): a row without any would read an average of the values."""
+
+    @staticmethod
+    def tile_fates(blocks, block_q: int, block_k: int, by_key: bool = False):
+        """``blocks`` (B, T / 128, T / 128) -> (B, n_q, n_k) int32 (``by_key``:
+        (B, n_k, n_q)): 1 where the tile holds a selected pair."""
+        b, nq, nk = blocks.shape
+        rq, rk = block_q // _LANES, block_k // _LANES
+        fates = jnp.any(blocks.reshape(b, nq // rq, rq, nk // rk, rk),
+                        axis=(2, 4))
+        return (jnp.swapaxes(fates, 1, 2) if by_key else fates).astype(
+            jnp.int32)
+
+    @staticmethod
+    def tiles(blocks, block_q: int, block_k: int, causal: bool):
+        """(tiles that run, tiles ``causal`` alone would run) for each row of
+        the batch, (B,) int32 each: what the counters report."""
+        fates = SelectedKeysMask.tile_fates(blocks, block_q, block_k) != 0
+        nq, nk = fates.shape[1:]
+        below = jnp.ones((nq, nk), bool) if not causal else (
+            jnp.arange(nk)[None, :] * block_k
+            <= jnp.arange(nq)[:, None] * block_q + block_q - 1)
+        return jnp.sum(fates & below, axis=(1, 2), dtype=jnp.int32), \
+            jnp.full((fates.shape[0],), jnp.sum(below), jnp.int32)
+
+
+def _at_or_before(shape, q_axis: int, gap):
+    """Of a tile of ``shape`` whose queries lie along ``q_axis``: the pairs
+    whose key is at or before the query, ``gap`` the tile's first key
+    position less its first query position."""
+    return lax.broadcasted_iota(jnp.int32, shape, q_axis) \
+        - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) >= gap
+
+
+def _when_selected(tile, fate, causal: bool, qi, ki, block_q: int,
+                   block_k: int):
+    """Run ``tile(masked)`` for the (qi, ki) tile under a selection: not at
+    all where its ``fate`` is 0 or, under ``causal``, where it lies above
+    the diagonal; ``masked`` says whether the diagonal crosses it (the
+    bitmap is applied either way)."""
+    runs = fate != 0
+    if not causal:
+        pl.when(runs)(functools.partial(tile, False))
+        return
+    runs = runs & (ki * block_k <= qi * block_q + block_q - 1)
+    crossed = ki * block_k + block_k - 1 > qi * block_q
+    pl.when(runs & crossed)(functools.partial(tile, True))
+    pl.when(runs & jnp.logical_not(crossed))(functools.partial(tile, False))
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                  acc_ref, m_ref, l_ref, *,
                  scale: float, causal: bool, block_q: int, block_k: int,
-                 n_k: int, mask: Optional[BlockDiffusionMask] = None):
+                 n_k: int, mask: Optional[BlockDiffusionMask] = None,
+                 sel=None):
     """One (bh, q_block, k_block) grid step; kv axis is sequential, so the
-    VMEM scratch (acc, m, l) carries the online softmax across it."""
+    VMEM scratch (acc, m, l) carries the online softmax across it.  ``sel``
+    (under a ``SelectedKeysMask``) is ``(fate, words)``: the tile's entry
+    of the fate table and the ref of its queries' bitmap block."""
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -321,7 +503,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         q, k, v = q_ref[0], k_ref[0], v_ref[0]        # (BQ, D), (BK, D) x2
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if mask is not None:
+        if sel is not None:
+            seen = unpack_tile(sel[1][0, 0], ki * block_k, block_k) != 0
+            if masked:
+                seen = seen & _at_or_before(s.shape, 0,
+                                            ki * block_k - qi * block_q)
+            s = jnp.where(seen, s, NEG_INF)
+        elif mask is not None:
             if masked is not None:
                 s = jnp.where(_band_mask(mask, masked, 0, s.shape), s,
                               NEG_INF)
@@ -344,7 +532,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    if mask is not None:
+    if sel is not None:
+        _when_selected(_attend, sel[0], causal, qi, ki, block_q, block_k)
+    elif mask is not None:
         _when_block_diffusion(_attend, mask, qi, ki, block_q, block_k)
     elif causal:
         _when_causal(_attend, qi, ki, block_q, block_k)
@@ -367,12 +557,22 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             block_q // _LANES, _LANES, _LANES).sum(axis=1)
 
 
+def _attn_kernel_sel(fate_ref, q_ref, k_ref, v_ref, words_ref, *rest,
+                     heads: int, **kw):
+    """``_attn_kernel`` under a ``SelectedKeysMask``: the fate table comes
+    first (scalar prefetch), the queries' bitmap block after v."""
+    fate = fate_ref[pl.program_id(0) // heads, pl.program_id(1),
+                    pl.program_id(2)]
+    _attn_kernel(q_ref, k_ref, v_ref, *rest, sel=(fate, words_ref), **kw)
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "causal", "block_q", "block_k", "interpret", "mask"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
-                      interpret, mask=None):
+                      interpret, mask=None, selection=None):
     """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S)).  ``block_q`` /
     ``block_k`` of None are derived from the shapes (``forward_tiles``).
+    ``selection`` is the ``Selection`` a ``SelectedKeysMask`` reads.
 
     Jitted and inlined: a model's layers share one trace of the kernel's
     body (Pallas traces it anew for every call otherwise, 24 times a
@@ -380,8 +580,10 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     and so its event's name."""
     bh, s, d = q3.shape
     sk = k3.shape[1]
+    selected = isinstance(mask, SelectedKeysMask)
     if block_q is None or block_k is None:
-        dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize, mask)
+        dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize,
+                               None if selected else mask)
         block_q, block_k = block_q or dq, block_k or dk
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask)
     n_q = s // block_q
@@ -389,47 +591,69 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     kern = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k, mask=mask)
-    if mask is not None:
-        kv_map = lambda b, qi, ki: (
-            b, mask.key_tile(qi, ki, block_q, block_k), 0)
+    # the index maps take the grid's indices and, under a selection, the
+    # scalar-prefetch table after them
+    if mask is not None and not selected:
+        key_tile = lambda qi, ki: mask.key_tile(qi, ki, block_q, block_k)
     elif causal:
         # a skipped step names the last block its query tile needs: the
         # same block as the step before, so nothing is fetched for it
-        kv_map = lambda b, qi, ki: (
-            b, jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k), 0)
+        key_tile = lambda qi, ki: jnp.minimum(
+            ki, (qi * block_q + block_q - 1) // block_k)
     else:
-        kv_map = lambda b, qi, ki: (b, ki, 0)
+        key_tile = lambda qi, ki: ki
+    kv_map = lambda b, qi, ki, *_: (b, key_tile(qi, ki), 0)
+    q_map = lambda b, qi, ki, *_: (b, qi, 0)
     sub = block_q // _LANES
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, d), kv_map),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, 1, sub, _LANES), lambda b, qi, ki, *_: (b, qi, 0, 0)),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+    ]
+    operands = (q3, k3, v3)
+    if selected:
+        # the queries' bitmap block of the key tile's group: one fetch for
+        # the SEL_GROUP / block_k key tiles that share it
+        heads = bh // selection.by_query.shape[0]
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_q, _LANES), lambda b, qi, ki, *_: (
+                b // heads, key_tile(qi, ki) * block_k // SEL_GROUP, qi, 0)))
+        kern = functools.partial(_attn_kernel_sel, heads=heads,
+                                 **kern.keywords)
+        operands = (SelectedKeysMask.tile_fates(selection.blocks, block_q,
+                                                block_k),
+                    q3, k3, v3, selection.by_query)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, n_q, n_k), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes))
+    else:
+        grid = dict(grid=(bh, n_q, n_k), in_specs=in_specs,
+                    out_specs=out_specs, scratch_shapes=scratch_shapes)
     out, lse = pl.pallas_call(
         kern,
         # under a mask rule the forward has a name of its own in a trace;
         # otherwise its events carry its caller's, as they always have
-        **({} if mask is None else {"name": "flash_fwd_bd"}),
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, 1, sub, _LANES),
-                         lambda b, qi, ki: (b, qi, 0, 0)),
-        ],
+        **({} if mask is None else
+           {"name": "flash_fwd_sel" if selected else "flash_fwd_bd"}),
+        **grid,
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, n_q, sub, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
-    )(q3, k3, v3)
+    )(*operands)
     return out, lse.reshape(bh, s)
 
 
@@ -443,10 +667,12 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
     the gauges' label ``mask`` name it, with the tiles that run of the
     grid's.  Trace time only."""
     s, sk, d, dtype = shape
-    rule = "" if mask is None else (
-        f"block_diffusion.half{mask.half}.block{mask.block}.run"
-        f"{mask.tiles_run(block_q, block_k)}of"
-        f"{(s // block_q) * (sk // block_k)}")
+    if isinstance(mask, BlockDiffusionMask):
+        rule = (f"block_diffusion.half{mask.half}.block{mask.block}.run"
+                f"{mask.tiles_run(block_q, block_k)}of"
+                f"{(s // block_q) * (sk // block_k)}")
+    else:   # a selection's tiles are data: the model's counters have them
+        rule = "" if mask is None else "selected_keys"
     logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
                  "block_k=%d%s", "bwd_" if bwd else "", s, sk, d, dtype,
                  block_q, block_k, " mask=" + rule if rule else "")
@@ -497,7 +723,7 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       scale: float, causal: bool, block_q: int, block_k: int,
                       n_q: int, n_k: int,
-                      mask: Optional[BlockDiffusionMask] = None):
+                      mask: Optional[BlockDiffusionMask] = None, sel=None):
     """One (bh, k_block, q_block) grid step of the backward.  The tile is
     held keys by queries (``s^T = k q^T``): the log-sum-exp and delta of the
     query rows are then lane rows that broadcast down the sublanes, and
@@ -521,7 +747,14 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         nt = (((1,), (1,)), ((), ()))
         st = jax.lax.dot_general(k, q, nt,
                                  preferred_element_type=jnp.float32) * scale
-        if mask is not None:
+        if sel is not None:
+            # the keys' bitmap block: rows are keys, bits queries
+            seen = unpack_tile(sel[1][0, 0], qi * block_q, block_q) != 0
+            if masked:
+                seen = seen & _at_or_before(st.shape, 1,
+                                            ki * block_k - qi * block_q)
+            st = jnp.where(seen, st, NEG_INF)
+        elif mask is not None:
             if masked is not None:
                 st = jnp.where(_band_mask(mask, masked, 1, st.shape), st,
                                NEG_INF)
@@ -548,7 +781,9 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if mask is not None:
+    if sel is not None:
+        _when_selected(_tile, sel[0], causal, qi, ki, block_q, block_k)
+    elif mask is not None:
         _when_block_diffusion(_tile, mask, qi, ki, block_q, block_k)
     elif causal:
         _when_causal(_tile, qi, ki, block_q, block_k)    # as the forward
@@ -565,20 +800,33 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
+def _flash_bwd_kernel_sel(fate_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
+                          v_ref, words_ref, *rest, heads: int, **kw):
+    """``_flash_bwd_kernel`` under a ``SelectedKeysMask``: the fate table
+    (key tiles by query tiles) first, the keys' bitmap block after v."""
+    fate = fate_ref[pl.program_id(0) // heads, pl.program_id(1),
+                    pl.program_id(2)]
+    _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
+                      sel=(fate, words_ref), **kw)
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "causal", "block_q", "block_k", "interpret", "mask"))
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
-                      block_q=None, block_k=None, mask=None):
+                      block_q=None, block_k=None, mask=None, selection=None):
     """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
     (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
-    ``flash_bwd``.  Its tiles come from the shapes (``backward_tiles``).
+    ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel`` under a mask rule).
+    Its tiles come from the shapes (``backward_tiles``).
 
     Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
     kernel's body for all of a model's layers."""
     bh, s, d = q3.shape
     sk = k3.shape[1]
+    selected = isinstance(mask, SelectedKeysMask)
     if block_q is None or block_k is None:
-        tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize, mask)
+        tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize,
+                                None if selected else mask)
         block_q, block_k = block_q or tq, block_k or tk
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
                 mask=mask)
@@ -589,7 +837,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     kern = functools.partial(
         _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_q=n_q, n_k=n_k, mask=mask)
-    if mask is not None:
+    if mask is not None and not selected:
         first = lambda ki, qi: mask.query_tile(ki, qi, block_q, block_k)
     elif causal:
         # a skipped step names the first query block its key tile needs:
@@ -598,35 +846,55 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
             jnp.maximum(qi, ki * block_k // block_q), n_q - 1)
     else:
         first = lambda ki, qi: qi
+    # the index maps take the grid's indices and, under a selection, the
+    # scalar-prefetch table after them
     q_spec = pl.BlockSpec((1, block_q, d),
-                          lambda b, ki, qi: (b, first(ki, qi), 0))
+                          lambda b, ki, qi, *_: (b, first(ki, qi), 0))
     row_spec = pl.BlockSpec((1, 1, block_q),
-                            lambda b, ki, qi: (b, 0, first(ki, qi)))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
+                            lambda b, ki, qi, *_: (b, 0, first(ki, qi)))
+    k_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi, *_: (b, ki, 0))
+    in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec, k_spec]
+    out_specs = [pl.BlockSpec((1, s, d), lambda b, ki, qi, *_: (b, 0, 0)),
+                 k_spec, k_spec]
+    scratch_shapes = [
+        pltpu.VMEM((s, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+    ]
+    operands = (q3, do3, lse.reshape(bh, 1, s), delta.reshape(bh, 1, s), k3,
+                v3)
+    if selected:
+        # the keys' bitmap block of the query tile's group
+        heads = bh // selection.by_key.shape[0]
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_k, _LANES), lambda b, ki, qi, *_: (
+                b // heads, first(ki, qi) * block_q // SEL_GROUP, ki, 0)))
+        kern = functools.partial(_flash_bwd_kernel_sel, heads=heads,
+                                 **kern.keywords)
+        operands = (SelectedKeysMask.tile_fates(
+            selection.blocks, block_q, block_k, by_key=True),) + operands \
+            + (selection.by_key,)
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bh, n_k, n_q), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes))
+    else:
+        grid = dict(grid=(bh, n_k, n_q), in_specs=in_specs,
+                    out_specs=out_specs, scratch_shapes=scratch_shapes)
     dq, dk, dv = pl.pallas_call(
         kern,
-        name="flash_bwd" if mask is None else "flash_bwd_bd",
-        grid=(bh, n_k, n_q),
-        in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
-        out_specs=[
-            pl.BlockSpec((1, s, d), lambda b, ki, qi: (b, 0, 0)),
-            k_spec, k_spec,
-        ],
+        name="flash_bwd" if mask is None else (
+            "flash_bwd_sel" if selected else "flash_bwd_bd"),
+        **grid,
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v3.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((s, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=2 * VMEM_BUDGET),
         interpret=interpret,
-    )(q3, do3, lse.reshape(bh, 1, s), delta.reshape(bh, 1, s), k3, v3)
+    )(*operands)
     return dq, dk, dv
 
 
@@ -664,12 +932,47 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, mask, res,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+# under a SelectedKeysMask: a function of its own (the selection is an
+# operand and the log-sum-exp a result), so that without the rule ``_flash``
+# and what it lowers to are what they were
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_sel(q3, k3, v3, selection, scale, causal, block_q, block_k,
+               interpret):
+    return _flash_fwd_pallas(
+        q3, k3, v3, scale=scale, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, mask=SelectedKeysMask(),
+        selection=selection)
+
+
+def _flash_sel_fwd_rule(q3, k3, v3, selection, scale, causal, block_q,
+                        block_k, interpret):
+    out, lse = _flash_sel(q3, k3, v3, selection, scale, causal, block_q,
+                          block_k, interpret)
+    out = checkpoint_name(out, "flash_out")         # as _flash_fwd_rule
+    lse = checkpoint_name(lse, "flash_lse")
+    return (out, lse), (q3, k3, v3, selection, out, lse)
+
+
+def _flash_sel_bwd_rule(scale, causal, block_q, block_k, interpret, res, cts):
+    # the log-sum-exp's cotangent is dropped: what reads it (the indexer's
+    # KL term) takes it as a constant
+    q3, k3, v3, selection, out, lse = res
+    dq, dk, dv = _flash_bwd_pallas(
+        q3, k3, v3, out, lse, cts[0], scale=scale, causal=causal,
+        interpret=interpret, mask=SelectedKeysMask(), selection=selection)
+    return dq, dk, dv, None
+
+
+_flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    mask: Optional[BlockDiffusionMask] = None) -> jax.Array:
+                    mask=None, selection: Optional[Selection] = None,
+                    return_lse: bool = False):
     """Fused attention, (B, S, H, D) layout (``full_attention`` oracle).
 
     Sequence lengths must be multiples of ``DEFAULT_BLOCK`` (pad upstream;
@@ -683,7 +986,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``mask`` is a rule beside ``causal`` (and instead of it): a
     ``BlockDiffusionMask(half, block)`` over ``2 half`` positions, queries
     and keys alike, each half a multiple of ``DEFAULT_BLOCK``.  Both kernels
-    prune, mask and fetch tile by tile by the rule.
+    prune, mask and fetch tile by tile by the rule.  Or a
+    ``SelectedKeysMask()`` with its ``selection`` (a ``Selection`` over
+    ``s`` queries and as many keys), beside ``causal`` or without it;
+    ``return_lse`` then also returns the float32 log-sum-exp (B, H, S), a
+    constant to whatever reads it.
     """
     if interpret is None:
         interpret = _default_interpret()
@@ -691,18 +998,35 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scale = 1.0 / (q.shape[-1] ** 0.5)
     b, s, h, d = q.shape
     sk = k.shape[1]
-    if mask is not None and (causal or s != sk or s != 2 * mask.half):
+    selected = isinstance(mask, SelectedKeysMask)
+    if selected != (selection is not None) or (return_lse and not selected):
+        raise ValueError("a SelectedKeysMask and its selection go together, "
+                         "and only they return the log-sum-exp")
+    if selected:
+        want = (b, -(-s // SEL_GROUP), s, _LANES)
+        if s != sk or selection.by_query.shape != want \
+                or selection.by_key.shape != want:
+            raise ValueError(f"a selection over ({s}, {sk}) positions is two "
+                             f"bitmaps of {want}, not "
+                             f"{selection.by_query.shape}")
+    elif mask is not None and (causal or s != sk or s != 2 * mask.half):
         raise ValueError(f"{mask} is a rule over {2 * mask.half} positions, "
                          f"queries and keys alike, and not beside causal: "
                          f"got ({s}, {sk}), causal={causal}")
-    for n, block in ((s if mask is None else mask.half, block_q),
-                     (sk if mask is None else mask.half, block_k)):
+    half = mask is not None and not selected
+    for n, block in ((mask.half if half else s, block_q),
+                     (mask.half if half else sk, block_k)):
         block = DEFAULT_BLOCK if block is None else block
         if n % block or block % _LANES:
             raise ValueError(f"seq lengths ({s}, {sk}) must be multiples "
                              f"of blocks ({block_q}, {block_k}), and those "
                              f"of {_LANES}")
     to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
-    out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
-                  interpret, mask)
-    return jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2)
+    if selected:
+        out3, lse = _flash_sel(to3(q), to3(k), to3(v), selection, scale,
+                               causal, block_q, block_k, interpret)
+    else:
+        out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q,
+                      block_k, interpret, mask)
+    out = jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2)
+    return (out, lse.reshape(b, h, s)) if return_lse else out

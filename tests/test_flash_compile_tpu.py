@@ -91,3 +91,40 @@ def test_mosaic_takes_both_kernels_under_the_mask_rule(one_chip, bh, half,
         calls = [ln.split("=")[0] for ln in text.splitlines()
                  if "tpu_custom_call" in ln and "custom-call(" in ln]
         assert calls and all(name in c for c in calls), calls
+
+
+# under the selection rule beside causal: the sparse-attention cell's shape
+# (32 heads of 128 over 16,384 positions, the selection as two bitmaps of
+# four groups) and a length under one group that only 128 divides
+SELECTED = [
+    (1, 32, 16384, 128, jnp.bfloat16),
+    (2, 2, 640, 64, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("b,h,s,d,dtype", SELECTED)
+def test_mosaic_takes_both_kernels_under_a_selection(one_chip, b, h, s, d,
+                                                     dtype):
+    """Forward and backward with the bitmap block and the scalar-prefetch
+    fate table, under the names the benchmark's ``kernel.flash_*.sel`` find
+    them by."""
+    shape = lambda *a: jax.ShapeDtypeStruct(*a, sharding=one_chip)  # noqa: E731
+    x = shape((b * h, s, d), dtype)
+    lse = shape((b * h, s), jnp.float32)
+    words = shape((b, -(-s // attn.SEL_GROUP), s, 128), jnp.int32)
+    sel = attn.Selection(words, words, shape((b, s // 128, s // 128),
+                                             jnp.bool_))
+    rule = attn.SelectedKeysMask()
+    fwd = jax.jit(lambda q, k, v, sel: attn._flash_fwd_pallas(
+        q, k, v, scale=d ** -0.5, causal=True, block_q=None, block_k=None,
+        interpret=False, mask=rule, selection=sel))
+    bwd = jax.jit(lambda q, k, v, o, lse, do, sel: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, scale=d ** -0.5, causal=True, interpret=False,
+        mask=rule, selection=sel))
+    for text, name in ((fwd.lower(x, x, x, sel).compile().as_text(),
+                        "flash_fwd_sel"),
+                       (bwd.lower(x, x, x, x, lse, x, sel).compile().as_text(),
+                        "flash_bwd_sel")):
+        calls = [ln.split("=")[0] for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "custom-call(" in ln]
+        assert calls and all(name in c for c in calls), calls

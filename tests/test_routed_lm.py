@@ -32,9 +32,10 @@ import sdar_drivers  # noqa: E402
 import remat_held  # noqa: E402  (tests/)
 
 
-def _load_reference():
-    path = os.path.join(BENCH, "configs", "sdar-30b-a3b-chat_reference.py")
-    spec = importlib.util.spec_from_file_location("sdar_reference", path)
+def _load_reference(config="sdar-30b-a3b-chat"):
+    path = os.path.join(BENCH, "configs", config + "_reference.py")
+    spec = importlib.util.spec_from_file_location(
+        config.split("-")[0] + "_reference", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
@@ -216,13 +217,27 @@ def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0):
                        mutable=["aux_loss", "counters"])
 
 
-def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("config", ["sdar-30b-a3b-chat",
+                                    "keye-vl-2.0-30b-a3b"])
+def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
     """16 experts over 8 chips, 2 each: each share computed by the routed
-    layer, with the router whole; together the uncut reference's layer."""
-    whole = {**LAYER, "num_experts": 16, "held_experts_first": 0}
+    layer, with the router whole; together the uncut reference's layer, by
+    the plain reference of each configuration that is cut this way."""
+    ref, base = REF, LAYER
+    if config != "sdar-30b-a3b-chat":
+        ref = _load_reference(config)
+        with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+            published = json.load(f)
+        base = {**published, **{k: LAYER[k] for k in (
+            "hidden_size", "head_dim", "num_attention_heads",
+            "num_key_value_heads", "moe_intermediate_size",
+            "num_experts_per_tok", "num_hidden_layers", "vocab_size",
+            "residual_out_initializer_range")},
+            "published": {**published["published"], "num_experts": 16}}
+    whole = {**base, "num_experts": 16, "held_experts_first": 0}
     x = jax.random.normal(jax.random.PRNGKey(0), (BATCH, 2 * SEQ, 32))
-    blk = REF.init(jax.random.PRNGKey(1), whole)["blocks"][0]
-    want, _ = REF.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
+    blk = ref.init(jax.random.PRNGKey(1), whole)["blocks"][0]
+    want, _ = ref.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
     shares = [_routed(x, blk, (2 * i, 2)) for i in range(8)]
     total = sum(out for out, _ in shares)
     np.testing.assert_allclose(total.reshape(-1, 32), want, atol=2e-6)
@@ -490,8 +505,12 @@ def _a_block_keeps_its_list():
         # the indices jax derives from them for the two gathers
         "moe_route": {((t, k), f32): 1, ((count,), "int32"): 1,
                       ((r,), "int32"): 4},
-        "moe_gate": {((r, i), f32): 1}, "moe_up": {((r, i), f32): 1}}
-    formula = {"flash_out": t * h * hd * 4, "flash_lse": t * h * 4,
+        "moe_gate": {((r, i), f32): 1}, "moe_up": {((r, i), f32): 1},
+        # the names of a layer with an index: under a mask rule the block
+        # has none and keeps nothing for them (tests/test_sparse_attention)
+        "dsa_selection": {}, "indexer_kl_grads": {}}
+    formula = {"dsa_selection": 0, "indexer_kl_grads": 0,
+               "flash_out": t * h * hd * 4, "flash_lse": t * h * 4,
                "attn_out": t * d * 4, "attn_qkv": t * (h + 2 * 2) * hd * 4,
                "moe_route": t * k * 4 + 2 * r * 4 + count * 4 + 2 * r * 4,
                "moe_gate": r * i * 4, "moe_up": r * i * 4}
