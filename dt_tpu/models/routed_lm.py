@@ -300,7 +300,7 @@ class RotaryAttention(linen.Module):
 #:   moe_route   T x k x 4 + 2 x R x 4 + held x 4   weights; order and the
 #:               token each row holds; sizes (and 2 x R x 4 more: the
 #:               indices jax derives from those two for the two gathers)
-#:   moe_up      R x I x 4          float32, as ragged_dot returns it
+#:   moe_up      R x I x 4          float32, as the grouped product returns it
 #: and under an index (``RotaryAttention(indexer=...)``), for H_I index heads
 #: of D_I:
 #:   dsa_selection     2 x T x T / 8 + (T / 128)^2 + T x 4   the two bitmaps

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from dt_tpu.ops.pallas.grouped import grouped_matmul
+
 Array = jax.Array
 
 
@@ -185,10 +187,12 @@ class RoutedExperts(linen.Module):
     ``highest`` precision).  The assignments to held experts are sorted by
     expert into a buffer of ``buffer_rows`` rows, the receive side of an
     expert-parallel exchange, and three grouped products
-    (``jax.lax.ragged_dot``: on the TPU one grouped kernel each) run over
-    the whole buffer: rows past the load are padding the products still
-    multiply (they go through the last expert with weight zero), so a step
-    costs the same whatever the router decides.  ``buffer_rows=None`` is
+    (``ops.pallas.grouped.grouped_matmul``: a Pallas kernel each where the
+    widths are whole lane tiles, ``jax.lax.ragged_dot`` at toy sizes) run
+    over the whole buffer: rows past the load are padding the products still
+    multiply (they go through the last expert with weight zero) and the
+    kernels' grid does not read the sizes, so a step costs the same
+    whatever the router decides.  ``buffer_rows=None`` is
     the exact worst case ``T x top_k``.  An assignment that finds no room
     is dropped from the result and counted, never silently lost.
 
@@ -249,9 +253,8 @@ class RoutedExperts(linen.Module):
         with jax.named_scope("dispatch"):
             buf = jnp.take(tokens, source, axis=0).astype(self.dtype)
         with jax.named_scope("experts"):
-            grouped = lambda lhs, w: jax.lax.ragged_dot(  # noqa: E731
-                lhs, w.astype(self.dtype), groups,
-                preferred_element_type=jnp.float32)
+            grouped = lambda lhs, w: grouped_matmul(  # noqa: E731
+                lhs, w.astype(self.dtype), groups, jnp.float32)
             # the two products in the float32 they are made in, by name
             hidden = jax.nn.silu(
                 checkpoint_name(grouped(buf, w_gate), "moe_gate")) \

@@ -38,8 +38,10 @@ def held_bytes(kept):
 
 def kernel_calls(block, variables, x, mutable=False):
     """The ``pallas_call``s in the jaxpr of the block's gradient: (those
-    with a ``flash_`` name of their own, by name; all of them)."""
+    with a ``flash_`` or ``grouped_mm`` name of their own, by name; all of
+    them)."""
     text = str(jax.make_jaxpr(jax.grad(_objective(block, mutable)))(
         variables, x))
-    return (Counter(re.findall(r"\bname=(flash_(?:fwd|bwd)\w*)", text)),
+    return (Counter(re.findall(
+        r"\bname=(flash_(?:fwd|bwd)\w*|grouped_mm\w*)", text)),
             text.count("pallas_call["))

@@ -198,14 +198,25 @@ def test_tile_notes_name_the_mask_rule(caplog):
 LAYER = {**SMALL, "residual_out_initializer_range": 0.02}
 
 
-def _layer_inputs(seed=0):
-    x = jax.random.normal(jax.random.PRNGKey(seed), (BATCH, 2 * SEQ, 32))
-    blk = REF.init(jax.random.PRNGKey(seed + 1), LAYER)["blocks"][0]
+#: widths that are whole lane tiles, with a buffer that is whole row tiles:
+#: what the grouped products' kernels take (``ops/pallas/grouped.py``; the
+#: widths in the tens take ``jax.lax.ragged_dot``), so the path the cells
+#: run is compared here too.  One row tile of 128 rows for the 192
+#: assignments of 96 tokens, of which 4 experts of 16 are held
+LANES = {"hidden_size": 128, "moe_intermediate_size": 128,
+         "buffer_rows": 128}
+
+
+def _layer_inputs(seed=0, cfg=LAYER):
+    x = jax.random.normal(jax.random.PRNGKey(seed),
+                          (BATCH, 2 * SEQ, cfg["hidden_size"]))
+    blk = REF.init(jax.random.PRNGKey(seed + 1), cfg)["blocks"][0]
     return x, blk
 
 
 def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0):
-    layer = moe.RoutedExperts(num_experts=16, top_k=2, intermediate=24,
+    layer = moe.RoutedExperts(num_experts=16, top_k=2,
+                              intermediate=blk["gate"].shape[-1],
                               held=held, buffer_rows=buffer_rows,
                               aux_weight=aux_weight)
     first, count = held
@@ -248,11 +259,15 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
     assert (counted[:, -2] == 0).all()
 
 
-def test_a_share_matches_the_references_share_and_its_auxiliary_term():
-    x, blk = _layer_inputs()
-    out, mutated = _routed(x, blk, (4, 4), aux_weight=0.5)
-    want, aux = REF.experts(x.reshape(-1, 32), blk, SMALL, lambda a: a)
-    np.testing.assert_allclose(out.reshape(-1, 32), want, atol=2e-6)
+@pytest.mark.parametrize("widths", [{}, LANES], ids=["tens", "lanes"])
+def test_a_share_matches_the_references_share_and_its_auxiliary_term(widths):
+    cfg = {**LAYER, **widths}
+    x, blk = _layer_inputs(cfg=cfg)
+    out, mutated = _routed(x, blk, (4, 4), aux_weight=0.5,
+                           buffer_rows=cfg["buffer_rows"])
+    d = cfg["hidden_size"]
+    want, aux = REF.experts(x.reshape(-1, d), blk, cfg, lambda a: a)
+    np.testing.assert_allclose(out.reshape(-1, d), want, atol=2e-6)
     np.testing.assert_allclose(mutated["aux_loss"]["load_balance"][0],
                                0.5 * aux, rtol=1e-6)
     # the term is E sum f P over all 16 outputs; 2 (= k) where even
@@ -308,9 +323,12 @@ def _gap(got, want):
                            / (jnp.max(jnp.abs(b)) + 1e-12)), got, want)))
 
 
-@pytest.mark.parametrize("attention,remat", [(None, False), ("flash", True)])
-def test_objective_and_gradient_match_the_reference(attention, remat):
-    cfg = {**SMALL, "attention": attention, "remat_blocks": remat}
+@pytest.mark.parametrize("attention,remat,widths", [
+    (None, False, {}), ("flash", True, {}), ("flash", True, LANES)],
+    ids=["plain", "flash-remat", "flash-remat-lanes"])
+def test_objective_and_gradient_match_the_reference(attention, remat,
+                                                    widths):
+    cfg = {**SMALL, **widths, "attention": attention, "remat_blocks": remat}
     params = REF.init(jax.random.PRNGKey(3), cfg)
     data, labels = _batch()
     with jax.default_matmul_precision("highest"):
@@ -443,22 +461,23 @@ def test_the_iterator_noises_each_batch_anew_from_its_seed():
 
 # -- what a rematerialised block keeps ----------------------------------------
 
-def _block(policy_names, attention="flash"):
+def _block(policy_names, attention="flash", d=32, i=24):
     """One ``RoutedBlock`` as ``RoutedLM`` wraps it, with its variables and
-    an input: 2 x 256 positions of width 32, four heads of 8 over two, 16
-    experts of width 24 of which 4 are held, 2 a token, the worst-case
-    buffer."""
+    an input: 2 x 256 positions of width ``d``, four heads of 8 over two, 16
+    experts of width ``i`` of which 4 are held, 2 a token, the worst-case
+    buffer (1,024 rows).  At 128 and 128 the grouped products are the
+    Pallas kernels."""
     import flax.linen as linen
     attn_kw = dict(num_heads=4, num_kv_heads=2, head_dim=8, rope_theta=1e6,
                    mask=BlockDiffusionMask(128, 4), attention=attention)
-    moe_kw = dict(num_experts=16, top_k=2, intermediate=24, held=(4, 4),
+    moe_kw = dict(num_experts=16, top_k=2, intermediate=i, held=(4, 4),
                   buffer_rows=None, aux_weight=0.001)
     cls = routed_lm.RoutedBlock if policy_names is None else linen.remat(
         routed_lm.RoutedBlock,
         policy=jax.checkpoint_policies.save_only_these_names(*policy_names))
     blk = cls(tuple(sorted(attn_kw.items())), tuple(sorted(moe_kw.items())),
               1e-6, jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 256, 32))
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 256, d))
     params = jax.jit(routed_lm.RoutedBlock(
         tuple(sorted(attn_kw.items())), tuple(sorted(moe_kw.items())),
         1e-6, jnp.float32).init)(jax.random.PRNGKey(1), x)["params"]
@@ -488,11 +507,11 @@ def _remat_changes_no_number():
                                    err_msg=jax.tree_util.keystr(path))
 
 
-def _a_block_keeps_its_list():
+def _a_block_keeps_its_list(d=32, i=24):
     """Beyond its input and its parameters the block keeps the values on
     ``SAVED``, at the sizes the list's comment gives, and the integer
     indices jax derives from ``order`` for the two gathers."""
-    b, s, d, h, hd, k, i, count = 2, 256, 32, 4, 8, 2, 24, 4
+    b, s, h, hd, k, count = 2, 256, 4, 8, 2, 4
     t, r, f32 = b * s, b * s * k, "float32"
     # each name's values, (shape, dtype) -> count; the formula in the
     # list's comment at c = 4 bytes
@@ -516,7 +535,7 @@ def _a_block_keeps_its_list():
                "moe_gate": r * i * 4, "moe_up": r * i * 4}
     assert set(routed_lm.SAVED) <= set(by_name)
     for names in (routed_lm.SAVED, tuple(by_name)):   # the list; every name
-        blk, variables, x = _block(names)
+        blk, variables, x = _block(names, d=d, i=i)
         kept, args = remat_held.held(blk, variables, x,
                                      mutable=["aux_loss", "counters"])
         assert sum(args.values()) == 1 + len(
@@ -524,21 +543,26 @@ def _a_block_keeps_its_list():
         assert kept == sum((Counter(by_name[n]) for n in names), Counter())
         assert remat_held.held_bytes(kept) == sum(formula[n] for n in names)
     # and a policy that names nothing keeps nothing
-    blk, variables, x = _block(())
+    blk, variables, x = _block((), d=d, i=i)
     assert not remat_held.held(blk, variables, x,
                                mutable=["aux_loss", "counters"])[0]
 
 
-def _the_gradient_calls_each_kernel_once():
+def _the_gradient_calls_each_kernel_once(d=32, i=24, grouped=None):
     """``flash_out`` and ``flash_lse`` are on the list, so the backward pass
     does not run the forward kernel again; a block that keeps nothing runs
-    it twice."""
-    for names, forward in ((routed_lm.SAVED, 1), ((), 2)):
-        blk, variables, x = _block(names)
+    it twice.  At widths that take the grouped products' kernels
+    (``grouped`` = their calls with the list, with nothing kept) the list
+    spares the recomputation of gate and up."""
+    for n, (names, forward) in enumerate(((routed_lm.SAVED, 1), ((), 2))):
+        blk, variables, x = _block(names, d=d, i=i)
         calls, total = remat_held.kernel_calls(
             blk, variables, x, mutable=["aux_loss", "counters"])
-        assert calls == {"flash_fwd_bd": forward, "flash_bwd_bd": 1}
-        assert total == forward + 1
+        want = {"flash_fwd_bd": forward, "flash_bwd_bd": 1}
+        if grouped:
+            want.update(grouped_mm=grouped[n] - 3, grouped_mm_t=3)
+        assert calls == want
+        assert total == sum(want.values())
 
 
 def _the_gauge_counts_the_list(remat):
@@ -564,8 +588,13 @@ def _the_gauge_counts_the_list(remat):
     _remat_changes_no_number, _a_block_keeps_its_list,
     _the_gradient_calls_each_kernel_once,
     lambda: _the_gauge_counts_the_list(True),
-    lambda: _the_gauge_counts_the_list(False)],
-    ids=["numbers", "held", "kernels", "gauge-on", "gauge-off"])
+    lambda: _the_gauge_counts_the_list(False),
+    lambda: _a_block_keeps_its_list(128, 128),
+    # a layer's products: 3 forward, those the list does not spare again,
+    # 6 backward (3 of them transposed)
+    lambda: _the_gradient_calls_each_kernel_once(128, 128, (11, 12))],
+    ids=["numbers", "held", "kernels", "gauge-on", "gauge-off",
+         "held-lanes", "kernels-lanes"])
 def test_rematerialised_blocks_keep_the_named_values(check):
     check()
 

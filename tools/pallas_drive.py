@@ -18,6 +18,7 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only fused_bn_inference  # one kernel
         python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
         python tools/pallas_drive.py --only flash_bwd_tiles  # the backward's
+        python tools/pallas_drive.py --only grouped_mm_tiles  # routed layer's
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -257,6 +258,111 @@ def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
         yield rec
 
 
+# the two routed cells' buffers (sdar30b..., keye30b...) against gate/up and
+# down, 16 experts held
+GROUPED_CELL_SHAPES = [(49152, 2048, 768), (24576, 2048, 768),
+                       (49152, 768, 2048), (24576, 768, 2048)]
+GROUPED_SWEEP_TILES = (256, 512, 1024)
+
+
+def grouped_loads(rng, m, groups):
+    """Three ways to fill ``m`` rows: ``even`` (every boundary on a tile's
+    edge: the grid's spare visits all run masked), ``ragged`` (random sizes,
+    every boundary inside a tile) and ``padded`` (a third of the rows
+    loaded, unevenly, the rest in the last group: the routed layer's
+    buffer under a clumped load)."""
+    import numpy as np
+    ragged = rng.multinomial(m - groups, np.ones(groups) / groups) + 1
+    held = rng.multinomial(m // 3, rng.dirichlet(np.ones(groups) * 0.7))
+    held[-1] += m - held.sum()
+    return {"even": np.full(groups, m // groups), "ragged": ragged,
+            "padded": held}
+
+
+def grouped_mm_tiles_sweep(rng, m, k, n, dt, groups=16, iters=30,
+                           interpret=None):
+    """The three grouped products (value, ``d_lhs``, ``d_rhs``) one at a
+    time at ``m`` rows against ``groups`` matrices ``k x n``: XLA's kernel
+    for ``jax.lax.ragged_dot`` and its transposes, megablox's ``gmm`` at the
+    derived tile (the value and the turned product only: its ``tgmm`` wants
+    a transposed copy), and this repo's kernels at every row tile of
+    GROUPED_SWEEP_TILES plus the derived one under each of
+    ``grouped_loads``.  One record a timing, with its share of the peak for
+    ``2 m k n`` operations (host clock; the jitted call holds the visit
+    tables' few small operations too)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from dt_tpu.ops.pallas import grouped
+    if interpret is None:
+        interpret = grouped._default_interpret()
+    lhs = jnp.asarray(rng.randn(m, k) * 0.3, dt)
+    rhs = jnp.asarray(rng.randn(groups, k, n) * 0.05, dt)
+    d_out = jnp.asarray(rng.randn(m, n) * 0.3, jnp.float32)
+    loads = {name: jnp.asarray(v, jnp.int32)
+             for name, v in grouped_loads(rng, m, groups).items()}
+    a, b = lhs.dtype.itemsize, rhs.dtype.itemsize
+    derived = {"value": grouped.row_tile(m, k, n, a, b, 4),
+               "d_lhs": grouped.row_tile(m, n, k, 4, b, a),
+               "d_rhs": grouped.row_tile(m, k, n, a, 4, b, transposed=True)}
+    ragged = lambda x, w, gs: jax.lax.ragged_dot(  # noqa: E731
+        x, w, gs, preferred_element_type=jnp.float32)
+    xla = {
+        "value": (jax.jit(ragged), (lhs, rhs)),
+        "d_lhs": (jax.jit(lambda do, w, gs: jax.vjp(
+            lambda x: ragged(x, w, gs), lhs)[1](do)[0]), (d_out, rhs)),
+        "d_rhs": (jax.jit(lambda x, do, gs: jax.vjp(
+            lambda w: ragged(x, w, gs), rhs)[1](do)[0]), (lhs, d_out)),
+    }
+
+    def ours(product, tm):
+        if product == "d_rhs":
+            return jax.jit(lambda x, do, gs: grouped._grouped_mm_t(
+                x, do, gs, out_dtype=rhs.dtype, tm=tm, interpret=interpret))
+        turned = product == "d_lhs"
+        return jax.jit(lambda x, w, gs: grouped._grouped_mm(
+            x, w, gs, turned=turned, tm=tm, interpret=interpret,
+            out_dtype=lhs.dtype if turned else jnp.dtype(jnp.float32)))
+
+    def record(product, which, tm, load, fn, args, want=None):
+        rec = {"kernel": "grouped_mm_tiles", "product": product,
+               "shape": f"M{m}xK{k}xN{n}xG{groups} {jnp.dtype(dt).name}",
+               "which": which, "tm": tm, "load": load,
+               "derived": tm == derived[product],
+               "backend": jax.default_backend()}
+        try:
+            got = fn(*args, loads[load])
+            if want is not None:
+                rec["vs_xla_rel_err"] = rel_err(got, want)
+            ms = _timeit(fn, *args, loads[load], iters=iters)
+            rec["ms"] = round(ms, 4)
+            rec["pct_of_197_tflops"] = round(
+                100 * 2 * m * k * n / (ms * 1e-3) / 197e12, 1)
+        except Exception as e:  # noqa: BLE001 — a tile Mosaic refuses
+            rec["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+            got = None
+        return rec, got
+
+    for product, (fn, args) in xla.items():
+        rec, want = record(product, "ragged_dot", None, "ragged", fn, args)
+        yield rec
+        if product != "d_rhs" and not interpret:
+            from jax.experimental.pallas.ops.tpu.megablox import gmm
+            turned = product == "d_lhs"
+            tm = derived[product]
+            mega = jax.jit(lambda x, w, gs, turned=turned, tm=tm: gmm(
+                x, w, gs, jnp.float32, (tm, *x.shape[1:], w.shape[
+                    1 if turned else 2]), transpose_rhs=turned))
+            yield record(product, "megablox", tm, "ragged", mega, args,
+                         want)[0]
+        for tm in sorted({*GROUPED_SWEEP_TILES, derived[product]} - {None}):
+            if m % tm:
+                continue
+            for load in (loads if tm == derived[product] else ("ragged",)):
+                yield record(product, "pallas", tm, load, ours(product, tm),
+                             args, want if load == "ragged" else None)[0]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--small", action="store_true",
@@ -333,6 +439,15 @@ def main():
                            FLASH_CELL_SHAPES):
             for rec in flash_bwd_tiles_sweep(rng, B, S, H, D, dt,
                                              iters=args.iters):
+                print(json.dumps(rec), flush=True)
+
+    # ---- the routed layer's grouped products, by row tile (PR 38) --------
+    if wanted("grouped_mm_tiles"):
+        for m, k, n in ([(512, 128, 256)] if args.small else
+                        GROUPED_CELL_SHAPES):
+            for rec in grouped_mm_tiles_sweep(rng, m, k, n, dt,
+                                              groups=4 if args.small else 16,
+                                              iters=args.iters):
                 print(json.dumps(rec), flush=True)
 
 
