@@ -55,34 +55,39 @@ def _device():
             "count": len(jax.devices())}
 
 
-class _CompileCounter:
-    """Counts what jax itself reports: backend compiles (a persistent-cache
-    retrieval counts as one, and its seconds are the retrieval's), cache
-    requests and cache hits."""
+class _Builds:
+    """What jax itself reports of this stage's builds, read from the
+    program's own build account (``dt_tpu/obs/trace.py``: one row for
+    each trace, lowering and backend compile, written by listeners of
+    ``jax.monitoring`` that ``dt_tpu.training`` registers at import):
+    backend compiles (a persistent-cache retrieval counts as one, and its
+    seconds are the retrieval's), those the cache was asked for and those
+    it served, and the seconds in tracing and in lowering, which no cache
+    saves."""
 
     def __init__(self):
-        from jax import monitoring
-        self.compiles, self.compile_s = 0, 0.0
-        self.cache_requests = self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
+        from dt_tpu.obs import trace
+        self._trace = trace
+        self._mark = trace.tracer().builds()
 
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
+    def rows(self):
+        return self._trace.tracer().build_rows(since=self._mark)
 
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.cache_requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+    @property
+    def compiles(self):
+        return sum(r[2] == "backend" for r in self.rows())
 
     def report(self):
-        return {"backend_compiles": self.compiles,
-                "compile_s": round(self.compile_s, 1),
-                "cache_requests": self.cache_requests,
-                "cache_hits": self.cache_hits}
+        rows = self.rows()
+        cache = [r[self._trace.BUILD_ROW_FIELDS.index("cache")]
+                 for r in rows if r[2] == "backend"]
+        seconds = {stage: round(self._trace.stage_ns(rows, stage) / 1e9, 1)
+                   for stage in ("trace", "lower", "backend")}
+        return {"backend_compiles": len(cache),
+                "compile_s": seconds["backend"],
+                "trace_s": seconds["trace"], "lower_s": seconds["lower"],
+                "cache_requests": sum(c != "off" for c in cache),
+                "cache_hits": sum(c == "hit" for c in cache)}
 
 
 def _check(cond, msg):
@@ -111,7 +116,7 @@ def stage_a(size="full"):
     import common
     from dt_tpu import parallel
 
-    counter = _CompileCounter()
+    counter = _Builds()
     cfg = _A_SIZES[size]
     devices = jax.local_devices()
     batch = cfg["per_chip"] * len(devices)
@@ -225,7 +230,7 @@ def stage_b(size="full", interpret=False):
     from dt_tpu.parallel import mesh as mesh_lib
     from dt_tpu.training import Module
 
-    counter = _CompileCounter()
+    counter = _Builds()
     cfg = _B_SIZES[size]
     dt = jnp.dtype(cfg["dtype"]).type
     # bf16 carries 8 bits of mantissa and the oracles accumulate in another

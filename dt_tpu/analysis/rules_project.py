@@ -293,12 +293,14 @@ _OBS_NAMES_RELPATH = "dt_tpu/obs/names.py"
 #: silently vanish from the Prometheus exposition and dtop health board
 #: PR 24 adds ``StepAccount.phase`` (``dt_tpu/obs/trace.py``): a phase of
 #: the step account is a span under ``DT_OBS=1``, named at its call site
+#: PR 39 adds ``Tracer.ended_span``: a span written after it has ended, at
+#: an account row's readings (the ``fit`` and ``build.*`` spans)
 _OBS_EMITTERS = frozenset({"span", "complete_span", "event", "counter",
-                           "gauge", "observe", "phase"})
+                           "gauge", "observe", "phase", "ended_span"})
 _OBS_KIND_OF = {"span": "span", "complete_span": "span",
                 "event": "event", "counter": "counter",
                 "gauge": "gauge", "observe": "histogram",
-                "phase": "span"}
+                "phase": "span", "ended_span": "span"}
 
 
 def _load_obs_registry(project: ProjectContext) -> Dict[str, Tuple[str,

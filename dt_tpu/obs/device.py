@@ -26,11 +26,14 @@ planes; ``tests/test_device_obs.py`` holds the guards):
   first call per abstract signature runs the AOT ``lower().compile()``
   path inside a named ``compile.<what>`` span (so the blackbox
   open-span table — and therefore the hang watchdog — can SEE a
-  compile in progress), timing exactly the compile, counting
-  persistent-cache hits/misses (new cache files
-  after the compile = miss), and capturing XLA's own
-  ``memory_analysis()`` (the static estimate, taken at every real
-  compile).  Off, :func:`instrument` returns the function UNCHANGED.
+  compile in progress) and captures XLA's own ``memory_analysis()``
+  (the static estimate, taken at every real compile).  What the build
+  took and whether the persistent cache served it are not measured
+  here: they are read from the build account's rows
+  (``obs/trace.py``, written by listeners of ``jax.monitoring`` with
+  every gate off), so the span's ``trace_ms``, ``lower_ms`` and
+  ``backend_ms`` and its ``cache`` are jax's own words for this build.
+  Off, :func:`instrument` returns the function UNCHANGED.
 - **Recompile-cause ledger** — a second compile of the same ``what``
   diffs the new abstract signature against the previous one and emits a
   ``compile.recompile`` event naming the delta (``shape`` / ``dtype`` /
@@ -206,48 +209,36 @@ def _sig_delta(prev: Dict[str, Any], new: Dict[str, Any]) -> List[str]:
     return changed or ["rebuild"]
 
 
-class _CacheProbe:
-    """Persistent-cache accounting over the EFFECTIVE
-    ``jax_compilation_cache_dir`` (wherever ``JAX_COMPILATION_CACHE_DIR``
-    or ``config.enable_compilation_cache`` placed it): count the cache
-    dir's entries before/after a compile — new files mean the compiler
-    wrote a fresh program (miss); none, with the cache configured, means
-    it was served from the cache (hit).  With no cache dir configured the
-    outcome is ``"off"`` (every retry pays the full recompile)."""
-
-    def __init__(self):
-        import jax
-        self.dir = (jax.config.jax_enable_compilation_cache
-                    and jax.config.jax_compilation_cache_dir) or ""
-        self.before = self._count()
-
-    def _count(self) -> int:
-        if not self.dir:
-            return 0
-        try:
-            return len(os.listdir(self.dir))
-        except OSError:
-            return 0
-
-    def outcome(self) -> str:
-        if not self.dir:
-            return "off"
-        return "miss" if self._count() > self.before else "hit"
-
-
-def cache_probe() -> _CacheProbe:
-    """Start a persistent-cache probe around a compile."""
-    return _CacheProbe()
+def build_stages(tr: obs_trace.Tracer, mark: int) -> Dict[str, Any]:
+    """What the build account (``obs/trace.py``) says of the builds this
+    thread has made since ``mark`` (a reading of ``tr.builds()``):
+    milliseconds in each stage, and what the persistent cache did for
+    the compile — ``miss`` if it was asked and did not serve it, ``hit``
+    if it served it, ``off`` if it could not be asked (no directory in
+    effect, or ``jax_enable_compilation_cache`` off)."""
+    tid = tr._ident()
+    at_tid, at_cache = (obs_trace.BUILD_ROW_FIELDS.index(f)
+                        for f in ("tid", "cache"))
+    rows = [r for r in tr.build_rows(since=mark) if r[at_tid] == tid]
+    said = {r[at_cache] for r in rows}
+    out = {f"{stage}_ms": round(obs_trace.stage_ns(rows, stage) / 1e6, 3)
+           for stage in ("trace", "lower", "backend")}
+    out["cache"] = "miss" if "miss" in said else \
+        "hit" if "hit" in said else "off"
+    return out
 
 
 def _record_compile(what: str, sig: Dict[str, Any], elapsed_ms: float,
                     cache: str, mem: Optional[dict],
                     tracer: Optional[obs_trace.Tracer] = None,
-                    now_ms: Optional[int] = None) -> Optional[dict]:
+                    now_ms: Optional[int] = None,
+                    stages: Optional[Dict[str, float]] = None
+                    ) -> Optional[dict]:
     """Fold one observed compile into the ledger; returns the recompile
     record when this ``what`` had compiled before (the cause event the
-    chaos recompile-churn gate counts).  Injectable tracer/clock for
-    deterministic tests."""
+    chaos recompile-churn gate counts).  ``stages``: the build's
+    ``trace_ms`` / ``lower_ms`` / ``backend_ms``, carried by the record.
+    Injectable tracer/clock for deterministic tests."""
     tr = tracer if tracer is not None else obs_trace.tracer()
     ts = int(now_ms if now_ms is not None else time.time() * 1000)
     recompile = None
@@ -270,7 +261,7 @@ def _record_compile(what: str, sig: Dict[str, Any], elapsed_ms: float,
             recompile = {"what": what, "changed": _sig_delta(prev, sig),
                          "prev": prev["digest"], "new": sig["digest"],
                          "elapsed_ms": round(elapsed_ms, 3),
-                         "cache": cache, "ts_ms": ts}
+                         **(stages or {}), "cache": cache, "ts_ms": ts}
             _TOTALS["recompiles"] += 1
             _RECOMPILES.append(recompile)
             del _RECOMPILES[:-_LEDGER_MAX]
@@ -406,25 +397,28 @@ class _Instrumented:
         tr = obs_trace.tracer()
         t0 = tr.begin(f"compile.{self._what}",
                       {"what": self._what, "digest": sig["digest"]})
-        probe = cache_probe()
+        mark = tr.builds()
         tm0 = time.monotonic()
         try:
             comp = self._fn.lower(*args).compile()
         except Exception:  # noqa: BLE001 — not AOT-able: observe the
-            # plain jit call's first dispatch instead (compile happens
-            # inside it; no memory analysis, the timing still lands)
+            # plain jit call's first dispatch instead (the build happens
+            # inside it; no memory analysis, its stages still land)
             try:
                 out = self._fn(*args)
             finally:
                 elapsed = (time.monotonic() - tm0) * 1000.0
+                built = build_stages(tr, mark)
                 tr.complete_span(f"compile.{self._what}", t0,
                                  {"what": self._what, "aot": False,
-                                  "cache": probe.outcome()})
-            _record_compile(self._what, sig, elapsed, probe.outcome(),
-                            None)
+                                  **built})
+            cache = built.pop("cache")
+            _record_compile(self._what, sig, elapsed, cache, None,
+                            stages=built)
             self._compiled[key] = self._fn
             return out
         elapsed = (time.monotonic() - tm0) * 1000.0
+        built = build_stages(tr, mark)
         mem = None
         try:
             mem = memory_analysis_row(comp.memory_analysis())
@@ -432,9 +426,9 @@ class _Instrumented:
             pass
         tr.complete_span(f"compile.{self._what}", t0,
                          {"what": self._what, "digest": sig["digest"],
-                          "cache": probe.outcome(),
-                          "elapsed_ms": round(elapsed, 1)})
-        _record_compile(self._what, sig, elapsed, probe.outcome(), mem)
+                          "elapsed_ms": round(elapsed, 1), **built})
+        cache = built.pop("cache")
+        _record_compile(self._what, sig, elapsed, cache, mem, stages=built)
         try:
             out = comp(*args)
         except (TypeError, ValueError):

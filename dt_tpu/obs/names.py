@@ -56,9 +56,32 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                            "watchdog beat, capture tick, health "
                            "sentinel's fetch, checkpoint cadence, drain "
                            "poll"),
+    # the build account and the fit rows (obs/trace.py BUILD_ROW_FIELDS,
+    # FIT_ROW_FIELDS): always-live rows and, under DT_OBS=1, spans that
+    # have already ended, at the rows' own readings; read by
+    # benchmark/builds.py (setup.build_*, setup.fit_*), chip_smoke.py
+    # and the device plane's compile.<what> span
+    "fit": ("span", "one Module.fit call, from its entry to its return "
+                    "or raise (attrs: fit, its number; iterations)"),
+    "fit.enter": ("span", "from fit's entry to its first iteration's "
+                          "begin: bind, the steps built for the metric, "
+                          "resume, drain and watchdog installs, the first "
+                          "barrier, the iterator's reset; child of fit"),
+    "fit.exit": ("span", "from the last iteration's close to fit's "
+                         "return: epoch end, snapshot, evaluation, "
+                         "checkpoint flush; child of fit"),
+    "build.*": ("span", "one stage of a build as jax.monitoring reports "
+                        "it: build.trace (jaxpr), build.lower (to MLIR, "
+                        "the Pallas kernels' lowering included), "
+                        "build.backend (XLA compile, or the persistent "
+                        "cache's read); attrs: fun, fit, cache "
+                        "(hit/miss/off, backend only); a first call's "
+                        "lie inside its step.dispatch"),
     "epoch": ("span", "one training epoch (Module.fit)"),
-    "epoch.rebuild": ("span", "mesh_manager.rebuild and the recompile of "
-                              "the steps for the new mesh"),
+    "epoch.rebuild": ("span", "mesh_manager.rebuild and new jit objects "
+                              "for the new mesh; it holds no compile: the "
+                              "build.* spans of the next step's "
+                              "step.dispatch do"),
     "epoch.data_reshard": ("span", "the elastic iterator factory rebuilding "
                                    "the data iterators after a change"),
     "epoch.snapshot": ("span", "_publish_snapshot at the epoch's end"),
@@ -231,10 +254,12 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
     "hang.clear": ("event", "a suspected hang recovered (progress "
                             "resumed / the stalled round completed)"),
     # -- device plane (obs/device.py, r18) ---------------------------------
-    "compile.*": ("span", "one XLA compile of an instrumented step "
+    "compile.*": ("span", "one build of an instrumented step "
                           "(compile.<what>); open while the compiler "
                           "runs, so hang bundles can label a "
-                          "compile-in-progress stall"),
+                          "compile-in-progress stall; attrs trace_ms, "
+                          "lower_ms, backend_ms and cache are the build "
+                          "account's rows of it"),
     "compile.recompile": ("event", "an instrumented step compiled AGAIN "
                                    "(attrs name the signature delta: "
                                    "shape/dtype/mesh/donate/nargs, or "
@@ -244,8 +269,8 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                     "plane"),
     "compile.cache_hits": ("counter", "compiles served from the "
                                       "persistent compilation cache"),
-    "compile.cache_misses": ("counter", "compiles that wrote fresh "
-                                        "persistent-cache entries"),
+    "compile.cache_misses": ("counter", "compiles the persistent cache "
+                                        "was asked for and did not serve"),
     "device.hbm_bytes": ("gauge", "per-device HBM bytes in use "
                                   "(jax.Device.memory_stats)"),
     "device.hbm_peak_bytes": ("gauge", "per-device peak HBM bytes in use"),

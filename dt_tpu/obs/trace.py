@@ -38,6 +38,14 @@ Design points
   :data:`STEP_PHASES`, which sum to the iteration exactly.  With tracing
   on the same boundaries are spans, children of the iteration's ``step``
   span, and ``jax.profiler`` annotations.
+- **The build account** is the step account's other half, live the same
+  way: one row for every trace, lowering and compile (or cache read) that
+  jax itself reports (:data:`BUILD_ROW_FIELDS`, written by listeners of
+  ``jax.monitoring``: nothing is wrapped), and one row for every ``fit``
+  call (:data:`FIT_ROW_FIELDS`: entry, iterations, exit), all on the step
+  rows' clock.  With tracing on each is also a completed span
+  (``build.trace`` / ``build.lower`` / ``build.backend``; ``fit`` with
+  ``fit.enter`` and ``fit.exit``) at the row's own readings.
 
 Record schema (flat tuples, ring/wire-compact)::
 
@@ -45,7 +53,7 @@ Record schema (flat tuples, ring/wire-compact)::
     ("i", rseq, name, ts_us, 0,      tid, event_id, parent_id, attrs) event
 
 Account rows are flat tuples too, their fields named by
-:data:`STEP_ROW_FIELDS`.
+:data:`STEP_ROW_FIELDS`, :data:`BUILD_ROW_FIELDS` and :data:`FIT_ROW_FIELDS`.
 
 ``rseq`` increases strictly in buffer order — the heartbeat export's
 at-least-once dedup key (the scheduler ignores records at-or-below the
@@ -55,6 +63,7 @@ last ``rseq`` it ingested for a (host, incarnation) track).
 from __future__ import annotations
 
 import contextvars
+import sys
 import threading
 import time
 from collections import deque
@@ -263,6 +272,17 @@ STEP_ROW_FIELDS = ("fit", "epoch", "iteration", "dispatched", "flushed",
                    "wall_ns", "total_ns") + STEP_PHASES
 _PHASE_INDEX = {name: i for i, name in enumerate(STEP_PHASES)}
 _HOOKS = _PHASE_INDEX["step.hooks"]
+#: one row a ``fit`` call, written at its exit (every way out): its number,
+#: its entry on the wall clock, its monotonic length, and that length's four
+#: parts, which sum to it: from the entry to the first iteration's begin
+#: (bind, the steps built for the metric, resume, drain and watchdog
+#: installs, the first barrier, the iterator's reset), inside iterations
+#: (its step rows' lengths added up), between one epoch's last iteration
+#: and the next one's first (snapshot, evaluation, callbacks, barrier), and
+#: from the last iteration's close to the return; then its iterations.  A
+#: call that never reached an iteration is all entry
+FIT_ROW_FIELDS = ("fit", "wall_ns", "total_ns", "enter_ns", "steps_ns",
+                  "between_ns", "exit_ns", "iterations")
 
 
 class StepAccount:
@@ -276,11 +296,18 @@ class StepAccount:
     and writes into slots made once per ``fit`` call; a row is one tuple
     and one append.  With tracing on (or the blackbox's open-span table
     armed) every iteration is also a ``step`` span and every phase a
-    child span of it, opened and closed at the account's own readings."""
+    child span of it, opened and closed at the account's own readings.
+
+    The writer is made at the call's entry and ``exit`` writes the call's
+    own row (:data:`FIT_ROW_FIELDS`): two more clock boundaries a call,
+    and what it needs of the iterations is kept where an epoch's first one
+    begins and its last one ends, never in between."""
 
     __slots__ = ("_tr", "fit", "_ns", "_cur", "_t", "_t0m", "_t0w",
                  "_live", "epoch", "iteration", "dispatched", "flushed",
-                 "_step_span", "_phase_span")
+                 "_step_span", "_phase_span", "_enter_w", "_enter_m",
+                 "_first_m", "_last_m", "_epoch_m", "_epoch_i",
+                 "_steps_ns", "_iterations", "_thread", "_outer")
 
     def __init__(self, tr: "Tracer", fit: int):
         self._tr = tr
@@ -292,6 +319,18 @@ class StepAccount:
         #: whose metric it flushed
         self.dispatched: Optional[int] = None
         self.flushed: Optional[int] = None
+        # the call's own row: its first iteration's begin and its last
+        # one's close, the nanoseconds and the count of its iterations
+        self._first_m = self._last_m = None
+        self._steps_ns = self._iterations = 0
+        # build rows written by this thread while the call is open carry
+        # its number (a table by thread, not a ContextVar: a variable set
+        # in the loop's context would make every ContextVar lookup of the
+        # process a little dearer, numpy's error state among them)
+        self._thread = tr._ident()
+        self._outer = tr._open_fits.get(self._thread)
+        tr._open_fits[self._thread] = fit
+        self._enter_m, self._enter_w = tr._mono(), tr._wall()
 
     def begin(self, epoch: int, iteration: int,
               step_num: Optional[int] = None) -> Optional[tuple]:
@@ -300,7 +339,13 @@ class StepAccount:
         profiler session (``StepTraceAnnotation``)."""
         tr = self._tr
         now, wall = tr._mono(), tr._wall()
-        row = self._close(now) if self._live else None
+        if self._live:
+            row = self._close(now)
+        else:   # an epoch's first iteration
+            row = None
+            self._epoch_m, self._epoch_i = now, iteration
+            if self._first_m is None:
+                self._first_m = now
         self.epoch, self.iteration = epoch, iteration
         self.dispatched = self.flushed = None
         self._t0m = self._t = now
@@ -330,7 +375,45 @@ class StepAccount:
         """Close the open iteration, if any (the loop's end, and every
         way out of ``fit``: an iteration that an exception leaves still
         writes its row, with the phases it got to).  Returns its row."""
-        return self._close(self._tr._mono()) if self._live else None
+        if not self._live:
+            return None
+        now = self._tr._mono()
+        self._steps_ns += now - self._epoch_m
+        self._iterations += self.iteration - self._epoch_i + 1
+        self._last_m = now
+        return self._close(now)
+
+    def exit(self) -> Optional[tuple]:
+        """The ``fit`` call leaves, by any way out: write its row (once)
+        and return it.  With tracing on the row is also a ``fit`` span with
+        ``fit.enter`` and ``fit.exit`` children, at the row's readings."""
+        if self._thread is None:
+            return None
+        self.end()
+        tr = self._tr
+        now = tr._mono()
+        if self._outer is None:
+            tr._open_fits.pop(self._thread, None)
+        else:   # a call made inside another call's callback
+            tr._open_fits[self._thread] = self._outer
+        self._thread = None
+        total = now - self._enter_m
+        if self._first_m is None:
+            enter, steps, out = total, 0, 0
+        else:
+            enter = self._first_m - self._enter_m
+            steps, out = self._steps_ns, now - self._last_m
+        row = (self.fit, self._enter_w, total, enter, steps,
+               total - enter - steps - out, out, self._iterations)
+        tr._push_fit_row(row)
+        if tr.on():
+            attrs = {"fit": self.fit}
+            sid = tr.ended_span("fit", self._enter_w, total,
+                                {**attrs, "iterations": self._iterations})
+            tr.ended_span("fit.enter", self._enter_w, enter, attrs, sid)
+            tr.ended_span("fit.exit", self._enter_w + total - out, out,
+                          attrs, sid)
+        return row
 
     def _close(self, now: int) -> tuple:
         ns = self._ns
@@ -394,8 +477,16 @@ class Tracer:
         # bounded like the record ring, oldest dropped first
         self._step_rows: deque = deque(maxlen=self._cap)  # guarded-by: _lock
         self._fits = 0  # guarded-by: _lock
+        # the build account's rows (the listeners below) and the fit
+        # calls' own rows; live with tracing off, bounded the same way
+        self._build_rows: deque = deque(maxlen=self._cap)  # guarded-by: _lock
+        self._builds = 0  # rows ever written; guarded-by: _lock
+        self._fit_rows: deque = deque(maxlen=self._cap)  # guarded-by: _lock
         self._ctx: contextvars.ContextVar = contextvars.ContextVar(
             f"dt_obs_span_{id(self)}", default=None)
+        # thread -> the number of the fit call it has open (each thread
+        # writes its own key only)
+        self._open_fits: Dict[int, int] = {}
 
     # -- gate -------------------------------------------------------------
 
@@ -495,6 +586,22 @@ class Tracer:
                     t0[2] if len(t0) > 2 else None,
                     self._ctx.get(), attrs))
 
+    def ended_span(self, name: str, wall_ns: int, dur_ns: int,
+                   attrs: Optional[dict] = None,
+                   parent: Optional[int] = None) -> Optional[int]:
+        """Record a span that has already ended, at readings the caller
+        holds (an account row's): its start on the wall clock and its
+        length.  Returns its id, for a child's ``parent`` (default: the
+        enclosing open span); ``None`` with tracing off."""
+        if not self.on():
+            return None
+        sid = self._next_seq()
+        self._push(("X", None, name, wall_ns // 1000,
+                    max(dur_ns, 0) // 1000, self._ident(), sid,
+                    parent if parent is not None else self._ctx.get(),
+                    attrs))
+        return sid
+
     # -- open-span table (r16 flight recorder, dt_tpu/obs/blackbox.py) ----
 
     def _open_add(self, sid: int, name: str, t0w: int, t0m: int,
@@ -572,6 +679,38 @@ class Tracer:
             fit = rows[-1][0]
         return [r for r in rows if r[0] == fit]
 
+    def _push_fit_row(self, row: tuple) -> None:
+        with self._lock:
+            self._fit_rows.append(row)
+
+    def fit_rows(self) -> List[tuple]:
+        """The retained rows of the ``fit`` calls that have left, oldest
+        first (:data:`FIT_ROW_FIELDS`)."""
+        with self._lock:
+            return list(self._fit_rows)
+
+    # -- the build account (live even when tracing is off) ----------------
+
+    def _push_build_row(self, row: tuple) -> None:
+        with self._lock:
+            self._builds += 1
+            self._build_rows.append(row)
+
+    def builds(self) -> int:
+        """How many build rows this tracer was ever given: a mark for
+        :meth:`build_rows` to read on from."""
+        with self._lock:
+            return self._builds
+
+    def build_rows(self, since: int = 0) -> List[tuple]:
+        """The retained build rows, oldest first (:data:`BUILD_ROW_FIELDS`);
+        with ``since`` (a reading of :meth:`builds`) those written after
+        it."""
+        with self._lock:
+            rows = list(self._build_rows)
+            new = self._builds - max(since, 0)
+        return rows[max(len(rows) - new, 0):] if new > 0 else []
+
     # -- counters (live even when tracing is off) -------------------------
 
     def counter(self, name: str, n: int = 1) -> None:
@@ -630,10 +769,168 @@ _DEFAULT_LOCK = threading.Lock()
 
 
 def tracer() -> Tracer:
-    """The process-wide default tracer (one worker process = one track)."""
+    """The process-wide default tracer (one worker process = one track).
+    Making it is also when the build account starts to listen, in a
+    process that has jax loaded."""
     global _DEFAULT
     if _DEFAULT is None:
         with _DEFAULT_LOCK:
             if _DEFAULT is None:
                 _DEFAULT = Tracer(name="process")
+    if not _LISTENING:
+        _listen_for_builds()
     return _DEFAULT
+
+
+# ---------------------------------------------------------------------------
+# the build account: every trace, lowering and compile, as jax reports them
+# ---------------------------------------------------------------------------
+
+#: the ``jax.monitoring`` events that end a stage of a build -> the stage
+BUILD_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: one build row: the ``fit`` call that the building thread had open
+#: (``None`` outside one), the function's name (the same in all three
+#: stages: the module name's ``jit(...)`` is cut), the stage, its start on
+#: the wall clock (jax's own ``time.time()``: the step rows' clock) and its
+#: length, then for ``backend`` whether the persistent cache served it
+#: (``hit``; it was asked and did not: ``miss``; it is disabled or has no
+#: directory: ``off``),
+#: the seconds the retrieval took and the compile seconds jax says it saved
+#: (``None`` in the other stages and without a hit), and the building
+#: thread.  Every lowering and every backend compile is a row; a trace
+#: that ran inside another stage (every ``jnp`` call inside a traced
+#: function is a ``jit`` of its own, traced inside its caller's trace) is
+#: one only if it took :data:`NESTED_ROW_NS` or more, so that a kernel's
+#: own trace is named and a model's thousands of small ones are not:
+#: seconds in a stage are the union of its rows' intervals
+#: (:func:`stage_ns`), not their sum
+BUILD_ROW_FIELDS = ("fit", "fun", "stage", "wall_ns", "dur_ns", "cache",
+                    "retrieval_s", "saved_s", "tid")
+NESTED_ROW_NS = 100_000_000
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "asked",
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "wrote",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+_LISTENING = False
+
+
+class _Building(threading.local):
+    """What this thread's builds have said so far: the stages that are
+    open, and the cache's words since the last ``backend`` stage ended."""
+    open = 0
+
+    def __init__(self):
+        self.cache = {}
+
+
+_BUILDING = _Building()
+
+
+def _on_stage_begin(event: str, start: float, **_) -> None:
+    if event in BUILD_STAGES:
+        _BUILDING.open += 1
+
+
+def _on_cache_event(event: str, **_) -> None:
+    what = _CACHE_EVENTS.get(event)
+    if what is not None:
+        _BUILDING.cache[what] = True
+
+
+def _on_cache_seconds(event: str, secs: float, **_) -> None:
+    what = _CACHE_SECONDS.get(event)
+    if what is not None:
+        _BUILDING.cache[what] = secs
+
+
+def _on_stage(event: str, start: float, end: float, fun_name: str = "",
+              **_) -> None:
+    """A stage of a build has ended: one row on the process tracer, and
+    with tracing on one ``build.<stage>`` span at the same readings."""
+    stage = BUILD_STAGES.get(event)
+    if stage is None:
+        return
+    mine = _BUILDING
+    mine.open = max(mine.open - 1, 0)
+    cache = retrieval = saved = None
+    if stage == "backend":
+        said = mine.cache
+        # jax asks the cache wherever it is enabled, with or without a
+        # directory to ask: a request that nothing came of counts as a
+        # miss only where a directory is in effect
+        cache = "hit" if "hit" in said else "miss" if "wrote" in said or (
+            "asked" in said and
+            sys.modules["jax"].config.jax_compilation_cache_dir) else "off"
+        retrieval, saved = said.get("retrieval_s"), said.get("saved_s")
+        said.clear()
+    tr = _DEFAULT
+    wall_ns, dur_ns = int(start * 1e9), int((end - start) * 1e9)
+    if tr is None or (mine.open and stage == "trace"
+                      and dur_ns < NESTED_ROW_NS):
+        return
+    fun = str(fun_name)
+    for head in ("jit(", "pmap("):      # the module's name -> the function's
+        if fun.startswith(head) and fun.endswith(")"):
+            fun = fun[len(head):-1]
+    fit = tr._open_fits.get(tr._ident())
+    tr._push_build_row((fit, fun, stage, wall_ns, dur_ns, cache, retrieval,
+                        saved, tr._ident()))
+    if tr.on():
+        tr.ended_span(f"build.{stage}", wall_ns, dur_ns,
+                      {"fun": fun, "fit": fit, "cache": cache})
+
+
+def _listen_for_builds() -> None:
+    """Register the build account's four ``jax.monitoring`` listeners, once
+    a process, as soon as the process tracer is asked for with jax loaded
+    (``training/module.py`` asks at import, so before a ``Module`` builds
+    anything; a process without jax never listens and never imports it).
+    They stay for the life of the process and write to whichever tracer is
+    the process's then.
+
+    jax emits these events where a program is built and nowhere in a step:
+    a build costs a begin and an end call for each of its three stages and
+    for each ``jit`` traced inside it (a dictionary lookup and a counter,
+    under a microsecond each beside a trace of milliseconds), and with the
+    persistent cache on two or three calls of each of the cache's; a step
+    that is already built costs none (``tests/test_build_account.py`` holds
+    both).  Nothing is wrapped: the rows describe the ``jit`` call as the
+    caller made it."""
+    global _LISTENING
+    if "jax" not in sys.modules:
+        return
+    with _DEFAULT_LOCK:
+        if _LISTENING:
+            return
+        try:
+            from jax import monitoring
+        except ImportError:   # jax is half imported: ask again later
+            return
+        monitoring.register_scalar_listener(_on_stage_begin)
+        monitoring.register_event_time_span_listener(_on_stage)
+        monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+        monitoring.register_event_listener(_on_cache_event)
+        _LISTENING = True
+
+
+def stage_ns(rows, stage: str) -> int:
+    """Nanoseconds that ``rows`` (build rows) spent in ``stage``: the
+    union of their intervals, since an inner function's trace is inside
+    its caller's."""
+    total, upto = 0, 0
+    for r in sorted((r for r in rows if r[2] == stage),
+                    key=lambda r: r[3]):
+        end = r[3] + r[4]
+        if end > upto:
+            total += end - max(r[3], upto)
+            upto = end
+    return total

@@ -52,6 +52,10 @@ from dt_tpu.training.train_state import TrainState
 
 logger = logging.getLogger("dt_tpu")
 
+# the process tracer is made here: the build account (obs/trace.py) listens
+# from then on, before anything of this process is built through a Module
+obs_trace.tracer()
+
 _ROW_DISPATCHED = obs_trace.STEP_ROW_FIELDS.index("dispatched")
 _ROW_TOTAL_NS = obs_trace.STEP_ROW_FIELDS.index("total_ns")
 
@@ -736,6 +740,24 @@ class Module:
         """Train.  Mirrors ``BaseModule.fit`` (``base_module.py:497-623``)
         including the elastic control path §3.3 of SURVEY.md.
         """
+        # the step account (obs/trace.py StepAccount), made at the call's
+        # entry: where every iteration of the step loop spent its wall
+        # time and, at every way out, the call's own row (entry,
+        # iterations, exit); live with tracing off.  What is built while
+        # the call is open carries its number in the build rows
+        acct = obs_trace.tracer().step_account()
+        try:
+            return self._fit(
+                acct, train_data, eval_data, eval_metric, num_epoch,
+                begin_epoch, batch_end_callback, epoch_end_callback,
+                eval_end_callback, elastic_data_iterator, validation_metric)
+        finally:
+            acct.exit()
+
+    def _fit(self, acct, train_data, eval_data, eval_metric, num_epoch,
+             begin_epoch, batch_end_callback, epoch_end_callback,
+             eval_end_callback, elastic_data_iterator, validation_metric):
+        """``fit``'s body, between the account's entry and exit."""
         # --- elastic env contract (base_module.py:503-506) ---
         is_new_worker = config_lib.env_flag(config_lib.ENV_NEW_WORKER)
         elastic_enabled = config_lib.env_flag(config_lib.ENV_ELASTIC_ENABLED)
@@ -834,12 +856,9 @@ class Module:
 
         from dt_tpu.elastic import faults as faults_lib
         from dt_tpu.obs import blackbox as bb_lib
-        _obs = obs_trace.tracer()  # epoch/step spans (off unless DT_OBS)
-        # the step account (obs/trace.py StepAccount): where every
-        # iteration of the step loop spent its wall time, live with
-        # tracing off; with DT_OBS=1 the same boundaries are the `step`
-        # span and its phase spans
-        acct = _obs.step_account()
+        # epoch/step spans (off unless DT_OBS): with DT_OBS=1 the
+        # account's boundaries are the `step` span and its phase spans
+        _obs = obs_trace.tracer()
         # r16 flight recorder: the per-worker hang watchdog (deadman on
         # step progress, DT_HANG_S) runs for the whole fit and is torn
         # down on EVERY exit path; no-op unless DT_BLACKBOX=1

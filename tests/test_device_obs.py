@@ -181,22 +181,68 @@ def test_cache_defaults_to_checkout_xla_cache(_restore_cache_dir):
     assert config.enable_compilation_cache() == want
 
 
-def test_cache_probe_follows_effective_dir(tmp_path, _restore_cache_dir):
+def test_ledger_cache_outcome_follows_jax_events_and_effective_setting(
+        tmp_path, _restore_cache_dir):
+    """``hit`` / ``miss`` / ``off`` in the ``compile.<what>`` span and the
+    ledger's totals are what jax's own cache events said of that build
+    (read from the build account's rows), under whatever cache setting is
+    in effect: disabled (conftest), enabled with a directory (a fresh
+    program is written, a second build of it is served), enabled with the
+    directory taken away.  jax decides once a process whether the cache is
+    used: ``reset_cache`` makes it look again."""
     import jax
-    d = str(tmp_path / "jaxcache")
-    os.makedirs(d)
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_enable_compilation_cache", True)  # conftest: off
-    p = dev.cache_probe()
-    assert p.dir == d
-    assert p.outcome() == "hit"  # configured + no new files
-    open(os.path.join(d, "entry-0"), "w").write("x")
-    assert p.outcome() == "miss"  # a fresh program was written
-    jax.config.update("jax_enable_compilation_cache", False)
-    assert dev.cache_probe().outcome() == "off"  # placed but disabled
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", None)
-    assert dev.cache_probe().outcome() == "off"
+    import jax.numpy as jnp
+    from jax._src import compilation_cache
+    dev.set_enabled(True)
+    obs_trace.set_enabled(True)
+    small = (jax.config.jax_persistent_cache_min_compile_time_secs,
+             jax.config.jax_persistent_cache_min_entry_size_bytes)
+
+    def build(k):
+        """A new jit object of a program that differs only by ``k`` ->
+        (the span's attrs, the ledger's hits, its misses)."""
+        f = dev.instrument("toy", jax.jit(lambda x: x * k + 1), {})
+        f(jnp.ones(16))
+        span = [r for r in obs_trace.tracer().drain()
+                if r[0] == "X" and r[2] == "compile.toy"][-1]
+        s = dev.summary()
+        return span[8], s["cache_hits"], s["cache_misses"]
+
+    try:
+        attrs, hits, misses = build(2.0)
+        assert (attrs["cache"], hits, misses) == ("off", 0, 0)
+        # the stages are jax's own readings of this build
+        assert attrs["backend_ms"] > 0 and attrs["lower_ms"] > 0
+        assert attrs["trace_ms"] + attrs["lower_ms"] + attrs["backend_ms"] \
+            <= attrs["elapsed_ms"] + 0.1
+        jax.config.update("jax_compilation_cache_dir",
+                          str(tmp_path / "jaxcache"))
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        assert build(3.0)[0]["cache"] == "miss"     # asked, then written
+        attrs, hits, misses = build(3.0)            # the same program
+        assert (attrs["cache"], hits, misses) == ("hit", 1, 1)
+        assert os.listdir(str(tmp_path / "jaxcache"))
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        assert build(5.0)[0]["cache"] == "off"      # placed but disabled
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", None)
+        compilation_cache.reset_cache()
+        attrs, hits, misses = build(6.0)            # enabled, nowhere
+        assert (attrs["cache"], hits, misses) == ("off", 1, 1)
+        log = dev.summary()["recompile_log"]
+        assert [r["cache"] for r in log[:3]] == ["miss", "hit", "off"]
+        assert all("backend_ms" in r and "trace_ms" in r for r in log)
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          small[0])
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          small[1])
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
 
 
 # ---------------------------------------------------------------------------
