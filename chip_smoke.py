@@ -203,14 +203,15 @@ def stage_a(size="full"):
 _B_SIZES = {
     # flash: the stage's own LM shape below (8 heads of 64 at sequence
     # 2048), at the derived tiles of either pass (flash_case passes none); BN:
-    # ResNet-50's first and last BN inputs at batch 128
+    # ResNet-50's first and last BN inputs at batch 128; ssd: the hybrid
+    # cell's state-space scan (B, L, H, P, G, N, chunk)
     "full": dict(flash=(8, 2048, 8, 64), bn=[(128, 112, 112, 64),
                                              (128, 7, 7, 2048)],
-                 dtype="bfloat16",
+                 ssd=(2, 4096, 64, 64, 1, 128, 256), dtype="bfloat16",
                  lm=dict(vocab=8192, embed=512, layers=6, heads=8, seq=2048,
                          batch=8, steps=10)),
     "toy": dict(flash=(1, 256, 2, 64), bn=[(2, 8, 8, 64)],
-                dtype="float32",
+                ssd=(1, 200, 2, 64, 1, 128, 128), dtype="float32",
                 lm=dict(vocab=64, embed=32, layers=1, heads=2, seq=128,
                         batch=2, steps=2)),
 }
@@ -245,6 +246,9 @@ def stage_b(size="full", interpret=False):
                    lambda s=shape: pd.bn_inference_case(rng, s, dt, interpret)),
                   ("fused_bn_train_fwd_bwd", shape, tol,
                    lambda s=shape: pd.bn_train_case(rng, s, dt, interpret))]
+    # the scan and its five gradients against the XLA body in float32
+    cases.append(("ssd_scan_fwd_bwd", cfg["ssd"], tol,
+                  lambda: pd.ssd_scan_case(rng, *cfg["ssd"], dt, interpret)))
 
     # the flash gate runs at the tiles the models get: derived from the shape
     _, seq, _, head = cfg["flash"]

@@ -216,6 +216,14 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                    "(backward_tiles)"),
     "flash.bwd_block_k": ("gauge", "key rows in one tile of the flash "
                                    "backward (backward_tiles)"),
+    # set at trace time by every call of ops/ssm.py ssd_scan
+    # (ops/pallas/ssd.py note_path): the process's counts so far
+    "ssd.kernel_calls": ("gauge", "calls of ssd_scan traced so far that "
+                                  "took the Pallas kernels (ssd_fwd, "
+                                  "ssd_bwd)"),
+    "ssd.xla_calls": ("gauge", "calls of ssd_scan traced so far that fell "
+                               "back to ssd_scan_xla: a chunk, a state "
+                               "width or a head size off the lane tiles"),
     "worker.step_rate": ("gauge", "scheduler-derived per-worker step "
                                   "rate (steps/s) from the shipped "
                                   "train.steps series"),

@@ -1,9 +1,8 @@
-"""State-space (Mamba-2) mixer parts, as plain ``jax.numpy``/``lax`` that XLA
-lowers: the causal depthwise convolution, the selective state-space
-recurrence as a chunked scan, and the gated RMSNorm.  The reference's
-recurrent ceiling is the cuDNN fused RNN (``src/operator/cudnn_rnn-inl.h:1``;
-SURVEY §5.7), one position at a time; this is the recurrence that trains in
-parallel over the sequence.
+"""State-space (Mamba-2) mixer parts: the causal depthwise convolution, the
+selective state-space recurrence as a chunked scan, and the gated RMSNorm.
+The reference's recurrent ceiling is the cuDNN fused RNN
+(``src/operator/cudnn_rnn-inl.h:1``; SURVEY §5.7), one position at a time;
+this is the recurrence that trains in parallel over the sequence.
 
 The recurrence, per head (``x_t`` of ``P`` channels, ``B_t`` and ``C_t`` of
 ``N`` state channels shared by the heads of a group, a scalar step ``dt_t > 0``
@@ -14,13 +13,19 @@ and a scalar ``a < 0``)::
 
 ``ssd_scan`` computes it in chunks of ``chunk`` positions (Dao & Gu 2024,
 "state-space duality"): inside a chunk as masked products of ``C B^T`` with
-the decays' cumulative sums, between chunks as a ``lax.scan`` over the chunk
-states.  Decays, cumulative sums and the carried states are float32 whatever
-the compute type; the four products take operands in ``x``'s type and
-accumulate in float32.  It is differentiated as written.  No Pallas kernel
-computes any of this yet: the scopes ``conv1d``, ``ssd_scan`` and
+the decays' cumulative sums, between chunks by carrying the chunk states.
+Decays, cumulative sums and the carried states are float32 whatever the
+compute type; the four products take operands in ``x``'s type and accumulate
+in float32.  Two bodies compute it, chosen by the shapes alone: the Pallas
+kernels of ``ops/pallas/ssd.py`` (``ssd_fwd`` and a hand-written backward,
+``ssd_bwd``: a chunk's squares stay in VMEM and nothing is differentiated as
+written) where the chunk, the state width and a lane tile's heads are whole
+lane tiles, and ``ssd_scan_xla`` (plain ``jax.numpy`` and a ``lax.scan`` over
+the chunk states, differentiated as written) otherwise, which is what widths
+in the units take.  The convolution and the gated norm are plain
+``jax.numpy`` that XLA lowers; the scopes ``conv1d``, ``ssd_scan`` and
 ``gated_norm`` that ``models/hybrid_lm.py`` puts around these calls are what
-a later kernel is sized by.
+a device trace finds them by.
 """
 
 from __future__ import annotations
@@ -28,6 +33,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from dt_tpu.ops.pallas import ssd
 
 F32 = jnp.float32
 
@@ -56,7 +63,26 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int):
     (B, L, H, P) in ``x``'s type, without the skip term ``D x``.  ``L`` need
     not be a multiple of ``chunk``: the tail is padded with ``dt = 0``, which
     neither decays nor feeds the state.
+
+    Which path runs follows from the shapes alone: the Pallas kernels where
+    ``ssd.head_block`` has a head block for them (the chunk, the state width
+    and a lane tile's heads whole lane tiles), ``ssd_scan_xla`` otherwise.
     """
+    bsz, l, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    q = min(int(chunk), l)
+    hb = ssd.head_block(h, g, p, n, q, x.dtype.itemsize)
+    ssd.note_path((bsz, l, h, p, g, n, q, x.dtype.name), hb)
+    if hb is None:
+        return ssd_scan_xla(x, dt, a, b, c, chunk=chunk)
+    return ssd.ssd_scan_pallas(x, dt, a, b, c, q=q, hb=hb)
+
+
+def ssd_scan_xla(x, dt, a, b, c, *, chunk: int):
+    """``ssd_scan`` as plain ``jax.numpy``, differentiated as written: the
+    path for shapes the kernels do not take, and the tests' second oracle.
+    A chunk's decays are a (B, chunks, H, chunk, chunk) float32 array in
+    HBM here, and the chunk states a ``lax.scan``."""
     bsz, l, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     k = h // g

@@ -25,7 +25,7 @@ def test_stage_b_toy_in_interpret_mode():
     """The kernel stage's plumbing (cases, tolerances, the flash LM through
     Module.fit) with the Pallas interpreter standing in for Mosaic."""
     out = chip_smoke.stage_b("toy", interpret=True)
-    assert all(k["ok"] for k in out["kernels"]) and len(out["kernels"]) == 3
+    assert all(k["ok"] for k in out["kernels"]) and len(out["kernels"]) == 4
     assert out["lm_steps"] == 2
 
 

@@ -1,9 +1,13 @@
 """``ops/ssm.py`` at a small size on the CPU: the chunked scan against the
 one-position recurrence of the benchmark's plain reference
 (``benchmark/configs/granite-4.0-h-micro_reference.py``), forward and
-gradients; the causal convolution; the gated norm; grouped-query attention
-through the flash kernel (interpret mode) against plain softmax.
+gradients, through the XLA body at widths in the units and through the
+Pallas kernels of ``ops/pallas/ssd.py`` (interpret mode) at the narrowest
+widths they take; the causal convolution; the gated norm; grouped-query
+attention through the flash kernel (interpret mode) against plain softmax.
 """
+
+import logging
 
 import numpy as np
 import pytest
@@ -11,14 +15,15 @@ import jax
 import jax.numpy as jnp
 
 from dt_tpu.models import hybrid_lm
+from dt_tpu.obs import metrics as obs_metrics
 from dt_tpu.ops import ssm
+from dt_tpu.ops.pallas import ssd
 
 from hybrid_small import REF, SMALL
 
 
-def _scan_inputs(length, groups, seed):
+def _scan_inputs(length, groups, seed, b=2, h=4, p=3, n=5):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    b, h, p, n = 2, 4, 3, 5
     return (jax.random.normal(ks[0], (b, length, h, p)),
             jax.nn.softplus(jax.random.normal(ks[1], (b, length, h))),
             -jnp.exp(jax.random.normal(ks[2], (h,))),
@@ -32,6 +37,13 @@ def _one_position_at_a_time(x, dt, a, b, c):
         xs, ds, jnp.log(-a), bs, cs))(x, dt, b, c)
 
 
+def _value_and_gradients(f, args, weights):
+    """The scan's output and the gradient of every input, one program."""
+    return jax.jit(lambda *t: (f(*t), jax.grad(
+        lambda *u: jnp.sum(f(*u).astype(jnp.float32) * weights),
+        argnums=(0, 1, 2, 3, 4))(*t)))(*args)
+
+
 @pytest.mark.parametrize("length,chunk,groups", [
     (16, 4, 1),     # the chunk divides the length
     (13, 4, 2),     # it does not: the tail is padded with dt = 0
@@ -41,18 +53,93 @@ def _one_position_at_a_time(x, dt, a, b, c):
 def test_chunked_scan_is_the_recurrence(length, chunk, groups):
     args = _scan_inputs(length, groups, seed=length)
     weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
-
-    def both(f):
-        """The scan's output and the gradient of every input, one program."""
-        return jax.jit(lambda *t: (f(*t), jax.grad(
-            lambda *u: jnp.sum(f(*u) * weights), argnums=(0, 1, 2, 3, 4))(
-                *t)))(*args)
-
-    got, grads = both(lambda *t: ssm.ssd_scan(*t, chunk=chunk))
-    want, want_grads = both(_one_position_at_a_time)
+    got, grads = _value_and_gradients(
+        lambda *t: ssm.ssd_scan(*t, chunk=chunk), args, weights)
+    want, want_grads = _value_and_gradients(_one_position_at_a_time, args,
+                                            weights)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
     for name, g, w in zip(("x", "dt", "a", "b", "c"), grads, want_grads):
         np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-4, err_msg=name)
+
+
+ORACLES = {"recurrence": _one_position_at_a_time,
+           "xla": lambda *t: ssm.ssd_scan_xla(*t, chunk=128)}
+
+
+@pytest.mark.parametrize("oracle", sorted(ORACLES))
+@pytest.mark.parametrize("length,groups,dtype", [
+    (128, 1, "float32"),     # one chunk
+    (384, 1, "float32"),     # several: the state is carried
+    (200, 2, "float32"),     # a tail padded with dt = 0; a group a head pair
+    (256, 2, "float32"),
+    (256, 1, "bfloat16"),    # bfloat16 operands against the float32 oracle
+    (200, 2, "bfloat16")])
+def test_the_kernels_are_the_recurrence(length, groups, dtype, oracle):
+    """``ssd_scan`` at the narrowest widths the kernels take (heads of 64,
+    states of 128, chunks of 128; the Pallas interpreter here): the value
+    and all five gradients against the one-position recurrence and against
+    ``ssd_scan_xla``, both in float32."""
+    args = _scan_inputs(length, groups, seed=length, b=2, h=2 * groups,
+                        p=64, n=128)
+    assert ssd.head_block(2 * groups, groups, 64, 128, 128, 4) == 2
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    cast = lambda t: [v.astype(dtype) if v.ndim == 4 else v  # noqa: E731
+                      for v in t]
+    got, grads = _value_and_gradients(
+        lambda *t: ssm.ssd_scan(*cast(t), chunk=128), args, weights)
+    want, want_grads = _value_and_gradients(ORACLES[oracle], args, weights)
+    assert got.dtype == jnp.dtype(dtype)
+    if dtype == "float32":
+        # test_chunked_scan_is_the_recurrence's tolerances, the absolute
+        # ones times the array's largest element (sums of 128 terms here)
+        top = lambda w: float(jnp.max(jnp.abs(w)))  # noqa: E731
+        np.testing.assert_allclose(got, want, rtol=2e-4,
+                                   atol=2e-5 * top(want))
+        for name, g, w in zip(("x", "dt", "a", "b", "c"), grads, want_grads):
+            np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-4 * top(w),
+                                       err_msg=name)
+        return
+    # eight bits of mantissa in every operand: a hundredth of each array's
+    # largest element (a wrong term or a dropped chunk is of order one)
+    for name, g, w in zip(("y", "x", "dt", "a", "b", "c"), (got, *grads),
+                          (want, *want_grads)):
+        gap = float(jnp.max(jnp.abs(g.astype(jnp.float32) - w))
+                    / jnp.max(jnp.abs(w)))
+        assert gap < 2e-2, (name, gap)
+
+
+def test_a_shape_off_the_lane_tiles_falls_back_and_says_so(caplog):
+    """Which path a call took is recorded at trace time: a ``# ssd_scan``
+    debug line with the head block (None: ``ssd_scan_xla``) and the gauges
+    ``ssd.kernel_calls`` and ``ssd.xla_calls``."""
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        before = list(ssd._calls)
+        toy = _scan_inputs(16, 1, seed=0)           # heads of 3, states of 5
+        wide = _scan_inputs(128, 1, seed=0, b=1, h=2, p=64, n=128)
+        with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
+            got = ssm.ssd_scan(*toy, chunk=4)
+            np.testing.assert_array_equal(
+                got, ssm.ssd_scan_xla(*toy, chunk=4))
+            gauges = {g[0]: g[2] for g in obs_metrics.registry()
+                      .gauges_export() if g[0].startswith("ssd.")}
+            assert gauges == {"ssd.kernel_calls": before[0],
+                              "ssd.xla_calls": before[1] + 1}
+            ssm.ssd_scan(*wide, chunk=128)
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("# ssd_scan")]
+        assert lines == [
+            "# ssd_scan b=2 l=16 h=4 p=3 g=1 n=5 q=4 dtype=float32 hb=None",
+            "# ssd_scan b=1 l=128 h=2 p=64 g=1 n=128 q=128 dtype=float32 "
+            "hb=2"]
+        gauges = {g[0]: g[2] for g in obs_metrics.registry().gauges_export()
+                  if g[0].startswith("ssd.")}
+        assert gauges == {"ssd.kernel_calls": before[0] + 1,
+                          "ssd.xla_calls": before[1] + 1}
+    finally:
+        obs_metrics.set_enabled(None)
+        obs_metrics.registry().clear()
 
 
 def test_causal_conv_sees_only_the_past():

@@ -19,6 +19,7 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
         python tools/pallas_drive.py --only flash_bwd_tiles  # the backward's
         python tools/pallas_drive.py --only grouped_mm_tiles  # routed layer's
+        python tools/pallas_drive.py --only ssd_scan  # Mamba-2 scan, by hb
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -363,6 +364,115 @@ def grouped_mm_tiles_sweep(rng, m, k, n, dt, groups=16, iters=30,
                              args, want if load == "ragged" else None)[0]
 
 
+# (B, L, H, P, G, N, chunk): the hybrid cell's state-space layers
+# (granite-4.0-h-micro, benchmark/configs; nine of them a step)
+SSD_CELL_SHAPE = (2, 4096, 64, 64, 1, 128, 256)
+SSD_SWEEP_HEAD_BLOCKS = (4, 8, 16)
+
+
+def _ssd_inputs(rng, b, l, h, p, g, n, dt):
+    """``ssd_scan``'s five inputs at the sizes a trained mixer feeds it:
+    steps log-uniform in 0.001..0.1, ``a`` in -16..-1 (Mamba-2's own
+    initialisation, ``models/hybrid_lm.py``)."""
+    import jax.numpy as jnp
+    return (jnp.asarray(rng.randn(b, l, h, p), dt),
+            jnp.asarray(_np_exp_uniform(rng, (b, l, h)), jnp.float32),
+            jnp.asarray(-rng.uniform(1.0, 16.0, (h,)), jnp.float32),
+            jnp.asarray(rng.randn(b, l, g, n), dt),
+            jnp.asarray(rng.randn(b, l, g, n), dt))
+
+
+def _np_exp_uniform(rng, shape, lo=1e-3, hi=0.1):
+    import numpy as np
+    return np.exp(rng.uniform(np.log(lo), np.log(hi), shape))
+
+
+def _ssd_passes(scan, d_out):
+    """(forward alone, forward and the five gradients) of ``scan``, jitted
+    over its five inputs."""
+    import jax
+    fwd = jax.jit(scan)
+
+    def both(*t):
+        y, pull = jax.vjp(scan, *t)
+        return y, pull(d_out.astype(y.dtype))
+    return fwd, jax.jit(both)
+
+
+def _ssd_setup(rng, b, l, h, p, g, n, chunk, dt):
+    """What the case and the sweep share: the inputs, the cotangent, the
+    chunk's positions, the derived head block and the oracle (the XLA body
+    in float32 on the same numbers, forward and gradients)."""
+    import jax.numpy as jnp
+    from dt_tpu.ops import ssm
+    from dt_tpu.ops.pallas import ssd
+    args = _ssd_inputs(rng, b, l, h, p, g, n, dt)
+    d_out = jnp.asarray(rng.randn(b, l, h, p), jnp.float32)
+    q = min(chunk, l)
+    hb = ssd.head_block(h, g, p, n, q, jnp.dtype(dt).itemsize)
+    oracle = _ssd_passes(lambda *t: ssm.ssd_scan_xla(*(
+        v.astype(jnp.float32) for v in t), chunk=chunk), d_out)[1]
+    return args, d_out, q, hb, oracle
+
+
+def ssd_scan_case(rng, b, l, h, p, g, n, chunk, dt, interpret=None):
+    """The scan and its five gradients through the kernels, in ``dt``,
+    against the XLA body in float32 on the same numbers."""
+    from dt_tpu.ops.pallas import ssd
+    args, d_out, q, hb, oracle = _ssd_setup(rng, b, l, h, p, g, n, chunk, dt)
+    assert hb is not None, "the kernels do not take this shape"
+    pallas = _ssd_passes(lambda *t: ssd.ssd_scan_pallas(
+        *t, q=q, hb=hb, interpret=interpret), d_out)[1]
+    return oracle, pallas, args
+
+
+def ssd_scan_sweep(rng, b, l, h, p, g, n, chunk, dt, iters=20,
+                   interpret=None):
+    """Forward alone and forward with backward of the scan on the host's
+    clock: ``ssd_scan_xla`` and the kernels at each head block of
+    ``SSD_SWEEP_HEAD_BLOCKS`` and the derived one, each against the XLA body
+    in float32.  One record each; ``bwd_ms`` is the difference of the two
+    timings."""
+    import jax
+    import jax.numpy as jnp
+    from dt_tpu.ops import ssm
+    from dt_tpu.ops.pallas import ssd
+    args, d_out, q, derived, oracle = _ssd_setup(rng, b, l, h, p, g, n,
+                                                 chunk, dt)
+    want = oracle(*args)
+    jax.block_until_ready(want)
+    paths = [("xla", None, lambda *t: ssm.ssd_scan_xla(*t, chunk=chunk))]
+    for hb in sorted({*SSD_SWEEP_HEAD_BLOCKS, derived} - {None}):
+        if (h // g) % hb == 0 and hb * p % 128 == 0:
+            paths.append(("pallas", hb, lambda *t, hb=hb: ssd.ssd_scan_pallas(
+                *t, q=q, hb=hb, interpret=interpret)))
+    # the products a layer's forward needs: C B^T a chunk and group, and a
+    # head and chunk the square against x, the state and the state's part
+    flops = 2 * b * (l // q) * (g * q * q * n + h * (q * q * p
+                                                     + 2 * q * p * n))
+    for which, hb, scan in paths:
+        rec = {"kernel": "ssd_scan", "which": which, "hb": hb,
+               "derived": hb == derived and which == "pallas",
+               "shape": f"B{b}xL{l}xH{h}xP{p}xG{g}xN{n} chunk{chunk} "
+                        f"{jnp.dtype(dt).name}",
+               "backend": jax.default_backend()}
+        try:
+            fwd, both = _ssd_passes(scan, d_out)
+            got = both(*args)
+            rec["vs_f32_xla_rel_err"] = {
+                name: round(rel_err(a, b), 6) for name, a, b in zip(
+                    ("y", "dx", "ddt", "da", "db", "dc"),
+                    (got[0], *got[1]), (want[0], *want[1]))}
+            rec["fwd_ms"] = round(_timeit(fwd, *args, iters=iters), 4)
+            rec["fwd_bwd_ms"] = round(_timeit(both, *args, iters=iters), 4)
+            rec["bwd_ms"] = round(rec["fwd_bwd_ms"] - rec["fwd_ms"], 4)
+            rec["fwd_pct_of_197_tflops"] = round(
+                100 * flops / (rec["fwd_ms"] * 1e-3) / 197e12, 1)
+        except Exception as e:  # noqa: BLE001 — a head block Mosaic refuses
+            rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+        yield rec
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--small", action="store_true",
@@ -449,6 +559,12 @@ def main():
                                               groups=4 if args.small else 16,
                                               iters=args.iters):
                 print(json.dumps(rec), flush=True)
+
+    # ---- the Mamba-2 scan, forward and backward, by head block (PR 40) ---
+    if wanted("ssd_scan"):
+        shape = (1, 256, 2, 64, 1, 128, 128) if args.small else SSD_CELL_SHAPE
+        for rec in ssd_scan_sweep(rng, *shape, dt, iters=args.iters):
+            print(json.dumps(rec), flush=True)
 
 
 if __name__ == "__main__":
