@@ -1,6 +1,8 @@
 """A decoder of rotary grouped-query attention and routed experts, trained by
-diffusion over blocks or as a causal next-token model whose attention reads
-only the keys a learned indexer picks.
+diffusion over blocks or as a causal next-token model: one whose attention
+reads only the keys a learned indexer picks, or one whose layers differ from
+one another (windowed and full attention with their own head counts and
+rotary rules, a gate a head, a leading dense layer, a shared expert).
 
 Beyond the reference's RNN ceiling (the cuDNN fused LSTM,
 ``src/operator/cudnn_rnn-inl.h:1``; SURVEY.md §5.7) and beside ``HybridLM``
@@ -58,13 +60,49 @@ reaches the flash kernels as data, a packed bitmap
 under ``("aux_loss", "indexer_kl")`` and the layer's counts under
 ``("counters", "dsa")`` (``DSA_COUNTERS``).
 
+**Layers that differ inside one decoder** (``RoutedLM.layers``, one record a
+layer: kind of attention, ``num_heads``, rotary rule, dense or routed
+feed-forward; causal objective).  With ``a`` the normed stream, ``D`` the
+head size, ``W`` the window, ``E`` experts, ``k`` a token, as one published
+decoder of this kind has them (64 heads in a sliding layer and 48 in a full
+one over 8 key-value heads of 128, ``W`` 512, ``E`` 256, ``k`` 8)::
+
+    a = rms(x)
+    q = (a Wq) as [T, H_l, D] ;  k = (a Wk) as [T, KV, D] ;  v = (a Wv) as [T, KV, D]
+    full layer:     q, k = yarn_rope(q, pos), yarn_rope(k, pos)      the first rotary_dim of D dims turned, the rest passed through
+    sliding layer:  q, k = rope(q, pos, theta), rope(k, pos, theta)  all D dims
+    full:     o_t = softmax over s <= t           of q_t . k_s / sqrt(D)  v
+    sliding:  o_t = softmax over t - W < s <= t   of q_t . k_s / sqrt(D)  v      W keys, the query's own among them
+    g = sigmoid(a Wg) as [T, H_l]                                                 one gate a head
+    h = x + ((g[..., None] * o) as [T, H_l D]) Wo
+    dense layer:    x' = h + Wdown(silu(b Wgate) * (b Wup)) ,  b = rms(h)
+    routed layer:   s = sigmoid_f32(b Wr) over E ;  S = top_k(s) ;  w_e = scale * s_e / sum_S s
+                    x' = h + shared(b) + sum_{e in S, e held} w_e expert_e(b)     shared and routed experts gated SiLU
+    logits = rms(x_last) Whead   (float32) ;  loss = CE(next token, all T) + aux_loss_coef * load_balance
+
+``yarn_rope`` (``yarn_frequencies``, ``rope_part``): over the ``rotary_dim /
+2`` frequency pairs ``f_i = theta^(-2i / rotary_dim)``; a ramp between the
+pairs that make ``beta_fast`` and ``beta_slow`` turns inside
+``original_max_position_embeddings`` blends ``f_i`` (kept) with ``f_i /
+factor`` (interpolated), as the published YaRN rule (arXiv:2309.00071) and
+its reference implementation have it; cosine and sine are multiplied by
+``attention_factor``.  The turned pair is ``(x_i, x_{i + rotary_dim / 2})``
+inside the turned part, ``_turn``'s convention.  No norm on the heads'
+queries and keys (``qk_norm=False``).  The window reaches the flash kernels
+as the static rule ``WindowMask`` (``ops/pallas/attention.py``: the grids
+span only the tiles a band can touch), and a windowed layer sows the rule's
+static counts under ``("counters", "win")`` (``WIN_COUNTERS``).
+
 Module names and ``jax.named_scope``s tell the parts apart in an operation's
 scope path: ``block3/attn/q_proj``, ``block3/attn/rope``,
 ``block3/attn/indexer`` (the three projections, the index key's norm and the
 chunked scores), ``block3/attn/select`` (each row's k-th largest score and
 the bitmaps), ``block3/attn/indexer_kl``,
-``block3/moe/route`` (``dispatch``, ``experts``, ``combine``), ``embed``,
-``lm_head``.
+``block3/moe/route`` (``dispatch``, ``experts``, ``combine``, ``shared``),
+``embed``, ``lm_head``; in a decoder whose layers differ, the kind of a
+layer's attention (``block3/attn/window/...``, ``block0/attn/full/...``: no
+metric has to name a layer by its number), ``.../gate`` inside either, and
+``block0/mlp`` for a dense layer.
 
 With ``remat`` each block is rematerialised: it keeps its input and the
 values named in ``SAVED`` (the flash kernel's output among them, so the
@@ -73,6 +111,8 @@ kernel runs once a layer) and computes the rest again in the backward pass.
 
 from __future__ import annotations
 
+import contextlib
+import math
 from typing import Any, Optional
 
 import flax.linen as linen
@@ -81,22 +121,25 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from dt_tpu.models.hybrid_lm import RMSNorm
+from dt_tpu.models.hybrid_lm import GatedMLP, RMSNorm
 from dt_tpu.ops import sparse_index
 from dt_tpu.ops.pallas.attention import (BlockDiffusionMask, DEFAULT_BLOCK,
                                          NEG_INF, SelectedKeysMask,
-                                         backward_tiles, flash_attention,
-                                         forward_tiles, unpack_selection)
+                                         WindowMask, backward_tiles,
+                                         flash_attention, forward_tiles,
+                                         unpack_selection)
 from dt_tpu.parallel.moe import RoutedExperts
 
 F32 = jnp.float32
 
 
-def _turn(x, angle):
+def _turn(x, angle, scale: float = 1.0):
     """``x`` (B, S, H, D) with the pair ``(x_i, x_{i + D/2})`` turned by
-    ``angle`` (S, D/2), in float32."""
+    ``angle`` (S, D/2), in float32; the cosine and sine times ``scale``."""
     half = x.shape[-1] // 2
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     v = x.astype(F32)
     a, b = v[..., :half], v[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
@@ -110,6 +153,47 @@ def rope(x, positions, theta: float):
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=F32) / half)
     return _turn(x, positions.astype(F32)[:, None] * freq[None, :])
+
+
+def yarn_frequencies(rotary_dim: int, theta: float, factor: float,
+                     original_max_position_embeddings: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """The YaRN schedule (arXiv:2309.00071, as its reference implementation
+    has it) over the ``rotary_dim / 2`` frequency pairs: ``f_i = theta^(-2i
+    / rotary_dim)`` is kept where pair ``i`` turns at least ``beta_fast``
+    times inside the original context, divided by ``factor`` where it turns
+    at most ``beta_slow`` times, and blended along a linear ramp over the
+    pairs between the two (their indices rounded outwards).  A float32
+    numpy vector: made at trace time, a constant of the program."""
+    half = rotary_dim // 2
+
+    def pair_of(turns):     # the (fractional) pair that makes ``turns``
+        return rotary_dim * math.log(original_max_position_embeddings
+                                     / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    kept = 1.0 - np.clip((np.arange(half, dtype=np.float32) - low)
+                         / (high - low), 0.0, 1.0)
+    freq = theta ** (-np.arange(half, dtype=np.float32) / half)
+    return (freq / factor * (1.0 - kept) + freq * kept).astype(np.float32)
+
+
+def rope_part(x, positions, freq, scale: float = 1.0):
+    """Rotary positions on the first ``2 len(freq)`` of ``x``'s (B, S, H, D)
+    last axis, at ``positions`` (S,): inside that part the pair ``(x_i, x_{i
+    + len(freq)})`` is turned by ``pos * freq_i`` (``_turn``'s convention,
+    the cosine and sine times ``scale``), and the rest of the head passes
+    through.  ``rope`` where the part is the whole head, ``freq`` is
+    ``theta^(-i / (D/2))`` and ``scale`` 1."""
+    part = 2 * len(freq)
+    angle = positions.astype(F32)[:, None] * jnp.asarray(freq, F32)[None, :]
+    if part == x.shape[-1]:
+        return _turn(x, angle, scale)
+    return jnp.concatenate([_turn(x[..., :part], angle, scale),
+                            x[..., part:]], axis=-1)
 
 
 def mrope(x, positions, theta: float, sections):
@@ -135,6 +219,15 @@ DSA_COUNTERS = ("selected_pairs", "causal_pairs", "fwd_tiles_run",
                 "fwd_tiles_causal", "bwd_tiles_run", "bwd_tiles_causal",
                 "kl_millionths")
 
+#: the columns of the ``counters`` an attention layer under a window sows
+#: for each row of the batch, all static (``WindowMask``'s sums): the pairs
+#: a head's band needs, the pairs of the tiles the forward kernel and the
+#: backward kernel run, and each kernel's tiles run beside the tiles
+#: ``causal`` alone would run with the same tiles
+WIN_COUNTERS = ("needed_pairs", "fwd_pairs_run", "bwd_pairs_run",
+                "fwd_tiles_run", "fwd_tiles_causal", "bwd_tiles_run",
+                "bwd_tiles_causal")
+
 
 class RotaryAttention(linen.Module):
     """Grouped-query attention with a learned RMSNorm on each head's query
@@ -145,7 +238,21 @@ class RotaryAttention(linen.Module):
     ``positions`` (S,) or (3, S) (``mrope_section`` cuts the pairs), default
     ``0 .. S-1``; with ``indexer`` (``heads``, ``head_dim``, ``top_k``,
     ``q_chunk``, ``kv_chunk``, ``kl_weight``) each query reads only the keys
-    its learned index picks (the module's docstring has the equations)."""
+    its learned index picks (the module's docstring has the equations).
+
+    What a layer of a decoder with two kinds of attention sets (all off by
+    default): ``window`` (each query reads the ``window`` keys up to its
+    own: ``WindowMask`` in both flash kernels, and the layer's static counts
+    under ``("counters", "win")``, ``WIN_COUNTERS``); ``rotary_dim`` (only
+    the head's first ``rotary_dim`` dims turn) and ``yarn``
+    (``yarn_frequencies``' arguments beside ``rope_theta``, and
+    ``attention_factor`` on the cosine and sine); ``gate`` (``g =
+    sigmoid(x Wg)``, one a head, from the layer's normed input ``x``,
+    multiplied into the attention's output before ``o_proj``; module
+    ``gate_proj`` under the scope ``gate``); ``qk_norm`` False (no norm on
+    the heads' queries and keys); ``kind``, a ``jax.named_scope`` around
+    the whole layer (``attn/window/...``, ``attn/full/...``), so that an
+    operation's path tells the kinds apart."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -156,42 +263,93 @@ class RotaryAttention(linen.Module):
     dtype: Any = F32
     indexer: Any = None                  # a dict, or its items
     mrope_section: Optional[tuple] = None
+    window: Optional[int] = None
+    rotary_dim: Optional[int] = None     # None: the whole head
+    yarn: Any = None                     # a dict, or its items
+    gate: bool = False
+    qk_norm: bool = True
+    kind: Optional[str] = None
 
     @linen.compact
     def __call__(self, x, positions=None):
-        b, s, d = x.shape
-        h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dense = lambda n, name: linen.Dense(  # noqa: E731
-            n, use_bias=False, dtype=self.dtype, name=name)
-        q, k, v = checkpoint_name(
-            (dense(h * hd, "q_proj")(x), dense(kv * hd, "k_proj")(x),
-             dense(kv * hd, "v_proj")(x)), "attn_qkv")
-        q = q.reshape(b, s, h, hd)
-        k, v = k.reshape(b, s, kv, hd), v.reshape(b, s, kv, hd)
-        q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-        k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
-        mask = self.mask
-        if positions is None:
-            positions = jnp.arange(s) % (s if mask is None else mask.half)
-        with jax.named_scope("rope"):
-            if positions.ndim == 2:
-                q, k = (mrope(t, positions, self.rope_theta,
-                              self.mrope_section) for t in (q, k))
+        with jax.named_scope(self.kind) if self.kind \
+                else contextlib.nullcontext():
+            b, s, d = x.shape
+            h, kv, hd = self.num_heads, self.num_kv_heads, self.head_dim
+            dense = lambda n, name: linen.Dense(  # noqa: E731
+                n, use_bias=False, dtype=self.dtype, name=name)
+            q, k, v = checkpoint_name(
+                (dense(h * hd, "q_proj")(x), dense(kv * hd, "k_proj")(x),
+                 dense(kv * hd, "v_proj")(x)), "attn_qkv")
+            q = q.reshape(b, s, h, hd)
+            k, v = k.reshape(b, s, kv, hd), v.reshape(b, s, kv, hd)
+            if self.qk_norm:
+                q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
+                k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
+            mask = self.mask
+            if positions is None:
+                positions = jnp.arange(s) % (s if mask is None else mask.half)
+            with jax.named_scope("rope"):
+                if positions.ndim == 2:
+                    q, k = (mrope(t, positions, self.rope_theta,
+                                  self.mrope_section) for t in (q, k))
+                elif self.yarn is None and self.rotary_dim is None:
+                    q, k = (rope(t, positions, self.rope_theta)
+                            for t in (q, k))
+                else:
+                    freq, scale = self._frequencies()
+                    q, k = (rope_part(t, positions, freq, scale)
+                            for t in (q, k))
+            # the kernel takes one head count: each key-value head is repeated
+            # for the query heads it serves (its gradient sums over them)
+            k_all, v_all = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+            if self.indexer is not None:
+                out = self._sparse(x, q, k, k_all, v_all)
+            elif self.window is not None:
+                out = self._window(q, k_all, v_all)
+            elif mask is None:
+                out = self._causal(q, k_all, v_all)
+            elif self.attention == "flash":
+                out = self._flash(q, k_all, v_all)
             else:
-                q, k = (rope(t, positions, self.rope_theta) for t in (q, k))
-        # the kernel takes one head count: each key-value head is repeated
-        # for the query heads it serves (its gradient sums over them)
-        k_all, v_all = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
-        if self.indexer is not None:
-            out = self._sparse(x, q, k, k_all, v_all)
-        elif mask is None:
-            out = self._causal(q, k_all, v_all)
-        elif self.attention == "flash":
-            out = self._flash(q, k_all, v_all)
-        else:
-            out = self._plain(q, k_all, v_all)
-        return checkpoint_name(
-            dense(d, "o_proj")(out.reshape(b, s, h * hd)), "attn_out")
+                out = self._plain(q, k_all, v_all)
+            if self.gate:
+                with jax.named_scope("gate"):
+                    g = jax.nn.sigmoid(dense(h, "gate_proj")(x).astype(F32))
+                    out = (out.astype(F32) * g[..., None]).astype(out.dtype)
+            return checkpoint_name(
+                dense(d, "o_proj")(out.reshape(b, s, h * hd)), "attn_out")
+
+    def _frequencies(self):
+        """(the frequency of each turned pair, the scale on cosine and
+        sine) of a layer with ``rotary_dim`` or ``yarn``."""
+        part = self.rotary_dim or self.head_dim
+        if self.yarn is None:
+            return self.rope_theta ** (
+                -np.arange(part // 2, dtype=np.float32) / (part // 2)), 1.0
+        yarn = dict(self.yarn)
+        scale = yarn.pop("attention_factor", 1.0)
+        return yarn_frequencies(part, self.rope_theta, **yarn), scale
+
+    def _window(self, q, k, v):
+        """Causal attention over the ``window`` keys up to the query's own,
+        and the rule's static counts."""
+        rule, s = WindowMask(self.window), q.shape[1]
+        out = self._causal(q, k, v, rule)
+        if self.attention != "flash":       # no tiles to count
+            return out
+        padded = s + (-s) % DEFAULT_BLOCK
+        args = (padded, padded, self.head_dim,
+                jnp.dtype(self.dtype).itemsize, rule)
+        counts, tiles = [rule.pairs(s)], []
+        for bq, bk in (forward_tiles(*args), backward_tiles(*args)):
+            run = rule.tiles_run(padded, bq, bk)
+            counts.append(run * bq * bk)
+            tiles += [run, rule.causal_tiles(padded, bq, bk)]
+        self.sow("counters", "win", jnp.broadcast_to(
+            jnp.asarray(counts + tiles, jnp.int32),
+            (q.shape[0], len(WIN_COUNTERS))))
+        return out
 
     def _sparse(self, x, q, k, k_all, v_all):
         """Attention over the keys the index picks, and the index's own
@@ -242,15 +400,19 @@ class RotaryAttention(linen.Module):
                      jnp.int32)], axis=1))
         return out
 
-    def _causal(self, q, k, v):
+    def _causal(self, q, k, v, rule=None):
+        """Causal attention, under ``rule`` (a ``WindowMask``) where one is
+        given."""
         if self.attention != "flash":
-            s = q.shape[1]
-            return self._plain(q, k, v, jnp.tril(jnp.ones((s, s), bool)))
+            s, pos = q.shape[1], jnp.arange(q.shape[1])
+            return self._plain(
+                q, k, v, jnp.tril(jnp.ones((s, s), bool)) if rule is None
+                else rule.allowed(pos[:, None], pos[None, :]))
         # padded to the tile: a padded key lies after every real query
         s, pad = q.shape[1], (-q.shape[1]) % DEFAULT_BLOCK
         q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for t in (q, k, v))
-        return flash_attention(q, k, v, causal=True)[:, :s]
+        return flash_attention(q, k, v, causal=True, mask=rule)[:, :s]
 
     def _flash(self, q, k, v):
         s, mask = q.shape[1], self.mask
@@ -309,22 +471,34 @@ class RotaryAttention(linen.Module):
 #:   indexer_kl_grads  T x (H_I x D_I + D_I + H_I) x 4   what the KL term's
 #:               forward pass computed for its backward (72 MB): with it
 #:               held, the probabilities are made once a step
-#: Named and not kept: moe_gate (as moe_up: the pair fits the chip with
+#: and under a band (``RotaryAttention(window=...)``: the kernels' results
+#: carry names of their own there):
+#:   flash_win_out     T x H x D x c   the windowed kernel's output (268 MB a
+#:               layer at 16,384 positions of 64 heads; 8 ms a layer spared)
+#:   flash_win_lse     T x H x 4    its log-sum-exp
+#: Named and not kept: shared_gate and shared_up (T x I x c each: the
+#: shared expert's two products), mlp_gate and mlp_up (``GatedMLP``'s, in a
+#: dense layer: 268 MB each at 16,384 positions of width 8,192 for 3.5 ms),
+#: moe_gate (as moe_up: the pair fits the chip with
 #: under half a gigabyte to spare) and attn_qkv (T x (H + 2 KV) x D x c,
 #: the three projections' outputs: fewest milliseconds a gigabyte).
 #: PERF.md section 6, PR 35, has each name's measured milliseconds and
 #: bytes, and what the chip has room for.
 SAVED = ("flash_out", "flash_lse", "attn_out", "moe_route", "moe_up",
-         "dsa_selection", "indexer_kl_grads")
+         "dsa_selection", "indexer_kl_grads", "flash_win_out",
+         "flash_win_lse")
 
 
 class RoutedBlock(linen.Module):
-    """One layer: attention, then the routed experts, each on the RMSNorm
-    of the stream and added back."""
+    """One layer: attention, then the routed experts (module ``moe``) or,
+    where ``mlp`` is given, a dense gated feed-forward in their place
+    (``hybrid_lm.GatedMLP``, module ``mlp``), each on the RMSNorm of the
+    stream and added back."""
     attn: Any                 # kwargs of RotaryAttention
     moe: Any                  # kwargs of RoutedExperts
     eps: float = 1e-6
     dtype: Any = F32
+    mlp: Any = None           # kwargs of GatedMLP: a dense layer
 
     @linen.compact
     def __call__(self, x, positions=None):
@@ -333,8 +507,20 @@ class RoutedBlock(linen.Module):
                             **dict(self.attn))(h, positions)
         x = x + h.astype(x.dtype)
         h = RMSNorm(self.eps, self.dtype, name="post_norm")(x)
-        h = RoutedExperts(dtype=self.dtype, name="moe", **dict(self.moe))(h)
+        if self.mlp is not None:
+            h = GatedMLP(dtype=self.dtype, name="mlp", **dict(self.mlp))(h)
+        else:
+            h = RoutedExperts(dtype=self.dtype, name="moe",
+                              **dict(self.moe))(h)
         return x + h.astype(x.dtype)
+
+
+def _items(value):
+    """A dict (of dicts and lists) as sorted items, hashable: what a
+    rematerialised block's attributes have to be."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _items(v)) for k, v in value.items()))
+    return tuple(value) if isinstance(value, list) else value
 
 
 class RoutedLM(linen.Module):
@@ -348,7 +534,21 @@ class RoutedLM(linen.Module):
     are a small model; a published one passes its own ``config.json``'s
     numbers (``benchmark/sdar_drivers.py``, ``benchmark/keye_drivers.py``).
     ``held_experts`` and ``buffer_rows`` are ``RoutedExperts``' ``held`` and
-    ``buffer_rows``."""
+    ``buffer_rows``; ``scoring``, ``routed_scale`` and
+    ``shared_intermediate`` its switches of those names.
+
+    **The per-layer record.**  ``layers`` None builds ``num_layers`` layers
+    alike from the fields above, as ever.  Else it holds one dict a layer,
+    and a layer takes from its own what the dict names and the rest from
+    the fields: ``attention`` (``"full"``: causal; ``"window"``: the
+    ``window`` keys up to the query's own; the kind is also the layer's
+    ``RotaryAttention.kind`` scope), ``num_heads``, ``rope`` (a dict:
+    ``rope_theta``, and optionally ``rotary_dim`` and ``yarn``, see
+    ``RotaryAttention``), ``mlp`` (``"routed"``, or ``"dense"``: a
+    ``GatedMLP`` of ``dense_intermediate`` where the experts stand, which
+    sows no counters).  ``attn_gate`` and ``qk_norm`` are every layer's
+    (``RotaryAttention``'s ``gate`` and ``qk_norm``).  Causal objective
+    only."""
     vocab_size: int = 32000
     embed_dim: int = 256
     num_layers: int = 2
@@ -372,6 +572,14 @@ class RoutedLM(linen.Module):
     objective: str = "block_diffusion"      # or 'causal'
     indexer: Any = None                     # RotaryAttention's, a dict
     mrope_section: Optional[tuple] = None
+    layers: Optional[tuple] = None          # one dict a layer, see above
+    window: Optional[int] = None
+    dense_intermediate: Optional[int] = None
+    attn_gate: bool = False
+    qk_norm: bool = True
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    shared_intermediate: Optional[int] = None
     saved_names = SAVED     # no field: the policy's list, and the gauge's
 
     @linen.compact
@@ -380,9 +588,13 @@ class RoutedLM(linen.Module):
         causal = self.objective == "causal"
         if not causal and self.objective != "block_diffusion":
             raise ValueError(f"no objective {self.objective!r}")
-        if not causal and (s % 2 or self.indexer is not None):
+        if not causal and (s % 2 or self.indexer is not None
+                           or self.layers is not None):
             raise ValueError(f"[xt ; x0] has an even length, not {s}, and "
-                             f"no index")
+                             f"no index and no layers that differ")
+        if self.layers is not None and len(self.layers) != self.num_layers:
+            raise ValueError(f"{len(self.layers)} records for "
+                             f"{self.num_layers} layers")
         mask = None if causal else BlockDiffusionMask(s // 2,
                                                       self.block_length)
         if causal and positions is None and self.mrope_section is not None:
@@ -395,11 +607,23 @@ class RoutedLM(linen.Module):
                 mrope_section=self.mrope_section and tuple(self.mrope_section),
                 indexer=self.indexer and tuple(sorted(
                     dict(self.indexer).items())))
+        # the switches enter a layer's arguments only where they are set:
+        # a decoder without them builds the blocks it always built
+        if self.attn_gate:
+            attn["gate"] = True
+        if not self.qk_norm:
+            attn["qk_norm"] = False
         moe = dict(num_experts=self.num_experts,
                    top_k=self.num_experts_per_tok,
                    intermediate=self.moe_intermediate,
                    held=self.held_experts, buffer_rows=self.buffer_rows,
                    aux_weight=self.aux_loss_coef)
+        if self.scoring != "softmax":
+            moe["scoring"] = self.scoring
+        if self.routed_scale != 1.0:
+            moe["routed_scale"] = self.routed_scale
+        if self.shared_intermediate:
+            moe["shared_intermediate"] = self.shared_intermediate
         init = linen.initializers.normal(0.02)
         table = self.param("embedding", init,
                            (self.vocab_size, self.embed_dim), F32)
@@ -409,9 +633,11 @@ class RoutedLM(linen.Module):
             RoutedBlock, policy=jax.checkpoint_policies.save_only_these_names(
                 *self.saved_names)) if self.remat else RoutedBlock
         for i in range(self.num_layers):
-            x = block_cls(tuple(sorted(attn.items())),
-                          tuple(sorted(moe.items())), self.rms_norm_eps,
-                          self.dtype, name=f"block{i}")(x, positions)
+            mine, mlp = attn, None
+            if self.layers is not None:
+                mine, mlp = self._layer(dict(self.layers[i]), attn)
+            x = block_cls(_items(mine), _items(moe), self.rms_norm_eps,
+                          self.dtype, mlp, name=f"block{i}")(x, positions)
         if not causal:
             x = x[:, :mask.half]        # the head over the noisy half only
         x = RMSNorm(self.rms_norm_eps, self.dtype, name="final_norm")(x)
@@ -420,3 +646,24 @@ class RoutedLM(linen.Module):
         with jax.named_scope("lm_head"):
             return jnp.einsum("bsd,vd->bsv", x, head.astype(self.dtype),
                               preferred_element_type=F32)
+
+    def _layer(self, record, attn):
+        """One layer's record over the decoder's own attention arguments ->
+        (that layer's, its dense feed-forward's or None)."""
+        kind = record.pop("attention", None)
+        if kind not in (None, "full", "window"):
+            raise ValueError(f"no attention {kind!r}")
+        mlp = record.pop("mlp", "routed")
+        if mlp not in ("routed", "dense"):
+            raise ValueError(f"no feed-forward {mlp!r}")
+        mine = {**attn, **record.pop("rope", {})}
+        if "num_heads" in record:
+            mine["num_heads"] = record.pop("num_heads")
+        if record:
+            raise ValueError(f"a layer's record has no {sorted(record)}")
+        if kind is not None:
+            mine["kind"] = kind
+        if kind == "window":
+            mine["window"] = self.window
+        return mine, (_items({"intermediate": self.dense_intermediate})
+                      if mlp == "dense" else None)

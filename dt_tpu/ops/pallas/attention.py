@@ -49,6 +49,30 @@ skipped step fetches follow from its offsets, as under ``causal``; the
 kernels are then named ``flash_fwd_bd`` and ``flash_bwd_bd``.  The rule is
 static: without it the programs are what they were.
 
+A third static rule is a band: ``WindowMask(window)`` (sliding-window
+attention: query ``t`` sees the keys ``t - window < s <= t``; it implies
+``causal``).  A tile's fate and what a skipped step fetches follow from
+offsets here too, but nearly all of the other axis would be skipped (at
+8,192 positions, a window of 512 and tiles of 512 a query tile touches two
+key tiles of sixteen), and a grid step costs its 0.4 us whether it computes
+or not.  So **the forward's key axis and the backward's query axis span
+only the tiles a band can touch** (``WindowMask.key_steps``,
+``query_steps``: with square tiles of ``b``, ``ceil((window - 1) / b) + 1``)
+and the index maps add the tile's own offset: the forward's step ``j`` of
+query tile ``qi`` is key tile ``hi(qi) - (steps - 1) + j``, ``hi`` the
+diagonal tile's; the backward's step ``j`` of key tile ``ki`` is query tile
+``lo(ki) + j``.  The steps that fall outside the sequence (the forward's
+first at its start, the backward's last at its end) compute nothing and
+name the band's first (last) tile, which the neighbouring step fetches
+anyway.  dq still accumulates in a scratch for the whole sequence.  The
+tiles are square and no wider than the window (``WindowMask.tiles``:
+512 x 512 at a window of 512, which runs twice the band's pairs in the
+fewest steps; PERF.md section 6, PR 41, has the sweep); the kernels are
+named ``flash_win_fwd`` and ``flash_win_bwd`` (no ``flash_fwd`` or
+``flash_bwd`` in them: a decoder that alternates windowed and full layers
+counts each kind's events alone), and their results carry the names
+``flash_win_out`` and ``flash_win_lse`` for a remat policy.
+
 A second rule is *data*: ``SelectedKeysMask`` (attention over the keys a
 learned indexer picked for each query, ``ops/sparse_index.py``).  Which keys
 a query may see comes from an array, the ``Selection``, made once a layer
@@ -74,7 +98,7 @@ Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
 kernel is the single-device fusion.  ``TransformerLM(seq_parallel="flash")``,
 ``GroupedQueryAttention(attention="flash")`` and ``RotaryAttention(attention=
-"flash")`` (``models/routed_lm.py``, under either mask rule or plain
+"flash")`` (``models/routed_lm.py``, under any of the mask rules or plain
 ``causal``) select it.
 
 Parity: ``dt_tpu.parallel.ring_attention.full_attention`` is the oracle;
@@ -158,6 +182,8 @@ def forward_tiles(s: int, sk: int, d: int, itemsize: int, mask=None):
     times the rule's area where 512 x 512 runs 1.25 times and takes 1.6
     times as long (9.36 against 14.95 ms a call, PERF.md section 6, PR
     34)."""
+    if isinstance(mask, WindowMask):
+        return mask.tiles(s)
     if mask is not None:
         s = sk = mask.half
     return _largest_tiles(
@@ -305,6 +331,149 @@ def _band_mask(rule: BlockDiffusionMask, band, q_axis: int, shape):
         return pos >> shift if rule.block == 1 << shift else pos // rule.block
     gap = blocks(k0, 1 - q_axis) - blocks(q0, q_axis)       # kb - qb
     return (gap <= -c1) & (gap >= -c2)
+
+
+# ---------------------------------------------------------------------------
+# the third static rule: a band below the diagonal
+# ---------------------------------------------------------------------------
+
+#: under a ``WindowMask``: the candidate (square) tiles of either pass,
+#: largest first; ``WindowMask.tiles`` chooses among them (PERF.md section
+#: 6, PR 41: the sweep at 8,192 positions and a window of 512, where 512 x
+#: 512 won both passes)
+WINDOW_TILES = (512, 256, 128)
+
+
+def _floor0(x):
+    """``max(x, 0)`` of a Python or a traced integer."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMask:
+    """Sliding-window attention, the third static rule beside ``causal``
+    (which it implies) and ``BlockDiffusionMask``: query ``t`` sees the
+    ``window`` keys ``t - window < s <= t``, its own among them.  Of the
+    causal triangle only a band along the diagonal is needed (at 8,192
+    positions and a window of 512 an eighth), so the kernels do not step
+    over the whole other axis and skip: **the forward's key axis and the
+    backward's query axis span only the tiles a band can touch**
+    (``key_steps``, ``query_steps``: with square tiles of ``b``, ``ceil((W -
+    1) / b) + 1`` of them), and the index maps add the tile's own offset
+    (``key_tile``, ``query_tile``).  Query tile ``qi`` touches the key tiles
+    ``key_span(qi)``; its steps are the last ``key_steps`` tiles up to its
+    diagonal tile, so near the sequence's start the first steps fall before
+    the span: they compute nothing and name the span's first tile, which the
+    next step that runs fetches anyway.  The backward's key tile ``ki``
+    steps from its diagonal tile on; steps past ``query_span(ki)`` (at the
+    sequence's end) compute nothing and name the span's last tile again.  A
+    tile runs unmasked where every pair of it is in the band, else under
+    both edges (``_in_band``).  Static: fates and fetches follow from
+    offsets, as for the other rules.  The methods take Python or traced
+    integers; ``key_steps``, ``query_steps``, ``tiles_run``,
+    ``causal_tiles`` and ``pairs`` are sums of Python integers over one
+    axis' tiles (no array is made at trace time)."""
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a window of {self.window} keys")
+
+    def allowed(self, q_pos, k_pos):
+        """The rule, element by element: what a dense masked softmax
+        applies (the kernels' oracle)."""
+        return (k_pos <= q_pos) & (k_pos > q_pos - self.window)
+
+    def tiles(self, s: int):
+        """The (square) tile of either pass over ``s`` positions: the
+        largest of ``WINDOW_TILES`` that divides ``s`` and is no wider than
+        the window rounded up to a lane tile (a wider one computes more
+        outside the band than inside it)."""
+        cap = -(-self.window // _LANES) * _LANES
+        got = [t for t in WINDOW_TILES if s % t == 0 and t <= cap]
+        if not got:
+            raise ValueError(f"seq length {s} must be a multiple of "
+                             f"{WINDOW_TILES[-1]}")
+        return got[0], got[0]
+
+    def key_span(self, qi, block_q: int, block_k: int):
+        """First and last key tile that query tile ``qi`` needs."""
+        return _floor0(qi * block_q - self.window + 1) // block_k, \
+            (qi * block_q + block_q - 1) // block_k
+
+    def query_span(self, ki, block_q: int, block_k: int, n_q: int):
+        """First and last query tile that key tile ``ki`` is needed by."""
+        last = (ki * block_k + block_k + self.window - 2) // block_q
+        return ki * block_k // block_q, (
+            min(last, n_q - 1) if isinstance(last, int)
+            else jnp.minimum(last, n_q - 1))
+
+    def key_steps(self, s: int, block_q: int, block_k: int) -> int:
+        """The forward's grid along the keys: the most key tiles a query
+        tile needs."""
+        return max(hi - lo + 1 for lo, hi in (
+            self.key_span(qi, block_q, block_k)
+            for qi in range(s // block_q)))
+
+    def query_steps(self, s: int, block_q: int, block_k: int) -> int:
+        """The backward's grid along the queries, as ``key_steps``."""
+        n_q = s // block_q
+        return max(hi - lo + 1 for lo, hi in (
+            self.query_span(ki, block_q, block_k, n_q)
+            for ki in range(s // block_k)))
+
+    def key_tile(self, qi, step, steps: int, block_q: int, block_k: int):
+        """The key tile of the forward's step ``step`` of ``steps`` for
+        query tile ``qi``: the last ``steps`` tiles up to the diagonal
+        one's; below ``key_span``'s first (or 0) the step does not run."""
+        return self.key_span(qi, block_q, block_k)[1] - (steps - 1) + step
+
+    def query_tile(self, ki, step, block_q: int, block_k: int):
+        """The query tile of the backward's step ``step`` for key tile
+        ``ki``: from the diagonal one's on; past ``query_span``'s last the
+        step does not run."""
+        return ki * block_k // block_q + step
+
+    def tiles_run(self, s: int, block_q: int, block_k: int) -> int:
+        """How many tiles run in a pass over ``s`` positions (either pass:
+        the same tiles, walked the other way)."""
+        return sum(hi - lo + 1 for lo, hi in (
+            self.key_span(qi, block_q, block_k)
+            for qi in range(s // block_q)))
+
+    @staticmethod
+    def causal_tiles(s: int, block_q: int, block_k: int) -> int:
+        """The tiles ``causal`` alone would run with the same tiles."""
+        return sum((qi * block_q + block_q - 1) // block_k + 1
+                   for qi in range(s // block_q))
+
+    def pairs(self, s: int) -> int:
+        """The pairs one sequence of ``s`` positions needs: ``sum_t min(t +
+        1, window)``."""
+        w = min(s, self.window)
+        return w * (w + 1) // 2 + (s - w) * w
+
+
+def _in_band(shape, q_axis: int, gap, window: int):
+    """Of a tile of ``shape`` whose queries lie along ``q_axis``: the pairs
+    whose key is at or before the query and less than ``window`` before it,
+    ``gap`` the tile's first key position less its first query position."""
+    ahead = lax.broadcasted_iota(jnp.int32, shape, q_axis) \
+        - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return (ahead >= gap) & (ahead < gap + window)
+
+
+def _when_window(tile, rule: WindowMask, valid, qi, ki, block_q: int,
+                 block_k: int):
+    """Run ``tile(masked)`` for the (qi, ki) tile under ``rule``: not at
+    all where ``valid`` is false (a step outside the grid's span) or no pair
+    of it lies in the band, unmasked where all do."""
+    first_k, last_k = ki * block_k, ki * block_k + block_k - 1
+    first_q, last_q = qi * block_q, qi * block_q + block_q - 1
+    runs = valid & (first_k <= last_q) & (last_k > first_q - rule.window)
+    unmasked = (last_k <= first_q) & (first_k > last_q - rule.window)
+    pl.when(runs & jnp.logical_not(unmasked))(functools.partial(tile, True))
+    pl.when(runs & unmasked)(functools.partial(tile, False))
 
 
 def _when_causal(tile, qi, ki, block_q: int, block_k: int):
@@ -477,24 +646,38 @@ def _when_selected(tile, fate, causal: bool, qi, ki, block_q: int,
     pl.when(runs & jnp.logical_not(crossed))(functools.partial(tile, False))
 
 
+def _kernel_name(mask, which: str) -> str:
+    """The name a kernel's events carry in a trace under a mask rule.  The
+    band's hold neither ``flash_fwd`` nor ``flash_bwd``, so that a metric
+    that reads the plain causal kernels beside them (a decoder that
+    alternates the two kinds of layer) counts those alone."""
+    if isinstance(mask, WindowMask):
+        return f"flash_win_{which}"
+    return f"flash_{which}_" + (
+        "sel" if isinstance(mask, SelectedKeysMask) else "bd")
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                  acc_ref, m_ref, l_ref, *,
                  scale: float, causal: bool, block_q: int, block_k: int,
-                 n_k: int, mask: Optional[BlockDiffusionMask] = None,
-                 sel=None):
+                 n_k: int, mask=None, sel=None):
     """One (bh, q_block, k_block) grid step; kv axis is sequential, so the
-    VMEM scratch (acc, m, l) carries the online softmax across it.  ``sel``
-    (under a ``SelectedKeysMask``) is ``(fate, words)``: the tile's entry
-    of the fate table and the ref of its queries' bitmap block."""
-    ki = pl.program_id(2)
+    VMEM scratch (acc, m, l) carries the online softmax across it.  ``n_k``
+    is that axis' steps: the key tiles, or under a ``WindowMask`` the tiles
+    a band can touch, the step's key tile then being ``mask.key_tile``'s.
+    ``sel`` (under a ``SelectedKeysMask``) is ``(fate, words)``: the tile's
+    entry of the fate table and the ref of its queries' bitmap block."""
+    step = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     qi = pl.program_id(1)
+    windowed = isinstance(mask, WindowMask)
+    ki = mask.key_tile(qi, step, n_k, block_q, block_k) if windowed else step
 
     def _attend(masked):
         # ``masked``: False, True (the causal diagonal) or a band of
@@ -509,6 +692,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 seen = seen & _at_or_before(s.shape, 0,
                                             ki * block_k - qi * block_q)
             s = jnp.where(seen, s, NEG_INF)
+        elif windowed:
+            if masked:
+                s = jnp.where(_in_band(s.shape, 0, ki * block_k
+                                       - qi * block_q, mask.window), s,
+                              NEG_INF)
         elif mask is not None:
             if masked is not None:
                 s = jnp.where(_band_mask(mask, masked, 0, s.shape), s,
@@ -534,6 +722,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     if sel is not None:
         _when_selected(_attend, sel[0], causal, qi, ki, block_q, block_k)
+    elif windowed:
+        _when_window(_attend, mask, ki >= 0, qi, ki, block_q, block_k)
     elif mask is not None:
         _when_block_diffusion(_attend, mask, qi, ki, block_q, block_k)
     elif causal:
@@ -541,7 +731,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         _attend(False)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -581,19 +771,27 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     bh, s, d = q3.shape
     sk = k3.shape[1]
     selected = isinstance(mask, SelectedKeysMask)
+    windowed = isinstance(mask, WindowMask)
     if block_q is None or block_k is None:
         dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize,
                                None if selected else mask)
         block_q, block_k = block_q or dq, block_k or dk
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask)
     n_q = s // block_q
-    n_k = sk // block_k
+    # the key axis' steps: under a band, the tiles it can touch
+    n_k = mask.key_steps(s, block_q, block_k) if windowed else sk // block_k
     kern = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_k=n_k, mask=mask)
     # the index maps take the grid's indices and, under a selection, the
     # scalar-prefetch table after them
-    if mask is not None and not selected:
+    if windowed:
+        # a step before the band names the band's first tile, which the
+        # first step that runs fetches anyway
+        key_tile = lambda qi, step: jnp.maximum(
+            mask.key_tile(qi, step, n_k, block_q, block_k),
+            mask.key_span(qi, block_q, block_k)[0])
+    elif mask is not None and not selected:
         key_tile = lambda qi, ki: mask.key_tile(qi, ki, block_q, block_k)
     elif causal:
         # a skipped step names the last block its query tile needs: the
@@ -642,8 +840,7 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
         kern,
         # under a mask rule the forward has a name of its own in a trace;
         # otherwise its events carry its caller's, as they always have
-        **({} if mask is None else
-           {"name": "flash_fwd_sel" if selected else "flash_fwd_bd"}),
+        **({} if mask is None else {"name": _kernel_name(mask, "fwd")}),
         **grid,
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
@@ -670,6 +867,10 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
     if isinstance(mask, BlockDiffusionMask):
         rule = (f"block_diffusion.half{mask.half}.block{mask.block}.run"
                 f"{mask.tiles_run(block_q, block_k)}of"
+                f"{(s // block_q) * (sk // block_k)}")
+    elif isinstance(mask, WindowMask):
+        rule = (f"window{mask.window}.run"
+                f"{mask.tiles_run(s, block_q, block_k)}of"
                 f"{(s // block_q) * (sk // block_k)}")
     else:   # a selection's tiles are data: the model's counters have them
         rule = "" if mask is None else "selected_keys"
@@ -712,6 +913,8 @@ def backward_tiles(s: int, sk: int, d: int, itemsize: int, mask=None):
     sequence ``s``), within ``VMEM_BUDGET``; under a ``BlockDiffusionMask``
     a tile divides its half."""
     whole = s
+    if isinstance(mask, WindowMask):
+        return mask.tiles(s)
     if mask is not None:
         s = sk = mask.half
     return _largest_tiles(
@@ -722,20 +925,25 @@ def backward_tiles(s: int, sk: int, d: int, itemsize: int, mask=None):
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       scale: float, causal: bool, block_q: int, block_k: int,
-                      n_q: int, n_k: int,
-                      mask: Optional[BlockDiffusionMask] = None, sel=None):
+                      n_q: int, n_k: int, mask=None, sel=None,
+                      q_tiles: Optional[int] = None):
     """One (bh, k_block, q_block) grid step of the backward.  The tile is
     held keys by queries (``s^T = k q^T``): the log-sum-exp and delta of the
     query rows are then lane rows that broadcast down the sublanes, and
     dv, dk are plain products.  dk, dv accumulate over the query axis (the
-    innermost), dq over both in a scratch for the whole sequence."""
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    innermost), dq over both in a scratch for the whole sequence.  ``n_q``
+    is the query axis' steps: the query tiles, or under a ``WindowMask``
+    the tiles a band can touch (of ``q_tiles`` in all), the step's query
+    tile then being ``mask.query_tile``'s."""
+    ki, step = pl.program_id(1), pl.program_id(2)
+    windowed = isinstance(mask, WindowMask)
+    qi = mask.query_tile(ki, step, block_q, block_k) if windowed else step
 
-    @pl.when((ki == 0) & (qi == 0))
+    @pl.when((ki == 0) & (step == 0))
     def _init_dq():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_dkv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -754,6 +962,11 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 seen = seen & _at_or_before(st.shape, 1,
                                             ki * block_k - qi * block_q)
             st = jnp.where(seen, st, NEG_INF)
+        elif windowed:
+            if masked:
+                st = jnp.where(_in_band(st.shape, 1, ki * block_k
+                                        - qi * block_q, mask.window), st,
+                               NEG_INF)
         elif mask is not None:
             if masked is not None:
                 st = jnp.where(_band_mask(mask, masked, 1, st.shape), st,
@@ -783,6 +996,8 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
     if sel is not None:
         _when_selected(_tile, sel[0], causal, qi, ki, block_q, block_k)
+    elif windowed:
+        _when_window(_tile, mask, qi < q_tiles, qi, ki, block_q, block_k)
     elif mask is not None:
         _when_block_diffusion(_tile, mask, qi, ki, block_q, block_k)
     elif causal:
@@ -790,12 +1005,12 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     else:
         _tile(False)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(step == n_q - 1)
     def _finish_dkv():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-    @pl.when((ki == n_k - 1) & (qi == n_q - 1))
+    @pl.when((ki == n_k - 1) & (step == n_q - 1))
     def _finish_dq():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
@@ -816,7 +1031,8 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                       block_q=None, block_k=None, mask=None, selection=None):
     """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
     (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
-    ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel`` under a mask rule).
+    ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel``, ``flash_win_bwd``
+    under a mask rule).
     Its tiles come from the shapes (``backward_tiles``).
 
     Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
@@ -830,14 +1046,23 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
         block_q, block_k = block_q or tq, block_k or tk
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
                 mask=mask)
-    n_q, n_k = s // block_q, sk // block_k
+    windowed = isinstance(mask, WindowMask)
+    q_tiles, n_k = s // block_q, sk // block_k
+    # the query axis' steps: under a band, the tiles it can touch
+    n_q = mask.query_steps(s, block_q, block_k) if windowed else q_tiles
     # delta = rowsum(do * out), float32, in XLA: one pass over two arrays
     # the step already holds; a row vector per head, as the log-sum-exp
     delta = (do3.astype(jnp.float32) * o3.astype(jnp.float32)).sum(-1)
     kern = functools.partial(
         _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_q=n_q, n_k=n_k, mask=mask)
-    if mask is not None and not selected:
+        block_k=block_k, n_q=n_q, n_k=n_k, mask=mask,
+        **({"q_tiles": q_tiles} if windowed else {}))
+    if windowed:
+        # a step past the band names the band's last tile again
+        first = lambda ki, step: jnp.minimum(
+            mask.query_tile(ki, step, block_q, block_k),
+            mask.query_span(ki, block_q, block_k, q_tiles)[1])
+    elif mask is not None and not selected:
         first = lambda ki, qi: mask.query_tile(ki, qi, block_q, block_k)
     elif causal:
         # a skipped step names the first query block its key tile needs:
@@ -882,8 +1107,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                     out_specs=out_specs, scratch_shapes=scratch_shapes)
     dq, dk, dv = pl.pallas_call(
         kern,
-        name="flash_bwd" if mask is None else (
-            "flash_bwd_sel" if selected else "flash_bwd_bd"),
+        name="flash_bwd" if mask is None else _kernel_name(mask, "bwd"),
         **grid,
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
@@ -915,8 +1139,12 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     # on the values themselves, before they go into the result and the
     # residuals, so that a policy which saves them needs no second call.
     # q3, k3, v3 carry none: they are rebuilt from the projections
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    # Under a band the two have names of their own: a windowed forward
+    # costs a fraction of a causal one, so a policy may keep the one kind's
+    # output and compute the other's again
+    win = "win_" if isinstance(mask, WindowMask) else ""
+    out = checkpoint_name(out, f"flash_{win}out")
+    lse = checkpoint_name(lse, f"flash_{win}lse")
     return out, (q3, k3, v3, out, lse)
 
 
@@ -990,7 +1218,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``SelectedKeysMask()`` with its ``selection`` (a ``Selection`` over
     ``s`` queries and as many keys), beside ``causal`` or without it;
     ``return_lse`` then also returns the float32 log-sum-exp (B, H, S), a
-    constant to whatever reads it.
+    constant to whatever reads it.  Or a ``WindowMask(window)`` over ``s``
+    positions, queries and keys alike (it implies ``causal``, which may be
+    passed or not): both kernels' grids then span only the tiles the band
+    can touch.
     """
     if interpret is None:
         interpret = _default_interpret()
@@ -1009,11 +1240,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             raise ValueError(f"a selection over ({s}, {sk}) positions is two "
                              f"bitmaps of {want}, not "
                              f"{selection.by_query.shape}")
+    elif isinstance(mask, WindowMask):
+        if s != sk:
+            raise ValueError(f"{mask} is a rule over queries and keys "
+                             f"alike: got ({s}, {sk})")
     elif mask is not None and (causal or s != sk or s != 2 * mask.half):
         raise ValueError(f"{mask} is a rule over {2 * mask.half} positions, "
                          f"queries and keys alike, and not beside causal: "
                          f"got ({s}, {sk}), causal={causal}")
-    half = mask is not None and not selected
+    half = isinstance(mask, BlockDiffusionMask)
     for n, block in ((mask.half if half else s, block_q),
                      (mask.half if half else sk, block_k)):
         block = DEFAULT_BLOCK if block is None else block

@@ -68,7 +68,11 @@ _LANES = 128
 # of the matrix unit's pace whatever the tile, so the smaller tile's fewer
 # rows in shared tiles win (PERF.md section 6, PR 38: the sweep over 256,
 # 512 and 1,024 at the two routed cells' shapes: 256 ties 512 at 24,576
-# rows and beats it by 2 to 5% at 49,152; 1,024 loses 7 to 20%)
+# rows and beats it by 2 to 5% at 49,152; 1,024 loses 7 to 20%).  At 32
+# groups of 2,048 x 512 over 24,576 rows (PR 41: the sweep over 128, 256 and
+# 512; value / d_lhs / d_rhs, ms a call on the host clock): 0.534 / 0.546 /
+# 0.585 at 128, 0.500 / 0.524 / 0.584 at 256, 0.527 / 0.580 / 0.640 at 512:
+# 256 stays
 ROW_TILES = (256, 128)
 
 
@@ -103,7 +107,21 @@ def row_tile(m: int, k: int, n: int, lhs_itemsize: int, rhs_itemsize: int,
     then 0.81 GFLOP, 4.1 us at 197 TFLOP/s, and moves 1.75 MB, 2.1 us at 819
     GB/s: the matrix unit sets the pace.  The ``G - 1`` shared tiles cost
     ``m / tm + 15`` visits for ``m / tm``: +8% at 49,152 rows and +16% at
-    24,576 with 256 (+16% and +31% with 512)."""
+    24,576 with 256 (+16% and +31% with 512).
+
+    The same at the third routed cell's (``k x n`` = 2,048 x 512, 32 groups,
+    24,576 rows, ``tm`` 256): 2 x (1 + 2 + 0.5) + 0.5 = 7.5 MiB; turned 2 x
+    (0.5 + 2 + 1) + 2 = 9 MiB; transposed 2 x (1 + 0.5 + 2) + 4 = 11 MiB.  A
+    visit is 0.54 GFLOP, 2.7 us at the peak, and the ``G - 1`` = 31 shared
+    tiles make 127 visits of 96 tiles, +32% (223 of 192 with 128, +16% of
+    twice the visits; 79 of 48 with 512, +65%).  A group is 512 rows at even
+    load, two row tiles, so its 2 MiB matrix is fetched for two visits'
+    work: a call moves 101 MB of rows, 67 MB of matrices and 50 MB of
+    float32 result, 0.27 ms at 819 GB/s against 0.26 ms of operations at
+    the peak.  Bytes and operations balance, neither hides the other whole,
+    and the kernels read 45 to 52% of the peak alone and 61% in the cell's
+    step (0.44 ms a call; PERF.md section 6, PR 41) where the 768-wide
+    experts' 16 groups read 75 and 84."""
     if k % _LANES or n % _LANES:
         return None
     for tm in ROW_TILES:

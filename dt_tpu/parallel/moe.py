@@ -18,6 +18,14 @@ MXU-friendly.
 Usage: plain module on one device; for EP give ``mesh`` + ``axis`` and
 the expert dimension of the weights and the dispatched activations is
 sharding-constrained to that axis.
+
+Beside it, ``RoutedExperts``: ``k > 1`` routing over the share of the
+experts one chip holds, for the sparse-expert decoders of
+``models/routed_lm.py``.  Scores are the softmax over all the router's
+outputs or, with ``scoring="sigmoid"``, each output's own sigmoid; the
+top ``k`` are renormalised to sum to one and may carry a scale
+(``routed_scale``); a shared expert that every token visits
+(``shared_intermediate``) is added unweighted and is every chip's alike.
 """
 
 from __future__ import annotations
@@ -134,12 +142,24 @@ class MoEMLP(linen.Module):
 COUNTER_TAIL = ("held", "overflow", "assignments")
 
 
-def route_top_k(logits: Array, k: int):
+def route_top_k(logits: Array, k: int, scoring: str = "softmax"):
     """``logits`` (T, E) float32 -> (experts (T, k) int32, weights (T, k),
-    probs (T, E)): the softmax over all ``E``, its ``k`` largest, and their
-    weights renormalised to sum to one a token."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    probs (T, E)): the scores over all ``E``, their ``k`` largest, and
+    those weights renormalised to sum to one a token.  ``scoring``
+    ``"softmax"``: the scores are the softmax, and ``probs`` are they;
+    ``"sigmoid"``: each expert's score is the sigmoid of its own logit (they
+    do not compete before the top-k), and ``probs``, what the
+    load-balancing term reads, are the scores normalised to sum to one a
+    token."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        raise ValueError(f"no scoring {scoring!r}")
+    weights, experts = jax.lax.top_k(scores, k)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), weights, probs
 
@@ -181,6 +201,17 @@ class RoutedExperts(linen.Module):
         w_e = p_e / sum_S p
         y = sum_{e in S, e held} w_e * Wdown_e(silu(x Wgate_e) * (x Wup_e))
 
+    Three switches, whose defaults leave that as it is.  ``scoring``
+    ``"sigmoid"``: ``p = sigmoid_f32(x Wr)``, each expert scored alone
+    (``route_top_k``; the load-balancing term then reads ``p / sum_E p``).
+    ``routed_scale``: ``w_e = routed_scale * p_e / sum_S p``, a scale on the
+    routed sum.  ``shared_intermediate``: a shared expert of that width
+    that every token visits, ``y += Wdown_s(silu(x Wgate_s) * (x Wup_s))``,
+    a plain gated-SiLU feed-forward (modules ``shared_gate``, ``shared_up``,
+    ``shared_down`` under ``jax.named_scope("shared")``), unweighted and
+    computed by every chip alike: summing the shares of a layer over the
+    chips counts it once.
+
     What the experts that are not held would have added is left out: on one
     chip the layer runs without its exchange, and no code stands in for the
     other chips.  The router is float32 at its full width (its product at
@@ -212,6 +243,9 @@ class RoutedExperts(linen.Module):
     buffer_rows: Optional[int] = None     # None: T x top_k
     aux_weight: float = 0.0
     dtype: Any = jnp.float32
+    scoring: str = "softmax"              # or 'sigmoid'
+    routed_scale: float = 1.0
+    shared_intermediate: Optional[int] = None
 
     @linen.compact
     def __call__(self, x: Array) -> Array:
@@ -232,7 +266,9 @@ class RoutedExperts(linen.Module):
         with jax.named_scope("route"):
             logits = jnp.dot(tokens.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            experts, weights, probs = route_top_k(logits, k)
+            experts, weights, probs = route_top_k(logits, k, self.scoring)
+            if self.routed_scale != 1.0:
+                weights = weights * self.routed_scale
             order, sizes, _ = sort_held(experts, first, count, rows)
             # what route hands on, under one name a block's remat policy
             # can keep (models/routed_lm.py SAVED): with these held the
@@ -263,7 +299,18 @@ class RoutedExperts(linen.Module):
         with jax.named_scope("combine"):
             out = (out * row_weight[:, None]).astype(self.dtype)
             y = jnp.zeros((t, d), self.dtype).at[source].add(out)
-        return y.reshape(b, s, d)
+        y = y.reshape(b, s, d)
+        if self.shared_intermediate:
+            with jax.named_scope("shared"):
+                dense = lambda n, name: linen.Dense(  # noqa: E731
+                    n, use_bias=False, dtype=self.dtype, name=name)
+                width = self.shared_intermediate
+                y = y + dense(d, "shared_down")(jax.nn.silu(
+                    checkpoint_name(dense(width, "shared_gate")(x),
+                                    "shared_gate"))
+                    * checkpoint_name(dense(width, "shared_up")(x),
+                                      "shared_up"))
+        return y
 
     def _count(self, experts, first, count, order, placed, b, per_row):
         """Sow the layer's counters: per row of the batch, its assignments

@@ -93,6 +93,40 @@ def test_mosaic_takes_both_kernels_under_the_mask_rule(one_chip, bh, half,
         assert calls and all(name in c for c in calls), calls
 
 
+# under the band rule: the mixed-attention cell's sliding layers (64 heads of
+# 128 over 8,192 positions, a window of 512), and a window that is no whole
+# tile over a length that only 128 divides
+WINDOWED = [
+    (2 * 64, 8192, 512, 128, jnp.bfloat16),
+    (4, 640, 200, 64, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("bh,s,window,d,dtype", WINDOWED)
+def test_mosaic_takes_both_kernels_under_the_band_rule(one_chip, bh, s,
+                                                       window, d, dtype):
+    """Forward and backward over the shortened grid axis, under the names
+    the benchmark's ``kernel.flash_*.win`` find them by, which hold neither
+    ``flash_fwd`` nor ``flash_bwd``."""
+    rule = attn.WindowMask(window)
+    x = jax.ShapeDtypeStruct((bh, s, d), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((bh, s), jnp.float32, sharding=one_chip)
+    fwd = jax.jit(lambda q, k, v: attn._flash_fwd_pallas(
+        q, k, v, scale=d ** -0.5, causal=True, block_q=None, block_k=None,
+        interpret=False, mask=rule))
+    bwd = jax.jit(lambda q, k, v, o, lse, do: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, scale=d ** -0.5, causal=True, interpret=False,
+        mask=rule))
+    for text, name in ((fwd.lower(x, x, x).compile().as_text(),
+                        "flash_win_fwd"),
+                       (bwd.lower(x, x, x, x, lse, x).compile().as_text(),
+                        "flash_win_bwd")):
+        calls = [ln.split("=")[0] for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "custom-call(" in ln]
+        assert calls and all(name in c and "flash_bwd" not in c
+                             and "flash_fwd" not in c for c in calls), calls
+
+
 # under the selection rule beside causal: the sparse-attention cell's shape
 # (32 heads of 128 over 16,384 positions, the selection as two bitmaps of
 # four groups) and a length under one group that only 128 divides

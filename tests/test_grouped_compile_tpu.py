@@ -28,10 +28,13 @@ def one_chip():
 # the two routed cells' buffers against their experts' matrices (gate and
 # up, then down: the turned form of the other), 16 experts held
 SHAPES = [
-    (49152, 2048, 768),
-    (24576, 2048, 768),
-    (49152, 768, 2048),
-    (24576, 768, 2048),
+    (49152, 2048, 768, 16),
+    (24576, 2048, 768, 16),
+    (49152, 768, 2048, 16),
+    (24576, 768, 2048, 16),
+    # the mixed-attention cell's: 32 experts held, of width 512 (PR 41)
+    (24576, 2048, 512, 32),
+    (24576, 512, 2048, 32),
 ]
 
 
@@ -40,14 +43,16 @@ def _kernels(text):
             if "tpu_custom_call" in ln and "custom-call(" in ln]
 
 
-@pytest.mark.parametrize("m,k,n", SHAPES)
-def test_mosaic_takes_the_three_products(one_chip, monkeypatch, m, k, n):
+@pytest.mark.parametrize("m,k,n,groups", SHAPES)
+def test_mosaic_takes_the_three_products(one_chip, monkeypatch, m, k, n,
+                                         groups):
     """The value and both gradients at the derived tiles, under the names
     the benchmark's ``kernel.gmm_*.pl`` find them by, and no ``ragged_dot``
     left beside them."""
     shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)  # noqa: E731
-    lhs, rhs = shape((m, k), jnp.bfloat16), shape((16, k, n), jnp.bfloat16)
-    sizes, d_out = shape((16,), jnp.int32), shape((m, n), jnp.float32)
+    lhs, rhs = shape((m, k), jnp.bfloat16), shape((groups, k, n),
+                                                  jnp.bfloat16)
+    sizes, d_out = shape((groups,), jnp.int32), shape((m, n), jnp.float32)
 
     def value_and_gradients(lhs, rhs, sizes, d_out):
         out, pull = jax.vjp(
@@ -64,8 +69,8 @@ def test_mosaic_takes_the_three_products(one_chip, monkeypatch, m, k, n):
     assert "ragged-dot" not in text
 
 
-@pytest.mark.parametrize("m,k,n", SHAPES)
-def test_the_derived_tiles_are_within_the_budget(m, k, n):
+@pytest.mark.parametrize("m,k,n,groups", SHAPES)
+def test_the_derived_tiles_are_within_the_budget(m, k, n, groups):
     """bfloat16 operands, float32 out and cotangent: each product has a tile
     and ``vmem_bytes`` of it is within the budget ``row_tile`` states."""
     for args, transposed in (((m, k, n, 2, 2, 4), False),

@@ -214,13 +214,16 @@ def _layer_inputs(seed=0, cfg=LAYER):
     return x, blk
 
 
-def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0):
+def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0, **switches):
     layer = moe.RoutedExperts(num_experts=16, top_k=2,
                               intermediate=blk["gate"].shape[-1],
                               held=held, buffer_rows=buffer_rows,
-                              aux_weight=aux_weight)
+                              aux_weight=aux_weight, **switches)
     first, count = held
     params = {"router": blk["router"]}
+    if switches.get("shared_intermediate"):
+        params.update({n: {"kernel": blk[n]} for n in (
+            "shared_gate", "shared_up", "shared_down")})
     for name in ("gate", "up", "down"):     # a whole layer's: this share's
         params[name] = blk[name] if len(blk[name]) == count else \
             blk[name][first:first + count]
@@ -228,13 +231,27 @@ def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0):
                        mutable=["aux_loss", "counters"])
 
 
+#: what a configuration's layer adds to softmax scores over routed experts
+#: alone (``RoutedExperts``' switches), and which of the reference's layers
+#: is a routed one
+LAYER_SWITCHES = {"laguna-xs.2": dict(
+    scoring="sigmoid", routed_scale=2.5, shared_intermediate=24)}
+LAYER_EXTRA = {"laguna-xs.2": dict(
+    shared_expert_intermediate_size=24, intermediate_size=48,
+    layer_types=["full_attention", "sliding_attention"],
+    mlp_layer_types=["dense", "sparse"],
+    num_attention_heads_per_layer=[4, 4])}
+
+
 @pytest.mark.parametrize("config", ["sdar-30b-a3b-chat",
-                                    "keye-vl-2.0-30b-a3b"])
+                                    "keye-vl-2.0-30b-a3b", "laguna-xs.2"])
 def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
     """16 experts over 8 chips, 2 each: each share computed by the routed
     layer, with the router whole; together the uncut reference's layer, by
-    the plain reference of each configuration that is cut this way."""
+    the plain reference of each configuration that is cut this way.  What
+    every chip computes alike (a shared expert) is counted once."""
     ref, base = REF, LAYER
+    switches = LAYER_SWITCHES.get(config, {})
     if config != "sdar-30b-a3b-chat":
         ref = _load_reference(config)
         with open(os.path.join(BENCH, "configs", config + ".json")) as f:
@@ -244,13 +261,21 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
             "num_key_value_heads", "moe_intermediate_size",
             "num_experts_per_tok", "num_hidden_layers", "vocab_size",
             "residual_out_initializer_range")},
+            **LAYER_EXTRA.get(config, {}),
             "published": {**published["published"], "num_experts": 16}}
     whole = {**base, "num_experts": 16, "held_experts_first": 0}
     x = jax.random.normal(jax.random.PRNGKey(0), (BATCH, 2 * SEQ, 32))
-    blk = ref.init(jax.random.PRNGKey(1), whole)["blocks"][0]
+    blk = ref.init(jax.random.PRNGKey(1), whole)["blocks"][-1]
     want, _ = ref.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
-    shares = [_routed(x, blk, (2 * i, 2)) for i in range(8)]
+    shares = [_routed(x, blk, (2 * i, 2), **switches) for i in range(8)]
     total = sum(out for out, _ in shares)
+    if switches.get("shared_intermediate"):
+        # seven of the eight copies of what every chip computes alike
+        alone, _ = _routed(x, blk, (0, 2), **{**switches,
+                                              "shared_intermediate": None})
+        shared = shares[0][0] - alone
+        assert float(jnp.max(jnp.abs(shared))) > 1e-4
+        total = total - 7 * shared
     np.testing.assert_allclose(total.reshape(-1, 32), want, atol=2e-6)
     # no share is the whole, and every assignment is in exactly one share
     assert float(jnp.max(jnp.abs(shares[0][0] - total))) > 1e-4
@@ -527,8 +552,11 @@ def _a_block_keeps_its_list(d=32, i=24):
         "moe_gate": {((r, i), f32): 1}, "moe_up": {((r, i), f32): 1},
         # the names of a layer with an index: under a mask rule the block
         # has none and keeps nothing for them (tests/test_sparse_attention)
-        "dsa_selection": {}, "indexer_kl_grads": {}}
-    formula = {"dsa_selection": 0, "indexer_kl_grads": 0,
+        "dsa_selection": {}, "indexer_kl_grads": {},
+        # and of a layer under a band (tests/test_mixed_attention.py)
+        "flash_win_out": {}, "flash_win_lse": {}}
+    formula = {"dsa_selection": 0, "indexer_kl_grads": 0, "flash_win_out": 0,
+               "flash_win_lse": 0,
                "flash_out": t * h * hd * 4, "flash_lse": t * h * 4,
                "attn_out": t * d * 4, "attn_qkv": t * (h + 2 * 2) * hd * 4,
                "moe_route": t * k * 4 + 2 * r * 4 + count * 4 + 2 * r * 4,

@@ -19,6 +19,8 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only flash_fwd_tiles  # tile sweep
         python tools/pallas_drive.py --only flash_bwd_tiles  # the backward's
         python tools/pallas_drive.py --only grouped_mm_tiles  # routed layer's
+        python tools/pallas_drive.py --only flash_win_tiles  # under a band
+        python tools/pallas_drive.py --only grouped_mm_tiles_32  # 32 x 512
         python tools/pallas_drive.py --only ssd_scan  # Mamba-2 scan, by hb
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
@@ -183,10 +185,31 @@ def _sweep_pairs(S):
         if S % bq == 0 and S % bk == 0]
 
 
-def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
+# the band's cell (laguna-xs2-ep8share-swa512-seq8192): its sliding layers,
+# and beside them its full layers under plain causal
+FLASH_WIN_SHAPE = (2, 8192, 64, 128, 512)
+FLASH_WIN_FULL_SHAPE = (2, 8192, 48, 128)
+FLASH_WIN_PAIRS = [(None, None), (128, 128), (256, 256), (512, 512),
+                   (1024, 1024), (512, 256), (256, 512), (1024, 512),
+                   (512, 128), (1024, 256)]
+
+
+def _grid_steps(B, H, S, tq, tk, mask, bwd=False):
+    """The steps of either kernel's grid: under a band its shortened axis."""
+    if mask is None:
+        return B * H * (S // tq) * (S // tk)
+    if bwd:
+        return B * H * (S // tk) * mask.query_steps(S, tq, tk)
+    return B * H * (S // tq) * mask.key_steps(S, tq, tk)
+
+
+def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None,
+                      mask=None, pairs=None):
     """The causal forward alone at every tile pair of FLASH_SWEEP_TILES,
     at 128 x 128 and at the derived default (block None):
-    one record a pair, with its gap from the derived default's output."""
+    one record a pair, with its gap from the derived default's output.
+    ``mask``: a static rule beside causal (a ``WindowMask``); ``pairs``:
+    the pairs to time in place of ``_sweep_pairs``'."""
     import jax
     import jax.numpy as jnp
     from dt_tpu.ops.pallas import attention as attn
@@ -197,26 +220,29 @@ def flash_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
 
     if interpret is None:
         interpret = attn._default_interpret()
-    derived = attn.forward_tiles(S, S, D, jnp.dtype(dt).itemsize)
-    pairs = _sweep_pairs(S)
+    derived = attn.forward_tiles(S, S, D, jnp.dtype(dt).itemsize, mask)
+    pairs = pairs or _sweep_pairs(S)
     want = None
     for bq, bk in pairs:
         fn = jax.jit(lambda q, k, v, bq=bq, bk=bk: attn._flash_fwd_pallas(
             q, k, v, scale=D ** -0.5, causal=True, block_q=bq, block_k=bk,
-            interpret=interpret))
+            interpret=interpret, mask=mask))
         got = fn(*qkv)
         want = got if want is None else want   # the derived pair runs first
         tq, tk = bq or derived[0], bk or derived[1]
-        yield {"kernel": "flash_fwd_tiles",
-               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}",
+        yield {"kernel": "flash_fwd_tiles" if mask is None
+               else "flash_win_fwd_tiles",
+               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}"
+               + (f" {mask}" if mask else ""),
                "block_q": tq, "block_k": tk, "derived": bq is None,
-               "grid_steps": B * H * (S // tq) * (S // tk),
+               "grid_steps": _grid_steps(B, H, S, tq, tk, mask),
                "vs_derived_max_abs_err": _err(got, want),
                "fwd_ms": round(_timeit(fn, *qkv, iters=iters), 4),
                "backend": jax.default_backend()}
 
 
-def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
+def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None,
+                          mask=None, pairs=None):
     """The causal backward alone (delta in XLA and the one ``flash_bwd``
     call) at the derived default (block None), at 128 x 128 and at every
     tile pair of FLASH_SWEEP_TILES the compiler takes: one record a pair,
@@ -232,19 +258,21 @@ def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
     itemsize = jnp.dtype(dt).itemsize
     out, lse = attn._flash_fwd_pallas(        # jitted where it is defined
         q, k, v, scale=D ** -0.5, causal=True, block_q=None, block_k=None,
-        interpret=interpret)
-    derived = attn.backward_tiles(S, S, D, itemsize)
-    pairs = _sweep_pairs(S)
+        interpret=interpret, mask=mask)
+    derived = attn.backward_tiles(S, S, D, itemsize, mask)
+    pairs = pairs or _sweep_pairs(S)
     want = None
     for bq, bk in pairs:
         fn = jax.jit(lambda *a, bq=bq, bk=bk: attn._flash_bwd_pallas(
             *a, scale=D ** -0.5, causal=True, block_q=bq, block_k=bk,
-            interpret=interpret))
+            interpret=interpret, mask=mask))
         tq, tk = bq or derived[0], bk or derived[1]
-        rec = {"kernel": "flash_bwd_tiles",
-               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}",
+        rec = {"kernel": "flash_bwd_tiles" if mask is None
+               else "flash_win_bwd_tiles",
+               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}"
+               + (f" {mask}" if mask else ""),
                "block_q": tq, "block_k": tk, "derived": bq is None,
-               "grid_steps": B * H * (S // tq) * (S // tk),
+               "grid_steps": _grid_steps(B, H, S, tq, tk, mask, bwd=True),
                "vmem_mb": round(attn.backward_vmem_bytes(
                    tq, tk, S, D, itemsize) / 2 ** 20, 1),
                "backend": jax.default_backend()}
@@ -264,6 +292,8 @@ def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None):
 GROUPED_CELL_SHAPES = [(49152, 2048, 768), (24576, 2048, 768),
                        (49152, 768, 2048), (24576, 768, 2048)]
 GROUPED_SWEEP_TILES = (256, 512, 1024)
+# the third routed cell's buffer (laguna-xs2...): 32 experts held, of 512
+GROUPED_32_SHAPES = [(24576, 2048, 512), (24576, 512, 2048)]
 
 
 def grouped_loads(rng, m, groups):
@@ -281,7 +311,7 @@ def grouped_loads(rng, m, groups):
 
 
 def grouped_mm_tiles_sweep(rng, m, k, n, dt, groups=16, iters=30,
-                           interpret=None):
+                           interpret=None, tiles=GROUPED_SWEEP_TILES):
     """The three grouped products (value, ``d_lhs``, ``d_rhs``) one at a
     time at ``m`` rows against ``groups`` matrices ``k x n``: XLA's kernel
     for ``jax.lax.ragged_dot`` and its transposes, megablox's ``gmm`` at the
@@ -356,7 +386,7 @@ def grouped_mm_tiles_sweep(rng, m, k, n, dt, groups=16, iters=30,
                     1 if turned else 2]), transpose_rhs=turned))
             yield record(product, "megablox", tm, "ragged", mega, args,
                          want)[0]
-        for tm in sorted({*GROUPED_SWEEP_TILES, derived[product]} - {None}):
+        for tm in sorted({*tiles, derived[product]} - {None}):
             if m % tm:
                 continue
             for load in (loads if tm == derived[product] else ("ragged",)):
@@ -558,6 +588,31 @@ def main():
             for rec in grouped_mm_tiles_sweep(rng, m, k, n, dt,
                                               groups=4 if args.small else 16,
                                               iters=args.iters):
+                print(json.dumps(rec), flush=True)
+
+    # ---- both flash kernels under a band, by tile pair (PR 41) ------------
+    if wanted("flash_win_tiles"):
+        from dt_tpu.ops.pallas.attention import WindowMask
+        B, S, H, D, W = (1, 512, 2, 64, 200) if args.small \
+            else FLASH_WIN_SHAPE
+        pairs = [(None, None), (128, 128)] if args.small else FLASH_WIN_PAIRS
+        for sweep in (flash_tiles_sweep, flash_bwd_tiles_sweep):
+            for rec in sweep(rng, B, S, H, D, dt, iters=args.iters,
+                             mask=WindowMask(W), pairs=pairs):
+                print(json.dumps(rec), flush=True)
+            if not args.small:      # the cell's full layers, as derived
+                for rec in sweep(rng, *FLASH_WIN_FULL_SHAPE, dt,
+                                 iters=args.iters, pairs=[(None, None)]):
+                    print(json.dumps(rec), flush=True)
+
+    # ---- the grouped products at 32 groups of 2,048 x 512 (PR 41) --------
+    if wanted("grouped_mm_tiles_32"):
+        for m, k, n in ([(512, 128, 256)] if args.small else
+                        GROUPED_32_SHAPES):
+            for rec in grouped_mm_tiles_sweep(rng, m, k, n, dt,
+                                              groups=4 if args.small else 32,
+                                              iters=args.iters,
+                                              tiles=(128, 256, 512)):
                 print(json.dumps(rec), flush=True)
 
     # ---- the Mamba-2 scan, forward and backward, by head block (PR 40) ---
