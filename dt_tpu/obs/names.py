@@ -216,6 +216,14 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                    "(backward_tiles)"),
     "flash.bwd_block_k": ("gauge", "key rows in one tile of the flash "
                                    "backward (backward_tiles)"),
+    "flash.pairs_computed_pct": ("gauge", "pairs the flash forward computes "
+                                          "over the pairs of the tiles it "
+                                          "runs: under 100 where crossed "
+                                          "tiles are walked in sub-blocks "
+                                          "(computed_tiles), 100 where "
+                                          "every tile is computed whole"),
+    "flash.bwd_pairs_computed_pct": ("gauge", "the same of the flash "
+                                              "backward at its own tiles"),
     # set at trace time by every call of ops/ssm.py ssd_scan
     # (ops/pallas/ssd.py note_path): the process's counts so far
     "ssd.kernel_calls": ("gauge", "calls of ssd_scan traced so far that "

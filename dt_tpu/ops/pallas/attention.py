@@ -19,9 +19,14 @@ in the type they are stored in, with float32 accumulation; the
 probabilities are cast to ``v``'s type for the second product; max, exp,
 the running sum and the accumulator are float32.  Under ``causal`` a tile
 above the diagonal computes nothing and fetches nothing (its index map
-repeats the last block needed), and only tiles the diagonal crosses are
-masked.  ``flash_attention``'s ``block_q`` / ``block_k`` override the
-choice.
+repeats the last block needed), a tile below it runs unmasked, and a tile
+the diagonal crosses is **walked in sub-blocks** of ``SUB_BLOCK`` (128)
+positions a side: for each row block of queries the product against the
+key sub-blocks at or before it alone, the compare on the one sub-block
+the diagonal passes through, the online softmax of those rows (36 of a
+1,024-tile's 64 sub-blocks, 10 of a 512-tile's 16; see "Inside a crossed
+tile" below).  ``flash_attention``'s ``block_q`` / ``block_k`` override
+the choice of tiles.
 
 Backward is the standard flash backward from the saved log-sum-exp, one
 Pallas call named ``flash_bwd`` (its events carry that name in a trace,
@@ -33,7 +38,9 @@ scratch over the query axis, dq over both axes in a scratch for the whole
 sequence.  Five products and one exponential a tile pair.  Operands as
 stored and float32 accumulation, as the forward; ``p`` and ``ds`` are cast
 to the operands' type for the products they enter.  Pruned under ``causal``
-as the forward is.  Its tiles are its own (``backward_tiles``: at most 512
+as the forward is, and a crossed tile walked as the forward's with the
+sides exchanged (for each row block of keys the query sub-blocks at or
+after it).  Its tiles are its own (``backward_tiles``: at most 512
 a side), whatever the forward's are; nothing chooses another backward.  The
 whole sequence's dq in VMEM bounds the length one device takes: in
 bfloat16 the tiles fall to 128 x 128 from about 23,000 positions and the
@@ -44,8 +51,12 @@ compiler (given twice ``VMEM_BUDGET``) refuses from about 48,000
 Beside ``causal`` both kernels take a mask rule, ``BlockDiffusionMask``
 (training by diffusion over blocks: ``[noisy ; clean]`` halves, a block
 diagonal, two block-causal triangles and an empty quadrant, about a quarter
-of the square): a tile's fate (skip, run unmasked, run masked) and what a
-skipped step fetches follow from its offsets, as under ``causal``; the
+of the square): a tile's fate (skip, run unmasked, crossed by an edge) and
+what a skipped step fetches follow from its offsets, as under ``causal``;
+the crossed tiles are the three quadrants' diagonal tiles, each walked by
+its own edge (the block diagonal: a strip one sub-block wide, 8 of a
+1,024-tile's 64 sub-blocks; the strict and the inclusive block-causal
+triangle: 36 of 64), the compare on the diagonal sub-blocks alone; the
 kernels are then named ``flash_fwd_bd`` and ``flash_bwd_bd``.  The rule is
 static: without it the programs are what they were.
 
@@ -66,8 +77,14 @@ first at its start, the backward's last at its end) compute nothing and
 name the band's first (last) tile, which the neighbouring step fetches
 anyway.  dq still accumulates in a scratch for the whole sequence.  The
 tiles are square and no wider than the window (``WindowMask.tiles``:
-512 x 512 at a window of 512, which runs twice the band's pairs in the
-fewest steps; PERF.md section 6, PR 41, has the sweep); the kernels are
+512 x 512 at a window of 512, whose tiles hold twice the band's pairs in
+the fewest steps; PERF.md section 6, PR 41, has the sweep).  Where the
+window is whole tiles every tile that an edge crosses is walked: the
+diagonal tile by the causal edge alone, the tile a window before it by
+the band's edge alone (10 of 16 sub-blocks each at 512, so 1.25 squares
+are computed for the band's one); tiles between them run unmasked, and a
+window that is not whole tiles is masked tile by tile under both edges
+(``_in_band``) as before.  The kernels are
 named ``flash_win_fwd`` and ``flash_win_bwd`` (no ``flash_fwd`` or
 ``flash_bwd`` in them: a decoder that alternates windowed and full layers
 counts each kind's events alone), and their results carry the names
@@ -89,10 +106,32 @@ blocks hold a selected pair, each kernel is handed the any-reduction over
 its own tiles as a scalar-prefetch table, and a tile whose entry is 0
 computes nothing.  The rule composes with ``causal``: tiles above the
 diagonal neither run nor fetch, whatever the table says, and a tile the
-diagonal crosses is held to both.  33.5 MB a bitmap at 16,384 positions (an
+diagonal crosses is held to both (walked as ``causal``'s, the bitmap on
+every sub-block computed).  33.5 MB a bitmap at 16,384 positions (an
 int8 square would be 268 MB).  The kernels are then named ``flash_fwd_sel``
-and ``flash_bwd_sel``; they compute every pair of a tile that runs, so the
-result is exact and the work is the tiles', not the selection's.
+and ``flash_bwd_sel``; they compute every pair of a tile (below the
+diagonal: of a sub-block) that runs, so the result is exact and the work is
+the tiles', not the selection's.
+
+Inside a crossed tile (PERF.md section 6, PR 42).  With square tiles that
+divide the length (and the half, and the window) the place of an edge
+inside a crossed tile is static: ``crossed_kinds`` names the grid's kinds
+of crossed tile, each an ``_Edge`` (a band ``lo <= (k + gap) // unit - q //
+unit <= hi`` over positions counted from the tile's corner), and the
+kernels walk such a tile in a Python loop over static slices of the refs,
+unrolled at trace time.  A sub-block no pair of which is allowed is not
+computed (it added exact zeros before), one all of whose pairs are runs
+unmasked, and only one the edge passes through is compared and masked.  In
+the forward all the row blocks' score products come before the first
+softmax, in the backward each kind of product and of arithmetic runs over
+all the row blocks before the next: row block after row block the walks
+took a quarter longer than that.  Where the geometry gives no static
+pattern (rectangular tiles a caller forces, a window or a block that is not
+a whole part of a tile) every tile that runs is computed whole, as before;
+a call without ``causal`` or a rule has no crossed tile and its program is
+what it was.  The ``# flash_tiles`` / ``# flash_bwd_tiles`` debug lines and
+the gauges ``flash.pairs_computed_pct`` / ``flash.bwd_pairs_computed_pct``
+say which a shape took (``computed_tiles``).
 
 Composes with the distributed layer: ``ring_attention`` shards the
 sequence over the mesh and runs blockwise attention per shard; this
@@ -140,6 +179,10 @@ BACKWARD_TILES = (512, 256, 128)
 # compiler is given twice that (v5e has 128 MiB of it, 16 MiB scoped by
 # default)
 VMEM_BUDGET = 24 << 20
+# a tile that an edge of the mask crosses is walked in sub-blocks of this
+# many positions a side (``_Edge``; PERF.md section 6, PR 42: 128 beat 256
+# in both passes at every cell's shape, at head sizes 64 and 128 alike)
+SUB_BLOCK = 128
 
 
 def tile_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
@@ -319,17 +362,22 @@ def _when_block_diffusion(tile, rule: BlockDiffusionMask, qi, ki,
     pl.when(runs & unmasked)(functools.partial(tile, None))
 
 
+def _blocks_along(first, axis: int, shape, block: int):
+    """The block (of ``block`` positions) of each position from ``first``
+    along ``axis`` of ``shape``: a column or a row vector."""
+    vec = tuple(n if a == axis else 1 for a, n in enumerate(shape))
+    pos = first + lax.broadcasted_iota(jnp.int32, vec, axis)
+    shift = block.bit_length() - 1
+    return pos >> shift if block == 1 << shift else pos // block
+
+
 def _band_mask(rule: BlockDiffusionMask, band, q_axis: int, shape):
     """The allowed pairs of a masked tile of ``shape`` whose queries lie
     along ``q_axis``: the block of each query (a column or a row vector),
     of each key (the other), and the band between them."""
     q0, k0, c1, c2 = band
-    def blocks(first, axis):
-        vec = tuple(n if a == axis else 1 for a, n in enumerate(shape))
-        pos = first + lax.broadcasted_iota(jnp.int32, vec, axis)
-        shift = rule.block.bit_length() - 1
-        return pos >> shift if rule.block == 1 << shift else pos // rule.block
-    gap = blocks(k0, 1 - q_axis) - blocks(q0, q_axis)       # kb - qb
+    gap = _blocks_along(k0, 1 - q_axis, shape, rule.block) \
+        - _blocks_along(q0, q_axis, shape, rule.block)      # kb - qb
     return (gap <= -c1) & (gap >= -c2)
 
 
@@ -646,6 +694,192 @@ def _when_selected(tile, fate, causal: bool, qi, ki, block_q: int,
     pl.when(runs & jnp.logical_not(crossed))(functools.partial(tile, False))
 
 
+# ---------------------------------------------------------------------------
+# inside a crossed tile: only the sub-blocks that hold an allowed pair
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Edge:
+    """One kind of crossed tile (a tile an edge of the mask crosses) whose
+    pattern is static.  With ``q`` and ``k`` counted from the tile's first
+    query and first key, a pair is allowed where
+
+        lo <= (k + gap) // unit - q // unit <= hi
+
+    ``unit`` 1 and ``gap`` the tile's first key position less its first
+    query position under ``causal`` and a band; ``unit`` the rule's block
+    and ``gap`` 0 under ``BlockDiffusionMask`` (the crossed tiles are the
+    quadrants' diagonal ones, which start on a block).  The difference
+    takes every value between its least and its largest over a sub-block,
+    so a sub-block's fate follows from its corners, as a tile's does
+    (``BlockDiffusionMask.tile``); all Python integers."""
+    gap: int
+    lo: int
+    hi: int
+    unit: int = 1
+
+    def reach(self, q0: int, k0: int, n: int):
+        """Least and largest difference over the ``n x n`` sub-block at
+        query ``q0``, key ``k0``."""
+        return ((k0 + self.gap) // self.unit - (q0 + n - 1) // self.unit,
+                (k0 + n - 1 + self.gap) // self.unit - q0 // self.unit)
+
+    def fate(self, q0: int, k0: int, n: int) -> int:
+        """Of that sub-block: 0 no pair allowed, 2 all, 1 the edge passes
+        through it."""
+        least, most = self.reach(q0, k0, n)
+        if most < self.lo or least > self.hi:
+            return 0
+        return 2 if self.lo <= least and most <= self.hi else 1
+
+    def spans(self, block: int, sub: int, by_key: bool = False):
+        """The walk of a ``block x block`` tile in sub-blocks of ``sub``:
+        for each row block of queries (``by_key``: of keys, the backward's
+        tile lies keys by queries) ``(first, last + 1, fates)``, the
+        sub-blocks of the other side that hold an allowed pair (one run:
+        the rules are bands) and their fates."""
+        n = block // sub
+        walk = []
+        for i in range(n):
+            fates = [self.fate(j * sub, i * sub, sub) if by_key
+                     else self.fate(i * sub, j * sub, sub) for j in range(n)]
+            held = [j for j in range(n) if fates[j]]
+            lo, hi = (held[0], held[-1] + 1) if held else (0, 0)
+            assert len(held) == hi - lo, (self, fates)
+            walk.append((lo, hi, tuple(fates[lo:hi])))
+        return walk
+
+    def sub_blocks(self, block: int, sub: int) -> int:
+        """How many of the tile's ``(block / sub)^2`` sub-blocks the walk
+        computes."""
+        return sum(hi - lo for lo, hi, _ in self.spans(block, sub))
+
+    def allowed(self, q0: int, k0: int, n: int, q_axis: int):
+        """The allowed pairs of the crossed ``n x n`` sub-block at query
+        ``q0``, key ``k0``, queries along ``q_axis``: one compare where one
+        bound alone can fail inside it."""
+        shape = (n, n)
+        diff = _blocks_along(k0 + self.gap, 1 - q_axis, shape, self.unit) \
+            - _blocks_along(q0, q_axis, shape, self.unit)
+        least, most = self.reach(q0, k0, n)
+        if self.lo == self.hi:
+            return diff == self.lo
+        if least >= self.lo:
+            return diff <= self.hi
+        if most <= self.hi:
+            return diff >= self.lo
+        return (diff >= self.lo) & (diff <= self.hi)
+
+    def mask_span(self, s, q0: int, k0: int, sub: int, fates, q_axis: int):
+        """``s``, the scores of one row block against a run of sub-blocks
+        of the other side (along axis 1) from query ``q0``, key ``k0``:
+        ``NEG_INF`` outside the rule in the sub-blocks whose fate is 1, the
+        others as they are."""
+        if 1 not in fates:
+            return s
+        parts, j = [], 0
+        while j < len(fates):
+            end = j + 1
+            while fates[j] == 2 and end < len(fates) and fates[end] == 2:
+                end += 1
+            part = s if end - j == len(fates) else s[:, j * sub:end * sub]
+            if fates[j] == 1:
+                at = (q0 + j * sub, k0) if q_axis else (q0, k0 + j * sub)
+                part = jnp.where(self.allowed(*at, sub, q_axis), part,
+                                 NEG_INF)
+            parts.append(part)
+            j = end
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+class _Crossed(NamedTuple):
+    """One kind of crossed tile in a grid: its ``edge``, ``here(qi, ki)``
+    (whether a crossed tile is of this kind: a traced boolean, or True
+    where the grid has one kind) and how many ``tiles`` of the grid are."""
+    edge: _Edge
+    here: object
+    tiles: int
+
+
+def crossed_kinds(mask, causal: bool, s: int, sk: int, block_q: int,
+                  block_k: int, sub: int):
+    """The kinds of crossed tile of a grid, or None where the walk is not
+    taken (every tile that runs is then computed whole): the tiles are
+    square and whole sub-blocks of ``sub``, and every crossed tile lies on
+    its edge the same way.  That is so under ``causal`` (the diagonal
+    tiles); under a ``WindowMask`` whose window is whole tiles (the diagonal
+    tiles meet the causal edge alone, the tiles a window before them the
+    band's edge alone); under a ``BlockDiffusionMask`` whose block divides
+    the tile (the three quadrants' diagonal tiles).  A caller's rectangular
+    tiles, a window or a block that is not a whole part of a tile, a call
+    without ``causal`` or a rule: None."""
+    b = block_q
+    if not sub or block_q != block_k or b % sub or b == sub:
+        return None
+    n_diag = min(s // b, sk // b)
+    if isinstance(mask, WindowMask):
+        if mask.window % b:
+            return None
+        away = mask.window // b
+        band = dict(lo=1 - mask.window, hi=0)
+        return (_Crossed(_Edge(0, **band), lambda qi, ki: ki == qi, n_diag),
+                _Crossed(_Edge(-mask.window, **band),
+                         lambda qi, ki: ki != qi, max(n_diag - away, 0)))
+    if isinstance(mask, BlockDiffusionMask):
+        if b % mask.block or b == mask.block:
+            return None
+        n, far = mask.half // b, mask._FAR
+        return (
+            _Crossed(_Edge(0, 0, 0, mask.block),            # the diagonal
+                     lambda qi, ki: ki < n, n),
+            _Crossed(_Edge(0, -far, -1, mask.block),        # kb < qb
+                     lambda qi, ki: (qi < n) & (ki >= n), n),
+            _Crossed(_Edge(0, -far, 0, mask.block),         # kb <= qb
+                     lambda qi, ki: qi >= n, n))
+    if causal:      # alone, or beside a selection
+        return (_Crossed(_Edge(0, -BlockDiffusionMask._FAR, 0),
+                         lambda qi, ki: True, n_diag),)
+    return None
+
+
+def _run_tile(kinds, whole, walk, qi, ki, masked):
+    """A tile that runs: ``whole(masked)``, or where an edge crosses it
+    (``masked``) and the grid's crossed tiles have a static pattern
+    (``kinds``), ``walk(edge)`` of its kind."""
+    if kinds is None or not masked:
+        return whole(masked)
+    for kind in kinds:
+        pl.when(kind.here(qi, ki))(functools.partial(walk, kind.edge))
+
+
+def computed_tiles(mask, causal: bool, s: int, sk: int, block_q: int,
+                   block_k: int, sub: int):
+    """``(run, crossed, computed)`` for one head's grid: the tiles that
+    run, how many of them the walk takes sub-block by sub-block (0: it is
+    not taken), and the tile-equivalents computed in all, the crossed
+    tiles counted by their sub-blocks.  Under a selection the tiles that
+    run are data: the sums are then ``causal``'s (all of its tiles run
+    with seeded weights), or None without ``causal``.  Python integers
+    (``BlockDiffusionMask.tiles_run`` apart)."""
+    n_q, n_k = s // block_q, sk // block_k
+    if isinstance(mask, BlockDiffusionMask):
+        run = mask.tiles_run(block_q, block_k)
+    elif isinstance(mask, WindowMask):
+        run = mask.tiles_run(s, block_q, block_k)
+    elif causal:
+        run = sum(min((qi * block_q + block_q - 1) // block_k + 1, n_k)
+                  for qi in range(n_q))
+    elif mask is None:
+        run = n_q * n_k
+    else:
+        return None
+    kinds = crossed_kinds(mask, causal, s, sk, block_q, block_k, sub) or ()
+    crossed = sum(kind.tiles for kind in kinds)
+    part = sum(kind.tiles * kind.edge.sub_blocks(block_q, sub)
+               for kind in kinds) / (block_q // sub) ** 2 if kinds else 0
+    return run, crossed, run - crossed + part
+
+
 def _kernel_name(mask, which: str) -> str:
     """The name a kernel's events carry in a trace under a mask rule.  The
     band's hold neither ``flash_fwd`` nor ``flash_bwd``, so that a metric
@@ -660,13 +894,16 @@ def _kernel_name(mask, which: str) -> str:
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                  acc_ref, m_ref, l_ref, *,
                  scale: float, causal: bool, block_q: int, block_k: int,
-                 n_k: int, mask=None, sel=None):
+                 n_k: int, mask=None, sel=None, kinds=None, sub: int = 0):
     """One (bh, q_block, k_block) grid step; kv axis is sequential, so the
     VMEM scratch (acc, m, l) carries the online softmax across it.  ``n_k``
     is that axis' steps: the key tiles, or under a ``WindowMask`` the tiles
     a band can touch, the step's key tile then being ``mask.key_tile``'s.
     ``sel`` (under a ``SelectedKeysMask``) is ``(fate, words)``: the tile's
-    entry of the fate table and the ref of its queries' bitmap block."""
+    entry of the fate table and the ref of its queries' bitmap block.
+    ``kinds`` (``crossed_kinds``) are the grid's kinds of crossed tile,
+    walked in sub-blocks of ``sub``; None: a crossed tile is computed and
+    masked whole."""
     step = pl.program_id(2)
 
     @pl.when(step == 0)
@@ -709,25 +946,59 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             s = jnp.where(row_less_col >= ki * block_k - qi * block_q,
                           s, NEG_INF)
 
-        m_prev = m_ref[:]                             # (BQ, 1)
+        _update(slice(None), s, v)
+
+    def _update(rows, s, v):
+        # the online softmax of the query rows ``rows`` over the scores
+        # ``s`` of some of the tile's keys and their values ``v``
+        m_prev = m_ref[rows]                          # (BQ, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)                        # (BQ, BK)
         correction = jnp.exp(m_prev - m_new)          # (BQ, 1)
-        l_ref[:] = l_ref[:] * correction + p.sum(axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * correction + jax.lax.dot_general(
+        l_ref[rows] = l_ref[rows] * correction + p.sum(axis=1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * correction + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        m_ref[rows] = m_new
+
+    def _walk(edge):
+        # a crossed tile of the kind ``edge``: for each row block of
+        # queries the product against the key sub-blocks that hold an
+        # allowed pair, masked only where the edge passes, and the online
+        # softmax of those rows alone.  Static slices, unrolled; every row
+        # block's scores before the first softmax, so that one's softmax
+        # and the next one's products overlap (PERF.md section 6, PR 42:
+        # row block after row block the same walk took a quarter longer)
+        blocks, scores = [], []
+        for r, (lo, hi, fates) in enumerate(edge.spans(block_q, sub)):
+            if lo == hi:
+                continue
+            rows, keys = slice(r * sub, (r + 1) * sub), \
+                slice(lo * sub, hi * sub)
+            s = jax.lax.dot_general(
+                q_ref[0, rows], k_ref[0, keys], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = edge.mask_span(s, r * sub, lo * sub, sub, fates, 0)
+            if sel is not None:
+                seen = unpack_tile(sel[1][0, 0, rows],
+                                   ki * block_k + lo * sub, (hi - lo) * sub)
+                s = jnp.where(seen != 0, s, NEG_INF)
+            blocks.append((rows, keys))
+            scores.append(s)
+        for (rows, keys), s in zip(blocks, scores):
+            _update(rows, s, v_ref[0, keys])
+
+    _tile = functools.partial(_run_tile, kinds, _attend, _walk, qi, ki)
 
     if sel is not None:
-        _when_selected(_attend, sel[0], causal, qi, ki, block_q, block_k)
+        _when_selected(_tile, sel[0], causal, qi, ki, block_q, block_k)
     elif windowed:
-        _when_window(_attend, mask, ki >= 0, qi, ki, block_q, block_k)
+        _when_window(_tile, mask, ki >= 0, qi, ki, block_q, block_k)
     elif mask is not None:
-        _when_block_diffusion(_attend, mask, qi, ki, block_q, block_k)
+        _when_block_diffusion(_tile, mask, qi, ki, block_q, block_k)
     elif causal:
-        _when_causal(_attend, qi, ki, block_q, block_k)
+        _when_causal(_tile, qi, ki, block_q, block_k)
     else:
         _attend(False)
 
@@ -757,12 +1028,15 @@ def _attn_kernel_sel(fate_ref, q_ref, k_ref, v_ref, words_ref, *rest,
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret", "mask"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
-                      interpret, mask=None, selection=None):
+                      interpret, mask=None, selection=None, sub=None):
     """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S)).  ``block_q`` /
     ``block_k`` of None are derived from the shapes (``forward_tiles``).
     ``selection`` is the ``Selection`` a ``SelectedKeysMask`` reads.
+    ``sub`` is the side of a crossed tile's sub-blocks (None:
+    ``SUB_BLOCK``; 0: crossed tiles computed whole), for the sweep and the
+    tests: no caller in the package gives it.
 
     Jitted and inlined: a model's layers share one trace of the kernel's
     body (Pallas traces it anew for every call otherwise, 24 times a
@@ -776,13 +1050,16 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
         dq, dk = forward_tiles(s, sk, d, q3.dtype.itemsize,
                                None if selected else mask)
         block_q, block_k = block_q or dq, block_k or dk
-    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask)
+    sub = SUB_BLOCK if sub is None else sub
+    _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask,
+                causal=causal, sub=sub)
     n_q = s // block_q
     # the key axis' steps: under a band, the tiles it can touch
     n_k = mask.key_steps(s, block_q, block_k) if windowed else sk // block_k
     kern = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_k=n_k, mask=mask)
+        block_k=block_k, n_k=n_k, mask=mask, sub=sub,
+        kinds=crossed_kinds(mask, causal, s, sk, block_q, block_k, sub))
     # the index maps take the grid's indices and, under a selection, the
     # scalar-prefetch table after them
     if windowed:
@@ -856,24 +1133,35 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
 
 @functools.lru_cache(maxsize=None)
 def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
-                mask=None) -> None:
+                mask=None, causal: bool = False, sub: int = 0) -> None:
     """Record, once per distinct shape, tile and mask rule, what the forward
     (or with ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
     flash_bwd_tiles``) debug line and the metrics plane's gauges, so that a
-    shape that falls back to 128 is seen.  Under a mask rule the line and
-    the gauges' label ``mask`` name it, with the tiles that run of the
-    grid's.  Trace time only."""
+    shape that falls back to 128 is seen.  Under a mask rule or ``causal``
+    the line and the gauges' label ``mask`` name it, with the tiles that
+    run of the grid's and, where crossed tiles are walked in sub-blocks of
+    ``sub``, how many they are and the tile-equivalents computed in all
+    (``computed_tiles``); the gauge ``flash.pairs_computed_pct``
+    (``flash.bwd_pairs_computed_pct``) is that over the tiles that run, 100
+    where every tile is computed whole.  Trace time only."""
     s, sk, d, dtype = shape
     if isinstance(mask, BlockDiffusionMask):
-        rule = (f"block_diffusion.half{mask.half}.block{mask.block}.run"
-                f"{mask.tiles_run(block_q, block_k)}of"
-                f"{(s // block_q) * (sk // block_k)}")
+        rule = f"block_diffusion.half{mask.half}.block{mask.block}"
     elif isinstance(mask, WindowMask):
-        rule = (f"window{mask.window}.run"
-                f"{mask.tiles_run(s, block_q, block_k)}of"
-                f"{(s // block_q) * (sk // block_k)}")
-    else:   # a selection's tiles are data: the model's counters have them
-        rule = "" if mask is None else "selected_keys"
+        rule = f"window{mask.window}"
+    elif isinstance(mask, SelectedKeysMask):
+        # a selection's tiles are data: the model's counters have them
+        rule = "selected_keys" + (".causal" if causal else "")
+    else:
+        rule = "causal" if causal else ""
+    sums = computed_tiles(mask, causal, s, sk, block_q, block_k, sub)
+    pct = 100.0
+    if sums is not None and rule:
+        run, crossed, computed = sums
+        rule += f".run{run}of{(s // block_q) * (sk // block_k)}"
+        if crossed:
+            rule += f".crossed{crossed}.sub{round(computed, 4)}of{run}"
+            pct = 100.0 * computed / run
     logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
                  "block_k=%d%s", "bwd_" if bwd else "", s, sk, d, dtype,
                  block_q, block_k, " mask=" + rule if rule else "")
@@ -885,9 +1173,11 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
         if bwd:
             reg.gauge("flash.bwd_block_q", block_q, labels)
             reg.gauge("flash.bwd_block_k", block_k, labels)
+            reg.gauge("flash.bwd_pairs_computed_pct", pct, labels)
         else:
             reg.gauge("flash.block_q", block_q, labels)
             reg.gauge("flash.block_k", block_k, labels)
+            reg.gauge("flash.pairs_computed_pct", pct, labels)
 
 
 def backward_vmem_bytes(block_q: int, block_k: int, s: int, d: int,
@@ -926,7 +1216,8 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       scale: float, causal: bool, block_q: int, block_k: int,
                       n_q: int, n_k: int, mask=None, sel=None,
-                      q_tiles: Optional[int] = None):
+                      q_tiles: Optional[int] = None, kinds=None,
+                      sub: int = 0):
     """One (bh, k_block, q_block) grid step of the backward.  The tile is
     held keys by queries (``s^T = k q^T``): the log-sum-exp and delta of the
     query rows are then lane rows that broadcast down the sublanes, and
@@ -934,7 +1225,8 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     innermost), dq over both in a scratch for the whole sequence.  ``n_q``
     is the query axis' steps: the query tiles, or under a ``WindowMask``
     the tiles a band can touch (of ``q_tiles`` in all), the step's query
-    tile then being ``mask.query_tile``'s."""
+    tile then being ``mask.query_tile``'s.  ``kinds`` and ``sub`` as the
+    forward's: a crossed tile is walked key row block by key row block."""
     ki, step = pl.program_id(1), pl.program_id(2)
     windowed = isinstance(mask, WindowMask)
     qi = mask.query_tile(ki, step, block_q, block_k) if windowed else step
@@ -994,14 +1286,59 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    def _walk(edge):
+        # a crossed tile of the kind ``edge``, as the forward's walk with
+        # the sides exchanged: for each row block of keys the query
+        # sub-blocks that hold an allowed pair.  What ``_tile`` computes,
+        # one kind of product or of arithmetic after another over all the
+        # row blocks: in that order the kernel took a tenth to a quarter
+        # less than row block after row block (PERF.md section 6, PR 42)
+        nt, tn = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+        dot = functools.partial(jax.lax.dot_general,
+                                preferred_element_type=jnp.float32)
+        blocks, sts = [], []
+        for c, (lo, hi, fates) in enumerate(
+                edge.spans(block_k, sub, by_key=True)):
+            if lo == hi:
+                continue
+            keys, qs = slice(c * sub, (c + 1) * sub), \
+                slice(lo * sub, hi * sub)
+            st = dot(k_ref[0, keys], q_ref[0, qs], nt) * scale
+            st = edge.mask_span(st, lo * sub, c * sub, sub, fates, 1)
+            if sel is not None:
+                seen = unpack_tile(sel[1][0, 0, keys],
+                                   qi * block_q + lo * sub, (hi - lo) * sub)
+                st = jnp.where(seen != 0, st, NEG_INF)
+            blocks.append((keys, qs))
+            sts.append(st)
+        dpts = [dot(v_ref[0, keys], do_ref[0, qs], nt) for keys, qs in blocks]
+        pts = [jnp.exp(st - lse_ref[0, :, qs])
+               for (_, qs), st in zip(blocks, sts)]
+        dsts = [(pt * (dpt - delta_ref[0, :, qs])).astype(q_ref.dtype)
+                for (_, qs), pt, dpt in zip(blocks, pts, dpts)]
+        dvs = [dot(pt.astype(do_ref.dtype), do_ref[0, qs], tn)
+               for (_, qs), pt in zip(blocks, pts)]
+        dks = [dot(dst, q_ref[0, qs], tn)
+               for (_, qs), dst in zip(blocks, dsts)]
+        for (keys, _), dv, dk in zip(blocks, dvs, dks):
+            dv_acc[keys] += dv
+            dk_acc[keys] += dk
+        for (keys, qs), dst in zip(blocks, dsts):
+            rows = pl.ds(pl.multiple_of(qi * block_q + qs.start, sub),
+                         qs.stop - qs.start)
+            dq_acc[rows, :] += dot(dst, k_ref[0, keys],
+                                   (((0,), (0,)), ((), ())))
+
+    _run = functools.partial(_run_tile, kinds, _tile, _walk, qi, ki)
+
     if sel is not None:
-        _when_selected(_tile, sel[0], causal, qi, ki, block_q, block_k)
+        _when_selected(_run, sel[0], causal, qi, ki, block_q, block_k)
     elif windowed:
-        _when_window(_tile, mask, qi < q_tiles, qi, ki, block_q, block_k)
+        _when_window(_run, mask, qi < q_tiles, qi, ki, block_q, block_k)
     elif mask is not None:
-        _when_block_diffusion(_tile, mask, qi, ki, block_q, block_k)
+        _when_block_diffusion(_run, mask, qi, ki, block_q, block_k)
     elif causal:
-        _when_causal(_tile, qi, ki, block_q, block_k)    # as the forward
+        _when_causal(_run, qi, ki, block_q, block_k)     # as the forward
     else:
         _tile(False)
 
@@ -1026,14 +1363,16 @@ def _flash_bwd_kernel_sel(fate_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret", "mask"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub"))
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
-                      block_q=None, block_k=None, mask=None, selection=None):
+                      block_q=None, block_k=None, mask=None, selection=None,
+                      sub=None):
     """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
     (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
     ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel``, ``flash_win_bwd``
     under a mask rule).
-    Its tiles come from the shapes (``backward_tiles``).
+    Its tiles come from the shapes (``backward_tiles``); ``sub`` as the
+    forward's.
 
     Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
     kernel's body for all of a model's layers."""
@@ -1044,8 +1383,9 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
         tq, tk = backward_tiles(s, sk, d, q3.dtype.itemsize,
                                 None if selected else mask)
         block_q, block_k = block_q or tq, block_k or tk
+    sub = SUB_BLOCK if sub is None else sub
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
-                mask=mask)
+                mask=mask, causal=causal, sub=sub)
     windowed = isinstance(mask, WindowMask)
     q_tiles, n_k = s // block_q, sk // block_k
     # the query axis' steps: under a band, the tiles it can touch
@@ -1055,7 +1395,8 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     delta = (do3.astype(jnp.float32) * o3.astype(jnp.float32)).sum(-1)
     kern = functools.partial(
         _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, n_q=n_q, n_k=n_k, mask=mask,
+        block_k=block_k, n_q=n_q, n_k=n_k, mask=mask, sub=sub,
+        kinds=crossed_kinds(mask, causal, s, sk, block_q, block_k, sub),
         **({"q_tiles": q_tiles} if windowed else {}))
     if windowed:
         # a step past the band names the band's last tile again

@@ -302,9 +302,13 @@ def test_tiles_are_recorded_once_per_shape(caplog, prefix):
             run(q)
         lines = [r.getMessage() for r in caplog.records
                  if r.getMessage().startswith(f"# flash_{prefix}tiles")]
+        # fifteen of the grid's tiles run under causal; at one sub-block a
+        # tile none is walked
         assert lines == [f"# flash_{prefix}tiles s=640 sk=640 d=64 "
-                         "dtype=float32 block_q=128 block_k=128"]
-        labels = {"shape": "640x640x64.float32"}
+                         "dtype=float32 block_q=128 block_k=128 "
+                         "mask=causal.run15of25"]
+        labels = {"shape": "640x640x64.float32",
+                  "mask": "causal.run15of25"}
         gauges = [g for g in obs_metrics.registry().gauges_export()
                   if g[0].startswith(f"flash.{prefix}block_")]
         assert gauges == [[f"flash.{prefix}block_k", labels, 128.0],
@@ -312,3 +316,131 @@ def test_tiles_are_recorded_once_per_shape(caplog, prefix):
     finally:
         obs_metrics.set_enabled(None)
         obs_metrics.registry().clear()
+
+
+# -- crossed tiles walked in sub-blocks (PR 42) -------------------------------
+
+import flash_edge_cases as edge   # noqa: E402
+
+
+def _causal_oracle(q, k, v, allowed):
+    """``full_attention`` over (heads, S, d) operands (``allowed`` is its
+    own causal mask)."""
+    to4 = lambda x: x[:, :, None]  # noqa: E731 — heads as the batch
+    return full_attention(to4(q), to4(k), to4(v), causal=True)[:, :, 0]
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sub", [128, 256])
+def test_the_walk_under_causal_matches_the_oracle(sub, d):
+    """1,024 positions: the forward's one tile and the backward's two
+    diagonal tiles of 512 are crossed, and walked; inputs whose large scores
+    sit at the ends of every row's allowed keys."""
+    s = 1024
+    pos = np.arange(s)
+    assert attn.crossed_kinds(None, True, s, s, 1024, 1024, sub)
+    assert attn.crossed_kinds(None, True, s, s, 512, 512, sub)
+    edge.kernels_match(pos[None, :] <= pos[:, None], d, sub, causal=True,
+                       oracle=_causal_oracle)
+
+
+def test_the_walk_leaves_the_whole_tiles_results_where_they_were():
+    """The same call with crossed tiles computed whole (``sub`` 0): every
+    allowed pair is computed as before and a skipped sub-block added exact
+    zeros, so the two agree to rounding's last place."""
+    q, k, v, do = edge.spiky_inputs(np.tril(np.ones((1024, 1024), bool)), 64)
+    kw = dict(scale=0.125, causal=True, interpret=True)
+    got = []
+    for sub in (0, 128):
+        out, lse = attn._flash_fwd_pallas(q, k, v, block_q=None,
+                                          block_k=None, sub=sub, **kw)
+        got.append((out, lse) + tuple(attn._flash_bwd_pallas(
+            q, k, v, out, lse, do, sub=sub, **kw)))
+    for a, b in zip(*got):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("bq,bk", [(256, 128), (128, 256), (128, 128)])
+def test_tiles_without_a_static_pattern_are_computed_whole(bq, bk):
+    """A caller's rectangular tiles, and square ones of one sub-block:
+    no walk, the gauge reads 100 and the result is the oracle's."""
+    assert attn.crossed_kinds(None, True, 512, 512, bq, bk, 128) is None
+    q, k, v = _qkv(np.random.RandomState(5), b=1, s=512, h=1)
+    gauges = edge.pairs_computed_gauges(lambda: np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk),
+        full_attention(q, k, v, causal=True), rtol=2e-4, atol=2e-5))
+    assert gauges == {"flash.pairs_computed_pct": 100.0}
+
+
+def test_the_gauges_say_what_share_of_the_run_tiles_pairs_is_computed():
+    """The first LM cell's shape: the forward's whole square walked at
+    36 of 64 sub-blocks, the backward's three tiles of 512 at 2.25."""
+    q = jnp.zeros((1, 1024, 1, 64), jnp.bfloat16)
+    gauges = edge.pairs_computed_gauges(lambda: jax.eval_shape(jax.grad(
+        lambda q: flash_attention(q, q, q, causal=True).sum().astype(
+            jnp.float32)), q))
+    assert attn.SUB_BLOCK == 128
+    assert gauges == {"flash.pairs_computed_pct": 56.25,
+                      "flash.bwd_pairs_computed_pct": 75.0}
+
+
+def test_without_causal_or_a_rule_the_programs_hold_no_walk():
+    """No tile is crossed: the forward's and the backward's programs are
+    what they are with the walk off, two and five products a tile."""
+    x, lse = jnp.zeros((2, 1024, 64)), jnp.zeros((2, 1024))
+    kw = dict(scale=0.125, causal=False, interpret=True)
+    texts = {sub: (str(jax.make_jaxpr(lambda q: attn._flash_fwd_pallas(
+        q, q, q, block_q=None, block_k=None, sub=sub, **kw))(x)),
+        str(jax.make_jaxpr(lambda q: attn._flash_bwd_pallas(
+            q, q, q, q, lse, q, sub=sub, **kw))(x))) for sub in (None, 0)}
+    assert texts[None] == texts[0]
+    assert [t.count("dot_general") for t in texts[None]] == [2, 5]
+    assert attn.crossed_kinds(None, False, 1024, 1024, 1024, 1024, 128) \
+        is None
+
+
+# the five LM cells' flash calls: (cell, rule, causal, positions, head size,
+# bytes an element)
+CELL_CALLS = [
+    ("gpt2m", None, True, 1024, 64, 2),
+    ("granite4hm", None, True, 4096, 64, 2),
+    ("sdar30b", attn.BlockDiffusionMask(4096, 4), False, 8192, 128, 2),
+    ("laguna.win", attn.WindowMask(512), True, 8192, 128, 2),
+    ("laguna.full", None, True, 8192, 128, 2),
+    ("keye30b", attn.SelectedKeysMask(), True, 16384, 128, 2),
+]
+# what ISSUE 42 reckoned at sub-blocks of 128: tile-equivalents computed of
+# the tiles run, forward and backward
+RECKONED = {"gpt2m": ((0.5625, 1), (2.25, 3)),
+            "sdar30b": ((17.0, 24), (68.0, 80)),
+            "laguna.win": ((19.375, 31), (19.375, 31)),
+            "laguna.full": ((32.5, 36), (130.0, 136)),
+            "granite4hm": ((8.25, 10), (33.0, 36)),
+            "keye30b": ((129.0, 136), (516.0, 528))}
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("cell,rule,causal,s,d,itemsize", CELL_CALLS,
+                         ids=[c[0] for c in CELL_CALLS])
+def test_the_walks_sums_against_counts_made_one_sub_block_at_a_time(
+        cell, rule, causal, s, d, itemsize, sub):
+    """At each cell's shape and derived tiles, both passes: the tiles that
+    run, the crossed ones and the tile-equivalents computed are what a count
+    over ``rule.allowed``, tile by tile and sub-block by sub-block, gives;
+    the walk is engaged; and it computes no fewer pairs than the rule needs
+    (what keeps every roofline under 100)."""
+    selected = isinstance(rule, attn.SelectedKeysMask)
+    allowed = (lambda q, k: k <= q) if rule is None or selected \
+        else rule.allowed
+    for which, tiles in enumerate((attn.forward_tiles, attn.backward_tiles)):
+        b, bk = tiles(s, s, d, itemsize, None if selected else rule)
+        assert b == bk
+        assert attn.crossed_kinds(rule, causal, s, s, b, b, sub)
+        run, crossed, computed = attn.computed_tiles(rule, causal, s, s, b,
+                                                     b, sub)
+        *by_hand, pairs = edge.walked_by_hand(allowed, s, b, sub)
+        assert (run, crossed, computed) == tuple(by_hand)
+        assert 0 < crossed <= run and computed < run
+        assert computed * b * b >= pairs
+        if sub == 128:
+            assert (computed, run) == RECKONED[cell][which]

@@ -162,3 +162,53 @@ def test_mosaic_takes_both_kernels_under_a_selection(one_chip, b, h, s, d,
         calls = [ln.split("=")[0] for ln in text.splitlines()
                  if "tpu_custom_call" in ln and "custom-call(" in ln]
         assert calls and all(name in c for c in calls), calls
+
+
+# with crossed tiles walked in sub-blocks (PR 42): the six kernels at the
+# five LM cells' shapes (gpt2m, granite4hm, sdar30b, laguna's band and its
+# full layers, keye30b), at sub-blocks of either size the sweep tried
+WALKED = [
+    ("gpt2m", 8 * 16, 1024, 64, None, True),
+    ("granite4hm", 2 * 32, 4096, 64, None, True),
+    ("sdar30b", 2 * 32, 8192, 128, attn.BlockDiffusionMask(4096, 4), False),
+    ("laguna.win", 2 * 64, 8192, 128, attn.WindowMask(512), True),
+    ("laguna.full", 2 * 48, 8192, 128, None, True),
+    ("keye30b", 32, 16384, 128, attn.SelectedKeysMask(), True),
+]
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("cell,bh,s,d,rule,causal", WALKED,
+                         ids=[c[0] for c in WALKED])
+def test_mosaic_takes_the_walk_at_the_cells_shapes(one_chip, cell, bh, s, d,
+                                                   rule, causal, sub):
+    """Both passes compile with the walk's unrolled row blocks in them,
+    inside the scoped VMEM the calls ask for (twice ``VMEM_BUDGET``: a
+    sub-block's scores are smaller than a tile's), under the names the
+    benchmark's ``kernel.flash_*`` find them by."""
+    shape = lambda *a: jax.ShapeDtypeStruct(*a, sharding=one_chip)  # noqa: E731
+    x, lse = shape((bh, s, d), jnp.bfloat16), shape((bh, s), jnp.float32)
+    selected = isinstance(rule, attn.SelectedKeysMask)
+    sel = ()
+    if selected:
+        words = shape((1, -(-s // attn.SEL_GROUP), s, 128), jnp.int32)
+        sel = (attn.Selection(words, words, shape((1, s // 128, s // 128),
+                                                  jnp.bool_)),)
+    for tiles in (attn.forward_tiles, attn.backward_tiles):
+        b = tiles(s, s, d, 2, None if selected else rule)[0]
+        assert attn.crossed_kinds(rule, causal, s, s, b, b, sub)
+    kw = dict(scale=d ** -0.5, causal=causal, interpret=False, mask=rule,
+              sub=sub)
+    fwd = jax.jit(lambda q, k, v, *sel: attn._flash_fwd_pallas(
+        q, k, v, block_q=None, block_k=None, selection=(sel or (None,))[0],
+        **kw))
+    bwd = jax.jit(lambda q, k, v, o, lse, do, *sel: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, selection=(sel or (None,))[0], **kw))
+    texts = (fwd.lower(x, x, x, *sel).compile().as_text(),
+             bwd.lower(x, x, x, x, lse, x, *sel).compile().as_text())
+    names = ("", "flash_bwd") if rule is None else (
+        attn._kernel_name(rule, "fwd"), attn._kernel_name(rule, "bwd"))
+    for text, name in zip(texts, names):
+        calls = [ln.split("=")[0] for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "custom-call(" in ln]
+        assert calls and all(name in c for c in calls), calls

@@ -26,6 +26,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 sys.path.insert(0, BENCH)
 import laguna_drivers  # noqa: E402
+import flash_edge_cases as edge  # noqa: E402
 
 CONFIG = "laguna-xs.2"
 
@@ -159,6 +160,54 @@ def test_the_band_at_the_cells_shape():
     assert attn.backward_tiles(s, s, 128, 2) == (512, 512)
 
 
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("s,window", [(1536, 512), (1024, 256)])
+def test_the_walk_under_the_band_matches_a_dense_masked_softmax(s, window,
+                                                                sub, d):
+    """A window of one tile: the diagonal tile meets the causal edge alone,
+    the tile before it the band's edge alone, and both are walked in
+    sub-blocks (tiles of 256 are one sub-block of 256: computed whole);
+    inputs whose large scores sit at both ends of every row's band."""
+    rule = WindowMask(window)
+    assert attn.forward_tiles(s, s, d, 4, rule) == (window, window)
+    kinds = attn.crossed_kinds(rule, True, s, s, window, window, sub)
+    assert (kinds is None) == (sub == window)
+    pos = np.arange(s)
+    edge.kernels_match(np.asarray(rule.allowed(pos[:, None], pos[None, :])),
+                       d, sub, mask=rule, causal=True)
+    run, crossed, computed = attn.computed_tiles(rule, True, s, s, window,
+                                                 window, sub)
+    *by_hand, _ = edge.walked_by_hand(rule.allowed, s, window, sub)
+    assert (run, crossed, computed) == tuple(by_hand) or kinds is None
+    if kinds is None:
+        assert (crossed, computed) == (0, run)
+
+
+@pytest.mark.parametrize("s,window,tile", [(1024, 300, 256),
+                                           (1024, 384, 256)])
+def test_a_window_that_is_not_whole_tiles_is_computed_whole(s, window, tile):
+    """The band's edge then crosses a tile at another place in every
+    second tile: no static pattern, the whole-tile path, the gauges read
+    100 and the result is the dense one."""
+    rule = WindowMask(window)
+    assert attn.forward_tiles(s, s, 32, 4, rule) == (tile, tile)
+    assert attn.crossed_kinds(rule, True, s, s, tile, tile, 128) is None
+    q, k, v = (jax.random.normal(key, (1, s, 1, 32))
+               for key in jax.random.split(jax.random.PRNGKey(window), 3))
+
+    def trace():
+        flash = lambda *a: flash_attention(  # noqa: E731
+            *a, mask=rule, interpret=True)
+        np.testing.assert_allclose(flash(q, k, v),
+                                   _dense_attention(q, k, v, rule), atol=2e-6)
+        jax.eval_shape(jax.grad(lambda *a: flash(*a).sum()), q, k, v)
+
+    assert edge.pairs_computed_gauges(trace) == {
+        "flash.pairs_computed_pct": 100.0,
+        "flash.bwd_pairs_computed_pct": 100.0}
+
+
 def test_tile_notes_name_the_band(caplog):
     attn._note_tiles.cache_clear()
     q = jnp.zeros((1, 512, 1, 32))
@@ -171,6 +220,18 @@ def test_tile_notes_name_the_band(caplog):
                for ln in lines), lines
     assert any("# flash_bwd_tiles" in ln and "window100" in ln
                for ln in lines), lines
+    # the cell's shape: 31 tiles run, all crossed, 19.375 tile-equivalents
+    # at sub-blocks of 128 (ten of a tile's sixteen), and the gauges
+    q = jnp.zeros((1, 8192, 1, 128), jnp.bfloat16)
+    with caplog.at_level("DEBUG", logger="dt_tpu"):
+        gauges = edge.pairs_computed_gauges(lambda: jax.eval_shape(
+            jax.grad(lambda q: flash_attention(
+                q, q, q, mask=WindowMask(512), interpret=True).sum().astype(
+                    jnp.float32)), q))
+    assert gauges == {"flash.pairs_computed_pct": 62.5,
+                      "flash.bwd_pairs_computed_pct": 62.5}
+    assert sum("mask=window512.run31of256.crossed31.sub19.375of31" in
+               r.getMessage() for r in caplog.records) == 2
 
 
 # -- rotary over a part of a head, and the YaRN schedule ---------------------

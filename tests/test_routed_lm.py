@@ -30,6 +30,7 @@ BENCH = os.path.join(REPO, "benchmark")
 sys.path.insert(0, BENCH)
 import sdar_drivers  # noqa: E402
 import remat_held  # noqa: E402  (tests/)
+import flash_edge_cases as edge  # noqa: E402  (tests/)
 
 
 def _load_reference(config="sdar-30b-a3b-chat"):
@@ -143,6 +144,53 @@ def test_kernels_under_the_block_mask_match_a_dense_masked_softmax(half,
                                    err_msg=f"d{name} half={half}")
 
 
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("half,block,tile", [
+    (1024, 4, None),    # the cell's blocks, the derived tiles (1,024, 512)
+    (384, 6, 384),      # a block that is no power of two: it divides no
+                        # derived tile, so tiles of 384 are forced
+    (384, 96, 384),     # blocks nearly a sub-block long
+])
+def test_the_walk_under_the_block_mask_matches_a_dense_masked_softmax(
+        half, block, tile, sub, d):
+    """The three quadrants' diagonal tiles are crossed (by the block
+    diagonal, the strict and the inclusive block-causal triangle) and
+    walked in sub-blocks; inputs whose large scores sit at the ends of
+    every row's allowed keys.  Tiles of 384 are not whole sub-blocks of
+    256: computed whole."""
+    rule = BlockDiffusionMask(half, block)
+    fwd = tile or attn.forward_tiles(2 * half, 2 * half, d, 4, rule)[0]
+    kinds = attn.crossed_kinds(rule, False, 2 * half, 2 * half, fwd, fwd, sub)
+    assert (kinds is None) == bool(fwd % sub)
+    pos = np.arange(2 * half)
+    edge.kernels_match(np.asarray(rule.allowed(pos[:, None], pos[None, :])),
+                       d, sub, mask=rule, block=tile)
+    if kinds:
+        assert len(kinds) == 3
+        *by_hand, _ = edge.walked_by_hand(rule.allowed, 2 * half, fwd, sub)
+        assert attn.computed_tiles(rule, False, 2 * half, 2 * half, fwd, fwd,
+                                   sub) == tuple(by_hand)
+
+
+@pytest.mark.parametrize("half,block,bq,bk", [
+    (384, 6, None, None),   # a block that divides no tile: an edge crosses
+                            # every crossed tile at another place
+    (512, 4, 256, 128)])    # a caller's rectangular tiles
+def test_block_mask_tiles_without_a_static_pattern_are_computed_whole(
+        half, block, bq, bk):
+    rule = BlockDiffusionMask(half, block)
+    tq, tk = attn.forward_tiles(2 * half, 2 * half, 32, 4, rule)
+    assert attn.crossed_kinds(rule, False, 2 * half, 2 * half, bq or tq,
+                              bk or tk, 128) is None
+    q, k, v = (jax.random.normal(kk, (1, 2 * half, 1, 32), jnp.float32)
+               for kk in jax.random.split(jax.random.PRNGKey(half), 3))
+    gauges = edge.pairs_computed_gauges(lambda: np.testing.assert_allclose(
+        flash_attention(q, k, v, mask=rule, block_q=bq, block_k=bk),
+        _dense_attention(q, k, v, rule), rtol=2e-5, atol=2e-5))
+    assert gauges == {"flash.pairs_computed_pct": 100.0}
+
+
 def test_a_length_that_needs_padding_to_the_tile():
     """The attention module pads each half to the tile: 72 positions a
     half become 128, and a padded key lies in a block after every real
@@ -179,13 +227,19 @@ def test_tile_notes_name_the_mask_rule(caplog):
         lines = [r.getMessage() for r in caplog.records
                  if "tiles" in r.getMessage()]
         assert len(lines) == 2 and all(
-            "mask=block_diffusion.half256.block4.run" in ln for ln in lines)
+            "mask=block_diffusion.half256.block4.run3of4.crossed3."
+            "sub2.0of3" in ln for ln in lines)
         gauges = {(n, dict(lk).get("mask", "")) for n, lk, _ in
                   obs_metrics.registry().gauges_export()}
         assert any(n == "flash.block_q" and m.startswith("block_diffusion")
                    for n, m in gauges)
         assert any(n == "flash.bwd_block_k" and m.startswith(
             "block_diffusion") for n, m in gauges)
+        shares = {n: v for n, _, v in obs_metrics.registry().gauges_export()
+                  if n.endswith("pairs_computed_pct")}
+        assert shares == {
+            "flash.pairs_computed_pct": pytest.approx(200 / 3),
+            "flash.bwd_pairs_computed_pct": pytest.approx(200 / 3)}
     finally:
         obs_metrics.set_enabled(None)
         attn._note_tiles.cache_clear()

@@ -29,6 +29,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 sys.path.insert(0, BENCH)
 import keye_drivers  # noqa: E402
+import flash_edge_cases as edge  # noqa: E402  (tests/)
 
 
 def _load_reference():
@@ -113,6 +114,29 @@ def test_both_kernels_under_a_selection_match_a_dense_masked_softmax(
                    (0, 1, 2))(q, kk, v)
     for a, b in zip(got, ref):
         np.testing.assert_allclose(a, b, atol=5e-6)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sub", [128, 256])
+def test_the_walk_under_a_selection_beside_causal_matches_a_dense_softmax(
+        sub, d):
+    """1,024 positions, each query's 96 keys of random scores: the tiles
+    the diagonal crosses (the forward's one, the backward's two of 512) are
+    walked in sub-blocks with the bitmap on every sub-block computed and the
+    causal compare on the diagonal ones alone; the bitmap also holds keys
+    after the query, which the walk must not see.  Inputs whose large scores
+    sit at each row's first and last selected key."""
+    t = 1024
+    allowed = _top_keys(t, 96, True)[:1]
+    handed = allowed | (_top_keys(t, 96, False, 1)[:1]
+                        & ~jnp.tril(jnp.ones((t, t), bool)))
+    assert attn.crossed_kinds(SelectedKeysMask(), True, t, t, 512, 512, sub)
+    edge.kernels_match(np.asarray(allowed[0]), d, sub,
+                       mask=SelectedKeysMask(), causal=True,
+                       selection=pack_selection(handed))
+    # without causal no tile is crossed by a static edge: computed whole
+    assert attn.crossed_kinds(SelectedKeysMask(), False, t, t, 512, 512,
+                              sub) is None
 
 
 def test_a_tile_without_a_selected_pair_computes_nothing():

@@ -22,6 +22,7 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only flash_win_tiles  # under a band
         python tools/pallas_drive.py --only grouped_mm_tiles_32  # 32 x 512
         python tools/pallas_drive.py --only ssd_scan  # Mamba-2 scan, by hb
+        python tools/pallas_drive.py --only flash_edge_walk  # crossed tiles
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -284,6 +285,61 @@ def flash_bwd_tiles_sweep(rng, B, S, H, D, dt, iters=20, interpret=None,
                                           iters=iters), 4)
         except Exception as e:  # noqa: BLE001 — a pair Mosaic refuses
             rec["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+        yield rec
+
+
+# the five LM cells' flash calls (gpt2m, granite4hm, sdar30b's halves of
+# 4,096 in blocks of 4, laguna's band of 512 and its full layers, keye30b's
+# length without its selection): (B, S, H, D, rule), a rule a WindowMask's
+# window or a BlockDiffusionMask's (half, block)
+FLASH_EDGE_CASES = [(8, 1024, 16, 64, None), (2, 4096, 32, 64, None),
+                    (2, 8192, 32, 128, (4096, 4)), (2, 8192, 64, 128, 512),
+                    (2, 8192, 48, 128, None), (1, 16384, 32, 128, None)]
+
+
+def flash_edge_walk_sweep(rng, B, S, H, D, dt, rule=None, subs=(0, 128, 256),
+                          iters=20, interpret=None):
+    """Both kernels at their derived tiles with crossed tiles computed
+    whole (``sub`` 0) and walked in sub-blocks of 128 and of 256 (PERF.md
+    section 6, PR 42): one record a ``sub``, with its gap from the whole
+    tiles' results and the share of the run tiles' pairs it computes."""
+    import jax
+    import jax.numpy as jnp
+    from dt_tpu.ops.pallas import attention as attn
+    mask = None if rule is None else attn.WindowMask(rule) \
+        if isinstance(rule, int) else attn.BlockDiffusionMask(*rule)
+    causal = not isinstance(mask, attn.BlockDiffusionMask)
+    q, k, v, do = (jnp.asarray(rng.randn(B * H, S, D) * 0.3, dt)
+                   for _ in range(4))
+    if interpret is None:
+        interpret = attn._default_interpret()
+    itemsize = jnp.dtype(dt).itemsize
+    kw = dict(scale=D ** -0.5, causal=causal, interpret=interpret, mask=mask)
+    want = None
+    for sub in subs:
+        fwd = jax.jit(lambda q, k, v, sub=sub: attn._flash_fwd_pallas(
+            q, k, v, block_q=None, block_k=None, sub=sub, **kw))
+        bwd = jax.jit(lambda *a, sub=sub: attn._flash_bwd_pallas(
+            *a, sub=sub, **kw))
+        out, lse = fwd(q, k, v)
+        got = (out, lse) + tuple(bwd(q, k, v, out, lse, do))
+        want = want or got                      # whole tiles run first
+        rec = {"kernel": "flash_edge_walk", "sub": sub,
+               "shape": f"B{B}xS{S}xH{H}xD{D} {jnp.dtype(dt).name}"
+               + (f" {mask}" if mask else ""),
+               "vs_whole_max_abs_err": _err(got, want),
+               "fwd_ms": round(_timeit(fwd, q, k, v, iters=iters), 4),
+               "bwd_ms": round(_timeit(bwd, q, k, v, out, lse, do,
+                                       iters=iters), 4),
+               "backend": jax.default_backend()}
+        for name, tiles in (("fwd", attn.forward_tiles),
+                            ("bwd", attn.backward_tiles)):
+            tq, tk = tiles(S, S, D, itemsize, mask)
+            run, _, computed = attn.computed_tiles(mask, causal, S, S, tq,
+                                                   tk, sub)
+            rec[f"{name}_tile"] = tq
+            rec[f"{name}_pairs_computed_pct"] = round(100 * computed / run,
+                                                      2)
         yield rec
 
 
@@ -604,6 +660,15 @@ def main():
                 for rec in sweep(rng, *FLASH_WIN_FULL_SHAPE, dt,
                                  iters=args.iters, pairs=[(None, None)]):
                     print(json.dumps(rec), flush=True)
+
+    # ---- both flash kernels, crossed tiles whole or walked (PR 42) -------
+    if wanted("flash_edge_walk"):
+        for B, S, H, D, rule in ([(1, 512, 2, 64, None), (1, 512, 2, 64, 256),
+                                  (1, 512, 2, 64, (256, 4))] if args.small
+                                 else FLASH_EDGE_CASES):
+            for rec in flash_edge_walk_sweep(rng, B, S, H, D, dt, rule,
+                                             iters=args.iters):
+                print(json.dumps(rec), flush=True)
 
     # ---- the grouped products at 32 groups of 2,048 x 512 (PR 41) --------
     if wanted("grouped_mm_tiles_32"):
