@@ -2,7 +2,9 @@
 diffusion over blocks or as a causal next-token model: one whose attention
 reads only the keys a learned indexer picks, or one whose layers differ from
 one another (windowed and full attention with their own head counts and
-rotary rules, a gate a head, a leading dense layer, a shared expert).
+rotary rules, a gate a head, a leading dense layer, a shared expert; or
+gated short convolutions among attention layers, experts chosen under a
+selection bias that is balanced without a loss, a head tied to the table).
 
 Beyond the reference's RNN ceiling (the cuDNN fused LSTM,
 ``src/operator/cudnn_rnn-inl.h:1``; SURVEY.md §5.7) and beside ``HybridLM``
@@ -93,8 +95,46 @@ as the static rule ``WindowMask`` (``ops/pallas/attention.py``: the grids
 span only the tiles a band can touch), and a windowed layer sows the rule's
 static counts under ``("counters", "win")`` (``WIN_COUNTERS``).
 
+**Gated short convolutions among attention layers, experts under a
+selection bias** (a layer's record with ``attention: "conv"``;
+``selection_bias``, ``tie_word_embeddings``; causal objective).  As one
+published decoder of this kind has them (LFM2's ``Lfm2ShortConv``,
+``Lfm2Attention`` and ``Lfm2DecoderLayer``: 3 taps, 32 query heads over 8
+key-value heads of 64, ``E`` 32 experts of width 1,792, ``k`` 4, two leading
+dense layers of width 7,168, eps 1e-5)::
+
+    a = rms(x)                                                          operator_norm
+    conv layer:       [B | C | u] = a Win                               Win d x 3d, no bias; three equal parts, in this order
+                      c_t = sum_{j=0..2} w_j * (B * u)_{t-2+j}          depthwise over d channels, 3 taps, zero before position 0, no bias, no activation
+                      h = x + (C * c) Wout                              Wout d x d
+    attention layer:  q = rms_h(a Wq) as [T, H, D] ;  k = rms_h(a Wk) as [T, KV, D] ;  v = (a Wv) as [T, KV, D]
+                      q, k = rope(q, pos, theta), rope(k, pos, theta)   the whole head, pairs (x_i, x_{i+D/2})
+                      h = x + [softmax over s <= t of q_t . k_s / sqrt(D)] v Wo
+    b = rms(h)                                                          ffn_norm
+    dense layer:      x' = h + W2(silu(b W1) * (b W3))
+    routed layer:     s = sigmoid_f32(b Wr) over E
+                      S = top_k(s + bias)                               bias (E,) float32: the selection only
+                      w_e = routed_scale * s_e / (sum_{S} s + router_norm_eps) ,  e in S      from s, not from s + bias
+                      x' = h + sum_{e in S, e held} w_e W2_e(silu(b W1_e) * (b W3_e))
+    logits = rms(x_last) Table^T     (float32; tie_word_embeddings)
+    loss = CE(next token, all T positions)                              no auxiliary term (aux_loss_coef 0)
+    after a training step, each routed layer:  load_e = its assignments to e in the step, all E, all tokens
+                      bias_e <- bias_e + u * sign(mean_e(load) - load_e)
+
+The mixer is ``ShortConv`` (module ``conv`` where ``attn`` stands in the other
+layers; the taps through ``ops/ssm.py`` ``causal_conv1d``).  The bias is a
+variable of the ``batch_stats`` collection, no parameter: ``training.Module``
+carries it in its ``TrainState`` beside the parameters (through ``fit``,
+checkpoints and a joiner's bootstrap), no gradient reaches it and the
+optimizer holds nothing for it; ``parallel/moe.py`` ``RoutedExperts`` reads it
+and, in a training step, writes it moved (once a step: a rematerialised
+block's second forward does not move it again), and counts the picks it moved
+under ``("counters", "moe_bias")``.
+
 Module names and ``jax.named_scope``s tell the parts apart in an operation's
-scope path: ``block3/attn/q_proj``, ``block3/attn/rope``,
+scope path: ``block3/conv/in_proj``, ``block3/conv/gate_in`` (``B * u``),
+``block3/conv/conv1d`` (the taps), ``block3/conv/gate_out`` (``C * c``),
+``block3/conv/out_proj``; ``block3/attn/q_proj``, ``block3/attn/rope``,
 ``block3/attn/indexer`` (the three projections, the index key's norm and the
 chunked scores), ``block3/attn/select`` (each row's k-th largest score and
 the bitmaps), ``block3/attn/indexer_kl``,
@@ -122,7 +162,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from dt_tpu.models.hybrid_lm import GatedMLP, RMSNorm
-from dt_tpu.ops import sparse_index
+from dt_tpu.ops import sparse_index, ssm
 from dt_tpu.ops.pallas.attention import (BlockDiffusionMask, DEFAULT_BLOCK,
                                          NEG_INF, SelectedKeysMask,
                                          WindowMask, backward_tiles,
@@ -451,6 +491,43 @@ class RotaryAttention(linen.Module):
         return (out, jax.nn.logsumexp(scores, axis=-1)) if with_lse else out
 
 
+class ShortConv(linen.Module):
+    """A gated short convolution where an attention layer would stand (the
+    LFM2 family's mixer, ``Lfm2ShortConv``): from the layer's normed input
+    ``a`` (B, T, d)::
+
+        [B | C | u] = a Win                        Win d x 3d, three equal parts in this order
+        c_t = sum_j w_j * (B * u)_{t - taps + 1 + j}    depthwise over d channels, zero before position 0
+        y = (C * c) Wout                           Wout d x d
+
+    No bias, no activation, no positions (``positions`` is taken and
+    ignored, so that a block calls either mixer alike).  Modules
+    ``in_proj`` and ``out_proj``, the taps ``conv_kernel`` (``taps``, d) with
+    ``conv_kernel[taps - 1]`` on the current position; ``jax.named_scope``s
+    ``gate_in`` (``B * u``), ``conv1d`` (the taps, ``ops.ssm.causal_conv1d``:
+    shifted multiply-adds that XLA fuses) and ``gate_out`` (``C * c``) tell
+    the part that is no matrix product from the two that are."""
+    taps: int = 3
+    dtype: Any = F32
+
+    @linen.compact
+    def __call__(self, x, positions=None):
+        d = x.shape[-1]
+        dense = lambda n, name: linen.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
+        gate_in, gate_out, u = jnp.split(checkpoint_name(
+            dense(3 * d, "in_proj")(x), "conv_in_proj"), 3, axis=-1)
+        kernel = self.param("conv_kernel", linen.initializers.normal(
+            self.taps ** -0.5), (self.taps, d), F32)
+        with jax.named_scope("gate_in"):
+            u = gate_in * u
+        with jax.named_scope("conv1d"):
+            u = ssm.causal_conv1d(u, kernel)
+        with jax.named_scope("gate_out"):
+            u = gate_out * u
+        return dense(d, "out_proj")(u)
+
+
 #: what a rematerialised ``RoutedBlock`` keeps from its forward pass, by
 #: ``checkpoint_name``; the backward pass computes the rest again from the
 #: block's input.  Bytes a layer, for T positions (B x 2 L) of width d, H
@@ -476,6 +553,10 @@ class RotaryAttention(linen.Module):
 #:   flash_win_out     T x H x D x c   the windowed kernel's output (268 MB a
 #:               layer at 16,384 positions of 64 heads; 8 ms a layer spared)
 #:   flash_win_lse     T x H x 4    its log-sum-exp
+#: and in a layer whose mixer is a gated short convolution (``ShortConv``):
+#:   conv_in_proj      T x 3 d x c    in_proj's output, the three parts the
+#:               gates and the taps read (201 MB a layer at 16,384 positions
+#:               of width 2,048; PERF.md section 6, PR 43)
 #: Named and not kept: shared_gate and shared_up (T x I x c each: the
 #: shared expert's two products), mlp_gate and mlp_up (``GatedMLP``'s, in a
 #: dense layer: 268 MB each at 16,384 positions of width 8,192 for 3.5 ms),
@@ -486,12 +567,14 @@ class RotaryAttention(linen.Module):
 #: bytes, and what the chip has room for.
 SAVED = ("flash_out", "flash_lse", "attn_out", "moe_route", "moe_up",
          "dsa_selection", "indexer_kl_grads", "flash_win_out",
-         "flash_win_lse")
+         "flash_win_lse", "conv_in_proj")
 
 
 class RoutedBlock(linen.Module):
-    """One layer: attention, then the routed experts (module ``moe``) or,
-    where ``mlp`` is given, a dense gated feed-forward in their place
+    """One layer: attention (module ``attn``) or, where ``conv`` is given,
+    a gated short convolution in its place (``ShortConv``, module
+    ``conv``), then the routed experts (module ``moe``) or, where ``mlp`` is
+    given, a dense gated feed-forward in their place
     (``hybrid_lm.GatedMLP``, module ``mlp``), each on the RMSNorm of the
     stream and added back."""
     attn: Any                 # kwargs of RotaryAttention
@@ -499,12 +582,17 @@ class RoutedBlock(linen.Module):
     eps: float = 1e-6
     dtype: Any = F32
     mlp: Any = None           # kwargs of GatedMLP: a dense layer
+    conv: Any = None          # kwargs of ShortConv: no attention
 
     @linen.compact
     def __call__(self, x, positions=None):
         h = RMSNorm(self.eps, self.dtype, name="input_norm")(x)
-        h = RotaryAttention(eps=self.eps, dtype=self.dtype, name="attn",
-                            **dict(self.attn))(h, positions)
+        if self.conv is not None:
+            h = ShortConv(dtype=self.dtype, name="conv",
+                          **dict(self.conv))(h, positions)
+        else:
+            h = RotaryAttention(eps=self.eps, dtype=self.dtype, name="attn",
+                                **dict(self.attn))(h, positions)
         x = x + h.astype(x.dtype)
         h = RMSNorm(self.eps, self.dtype, name="post_norm")(x)
         if self.mlp is not None:
@@ -542,13 +630,25 @@ class RoutedLM(linen.Module):
     and a layer takes from its own what the dict names and the rest from
     the fields: ``attention`` (``"full"``: causal; ``"window"``: the
     ``window`` keys up to the query's own; the kind is also the layer's
-    ``RotaryAttention.kind`` scope), ``num_heads``, ``rope`` (a dict:
+    ``RotaryAttention.kind`` scope; ``"conv"``: no attention, a gated short
+    convolution of ``conv_taps`` taps, ``ShortConv``, module ``conv``, which
+    takes none of the record's other attention keys), ``num_heads``,
+    ``rope`` (a dict:
     ``rope_theta``, and optionally ``rotary_dim`` and ``yarn``, see
     ``RotaryAttention``), ``mlp`` (``"routed"``, or ``"dense"``: a
     ``GatedMLP`` of ``dense_intermediate`` where the experts stand, which
     sows no counters).  ``attn_gate`` and ``qk_norm`` are every layer's
     (``RotaryAttention``'s ``gate`` and ``qk_norm``).  Causal objective
-    only."""
+    only.
+
+    ``tie_word_embeddings``: the head is the table (``logits = rms(x_last)
+    Table^T``; no parameter ``lm_head``, and the table's gradient sums both
+    uses).  ``selection_bias``, ``bias_update_speed`` and ``router_norm_eps``
+    are ``RoutedExperts``' ``selection_bias``, ``bias_update_speed`` and
+    ``norm_eps``: the routed layers are balanced by a bias on the scores for
+    the selection only, a variable of the ``batch_stats`` collection that a
+    training step moves, and such a decoder usually sets ``aux_loss_coef``
+    0."""
     vocab_size: int = 32000
     embed_dim: int = 256
     num_layers: int = 2
@@ -580,6 +680,11 @@ class RoutedLM(linen.Module):
     scoring: str = "softmax"
     routed_scale: float = 1.0
     shared_intermediate: Optional[int] = None
+    conv_taps: int = 3
+    tie_word_embeddings: bool = False
+    selection_bias: bool = False
+    bias_update_speed: float = 0.001
+    router_norm_eps: float = 0.0
     saved_names = SAVED     # no field: the policy's list, and the gauge's
 
     @linen.compact
@@ -624,6 +729,11 @@ class RoutedLM(linen.Module):
             moe["routed_scale"] = self.routed_scale
         if self.shared_intermediate:
             moe["shared_intermediate"] = self.shared_intermediate
+        if self.router_norm_eps:
+            moe["norm_eps"] = self.router_norm_eps
+        if self.selection_bias:
+            moe.update(selection_bias=True,
+                       bias_update_speed=self.bias_update_speed)
         init = linen.initializers.normal(0.02)
         table = self.param("embedding", init,
                            (self.vocab_size, self.embed_dim), F32)
@@ -636,26 +746,37 @@ class RoutedLM(linen.Module):
             mine, mlp = attn, None
             if self.layers is not None:
                 mine, mlp = self._layer(dict(self.layers[i]), attn)
+            # no attention's arguments: the layer's mixer is the convolution
+            conv = _items({"taps": self.conv_taps}) if mine is None else None
             x = block_cls(_items(mine), _items(moe), self.rms_norm_eps,
-                          self.dtype, mlp, name=f"block{i}")(x, positions)
+                          self.dtype, mlp, conv, name=f"block{i}")(
+                              x, positions)
         if not causal:
             x = x[:, :mask.half]        # the head over the noisy half only
         x = RMSNorm(self.rms_norm_eps, self.dtype, name="final_norm")(x)
-        head = self.param("lm_head", init,
-                          (self.vocab_size, self.embed_dim), F32)
+        head = table if self.tie_word_embeddings else self.param(
+            "lm_head", init, (self.vocab_size, self.embed_dim), F32)
         with jax.named_scope("lm_head"):
             return jnp.einsum("bsd,vd->bsv", x, head.astype(self.dtype),
                               preferred_element_type=F32)
 
     def _layer(self, record, attn):
         """One layer's record over the decoder's own attention arguments ->
-        (that layer's, its dense feed-forward's or None)."""
+        (that layer's, or None where its mixer is no attention; its dense
+        feed-forward's or None)."""
         kind = record.pop("attention", None)
-        if kind not in (None, "full", "window"):
+        if kind not in (None, "full", "window", "conv"):
             raise ValueError(f"no attention {kind!r}")
         mlp = record.pop("mlp", "routed")
         if mlp not in ("routed", "dense"):
             raise ValueError(f"no feed-forward {mlp!r}")
+        mlp = _items({"intermediate": self.dense_intermediate}) \
+            if mlp == "dense" else None
+        if kind == "conv":
+            if record:
+                raise ValueError(f"a conv layer's record has no "
+                                 f"{sorted(record)}")
+            return None, mlp
         mine = {**attn, **record.pop("rope", {})}
         if "num_heads" in record:
             mine["num_heads"] = record.pop("num_heads")
@@ -665,5 +786,4 @@ class RoutedLM(linen.Module):
             mine["kind"] = kind
         if kind == "window":
             mine["window"] = self.window
-        return mine, (_items({"intermediate": self.dense_intermediate})
-                      if mlp == "dense" else None)
+        return mine, mlp

@@ -44,6 +44,11 @@ is a ``jax.custom_vjp``: ``d_lhs`` is the turned product of the cotangent,
 **Tiles from the shape.**  ``row_tile`` picks ``tm``; a shape it has no
 tile for (a side that 128 does not divide, matrices over the budget) takes
 ``jax.lax.ragged_dot``, which is also what the toy sizes of the tests take.
+The transposed product has a budget of its own, ``VMEM_BUDGET_T``: it holds
+the whole result with a float32 accumulator of its size, 33.5 MiB at the
+widest experts a model here has (8 groups of 2,048 x 1,792), which the
+compiler takes under the call's ``vmem_limit_bytes`` and which runs faster
+than the same product cut into column passes.
 Off the TPU the kernels run in the Pallas interpreter.
 """
 
@@ -72,8 +77,22 @@ _LANES = 128
 # groups of 2,048 x 512 over 24,576 rows (PR 41: the sweep over 128, 256 and
 # 512; value / d_lhs / d_rhs, ms a call on the host clock): 0.534 / 0.546 /
 # 0.585 at 128, 0.500 / 0.524 / 0.584 at 256, 0.527 / 0.580 / 0.640 at 512:
-# 256 stays
+# 256 stays.  At 8 groups of 2,048 x 1,792 over 24,576 rows (PR 43: the sweep
+# over 128 and 256; ms a call on the host clock, gate/up then down): value
+# 1.135 / 1.094 at 256 against 1.094 / 1.100 at 128 (within 4%, either way
+# round); d_lhs 1.079 / 1.079 against 1.093 / 1.095; d_rhs 1.127 / 1.135
+# against 1.138 / 1.147: 256 stays there too (XLA's ragged_dot: 2.23 / 1.95,
+# 2.48 / 3.03, 2.46 / 2.79)
 ROW_TILES = (256, 128)
+# what a visit of the transposed product may hold by vmem_bytes' reckoning:
+# the result matrix twice and a float32 accumulator of its size come to 33.5
+# MiB at 2,048 x 1,792 with tm 256 (33.75 at 1,792 x 2,048), and Mosaic
+# takes that under the call's vmem_limit_bytes, 48 MiB (PR 43, on the chip).
+# Held whole the product reads 1.127 / 1.135 ms a call; in two column passes
+# of 896 under VMEM_BUDGET, each reading the rows again, 1.197 / 1.177 (the
+# same sweep): the grid gets a column axis when a shape comes that Mosaic
+# refuses whole
+VMEM_BUDGET_T = 36 << 20
 
 
 def vmem_bytes(tm: int, k: int, n: int, lhs_itemsize: int,
@@ -96,9 +115,9 @@ def row_tile(m: int, k: int, n: int, lhs_itemsize: int, rhs_itemsize: int,
              out_itemsize: int, transposed: bool = False):
     """The row tile ``tm`` for ``m`` rows against ``k x n`` matrices: the
     largest of ``ROW_TILES`` that divides ``m`` and keeps ``vmem_bytes``
-    within ``VMEM_BUDGET``; None where ``k`` or ``n`` is not whole lane
-    tiles of 128 or no candidate fits (the caller then takes
-    ``jax.lax.ragged_dot``).
+    within ``VMEM_BUDGET`` (``VMEM_BUDGET_T`` for the ``transposed``
+    product); None where ``k`` or ``n`` is not whole lane tiles of 128 or no
+    candidate fits (the caller then takes ``jax.lax.ragged_dot``).
 
     The arithmetic at the routed cells' widths (``k x n`` = 2,048 x 768,
     bfloat16 in, float32 out, ``tm`` 256): 2 x (1 + 3 + 0.75) + 0.75 = 10.25
@@ -121,13 +140,24 @@ def row_tile(m: int, k: int, n: int, lhs_itemsize: int, rhs_itemsize: int,
     the peak.  Bytes and operations balance, neither hides the other whole,
     and the kernels read 45 to 52% of the peak alone and 61% in the cell's
     step (0.44 ms a call; PERF.md section 6, PR 41) where the 768-wide
-    experts' 16 groups read 75 and 84."""
+    experts' 16 groups read 75 and 84.
+
+    At the widest experts' (``k x n`` = 2,048 x 1,792, 8 groups, 24,576
+    rows, ``tm`` 256): 2 x (1 + 7 + 1.75) + 1.75 = 21.25 MiB; turned 2 x
+    (1.75 + 7 + 1) + 2 = 21.5 MiB; transposed 2 x (1 + 1.75 + 7) + 14 = 33.5
+    MiB (30.75 at ``tm`` 128): over ``VMEM_BUDGET`` and within
+    ``VMEM_BUDGET_T``.  A visit is 1.88 GFLOP, 9.5 us at the peak, and moves
+    2.75 MB of rows and result, 3.4 us; a group is 2,048 rows at even load,
+    eight row tiles for one fetch of its 7 MiB matrix, and the ``G - 1`` = 7
+    shared tiles make 103 visits of 96, +7%: a call is 180 GFLOP, 0.92 ms at
+    the peak, and reads 81 to 85% of it alone (1.08 to 1.14 ms; PERF.md
+    section 6, PR 43)."""
     if k % _LANES or n % _LANES:
         return None
+    budget = VMEM_BUDGET_T if transposed else VMEM_BUDGET
     for tm in ROW_TILES:
         if m % tm == 0 and vmem_bytes(tm, k, n, lhs_itemsize, rhs_itemsize,
-                                      out_itemsize, transposed) \
-                <= VMEM_BUDGET:
+                                      out_itemsize, transposed) <= budget:
             return tm
     return None
 

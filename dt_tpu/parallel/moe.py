@@ -1,31 +1,38 @@
-"""Mixture-of-experts layer with expert parallelism over the mesh.
+"""Mixture-of-experts layers: expert parallelism over the mesh.
 
 The reference caps out at data parallelism + manual model parallelism
 (``python/mxnet/module/executor_group.py:143`` group2ctx placement;
 SURVEY §2.3 parallelism inventory); this framework treats distributed
 execution as first-class, so the sharding family is completed with
-expert parallelism: experts shard over a mesh axis, and the
-dispatch/combine einsums carry GSPMD-inserted all_to_all-style
-collectives over ICI.
+expert parallelism.  Two layers.
 
-Switch-Transformer-style routing (Fedus et al. 2021, public recipe):
-top-1 gating, fixed expert capacity ``C = ceil(T/E * capacity_factor)``,
-overflow tokens dropped (their output is 0 and the residual path carries
-them), auxiliary load-balancing loss ``E * sum_e f_e * P_e``.  Everything
-is fixed-shape one-hot einsum dispatch — no sorting, no dynamic shapes,
-MXU-friendly.
+``RoutedExperts`` is the layer the models use (the sparse-expert decoders
+of ``models/routed_lm.py``): ``k > 1`` routing over the share of the
+experts one chip holds, the held assignments sorted into a static buffer,
+three grouped products over it (``ops/pallas/grouped.py``).  Scores are the
+softmax over all the router's outputs or, with ``scoring="sigmoid"``, each
+output's own sigmoid; the top ``k`` are renormalised to sum to one (plus
+``norm_eps``) and may carry a scale (``routed_scale``); a shared expert
+that every token visits (``shared_intermediate``) is added unweighted and
+is every chip's alike.  Balance is either the Switch auxiliary term
+(``aux_weight``) or, with ``selection_bias``, a bias on the scores **for
+the selection only** (arXiv:2408.15664; arXiv:2412.19437 section 2.1.2):
+``S = top_k(s + bias)`` while the weights are gathered from ``s``; the bias
+is no parameter (a variable of the ``batch_stats`` collection that
+``training.Module`` carries through ``fit``, checkpoints and a joiner's
+bootstrap; read under ``stop_gradient``, so no gradient reaches it and the
+optimizer holds nothing for it), and a training step moves it against the
+load its own selection made: ``bias_e += u sign(mean_e(load) - load_e)``.
 
-Usage: plain module on one device; for EP give ``mesh`` + ``axis`` and
-the expert dimension of the weights and the dispatched activations is
-sharding-constrained to that axis.
-
-Beside it, ``RoutedExperts``: ``k > 1`` routing over the share of the
-experts one chip holds, for the sparse-expert decoders of
-``models/routed_lm.py``.  Scores are the softmax over all the router's
-outputs or, with ``scoring="sigmoid"``, each output's own sigmoid; the
-top ``k`` are renormalised to sum to one and may carry a scale
-(``routed_scale``); a shared expert that every token visits
-(``shared_intermediate``) is added unweighted and is every chip's alike.
+``MoEMLP`` is the small top-1 layer of the sharding examples and tests:
+Switch-Transformer-style routing (Fedus et al. 2021, public recipe), fixed
+expert capacity ``C = ceil(T/E * capacity_factor)``, overflow tokens
+dropped (their output is 0 and the residual path carries them), auxiliary
+load-balancing loss ``E * sum_e f_e * P_e``; fixed-shape one-hot einsum
+dispatch, no sorting.  A plain module on one device; with ``mesh`` +
+``axis`` the expert dimension of the weights and the dispatched
+activations is sharding-constrained to that axis, and the dispatch/combine
+einsums carry GSPMD-inserted all_to_all-style collectives over ICI.
 """
 
 from __future__ import annotations
@@ -141,16 +148,26 @@ class MoEMLP(linen.Module):
 #: all the row made (``S x k``)
 COUNTER_TAIL = ("held", "overflow", "assignments")
 
+#: the columns of the second ``counters`` row a layer with a selection bias
+#: sows (``("counters", "moe_bias")``) for each row of the batch: the
+#: ``(token, slot)`` picks that ``top_k(scores)`` alone would not have made,
+#: and all the row made
+BIAS_COUNTERS = ("moved", "assignments")
 
-def route_top_k(logits: Array, k: int, scoring: str = "softmax"):
+
+def route_top_k(logits: Array, k: int, scoring: str = "softmax",
+                bias: Optional[Array] = None, norm_eps: float = 0.0):
     """``logits`` (T, E) float32 -> (experts (T, k) int32, weights (T, k),
     probs (T, E)): the scores over all ``E``, their ``k`` largest, and
-    those weights renormalised to sum to one a token.  ``scoring``
+    those weights renormalised to sum to one a token (``norm_eps`` added to
+    the sum where it is not zero).  ``scoring``
     ``"softmax"``: the scores are the softmax, and ``probs`` are they;
     ``"sigmoid"``: each expert's score is the sigmoid of its own logit (they
     do not compete before the top-k), and ``probs``, what the
     load-balancing term reads, are the scores normalised to sum to one a
-    token."""
+    token.  ``bias`` (E,): the ``k`` experts are those of largest ``scores +
+    bias``, and the weights are their *unbiased* scores: the bias decides
+    who is chosen and never how much a chosen expert counts."""
     logits = logits.astype(jnp.float32)
     if scoring == "softmax":
         scores = probs = jax.nn.softmax(logits, axis=-1)
@@ -159,9 +176,24 @@ def route_top_k(logits: Array, k: int, scoring: str = "softmax"):
         probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
     else:
         raise ValueError(f"no scoring {scoring!r}")
-    weights, experts = jax.lax.top_k(scores, k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    weights = weights / (total + norm_eps if norm_eps else total)
     return experts.astype(jnp.int32), weights, probs
+
+
+def moved_bias(bias: Array, experts: Array, speed: float) -> Array:
+    """The selection bias after a step whose selection was ``experts`` (T,
+    k), over all ``E = len(bias)`` router outputs: ``bias_e + speed *
+    sign(mean_e(load) - load_e)`` with ``load_e`` the assignments to expert
+    ``e`` (arXiv:2408.15664's rule): an expert under the mean load is
+    easier to choose next step, one over it harder, one at it unmoved."""
+    load = jnp.zeros(bias.shape, jnp.float32).at[experts.reshape(-1)].add(1.0)
+    return bias + speed * jnp.sign(jnp.mean(load) - load)
 
 
 def load_balancing_term(experts: Array, probs: Array) -> Array:
@@ -201,7 +233,7 @@ class RoutedExperts(linen.Module):
         w_e = p_e / sum_S p
         y = sum_{e in S, e held} w_e * Wdown_e(silu(x Wgate_e) * (x Wup_e))
 
-    Three switches, whose defaults leave that as it is.  ``scoring``
+    Five switches, whose defaults leave that as it is.  ``scoring``
     ``"sigmoid"``: ``p = sigmoid_f32(x Wr)``, each expert scored alone
     (``route_top_k``; the load-balancing term then reads ``p / sum_E p``).
     ``routed_scale``: ``w_e = routed_scale * p_e / sum_S p``, a scale on the
@@ -210,7 +242,27 @@ class RoutedExperts(linen.Module):
     a plain gated-SiLU feed-forward (modules ``shared_gate``, ``shared_up``,
     ``shared_down`` under ``jax.named_scope("shared")``), unweighted and
     computed by every chip alike: summing the shares of a layer over the
-    chips counts it once.
+    chips counts it once.  ``norm_eps``: ``w_e = p_e / (sum_S p +
+    norm_eps)``.  ``selection_bias``: ``S = top_k(p + bias)`` with the
+    weights still gathered from ``p``; ``bias`` (``num_experts``,) float32
+    is the variable ``selection_bias`` of the collection ``batch_stats``
+    (zeros from ``init``; ``training.Module`` carries the collection in its
+    ``TrainState`` beside the parameters), read under ``stop_gradient``.
+    Where that collection is mutable and the layer is not being
+    initialised, which is a training step under ``Module`` (evaluation
+    applies the model without it), the layer writes ``moved_bias``: the
+    bias it selected with, moved by ``bias_update_speed`` against the load
+    its own selection made over all ``num_experts`` outputs and all the
+    tokens (not the held experts' alone: every chip that shares the layer
+    moves the same bias alike).  The step's selection used the bias from
+    before the step.  The write is the forward pass's: a rematerialised
+    block's second forward computes it again and nobody reads that copy
+    (once a step; under ``Module(grad_accum=n)`` once a micro-batch, each
+    selecting with the bias the one before it left, as batch-norm's
+    statistics chain).  Such a layer also sows ``("counters",
+    "moe_bias")``, for each row of the batch the picks the bias moved (the
+    chosen experts that ``top_k(p)`` would not have chosen) and all it
+    made (``BIAS_COUNTERS``).
 
     What the experts that are not held would have added is left out: on one
     chip the layer runs without its exchange, and no code stands in for the
@@ -246,6 +298,9 @@ class RoutedExperts(linen.Module):
     scoring: str = "softmax"              # or 'sigmoid'
     routed_scale: float = 1.0
     shared_intermediate: Optional[int] = None
+    norm_eps: float = 0.0
+    selection_bias: bool = False
+    bias_update_speed: float = 0.001
 
     @linen.compact
     def __call__(self, x: Array) -> Array:
@@ -266,7 +321,11 @@ class RoutedExperts(linen.Module):
         with jax.named_scope("route"):
             logits = jnp.dot(tokens.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            experts, weights, probs = route_top_k(logits, k, self.scoring)
+            if self.selection_bias:
+                experts, weights, probs = self._route_biased(logits, b)
+            else:
+                experts, weights, probs = route_top_k(
+                    logits, k, self.scoring, norm_eps=self.norm_eps)
             if self.routed_scale != 1.0:
                 weights = weights * self.routed_scale
             order, sizes, _ = sort_held(experts, first, count, rows)
@@ -311,6 +370,28 @@ class RoutedExperts(linen.Module):
                     * checkpoint_name(dense(width, "shared_up")(x),
                                       "shared_up"))
         return y
+
+    def _route_biased(self, logits, b):
+        """``route_top_k`` under the layer's selection bias; the bias moved
+        where the step may write it, and the picks it moved counted."""
+        k = self.top_k
+        held = self.variable("batch_stats", "selection_bias", jnp.zeros,
+                             (self.num_experts,), jnp.float32)
+        bias = jax.lax.stop_gradient(held.value)
+        experts, weights, probs = route_top_k(
+            logits, k, self.scoring, bias, self.norm_eps)
+        if not self.is_initializing() \
+                and self.is_mutable_collection("batch_stats"):
+            held.value = moved_bias(bias, experts, self.bias_update_speed)
+        # a second top-k and a T x k x k compare, for the counter alone: 1.1
+        # ms a layer less on the chip than a gather of the chosen experts'
+        # scores held against the k-th largest (PERF.md section 6, PR 43)
+        plain, _, _ = route_top_k(logits, k, self.scoring)
+        moved = ~jnp.any(experts[:, :, None] == plain[:, None, :], axis=-1)
+        self.sow("counters", "moe_bias", jnp.stack(
+            [jnp.sum(moved.reshape(b, -1), axis=1, dtype=jnp.int32),
+             jnp.full((b,), moved.size // b, jnp.int32)], axis=1))
+        return experts, weights, probs
 
     def _count(self, experts, first, count, order, placed, b, per_row):
         """Sow the layer's counters: per row of the batch, its assignments

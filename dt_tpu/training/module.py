@@ -396,7 +396,13 @@ class Module:
             activation memory drops by ~accum x (each microbatch's
             activations die before the next starts); BN stats chain
             through the microbatches exactly as they would through
-            sequential steps."""
+            sequential steps, and so does whatever else a model keeps in
+            ``batch_stats`` (a routed layer's selection bias,
+            ``parallel/moe.py``: written by the forward pass, so once a
+            step, or once a micro-batch here, each selecting with the
+            bias the one before it left; never twice under a block's
+            rematerialisation, whose second forward's write nobody
+            reads)."""
             if accum <= 1:
                 (loss, (out, new_stats)), grads = jax.value_and_grad(
                     forward_loss, has_aux=True)(params, batch_stats,

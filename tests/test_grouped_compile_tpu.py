@@ -35,6 +35,10 @@ SHAPES = [
     # the mixed-attention cell's: 32 experts held, of width 512 (PR 41)
     (24576, 2048, 512, 32),
     (24576, 512, 2048, 32),
+    # the widest experts': 8 held, of width 1,792: the transposed product
+    # holds 33.5 MiB by vmem_bytes' reckoning, under its own budget (PR 43)
+    (24576, 2048, 1792, 8),
+    (24576, 1792, 2048, 8),
 ]
 
 
@@ -79,4 +83,4 @@ def test_the_derived_tiles_are_within_the_budget(m, k, n, groups):
         tm = grouped.row_tile(*args, transposed=transposed)
         assert tm in grouped.ROW_TILES and m % tm == 0
         assert grouped.vmem_bytes(tm, *args[1:], transposed=transposed) \
-            <= grouped.VMEM_BUDGET
+            <= (grouped.VMEM_BUDGET_T if transposed else grouped.VMEM_BUDGET)

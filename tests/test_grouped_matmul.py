@@ -121,6 +121,83 @@ def test_matrices_over_the_budget_have_no_tile():
     assert grouped.row_tile(384, 2048, 768, 2, 2, 4) == 128
 
 
+@pytest.mark.parametrize("m,k,n,g", [
+    (49152, 2048, 768, 16), (24576, 2048, 768, 16), (24576, 768, 2048, 16),
+    (24576, 2048, 512, 32), (24576, 512, 2048, 32)])
+def test_the_accepted_cells_keep_their_tiles(m, k, n, g):
+    """The transposed product's own budget moves no accepted cell: ``tm``
+    256 for all three products at 16 groups of 2,048 x 768 and 32 of 2,048 x
+    512, as before it."""
+    lhs = jax.ShapeDtypeStruct((m, k), jnp.bfloat16)
+    rhs = jax.ShapeDtypeStruct((g, k, n), jnp.bfloat16)
+    assert grouped._tiles(lhs, rhs, jnp.float32) == (256,) * 3
+
+
+def test_the_widest_experts_hold_the_transposed_product_whole():
+    """8 groups of 2,048 x 1,792 over 24,576 rows: the value and the turned
+    product are within ``VMEM_BUDGET`` (21.25 and 21.5 MiB), the transposed
+    one (33.5 MiB; 33.75 for the down projection's 1,792 x 2,048) over it
+    and within its own ``VMEM_BUDGET_T``, which stays under the limit the
+    compiler is given."""
+    mib = 2 ** 20
+    assert grouped.vmem_bytes(256, 2048, 1792, 2, 2, 4) == 21.25 * mib
+    assert grouped.vmem_bytes(256, 1792, 2048, 4, 2, 2) == 21.5 * mib
+    assert grouped.vmem_bytes(256, 2048, 1792, 2, 4, 2, True) == 33.5 * mib
+    assert grouped.vmem_bytes(256, 1792, 2048, 2, 4, 2, True) == 33.75 * mib
+    assert grouped.vmem_bytes(128, 2048, 1792, 2, 4, 2, True) == 30.75 * mib
+    assert grouped.VMEM_BUDGET < 30.75 * mib
+    assert 33.75 * mib <= grouped.VMEM_BUDGET_T < 2 * grouped.VMEM_BUDGET
+    assert grouped.row_tile(24576, 2048, 1792, 2, 4, 2, True) == 256
+    # a product that is not transposed keeps VMEM_BUDGET: 32.6 MiB at 128
+    assert grouped.row_tile(24576, 4096, 1792, 2, 2, 4) is None
+    shape = jax.ShapeDtypeStruct
+    for k, n in ((2048, 1792), (1792, 2048)):
+        assert grouped._tiles(shape((24576, k), jnp.bfloat16),
+                              shape((8, k, n), jnp.bfloat16),
+                              jnp.float32) == (256,) * 3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["skewed", "an-empty-group-in-the-middle",
+                                  "rows-past-the-last-group",
+                                  "every-boundary-inside-one-tile"])
+def test_a_matrix_over_the_budget_transposed_only_keeps_the_kernels(
+        monkeypatch, case, dtype):
+    """Budgets set for the test's shape so that the value and the turned
+    product fit ``VMEM_BUDGET`` and the transposed one only its own: all
+    three run as kernels and give what ``ragged_dot`` and a loop over the
+    groups give; without the second budget the call takes ``ragged_dot``."""
+    k, n = 256, 512
+    lhs, rhs, d_out = _operands(dtype, M, k, n)
+    a = lhs.dtype.itemsize
+    whole = [grouped.vmem_bytes(TM, k, n, a, a, 4),
+             grouped.vmem_bytes(TM, n, k, 4, a, a),
+             grouped.vmem_bytes(TM, k, n, a, 4, a, True)]
+    assert max(whole[:2]) < whole[2]
+    monkeypatch.setattr(grouped, "VMEM_BUDGET", max(whole[:2]))
+    monkeypatch.setattr(grouped, "VMEM_BUDGET_T", max(whole[:2]))
+    assert grouped._tiles(lhs, rhs, jnp.float32) is None
+    monkeypatch.setattr(grouped, "VMEM_BUDGET_T", whole[2])
+    assert grouped._tiles(lhs, rhs, jnp.float32) == (TM, TM, TM)
+    sizes = jnp.asarray(SIZES[case], jnp.int32)
+    run = _value_and_gradients(grouped.grouped_matmul)
+    assert str(jax.make_jaxpr(run)(lhs, rhs, sizes, d_out)).count(
+        "pallas_call") == 3
+    got = run(lhs, rhs, sizes, d_out)
+    value, gradients = (1e-5, 1e-5) if dtype == jnp.float32 else (1e-5, 1e-2)
+    for name, want in (("ragged_dot", _XLA(lhs, rhs, sizes, d_out)),
+                       ("loop", _loop(lhs, rhs, SIZES[case], d_out))):
+        for what, a, b, limit in zip(("value", "d_lhs", "d_rhs"), got, want,
+                                     (value, gradients, gradients)):
+            assert _gap(a, b) < limit, (name, what)
+    if case == "an-empty-group-in-the-middle":
+        assert not np.asarray(got[2], np.float32)[1].any()
+    if case == "rows-past-the-last-group":
+        assert not np.asarray(got[0])[200:].any()
+        assert not np.asarray(got[1], np.float32)[200:].any()
+
+
 def test_the_visit_tables_by_hand():
     """512 rows in tiles of 128, four groups: seven visits."""
     tables = lambda sizes, **kw: [a.tolist() for a in grouped.visit_tables(  # noqa: E731

@@ -275,37 +275,59 @@ def _routed(x, blk, held, buffer_rows=None, aux_weight=0.0, **switches):
                               aux_weight=aux_weight, **switches)
     first, count = held
     params = {"router": blk["router"]}
+    if switches.get("selection_bias"):      # state, read and not written
+        state = {"batch_stats": {"selection_bias": blk["bias"]}}
+        return layer.apply({"params": _share(params, blk, first, count),
+                            **state}, x, mutable=["aux_loss", "counters"])
     if switches.get("shared_intermediate"):
         params.update({n: {"kernel": blk[n]} for n in (
             "shared_gate", "shared_up", "shared_down")})
+    return layer.apply({"params": _share(params, blk, first, count)}, x,
+                       mutable=["aux_loss", "counters"])
+
+
+def _share(params, blk, first, count):
     for name in ("gate", "up", "down"):     # a whole layer's: this share's
         params[name] = blk[name] if len(blk[name]) == count else \
             blk[name][first:first + count]
-    return layer.apply({"params": params}, x,
-                       mutable=["aux_loss", "counters"])
+    return params
 
 
 #: what a configuration's layer adds to softmax scores over routed experts
 #: alone (``RoutedExperts``' switches), and which of the reference's layers
 #: is a routed one
-LAYER_SWITCHES = {"laguna-xs.2": dict(
-    scoring="sigmoid", routed_scale=2.5, shared_intermediate=24)}
-LAYER_EXTRA = {"laguna-xs.2": dict(
-    shared_expert_intermediate_size=24, intermediate_size=48,
-    layer_types=["full_attention", "sliding_attention"],
-    mlp_layer_types=["dense", "sparse"],
-    num_attention_heads_per_layer=[4, 4])}
+LAYER_SWITCHES = {
+    "laguna-xs.2": dict(scoring="sigmoid", routed_scale=2.5,
+                        shared_intermediate=24),
+    "lfm2-8b-a1b": dict(scoring="sigmoid", selection_bias=True,
+                        norm_eps=1e-6)}
+LAYER_EXTRA = {
+    "laguna-xs.2": dict(
+        shared_expert_intermediate_size=24, intermediate_size=48,
+        layer_types=["full_attention", "sliding_attention"],
+        mlp_layer_types=["dense", "sparse"],
+        num_attention_heads_per_layer=[4, 4]),
+    # a bias of the scores' own spread: it moves a share of the picks
+    "lfm2-8b-a1b": dict(
+        intermediate_size=48, layer_types=["conv", "full_attention"],
+        num_dense_layers=1, expert_bias_initial_std=0.2)}
+#: over how many chips a configuration shares a layer's 16 experts
+CHIPS = {"lfm2-8b-a1b": 4}
 
 
 @pytest.mark.parametrize("config", ["sdar-30b-a3b-chat",
-                                    "keye-vl-2.0-30b-a3b", "laguna-xs.2"])
+                                    "keye-vl-2.0-30b-a3b", "laguna-xs.2",
+                                    "lfm2-8b-a1b"])
 def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
-    """16 experts over 8 chips, 2 each: each share computed by the routed
-    layer, with the router whole; together the uncut reference's layer, by
-    the plain reference of each configuration that is cut this way.  What
-    every chip computes alike (a shared expert) is counted once."""
+    """16 experts over 8 chips, 2 each (over 4 chips, 4 each, where the
+    configuration is cut that way): each share computed by the routed
+    layer, with the router (and the selection bias, where there is one)
+    whole; together the uncut reference's layer, by the plain reference of
+    each configuration that is cut this way.  What every chip computes
+    alike (a shared expert) is counted once."""
     ref, base = REF, LAYER
     switches = LAYER_SWITCHES.get(config, {})
+    chips = CHIPS.get(config, 8)
     if config != "sdar-30b-a3b-chat":
         ref = _load_reference(config)
         with open(os.path.join(BENCH, "configs", config + ".json")) as f:
@@ -320,8 +342,18 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
     whole = {**base, "num_experts": 16, "held_experts_first": 0}
     x = jax.random.normal(jax.random.PRNGKey(0), (BATCH, 2 * SEQ, 32))
     blk = ref.init(jax.random.PRNGKey(1), whole)["blocks"][-1]
-    want, _ = ref.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
-    shares = [_routed(x, blk, (2 * i, 2), **switches) for i in range(8)]
+    if switches.get("selection_bias"):
+        want, _ = ref.experts(x.reshape(-1, 32), blk, blk["bias"], whole,
+                              lambda a: a)
+        # the bias moved some of the picks: its shares are another layer's
+        plain = sum(_routed(x, blk, (4 * i, 4), **{
+            **switches, "selection_bias": False})[0] for i in range(4))
+        assert float(jnp.max(jnp.abs(plain.reshape(-1, 32) - want))) > 1e-4
+    else:
+        want, _ = ref.experts(x.reshape(-1, 32), blk, whole, lambda a: a)
+    each = 16 // chips
+    shares = [_routed(x, blk, (each * i, each), **switches)
+              for i in range(chips)]
     total = sum(out for out, _ in shares)
     if switches.get("shared_intermediate"):
         # seven of the eight copies of what every chip computes alike
@@ -334,7 +366,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer(config):
     # no share is the whole, and every assignment is in exactly one share
     assert float(jnp.max(jnp.abs(shares[0][0] - total))) > 1e-4
     counted = sum(np.asarray(m["counters"]["moe"][0]) for _, m in shares)
-    assert (counted[:, -3] == counted[:, -1] // 8).all()      # held: S x k
+    assert (counted[:, -3] == counted[:, -1] // chips).all()  # held: S x k
     assert (counted[:, -2] == 0).all()
 
 
@@ -608,9 +640,12 @@ def _a_block_keeps_its_list(d=32, i=24):
         # has none and keeps nothing for them (tests/test_sparse_attention)
         "dsa_selection": {}, "indexer_kl_grads": {},
         # and of a layer under a band (tests/test_mixed_attention.py)
-        "flash_win_out": {}, "flash_win_lse": {}}
+        "flash_win_out": {}, "flash_win_lse": {},
+        # and of a layer whose mixer is a gated short convolution
+        # (tests/test_short_conv_lm.py)
+        "conv_in_proj": {}}
     formula = {"dsa_selection": 0, "indexer_kl_grads": 0, "flash_win_out": 0,
-               "flash_win_lse": 0,
+               "flash_win_lse": 0, "conv_in_proj": 0,
                "flash_out": t * h * hd * 4, "flash_lse": t * h * 4,
                "attn_out": t * d * 4, "attn_qkv": t * (h + 2 * 2) * hd * 4,
                "moe_route": t * k * 4 + 2 * r * 4 + count * 4 + 2 * r * 4,

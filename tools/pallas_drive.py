@@ -21,6 +21,7 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only grouped_mm_tiles  # routed layer's
         python tools/pallas_drive.py --only flash_win_tiles  # under a band
         python tools/pallas_drive.py --only grouped_mm_tiles_32  # 32 x 512
+        python tools/pallas_drive.py --only grouped_mm_tiles_8  # 8 x 1,792
         python tools/pallas_drive.py --only ssd_scan  # Mamba-2 scan, by hb
         python tools/pallas_drive.py --only flash_edge_walk  # crossed tiles
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
@@ -350,6 +351,8 @@ GROUPED_CELL_SHAPES = [(49152, 2048, 768), (24576, 2048, 768),
 GROUPED_SWEEP_TILES = (256, 512, 1024)
 # the third routed cell's buffer (laguna-xs2...): 32 experts held, of 512
 GROUPED_32_SHAPES = [(24576, 2048, 512), (24576, 512, 2048)]
+# the fourth routed cell's buffer (lfm2-8b-a1b...): 8 experts held, of 1,792
+GROUPED_8_SHAPES = [(24576, 2048, 1792), (24576, 1792, 2048)]
 
 
 def grouped_loads(rng, m, groups):
@@ -678,6 +681,16 @@ def main():
                                               groups=4 if args.small else 32,
                                               iters=args.iters,
                                               tiles=(128, 256, 512)):
+                print(json.dumps(rec), flush=True)
+
+    # ---- the grouped products at 8 groups of 2,048 x 1,792 (PR 43) -------
+    if wanted("grouped_mm_tiles_8"):
+        for m, k, n in ([(512, 128, 256)] if args.small else
+                        GROUPED_8_SHAPES):
+            for rec in grouped_mm_tiles_sweep(rng, m, k, n, dt,
+                                              groups=4 if args.small else 8,
+                                              iters=args.iters,
+                                              tiles=(128, 256)):
                 print(json.dumps(rec), flush=True)
 
     # ---- the Mamba-2 scan, forward and backward, by head block (PR 40) ---
