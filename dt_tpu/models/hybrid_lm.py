@@ -95,12 +95,11 @@ class GroupedQueryAttention(linen.Module):
         k = dense(self.num_kv_heads * self.head_dim, "k_proj")(x)
         v = dense(self.num_kv_heads * self.head_dim, "v_proj")(x)
         q = q.reshape(b, s, self.num_heads, self.head_dim)
-        rep = self.num_heads // self.num_kv_heads
-        # the kernel takes one head count: each key-value head is repeated
-        # for the query heads it serves (its gradient sums over them)
-        k, v = (jnp.repeat(t.reshape(b, s, self.num_kv_heads, self.head_dim),
-                           rep, axis=2) for t in (k, v))
+        k, v = (t.reshape(b, s, self.num_kv_heads, self.head_dim)
+                for t in (k, v))
         if self.attention == "flash":
+            # k and v keep their heads: the kernels' index maps read the
+            # head that serves a query head
             from dt_tpu.ops.pallas.attention import (flash_attention,
                                                      DEFAULT_BLOCK)
             pad = (-s) % DEFAULT_BLOCK
@@ -111,6 +110,10 @@ class GroupedQueryAttention(linen.Module):
                                   scale=self.scale)[:, :s]
         else:
             from dt_tpu.parallel.ring_attention import full_attention
+            # the oracle takes one head count: each key-value head spread
+            # over the query heads it serves
+            k, v = (jnp.repeat(t, self.num_heads // self.num_kv_heads, axis=2)
+                    for t in (k, v))
             out = full_attention(q, k, v, causal=True, scale=self.scale)
         return checkpoint_name(
             dense(d, "o_proj")(out.reshape(b, s, -1)), "mixer_out")

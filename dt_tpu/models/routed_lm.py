@@ -340,19 +340,19 @@ class RotaryAttention(linen.Module):
                     freq, scale = self._frequencies()
                     q, k = (rope_part(t, positions, freq, scale)
                             for t in (q, k))
-            # the kernel takes one head count: each key-value head is repeated
-            # for the query heads it serves (its gradient sums over them)
-            k_all, v_all = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+            # k and v go on with their ``kv`` heads: the kernels' index maps
+            # read the head that serves a query head (and its gradient comes
+            # back summed over them); only ``_plain`` spreads them
             if self.indexer is not None:
-                out = self._sparse(x, q, k, k_all, v_all)
+                out = self._sparse(x, q, k, v)
             elif self.window is not None:
-                out = self._window(q, k_all, v_all)
+                out = self._window(q, k, v)
             elif mask is None:
-                out = self._causal(q, k_all, v_all)
+                out = self._causal(q, k, v)
             elif self.attention == "flash":
-                out = self._flash(q, k_all, v_all)
+                out = self._flash(q, k, v)
             else:
-                out = self._plain(q, k_all, v_all)
+                out = self._plain(q, k, v)
             if self.gate:
                 with jax.named_scope("gate"):
                     g = jax.nn.sigmoid(dense(h, "gate_proj")(x).astype(F32))
@@ -391,7 +391,7 @@ class RotaryAttention(linen.Module):
             (q.shape[0], len(WIN_COUNTERS))))
         return out
 
-    def _sparse(self, x, q, k, k_all, v_all):
+    def _sparse(self, x, q, k, v):
         """Attention over the keys the index picks, and the index's own
         term and counts."""
         b, s = x.shape[:2]
@@ -416,11 +416,11 @@ class RotaryAttention(linen.Module):
                 kv_chunk=ix.get("kv_chunk", 512)), "dsa_selection")
         if self.attention == "flash":
             out, lse = flash_attention(
-                q, k_all, v_all, causal=True, mask=SelectedKeysMask(),
+                q, k, v, causal=True, mask=SelectedKeysMask(),
                 selection=selection, return_lse=True)
         else:
             out, lse = self._plain(
-                q, k_all, v_all, unpack_selection(selection.by_query, s)
+                q, k, v, unpack_selection(selection.by_query, s)
                 & jnp.tril(jnp.ones((s, s), bool)), with_lse=True)
         with jax.named_scope("indexer_kl"):
             kl = sparse_index.indexer_kl(
@@ -474,10 +474,13 @@ class RotaryAttention(linen.Module):
         return out
 
     def _plain(self, q, k, v, allowed=None, with_lse=False):
-        """A dense masked softmax in float32: the kernels' oracle.
+        """A dense masked softmax in float32: the kernels' oracle, with
+        each key-value head spread over the query heads it serves.
         ``allowed`` (S, S) or (B, S, S), the block-diffusion rule's where
         None."""
         s = q.shape[1]
+        k, v = (jnp.repeat(t, q.shape[2] // t.shape[2], axis=2)
+                for t in (k, v))
         if allowed is None:
             pos = jnp.arange(s)
             allowed = self.mask.allowed(pos[:, None], pos[None, :])

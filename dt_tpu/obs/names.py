@@ -205,7 +205,9 @@ NAME_REGISTRY: Mapping[str, Tuple[str, str]] = {
                                           "buffer and were dropped from "
                                           "the step's result"),
     # set at trace time, once per distinct (s, sk, d, dtype), label `shape`,
-    # and under a mask rule label `mask` (ops/pallas/attention.py)
+    # under a mask rule label `mask`, and where the keys and values have
+    # fewer heads than the queries label `rep`, the query heads a key-value
+    # head (ops/pallas/attention.py)
     "flash.block_q": ("gauge", "query rows in one tile of the flash "
                                "forward, derived from the shape "
                                "(forward_tiles) or given"),

@@ -28,6 +28,20 @@ the diagonal passes through, the online softmax of those rows (36 of a
 tile" below).  ``flash_attention``'s ``block_q`` / ``block_k`` override
 the choice of tiles.
 
+Grouped-query attention needs no spread heads: ``k`` and ``v`` come with
+their own head count ``KV``, a divisor of the queries' ``H``, as (B * KV,
+SK, D) beside q's (B * H, S, D), and the key and value block of a grid step
+is fetched from row ``b // rep`` (``rep = H // KV``: ``_kv_row`` in both
+kernels' index maps, under every mask rule), so a group's ``rep`` query
+heads read one head's tiles from an array a ``rep``-th the size and no
+caller runs ``jnp.repeat`` (PERF.md section 6, PR 44: the spread was about
+1.1 ms a layer and pass of XLA over 268 MB arrays in the mixed-attention
+cell, and 0.6 GB of its step's temporaries).  The backward's dk
+and dv leave the kernel one a query head and are summed over each group in
+float32 outside it (``_sum_groups``): summed inside, a group's dq
+accumulators, each a whole sequence of float32, would all have to stay in
+VMEM.  With ``KV == H`` the index maps and the programs are what they were.
+
 Backward is the standard flash backward from the saved log-sum-exp, one
 Pallas call named ``flash_bwd`` (its events carry that name in a trace,
 where the forward's carry its caller's): grid (batch*heads, k_tiles,
@@ -1027,6 +1041,14 @@ def _attn_kernel_sel(fate_ref, q_ref, k_ref, v_ref, words_ref, *rest,
     _attn_kernel(q_ref, k_ref, v_ref, *rest, sel=(fate, words_ref), **kw)
 
 
+def _kv_row(b, rep: int):
+    """The key-value row that query row ``b`` of (B * H) reads where a
+    key-value head serves ``rep`` consecutive query heads: row ``b // rep``
+    of (B * KV).  At ``rep`` 1 the index as it came, so that a call without
+    groups lowers to what it did."""
+    return b if rep == 1 else b // rep
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
@@ -1036,7 +1058,9 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     ``selection`` is the ``Selection`` a ``SelectedKeysMask`` reads.
     ``sub`` is the side of a crossed tile's sub-blocks (None:
     ``SUB_BLOCK``; 0: crossed tiles computed whole), for the sweep and the
-    tests: no caller in the package gives it.
+    tests: no caller in the package gives it.  ``k3`` / ``v3`` may hold
+    fewer rows than ``q3``, (B * KV, SK, D) for (B * H, S, D): query row
+    ``b`` then reads key-value row ``b // rep``, ``rep = H // KV``.
 
     Jitted and inlined: a model's layers share one trace of the kernel's
     body (Pallas traces it anew for every call otherwise, 24 times a
@@ -1051,8 +1075,9 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
                                None if selected else mask)
         block_q, block_k = block_q or dq, block_k or dk
     sub = SUB_BLOCK if sub is None else sub
+    rep = bh // k3.shape[0]
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask,
-                causal=causal, sub=sub)
+                causal=causal, sub=sub, rep=rep)
     n_q = s // block_q
     # the key axis' steps: under a band, the tiles it can touch
     n_k = mask.key_steps(s, block_q, block_k) if windowed else sk // block_k
@@ -1077,7 +1102,9 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
             ki, (qi * block_q + block_q - 1) // block_k)
     else:
         key_tile = lambda qi, ki: ki
-    kv_map = lambda b, qi, ki, *_: (b, key_tile(qi, ki), 0)
+    # the key-value head of query row b = batch * H + head: nothing spreads
+    # the heads in memory, a group's query heads fetch the same block
+    kv_map = lambda b, qi, ki, *_: (_kv_row(b, rep), key_tile(qi, ki), 0)
     q_map = lambda b, qi, ki, *_: (b, qi, 0)
     sub = block_q // _LANES
     in_specs = [
@@ -1133,7 +1160,8 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
 
 @functools.lru_cache(maxsize=None)
 def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
-                mask=None, causal: bool = False, sub: int = 0) -> None:
+                mask=None, causal: bool = False, sub: int = 0,
+                rep: int = 1) -> None:
     """Record, once per distinct shape, tile and mask rule, what the forward
     (or with ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
     flash_bwd_tiles``) debug line and the metrics plane's gauges, so that a
@@ -1143,7 +1171,10 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
     ``sub``, how many they are and the tile-equivalents computed in all
     (``computed_tiles``); the gauge ``flash.pairs_computed_pct``
     (``flash.bwd_pairs_computed_pct``) is that over the tiles that run, 100
-    where every tile is computed whole.  Trace time only."""
+    where every tile is computed whole.  Where the keys and values came
+    with fewer heads than the queries the line ends in ``rep=<query heads a
+    key-value head>`` and the gauges carry the label ``rep``: the index maps
+    read the group's head, nothing was spread.  Trace time only."""
     s, sk, d, dtype = shape
     if isinstance(mask, BlockDiffusionMask):
         rule = f"block_diffusion.half{mask.half}.block{mask.block}"
@@ -1163,13 +1194,16 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
             rule += f".crossed{crossed}.sub{round(computed, 4)}of{run}"
             pct = 100.0 * computed / run
     logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
-                 "block_k=%d%s", "bwd_" if bwd else "", s, sk, d, dtype,
-                 block_q, block_k, " mask=" + rule if rule else "")
+                 "block_k=%d%s%s", "bwd_" if bwd else "", s, sk, d, dtype,
+                 block_q, block_k, " mask=" + rule if rule else "",
+                 f" rep={rep}" if rep > 1 else "")
     if obs_metrics.enabled():
         reg = obs_metrics.registry()
         labels = {"shape": f"{s}x{sk}x{d}.{dtype}"}
         if rule:
             labels["mask"] = rule
+        if rep > 1:
+            labels["rep"] = str(rep)
         if bwd:
             reg.gauge("flash.bwd_block_q", block_q, labels)
             reg.gauge("flash.bwd_block_k", block_k, labels)
@@ -1372,7 +1406,9 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel``, ``flash_win_bwd``
     under a mask rule).
     Its tiles come from the shapes (``backward_tiles``); ``sub`` as the
-    forward's.
+    forward's.  With (B * KV, SK, D) k and v (the forward's ``rep``) dk and
+    dv still come out one a query row, (BH, SK, D): their sum over a group
+    is the caller's (``_sum_groups``).
 
     Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
     kernel's body for all of a model's layers."""
@@ -1384,8 +1420,9 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                                 None if selected else mask)
         block_q, block_k = block_q or tq, block_k or tk
     sub = SUB_BLOCK if sub is None else sub
+    rep = bh // k3.shape[0]
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
-                mask=mask, causal=causal, sub=sub)
+                mask=mask, causal=causal, sub=sub, rep=rep)
     windowed = isinstance(mask, WindowMask)
     q_tiles, n_k = s // block_q, sk // block_k
     # the query axis' steps: under a band, the tiles it can touch
@@ -1418,10 +1455,14 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                           lambda b, ki, qi, *_: (b, first(ki, qi), 0))
     row_spec = pl.BlockSpec((1, 1, block_q),
                             lambda b, ki, qi, *_: (b, 0, first(ki, qi)))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi, *_: (b, ki, 0))
+    # k and v come from the group's key-value row (the forward's kv_map);
+    # dk and dv go out one a query row
+    k_spec = pl.BlockSpec((1, block_k, d),
+                          lambda b, ki, qi, *_: (_kv_row(b, rep), ki, 0))
+    dk_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi, *_: (b, ki, 0))
     in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec, k_spec]
     out_specs = [pl.BlockSpec((1, s, d), lambda b, ki, qi, *_: (b, 0, 0)),
-                 k_spec, k_spec]
+                 dk_spec, dk_spec]
     scratch_shapes = [
         pltpu.VMEM((s, d), jnp.float32),
         pltpu.VMEM((block_k, d), jnp.float32),
@@ -1489,13 +1530,27 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     return out, (q3, k3, v3, out, lse)
 
 
+def _sum_groups(dx3, like):
+    """dk or dv as the backward kernel leaves it, one a query row (B * H, SK,
+    D), summed over each key-value head's group to ``like``'s (B * KV, SK,
+    D), in float32 (what the reduction that transposed ``jnp.repeat`` did).
+    Without groups ``dx3`` itself."""
+    rows = like.shape[0]
+    if dx3.shape[0] == rows:
+        return dx3
+    return dx3.reshape(rows, -1, *dx3.shape[1:]).sum(
+        axis=1, dtype=jnp.float32).astype(dx3.dtype)
+
+
 def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, mask, res,
                     do3):
     # the forward's tiles stop here: the backward derives its own from the
     # shapes (backward_tiles), whatever the forward was given
     q3, k3, v3, out, lse = res
-    return _flash_bwd_pallas(q3, k3, v3, out, lse, do3, scale=scale,
-                             causal=causal, interpret=interpret, mask=mask)
+    dq, dk, dv = _flash_bwd_pallas(q3, k3, v3, out, lse, do3, scale=scale,
+                                   causal=causal, interpret=interpret,
+                                   mask=mask)
+    return dq, _sum_groups(dk, k3), _sum_groups(dv, v3)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1529,7 +1584,7 @@ def _flash_sel_bwd_rule(scale, causal, block_q, block_k, interpret, res, cts):
     dq, dk, dv = _flash_bwd_pallas(
         q3, k3, v3, out, lse, cts[0], scale=scale, causal=causal,
         interpret=interpret, mask=SelectedKeysMask(), selection=selection)
-    return dq, dk, dv, None
+    return dq, _sum_groups(dk, k3), _sum_groups(dv, v3), None
 
 
 _flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
@@ -1543,6 +1598,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     mask=None, selection: Optional[Selection] = None,
                     return_lse: bool = False):
     """Fused attention, (B, S, H, D) layout (``full_attention`` oracle).
+
+    ``k`` and ``v`` are (B, SK, KV, D) with ``KV`` a divisor of ``H``
+    (grouped-query attention): key-value head ``j`` serves the query heads
+    ``j * rep .. (j + 1) * rep - 1``, ``rep = H // KV``, as
+    ``jnp.repeat(k, rep, axis=2)`` would lay them out, but nothing is
+    spread: both kernels' index maps fetch a grid step's key and value
+    block from head ``h // rep``, and the backward's dk, dv, one a query
+    head out of the kernel, are summed over each group in float32.  ``KV ==
+    H`` is plain multi-head attention, the program it always was.
 
     Sequence lengths must be multiples of ``DEFAULT_BLOCK`` (pad upstream;
     ``TransformerLM`` does).  ``block_q`` / ``block_k`` override the
@@ -1569,7 +1633,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     b, s, h, d = q.shape
-    sk = k.shape[1]
+    sk, kv = k.shape[1], k.shape[2]
+    if h % kv or v.shape != k.shape:
+        raise ValueError(f"{kv} key-value heads do not divide {h} query "
+                         f"heads, or k {k.shape} and v {v.shape} differ")
     selected = isinstance(mask, SelectedKeysMask)
     if selected != (selection is not None) or (return_lse and not selected):
         raise ValueError("a SelectedKeysMask and its selection go together, "
@@ -1597,7 +1664,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             raise ValueError(f"seq lengths ({s}, {sk}) must be multiples "
                              f"of blocks ({block_q}, {block_k}), and those "
                              f"of {_LANES}")
-    to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
+    # heads after the batch: row b * H + h of q, row b * KV + h // rep of k, v
+    to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, x.shape[1], d)
     if selected:
         out3, lse = _flash_sel(to3(q), to3(k), to3(v), selection, scale,
                                causal, block_q, block_k, interpret)

@@ -444,3 +444,120 @@ def test_the_walks_sums_against_counts_made_one_sub_block_at_a_time(
         assert computed * b * b >= pairs
         if sub == 128:
             assert (computed, run) == RECKONED[cell][which]
+
+
+# -- fewer key-value heads than query heads (PR 44) ---------------------------
+
+def _spread(t, heads):
+    """Each key-value head repeated for the query heads it serves."""
+    return jnp.repeat(t, heads // t.shape[2], axis=2)
+
+
+def _dense_spread(q, k, v, allowed):
+    """A dense masked softmax in float32 over spread heads; ``allowed``
+    (S, S)."""
+    k, v = _spread(k, q.shape[2]), _spread(v, q.shape[2])
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(allowed, s, attn.NEG_INF)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _grouped_inputs(seed, rep, d, dtype, b=2, s=256, kv=2):
+    """q of ``kv * rep`` heads, k and v of ``kv``, and a weight on the
+    output, as ``dtype`` and as the same values in float32."""
+    rng = np.random.RandomState(seed)
+    mk = lambda h: jnp.asarray(  # noqa: E731
+        rng.randn(b, s, h, d).astype(np.float32) * 0.5).astype(dtype)
+    args = (mk(kv * rep), mk(kv), mk(kv))
+    w = jnp.asarray(rng.randn(b, s, kv * rep, d).astype(np.float32))
+    return args, tuple(t.astype(jnp.float32) for t in args), w
+
+
+def _close(got, want, dtype, grad=False):
+    """The file's tolerances: float32 as the tile tests hold it, bfloat16
+    within steps of the largest value."""
+    want = np.asarray(want, np.float32)
+    if dtype == jnp.bfloat16:
+        steps = (4 if grad else 2) * 2.0 ** -8 * np.abs(want).max()
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=0, atol=steps)
+    else:
+        np.testing.assert_allclose(got, want, rtol=5e-4 if grad else 2e-4,
+                                   atol=5e-4 if grad else 2e-5)
+
+
+GROUPED_RULES = {
+    "causal": dict(causal=True),
+    "block_diffusion": dict(mask=attn.BlockDiffusionMask(128, 4)),
+    "window": dict(mask=attn.WindowMask(100)),
+}
+GROUPS = [(1, 128), (4, 64), (8, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rep,d", GROUPS)
+@pytest.mark.parametrize("rule", sorted(GROUPED_RULES))
+def test_grouped_heads_match_the_oracle_on_spread_heads(rule, rep, d, dtype):
+    """(B, S, KV, D) keys and values under each static rule: the output
+    and the three gradients are the oracle's on heads spread with
+    ``jnp.repeat``; dk and dv come back with ``KV`` heads, summed over
+    each group."""
+    kw = GROUPED_RULES[rule]
+    args, args32, w = _grouped_inputs(rep + d, rep, d, dtype)
+    flash = lambda q, k, v: flash_attention(q, k, v, **kw)  # noqa: E731
+    if rule == "causal":
+        ref = lambda q, k, v: full_attention(  # noqa: E731
+            q, _spread(k, q.shape[2]), _spread(v, q.shape[2]), causal=True)
+    else:
+        pos = np.arange(args[0].shape[1])
+        allowed = kw["mask"].allowed(pos[:, None], pos[None, :])
+        ref = lambda q, k, v: _dense_spread(q, k, v, allowed)  # noqa: E731
+    got = flash(*args)
+    assert got.dtype == dtype
+    _close(got, ref(*args32), dtype)
+    weighed = lambda f: lambda *a: (f(*a).astype(jnp.float32) * w).sum()  # noqa: E731
+    grads = jax.grad(weighed(flash), argnums=(0, 1, 2))(*args)
+    wants = jax.grad(weighed(ref), argnums=(0, 1, 2))(*args32)
+    for name, a, x, b in zip("qkv", grads, args, wants):
+        assert a.shape == x.shape and a.dtype == dtype, name
+        _close(a, b, dtype, grad=True)
+
+
+def test_key_value_heads_that_do_not_divide_the_query_heads_are_refused():
+    q, k = jnp.zeros((1, 128, 6, 64)), jnp.zeros((1, 128, 4, 64))
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q, k, k, causal=True)
+    with pytest.raises(ValueError, match="differ"):
+        flash_attention(q, k[:, :, :3], k[:, :, :2], causal=True)
+
+
+@pytest.mark.parametrize("prefix", ["", "bwd_"], ids=["forward", "backward"])
+def test_tile_notes_name_the_group(caplog, prefix):
+    """With grouped heads the debug line ends in ``rep=`` and the gauges
+    carry the label; without them both are what they were (the test
+    above)."""
+    import logging
+    from dt_tpu.obs import metrics as obs_metrics
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()
+        attn._flash_fwd_pallas.clear_cache()
+        attn._flash_bwd_pallas.clear_cache()
+        q, k = jnp.zeros((1, 256, 8, 64)), jnp.zeros((1, 256, 2, 64))
+        run = lambda q: flash_attention(q, k, k, causal=True).sum()  # noqa: E731
+        if prefix:
+            run = jax.grad(run)
+        with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
+            jax.eval_shape(run, q)
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith(f"# flash_{prefix}tiles")]
+        assert len(lines) == 1 and lines[0].endswith(" rep=4"), lines
+        labels = [lk for name, lk, _ in obs_metrics.registry().gauges_export()
+                  if name.startswith(f"flash.{prefix}")]
+        assert len(labels) == 3 and all(lk["rep"] == "4" for lk in labels)
+    finally:
+        obs_metrics.set_enabled(None)
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()

@@ -212,3 +212,44 @@ def test_mosaic_takes_the_walk_at_the_cells_shapes(one_chip, cell, bh, s, d,
         calls = [ln.split("=")[0] for ln in text.splitlines()
                  if "tpu_custom_call" in ln and "custom-call(" in ln]
         assert calls and all(name in c for c in calls), calls
+
+
+# with fewer key-value heads than query heads (PR 44): the index maps read
+# key-value row ``b // rep``; the five LM cells with grouped heads, by
+# (batch x query heads, batch x key-value heads)
+GROUPED = [
+    ("granite4hm", 2 * 32, 2 * 8, 4096, 64, None, True),
+    ("sdar30b", 2 * 32, 2 * 4, 8192, 128, attn.BlockDiffusionMask(4096, 4),
+     False),
+    ("laguna.win", 2 * 64, 2 * 8, 8192, 128, attn.WindowMask(512), True),
+    ("laguna.full", 2 * 48, 2 * 8, 8192, 128, None, True),
+    ("keye30b", 32, 4, 16384, 128, attn.SelectedKeysMask(), True),
+    ("lfm2", 32, 8, 16384, 64, None, True),
+]
+
+
+@pytest.mark.parametrize("cell,bh,bkv,s,d,rule,causal", GROUPED,
+                         ids=[c[0] for c in GROUPED])
+def test_mosaic_takes_grouped_heads_at_the_cells_shapes(one_chip, cell, bh,
+                                                        bkv, s, d, rule,
+                                                        causal):
+    """Both passes compile with (B * KV, S, D) keys and values under every
+    mask rule, and the backward's dk, dv come out one a query row."""
+    shape = lambda *a: jax.ShapeDtypeStruct(*a, sharding=one_chip)  # noqa: E731
+    x, kv = shape((bh, s, d), jnp.bfloat16), shape((bkv, s, d), jnp.bfloat16)
+    lse = shape((bh, s), jnp.float32)
+    sel = ()
+    if isinstance(rule, attn.SelectedKeysMask):
+        words = shape((1, -(-s // attn.SEL_GROUP), s, 128), jnp.int32)
+        sel = (attn.Selection(words, words, shape((1, s // 128, s // 128),
+                                                  jnp.bool_)),)
+    kw = dict(scale=d ** -0.5, causal=causal, interpret=False, mask=rule)
+    fwd = jax.jit(lambda q, k, v, *sel: attn._flash_fwd_pallas(
+        q, k, v, block_q=None, block_k=None, selection=(sel or (None,))[0],
+        **kw))
+    bwd = jax.jit(lambda q, k, v, o, lse, do, *sel: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, selection=(sel or (None,))[0], **kw))
+    assert "tpu_custom_call" in fwd.lower(x, kv, kv, *sel).compile().as_text()
+    compiled = bwd.lower(x, kv, kv, x, lse, x, *sel).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert [o.shape for o in compiled.out_info] == [(bh, s, d)] * 3

@@ -137,6 +137,32 @@ def test_reference_gradient_a_sequence_at_a_time_is_the_whole_batchs(seeded):
     assert _gap(grads, whole) < 1e-5
 
 
+def test_grouped_heads_reach_the_kernels_unspread():
+    """Eight query heads over two key-value heads under ``attention="flash"``
+    (PR 44): both kernels' key and value operands are ``(B * KV, S, D)``,
+    nothing computed from the key and value projections outside a kernel is
+    as large as a ``(B, S, H, D)`` array, and the layer's output and
+    gradients are the plain path's, which spreads the heads for the
+    oracle."""
+    b, s, h, kv, d = 2, 128, 8, 2, 8      # h * d over the layer's width
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, 32))
+    layer = lambda kind: hybrid_lm.GroupedQueryAttention(  # noqa: E731
+        num_heads=h, num_kv_heads=kv, head_dim=d, scale=d ** -0.5,
+        attention=kind)
+    variables = layer(None).init(jax.random.PRNGKey(1), x)
+    objective = lambda kind: lambda v, x: jnp.sum(  # noqa: E731
+        layer(kind).apply(v, x) ** 2)
+    remat_held.assert_keys_reach_the_kernels_unspread(
+        jax.grad(objective("flash"), argnums=(0, 1)), (variables, x), b, s, h, kv,
+        d)
+    got, want = (layer(kind).apply(variables, x) for kind in ("flash", None))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    grads = [jax.grad(objective(kind), argnums=(0, 1))(variables, x)
+             for kind in ("flash", None)]
+    for a, c in zip(*map(jax.tree_util.tree_leaves, grads)):
+        np.testing.assert_allclose(a, c, rtol=5e-4, atol=5e-5)
+
+
 def test_rematerialised_blocks_change_no_number_and_no_name():
     plain, remat = (models.create(
         "hybrid_lm", vocab_size=40, embed_dim=32, intermediate=48,

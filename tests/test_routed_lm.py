@@ -211,6 +211,31 @@ def test_a_length_that_needs_padding_to_the_tile():
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+def test_grouped_heads_reach_the_kernels_unspread():
+    """Eight query heads over two key-value heads under ``attention="flash"``
+    (PR 44): both kernels' key and value operands are ``(B * KV, S, D)``,
+    nothing computed from the key and value projections outside a kernel is
+    as large as a ``(B, S, H, D)`` array, and the layer's output and
+    gradients are the dense path's, which spreads the heads."""
+    b, s, h, kv, d = 2, 256, 8, 2, 16
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, 32))
+    kw = dict(num_heads=h, num_kv_heads=kv, head_dim=d,
+              mask=BlockDiffusionMask(s // 2, 4))
+    flash = routed_lm.RotaryAttention(attention="flash", **kw)
+    plain = routed_lm.RotaryAttention(attention=None, **kw)
+    variables = plain.init(jax.random.PRNGKey(1), x)
+    objective = lambda m: lambda v, x: jnp.sum(jnp.sin(m.apply(v, x)))  # noqa: E731
+    remat_held.assert_keys_reach_the_kernels_unspread(
+        jax.grad(objective(flash), argnums=(0, 1)), (variables, x), b, s, h, kv,
+        d)
+    np.testing.assert_allclose(flash.apply(variables, x),
+                               plain.apply(variables, x), atol=2e-5)
+    grad = lambda m: jax.grad(objective(m), argnums=(0, 1))(variables, x)  # noqa: E731
+    for a, c in zip(jax.tree_util.tree_leaves(grad(flash)),
+                    jax.tree_util.tree_leaves(grad(plain))):
+        np.testing.assert_allclose(a, c, atol=5e-5)
+
+
 def test_tile_notes_name_the_mask_rule(caplog):
     import logging
     from dt_tpu.obs import metrics as obs_metrics

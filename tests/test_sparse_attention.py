@@ -139,6 +139,50 @@ def test_the_walk_under_a_selection_beside_causal_matches_a_dense_softmax(
                               sub) is None
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rep,d", [(1, 128), (4, 64), (8, 128)])
+def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, dtype):
+    """(B, S, KV, D) keys and values under a selection beside ``causal``
+    (PR 44: the index maps read key-value head ``h // rep``, and the
+    bitmaps' own maps still divide by the query heads): output, log-sum-exp
+    and the three gradients are the dense softmax's on heads spread with
+    ``jnp.repeat``; float32 tight, bfloat16 within steps of the largest
+    value as ``tests/test_flash_attention.py`` holds it."""
+    t, kv = 256, 2
+    allowed = _top_keys(t, 48, True)
+    sel = pack_selection(allowed | (
+        _top_keys(t, 48, False, 1) & ~jnp.tril(jnp.ones((t, t), bool))))
+    keys = jax.random.split(jax.random.PRNGKey(rep + d), 4)
+    args = tuple((0.5 * jax.random.normal(key, (BATCH, t, h, d))).astype(
+        dtype) for key, h in zip(keys, (kv * rep, kv, kv)))
+    args32 = tuple(a.astype(jnp.float32) for a in args)
+    w = jax.random.normal(keys[3], args[0].shape)
+    flash = lambda *a: flash_attention(  # noqa: E731
+        *a, causal=True, mask=SelectedKeysMask(), selection=sel,
+        interpret=True, return_lse=True)
+    dense = lambda q, k, v: _dense(  # noqa: E731
+        q, *(jnp.repeat(x, rep, axis=2) for x in (k, v)), allowed,
+        with_lse=True)
+
+    def close(got, want, steps):
+        want = np.asarray(want, np.float32)
+        atol = steps * 2.0 ** -8 * np.abs(want).max() \
+            if dtype == jnp.bfloat16 else 5e-6
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=0, atol=atol)
+    (out, lse), (want, want_lse) = flash(*args), dense(*args32)
+    assert out.dtype == dtype and lse.shape == (BATCH, kv * rep, t)
+    close(out, want, 2)
+    close(lse, want_lse, 2)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a)[0].astype(jnp.float32) * w),
+                   (0, 1, 2))(*args)
+    ref = jax.grad(lambda *a: jnp.sum(dense(*a)[0] * w), (0, 1, 2))(*args32)
+    for a, x, b in zip(got, args, ref):
+        assert a.shape == x.shape and a.dtype == dtype
+        close(a, b, 4)
+
+
 def test_a_tile_without_a_selected_pair_computes_nothing():
     """The fate table is what the kernel goes by: with an entry struck out
     the tile's pairs are missing from the result, so the tile did not run;
