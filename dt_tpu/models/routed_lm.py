@@ -144,6 +144,25 @@ layer's attention (``block3/attn/window/...``, ``block0/attn/full/...``: no
 metric has to name a layer by its number), ``.../gate`` inside either, and
 ``block0/mlp`` for a dense layer.
 
+**Heads of whole lane tiles** (``head_dim % 128 == 0`` under
+``attention="flash"``: the three published decoders above; PERF.md section 6,
+PR 45; ``attention.lane_tiled`` is the one place that says so).  The layer
+then hands ``flash_attention`` q, k, v as the projections' ``(B, S, H * D)``
+arrays (rank 3, ``heads=H``), the kernels read them and write their output
+where those lie, and the layer's own work stays there too: the heads' norm,
+the rotary turn and the gate go over ``attention.by_head``'s view ``(B, S /
+8, H, 8, D)``, the same bytes under the TPU's (8, 128) tiling, where a ``(B,
+S, H, D)`` array is other bytes and every ``reshape`` to it a pass over the
+array.
+The turn is ``turn_heads``: each lane times its cosine plus its pair's lane
+times its signed sine, the pairs brought by a product with a permutation
+(``_pairs``; exact) and not by slices of the lanes, which XLA cuts out as
+arrays of their own; its backward pass is the turn by the opposite angle,
+written out (on the chip the step is 3.3% slower with that pass left to
+autodiff; the gate's, tried the same way, bought nothing and is autodiff's).
+Same arithmetic, float32 inside; at any other head size, and without the
+kernels, the layer holds ``(B, S, H, D)`` arrays as before.
+
 With ``remat`` each block is rematerialised: it keeps its input and the
 values named in ``SAVED`` (the flash kernel's output among them, so the
 kernel runs once a layer) and computes the rest again in the backward pass.
@@ -152,6 +171,7 @@ kernel runs once a layer) and computes the rest again in the backward pass.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from typing import Any, Optional
 
@@ -166,7 +186,9 @@ from dt_tpu.ops import sparse_index, ssm
 from dt_tpu.ops.pallas.attention import (BlockDiffusionMask, DEFAULT_BLOCK,
                                          NEG_INF, SelectedKeysMask,
                                          WindowMask, backward_tiles,
-                                         flash_attention, forward_tiles,
+                                         by_head, flash_attention,
+                                         forward_tiles, from_heads,
+                                         lane_tiled,
                                          unpack_selection)
 from dt_tpu.parallel.moe import RoutedExperts
 
@@ -186,13 +208,33 @@ def _turn(x, angle, scale: float = 1.0):
                            axis=-1).astype(x.dtype)
 
 
+def _turn_part(x, angle, scale: float = 1.0):
+    """``_turn`` on the first ``2 angle.shape[1]`` of ``x``'s last axis; the
+    rest of the head passes through."""
+    part = 2 * angle.shape[1]
+    if part == x.shape[-1]:
+        return _turn(x, angle, scale)
+    return jnp.concatenate([_turn(x[..., :part], angle, scale),
+                            x[..., part:]], axis=-1)
+
+
+def _rope_freq(half: int, theta: float):
+    """The plain rotary schedule over ``half`` pairs: ``theta^(-i / half)``."""
+    return theta ** (-jnp.arange(half, dtype=F32) / half)
+
+
+def _angle(positions, freq):
+    """``positions`` (S,) times each pair's frequency: the angles (S,
+    len(freq)), float32, that every rotary rule here turns by (``mrope``'s
+    are ``_mrope_angle``'s)."""
+    return positions.astype(F32)[:, None] * jnp.asarray(freq, F32)[None, :]
+
+
 def rope(x, positions, theta: float):
     """Rotary positions on ``x`` (B, S, H, D) at ``positions`` (S,): the
     pair ``(x_i, x_{i + D/2})`` turned by ``pos * theta^(-2i/D)``, in
     float32."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
-    return _turn(x, positions.astype(F32)[:, None] * freq[None, :])
+    return _turn(x, _angle(positions, _rope_freq(x.shape[-1] // 2, theta)))
 
 
 def yarn_frequencies(rotary_dim: int, theta: float, factor: float,
@@ -228,12 +270,7 @@ def rope_part(x, positions, freq, scale: float = 1.0):
     the cosine and sine times ``scale``), and the rest of the head passes
     through.  ``rope`` where the part is the whole head, ``freq`` is
     ``theta^(-i / (D/2))`` and ``scale`` 1."""
-    part = 2 * len(freq)
-    angle = positions.astype(F32)[:, None] * jnp.asarray(freq, F32)[None, :]
-    if part == x.shape[-1]:
-        return _turn(x, angle, scale)
-    return jnp.concatenate([_turn(x[..., :part], angle, scale),
-                            x[..., part:]], axis=-1)
+    return _turn_part(x, _angle(positions, freq), scale)
 
 
 def mrope(x, positions, theta: float, sections):
@@ -241,13 +278,94 @@ def mrope(x, positions, theta: float, sections):
     (3, S): the ``D/2`` frequency pairs are cut into ``sections`` (their
     sum), and pair ``i`` of section ``r`` turns by ``positions[r] *
     theta^(-2i/D)``: ``rope`` where the three rows are equal."""
-    half = x.shape[-1] // 2
+    return _turn(x, _mrope_angle(positions, theta, sections,
+                                 x.shape[-1] // 2))
+
+
+def _mrope_angle(positions, theta: float, sections, half: int):
+    """``mrope``'s angles (S, D/2) at ``positions`` (3, S)."""
     if len(sections) != 3 or sum(sections) != half:
         raise ValueError(f"sections {sections} do not cut {half} pairs in "
                          f"three")
-    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
     row = np.repeat(np.arange(3), sections)               # (D/2,)
-    return _turn(x, positions.astype(F32)[row, :].T * freq[None, :])
+    return positions.astype(F32)[row, :].T * _rope_freq(half, theta)[None, :]
+
+
+# -- heads of whole lane tiles: the layer's elementwise work where the
+# -- kernels' operands lie (PERF.md section 6, PR 45) -------------------------
+
+def _pairs(d: int, half: int):
+    """The (d, d) 0/1 matrix that brings each turned lane its pair: column
+    ``l`` has its one in row ``l + half`` below ``half`` and in row ``l -
+    half`` from there to ``2 half``; the lanes past the turned part get
+    nothing (their sine is 0)."""
+    p = np.zeros((d, d), np.float32)
+    lanes = np.arange(half)
+    p[lanes + half, lanes] = 1.0
+    p[lanes, lanes + half] = 1.0
+    return p
+
+
+def _turn_lanes(x5, cos, sin, half: int):
+    """``x5`` (B, S / 8, H, 8, D), ``cos`` and ``sin`` (S / 8, 1, 8, D)
+    float32 tables over a head's lanes (``_lane_tables``): each lane times
+    its cosine plus its pair's lane times its signed sine, in float32.  The
+    pairs come through the matrix unit, ``x5`` times a permutation (exact:
+    one term a sum), not through slices of the lanes, which XLA would cut
+    out as arrays of their own in another layout."""
+    d = x5.shape[-1]
+    # as one (rows, D) x (D, D) product over the view's rows as they lie
+    pair = jnp.dot(x5.reshape(-1, d), _pairs(d, half).astype(x5.dtype),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=F32).reshape(x5.shape)
+    return x5.astype(F32) * cos + pair * sin
+
+
+def _lane_tables(angle, scale: float, d: int):
+    """``angle`` (S, half) -> the cosine and the signed sine of every lane
+    of a head of ``d``, (S / 8, 1, 8, d) float32 each, as ``_turn_lanes``
+    reads them: ``(cos, cos, 1 ...)`` and ``(-sin, sin, 0 ...)``, the turned
+    part's times ``scale`` (``_turn``'s arithmetic, lane by lane)."""
+    s, half = angle.shape
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    rest = d - 2 * half
+    shape = lambda t: t.reshape(s // 8, 1, 8, d)  # noqa: E731
+    return (shape(jnp.concatenate([cos, cos, jnp.ones((s, rest), F32)], -1)),
+            shape(jnp.concatenate([-sin, sin, jnp.zeros((s, rest), F32)],
+                                  -1)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 4))
+def turn_heads(x3, heads: int, cos, sin, half: int):
+    """The rotary turn of ``x3`` (B, S, H * D) on its by-head view
+    (``attention.by_head``): the same shape and type back, float32 inside,
+    the tables from ``_lane_tables``.  Its transpose is the turn by the
+    opposite angle, written out (``custom_vjp``): the backward pass reads
+    the cotangent and the tables, keeps nothing of the forward's, and
+    goes through the view the same way round."""
+    return from_heads(_turn_lanes(by_head(x3, heads), cos, sin, half).astype(
+        x3.dtype))
+
+
+def _turn_heads_fwd(x3, heads, cos, sin, half):
+    return turn_heads(x3, heads, cos, sin, half), (cos, sin)
+
+
+def _turn_heads_bwd(heads, half, res, dy3):
+    cos, sin = res
+    return turn_heads(dy3, heads, cos, -sin, half), None, None
+
+
+turn_heads.defvjp(_turn_heads_fwd, _turn_heads_bwd)
+
+
+def _gates_by_head(g):
+    """(B, S, H) -> (B, S / 8, H, 8, 1): a value a head and position beside
+    the by-head view."""
+    b, s, h = g.shape
+    return g.reshape(b, s // 8, 8, h).transpose(0, 1, 3, 2)[..., None]
 
 
 #: the columns of the ``counters`` an attention layer with an index sows for
@@ -321,24 +439,33 @@ class RotaryAttention(linen.Module):
             q, k, v = checkpoint_name(
                 (dense(h * hd, "q_proj")(x), dense(kv * hd, "k_proj")(x),
                  dense(kv * hd, "v_proj")(x)), "attn_qkv")
-            q = q.reshape(b, s, h, hd)
-            k, v = k.reshape(b, s, kv, hd), v.reshape(b, s, kv, hd)
+            # a head of whole lane tiles: q, k, v and the kernels' output
+            # stay the projections' (B, S, H * D) arrays, which the flash
+            # kernels read and write as they are, and the norm, the turn and
+            # the gate work on their by-head view (``by_head``)
+            tiled = self.attention == "flash" and lane_tiled(hd, s)
+            if not tiled:
+                q = q.reshape(b, s, h, hd)
+                k, v = k.reshape(b, s, kv, hd), v.reshape(b, s, kv, hd)
             if self.qk_norm:
-                q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-                k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
+                norm = lambda name: RMSNorm(  # noqa: E731
+                    self.eps, self.dtype, name=name)
+                if tiled:
+                    q = from_heads(norm("q_norm")(by_head(q, h)))
+                    k = from_heads(norm("k_norm")(by_head(k, kv)))
+                else:
+                    q, k = norm("q_norm")(q), norm("k_norm")(k)
             mask = self.mask
             if positions is None:
                 positions = jnp.arange(s) % (s if mask is None else mask.half)
             with jax.named_scope("rope"):
-                if positions.ndim == 2:
-                    q, k = (mrope(t, positions, self.rope_theta,
-                                  self.mrope_section) for t in (q, k))
-                elif self.yarn is None and self.rotary_dim is None:
-                    q, k = (rope(t, positions, self.rope_theta)
-                            for t in (q, k))
+                if tiled:
+                    angle, scale = self._angles(positions)
+                    tables = _lane_tables(angle, scale, hd)
+                    q, k = (turn_heads(t, n, *tables, angle.shape[1])
+                            for t, n in ((q, h), (k, kv)))
                 else:
-                    freq, scale = self._frequencies()
-                    q, k = (rope_part(t, positions, freq, scale)
+                    q, k = (_turn_part(t, *self._angles(positions))
                             for t in (q, k))
             # k and v go on with their ``kv`` heads: the kernels' index maps
             # read the head that serves a query head (and its gradient comes
@@ -356,9 +483,28 @@ class RotaryAttention(linen.Module):
             if self.gate:
                 with jax.named_scope("gate"):
                     g = jax.nn.sigmoid(dense(h, "gate_proj")(x).astype(F32))
-                    out = (out.astype(F32) * g[..., None]).astype(out.dtype)
+                    if tiled:
+                        out = from_heads((by_head(out, h).astype(F32)
+                                          * _gates_by_head(g)).astype(
+                                              out.dtype))
+                    else:
+                        out = (out.astype(F32) * g[..., None]).astype(
+                            out.dtype)
             return checkpoint_name(
                 dense(d, "o_proj")(out.reshape(b, s, h * hd)), "attn_out")
+
+    def _angles(self, positions):
+        """(the angle of each turned pair at each position, (S, pairs); the
+        scale on cosine and sine): what the layer's rotary rule turns by,
+        ``mrope``'s, ``rope``'s or ``rope_part``'s."""
+        if positions.ndim == 2:
+            return _mrope_angle(positions, self.rope_theta,
+                                self.mrope_section, self.head_dim // 2), 1.0
+        if self.yarn is None and self.rotary_dim is None:
+            return _angle(positions, _rope_freq(self.head_dim // 2,
+                                                self.rope_theta)), 1.0
+        freq, scale = self._frequencies()
+        return _angle(positions, freq), scale
 
     def _frequencies(self):
         """(the frequency of each turned pair, the scale on cosine and
@@ -417,7 +563,10 @@ class RotaryAttention(linen.Module):
         if self.attention == "flash":
             out, lse = flash_attention(
                 q, k, v, causal=True, mask=SelectedKeysMask(),
-                selection=selection, return_lse=True)
+                selection=selection, return_lse=True, heads=self._heads(q))
+            # the KL term reads each head's queries and keys
+            q = q.reshape(b, s, self.num_heads, self.head_dim)
+            k = k.reshape(b, s, self.num_kv_heads, self.head_dim)
         else:
             out, lse = self._plain(
                 q, k, v, unpack_selection(selection.by_query, s)
@@ -450,9 +599,16 @@ class RotaryAttention(linen.Module):
                 else rule.allowed(pos[:, None], pos[None, :]))
         # padded to the tile: a padded key lies after every real query
         s, pad = q.shape[1], (-q.shape[1]) % DEFAULT_BLOCK
-        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                    for t in (q, k, v))
-        return flash_attention(q, k, v, causal=True, mask=rule)[:, :s]
+        return flash_attention(q, k, v, causal=True, mask=rule,
+                               heads=self._heads(q))[:, :s]
+
+    def _heads(self, q):
+        """``flash_attention``'s ``heads``: the query heads where ``q`` is
+        the projection's (B, S, H * D) array, None where it is (B, S, H,
+        D)."""
+        return self.num_heads if q.ndim == 3 else None
 
     def _flash(self, q, k, v):
         s, mask = q.shape[1], self.mask
@@ -462,11 +618,12 @@ class RotaryAttention(linen.Module):
         if pad:
             halves = lambda t: jnp.pad(  # noqa: E731
                 t.reshape((t.shape[0], 2, mask.half) + t.shape[2:]),
-                ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+                ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2)).reshape(
                     (t.shape[0], 2 * (mask.half + pad)) + t.shape[2:])
             q, k, v = halves(q), halves(k), halves(v)
         out = flash_attention(
-            q, k, v, mask=BlockDiffusionMask(mask.half + pad, mask.block))
+            q, k, v, mask=BlockDiffusionMask(mask.half + pad, mask.block),
+            heads=self._heads(q))
         if pad:
             out = out.reshape((out.shape[0], 2, mask.half + pad)
                               + out.shape[2:])[:, :, :mask.half].reshape(

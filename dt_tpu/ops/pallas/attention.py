@@ -42,6 +42,25 @@ float32 outside it (``_sum_groups``): summed inside, a group's dq
 accumulators, each a whole sequence of float32, would all have to stay in
 VMEM.  With ``KV == H`` the index maps and the programs are what they were.
 
+A caller that holds its heads as the projections leave them hands
+``flash_attention`` rank-3 operands, q ``(B, S, H * D)`` and k, v ``(B, SK,
+KV * D)`` (``lane_tiled``: ``D % 128 == 0``, the lengths multiples of 8), and
+then neither kernel's operand is turned at all (PERF.md section 6, PR 45): a
+block is ``(1, block, D)`` at ``(b // H, tile, b % H)``, one head's ``D``
+columns of ``block`` rows that lie ``H * D`` apart (``_head_at``).  The
+grid, the bodies, the tiles and the log-sum-exp's ``(B * H, ...)`` rows are
+the same; out, dq, dk, dv come back in that layout.  What XLA computes
+beside the kernels there (delta, the groups' sums) goes over the view
+``by_head`` gives, ``(B, S / 8, H, 8, D)``: under the TPU's (8, 128) tiling
+that view is the ``(B, S, H * D)`` array's own bytes, where a ``(B, S, H,
+D)`` view is tiled over ``(H, D)`` and costs a pass over the array for every
+``reshape`` (the compiler's count for one step of the mixed-attention cell
+rose by 33 GB with the kernels in this layout and the layer's work left on
+``(B, S, H, D)`` arrays, and fell by 70 GB with it on the view:
+``models/routed_lm.py`` ``RotaryAttention``).  So the rank decides, and a
+caller with ``(B, S, H, D)`` operands, at any head size, gets heads after
+the batch, ``(B * H, S, D)``, as it always did, to the jaxpr.
+
 Backward is the standard flash backward from the saved log-sum-exp, one
 Pallas call named ``flash_bwd`` (its events carry that name in a trace,
 where the forward's carry its caller's): grid (batch*heads, k_tiles,
@@ -1049,10 +1068,63 @@ def _kv_row(b, rep: int):
     return b if rep == 1 else b // rep
 
 
+def _head_at(heads: Optional[int], rep: int = 1):
+    """Where grid row ``b = batch * H + head`` finds its head in an operand:
+    ``b -> (row, column block)``, the first and the last entry of a block
+    index whose middle one is the tile.  ``heads`` None: the operand is (B *
+    H, S, D), heads after the batch, so row ``b`` (``_kv_row`` of it in an
+    operand of key-value heads) and column block 0.  ``heads = H``: the
+    operand is (B, S, H * D) as a projection leaves it, so row ``b // H``
+    and the head's own ``D`` columns, block ``b % H`` (``// rep`` in an
+    operand of key-value heads, (B, SK, KV * D))."""
+    if heads is None:
+        return lambda b: (_kv_row(b, rep), 0)
+    return lambda b: (b // heads, _kv_row(b % heads, rep))
+
+
+def lane_tiled(d: int, *lengths: int) -> bool:
+    """Whether heads of ``d`` over ``lengths`` positions can be read where a
+    projection leaves them: a head whole tiles of 128 lanes, the positions
+    whole tiles of 8 sublanes (``by_head``).  The one place that knows it:
+    ``flash_attention`` takes (B, S, H * D) operands where it holds, and a
+    layer asks it before it keeps its own work on those arrays."""
+    return d % _LANES == 0 and not any(n % 8 for n in lengths)
+
+
+def by_head(x3, heads: int):
+    """(B, S, H * D) -> (B, S / 8, H, 8, D): each head's ``D`` columns of
+    eight positions together, which is how the (8, 128) tiles of the (B, S,
+    H * D) array lie in memory, so XLA reads the view where the array is; a
+    (B, S, H, D) view would be tiled over (H, D) and cost a pass over the
+    array.  ``from_heads`` is the way back."""
+    b, s, width = x3.shape
+    return x3.reshape(b, s // 8, 8, heads, width // heads).transpose(
+        0, 1, 3, 2, 4)
+
+
+def from_heads(x5):
+    """(B, S / 8, H, 8, D) -> (B, S, H * D): ``by_head`` undone."""
+    b, s8, heads, _, d = x5.shape
+    return x5.transpose(0, 1, 3, 2, 4).reshape(b, s8 * 8, heads * d)
+
+
+def _rows_of(q3, k3, heads: Optional[int]):
+    """``(B * H, S, D, rep)`` of a call's operands: q (B * H, S, D) beside
+    k (B * KV, SK, D), or with ``heads = H`` q (B, S, H * D) beside k (B,
+    SK, KV * D)."""
+    if heads is None:
+        bh, s, d = q3.shape
+        return bh, s, d, bh // k3.shape[0]
+    b, s, width = q3.shape
+    return b * heads, s, width // heads, width // k3.shape[2]
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub",
+    "heads"))
 def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
-                      interpret, mask=None, selection=None, sub=None):
+                      interpret, mask=None, selection=None, sub=None,
+                      heads=None):
     """(BH, S, D) q/k/v -> (out (BH, S, D), lse (BH, S)).  ``block_q`` /
     ``block_k`` of None are derived from the shapes (``forward_tiles``).
     ``selection`` is the ``Selection`` a ``SelectedKeysMask`` reads.
@@ -1062,11 +1134,17 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     fewer rows than ``q3``, (B * KV, SK, D) for (B * H, S, D): query row
     ``b`` then reads key-value row ``b // rep``, ``rep = H // KV``.
 
+    With ``heads = H`` the operands are in the layout a projection leaves
+    them in, q (B, S, H * D) and k, v (B, SK, KV * D), ``D`` whole lane
+    tiles: the same grid over (B * H) rows, each block the ``D`` columns of
+    the row's head (``_head_at``), and ``out`` comes back (B, S, H * D);
+    the log-sum-exp is (B * H, S) either way.
+
     Jitted and inlined: a model's layers share one trace of the kernel's
     body (Pallas traces it anew for every call otherwise, 24 times a
     build of the gpt2-medium step), and the call keeps its caller's scope
     and so its event's name."""
-    bh, s, d = q3.shape
+    bh, s, d, rep = _rows_of(q3, k3, heads)
     sk = k3.shape[1]
     selected = isinstance(mask, SelectedKeysMask)
     windowed = isinstance(mask, WindowMask)
@@ -1075,9 +1153,8 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
                                None if selected else mask)
         block_q, block_k = block_q or dq, block_k or dk
     sub = SUB_BLOCK if sub is None else sub
-    rep = bh // k3.shape[0]
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, mask=mask,
-                causal=causal, sub=sub, rep=rep)
+                causal=causal, sub=sub, rep=rep, bshd=heads is not None)
     n_q = s // block_q
     # the key axis' steps: under a band, the tiles it can touch
     n_k = mask.key_steps(s, block_q, block_k) if windowed else sk // block_k
@@ -1104,8 +1181,15 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
         key_tile = lambda qi, ki: ki
     # the key-value head of query row b = batch * H + head: nothing spreads
     # the heads in memory, a group's query heads fetch the same block
-    kv_map = lambda b, qi, ki, *_: (_kv_row(b, rep), key_tile(qi, ki), 0)
-    q_map = lambda b, qi, ki, *_: (b, qi, 0)
+    q_at, kv_at = _head_at(heads), _head_at(heads, rep)
+
+    def kv_map(b, qi, ki, *_):
+        row, col = kv_at(b)
+        return row, key_tile(qi, ki), col
+
+    def q_map(b, qi, ki, *_):
+        row, col = q_at(b)
+        return row, qi, col
     sub = block_q // _LANES
     in_specs = [
         pl.BlockSpec((1, block_q, d), q_map),
@@ -1125,11 +1209,12 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
     if selected:
         # the queries' bitmap block of the key tile's group: one fetch for
         # the SEL_GROUP / block_k key tiles that share it
-        heads = bh // selection.by_query.shape[0]
+        sel_heads = bh // selection.by_query.shape[0]
         in_specs.append(pl.BlockSpec(
             (1, 1, block_q, _LANES), lambda b, qi, ki, *_: (
-                b // heads, key_tile(qi, ki) * block_k // SEL_GROUP, qi, 0)))
-        kern = functools.partial(_attn_kernel_sel, heads=heads,
+                b // sel_heads, key_tile(qi, ki) * block_k // SEL_GROUP, qi,
+                0)))
+        kern = functools.partial(_attn_kernel_sel, heads=sel_heads,
                                  **kern.keywords)
         operands = (SelectedKeysMask.tile_fates(selection.blocks, block_q,
                                                 block_k),
@@ -1147,7 +1232,7 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
         **({} if mask is None else {"name": _kernel_name(mask, "fwd")}),
         **grid,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+            jax.ShapeDtypeStruct(q3.shape, q3.dtype),
             jax.ShapeDtypeStruct((bh, n_q, sub, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -1161,7 +1246,7 @@ def _flash_fwd_pallas(q3, k3, v3, *, scale, causal, block_q, block_k,
 @functools.lru_cache(maxsize=None)
 def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
                 mask=None, causal: bool = False, sub: int = 0,
-                rep: int = 1) -> None:
+                rep: int = 1, bshd: bool = False) -> None:
     """Record, once per distinct shape, tile and mask rule, what the forward
     (or with ``bwd`` the backward) was traced with: a ``# flash_tiles`` (``#
     flash_bwd_tiles``) debug line and the metrics plane's gauges, so that a
@@ -1174,7 +1259,10 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
     where every tile is computed whole.  Where the keys and values came
     with fewer heads than the queries the line ends in ``rep=<query heads a
     key-value head>`` and the gauges carry the label ``rep``: the index maps
-    read the group's head, nothing was spread.  Trace time only."""
+    read the group's head, nothing was spread.  Where the operands came as
+    a projection leaves them, (B, S, H * D) (``bshd``: head sizes of whole
+    lane tiles), the line ends in ``layout=bshd`` and the gauges carry the
+    label ``layout``.  Trace time only."""
     s, sk, d, dtype = shape
     if isinstance(mask, BlockDiffusionMask):
         rule = f"block_diffusion.half{mask.half}.block{mask.block}"
@@ -1194,9 +1282,10 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
             rule += f".crossed{crossed}.sub{round(computed, 4)}of{run}"
             pct = 100.0 * computed / run
     logger.debug("# flash_%stiles s=%d sk=%d d=%d dtype=%s block_q=%d "
-                 "block_k=%d%s%s", "bwd_" if bwd else "", s, sk, d, dtype,
+                 "block_k=%d%s%s%s", "bwd_" if bwd else "", s, sk, d, dtype,
                  block_q, block_k, " mask=" + rule if rule else "",
-                 f" rep={rep}" if rep > 1 else "")
+                 f" rep={rep}" if rep > 1 else "",
+                 " layout=bshd" if bshd else "")
     if obs_metrics.enabled():
         reg = obs_metrics.registry()
         labels = {"shape": f"{s}x{sk}x{d}.{dtype}"}
@@ -1204,6 +1293,8 @@ def _note_tiles(shape, block_q: int, block_k: int, bwd: bool = False,
             labels["mask"] = rule
         if rep > 1:
             labels["rep"] = str(rep)
+        if bshd:
+            labels["layout"] = "bshd"
         if bwd:
             reg.gauge("flash.bwd_block_q", block_q, labels)
             reg.gauge("flash.bwd_block_k", block_k, labels)
@@ -1397,10 +1488,11 @@ def _flash_bwd_kernel_sel(fate_ref, q_ref, do_ref, lse_ref, delta_ref, k_ref,
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub"))
+    "scale", "causal", "block_q", "block_k", "interpret", "mask", "sub",
+    "heads"))
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                       block_q=None, block_k=None, mask=None, selection=None,
-                      sub=None):
+                      sub=None, heads=None):
     """The flash backward from the saved log-sum-exp: (dq, dk, dv) for
     (BH, S, D) q and do, (BH, SK, D) k and v, in one Pallas call named
     ``flash_bwd`` (``flash_bwd_bd``, ``flash_bwd_sel``, ``flash_win_bwd``
@@ -1408,11 +1500,13 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     Its tiles come from the shapes (``backward_tiles``); ``sub`` as the
     forward's.  With (B * KV, SK, D) k and v (the forward's ``rep``) dk and
     dv still come out one a query row, (BH, SK, D): their sum over a group
-    is the caller's (``_sum_groups``).
+    is the caller's (``_sum_groups``).  ``heads = H``: q, out, do (B, S, H *
+    D) and k, v (B, SK, KV * D) as the forward's, dq (B, S, H * D) and dk,
+    dv (B, SK, H * D) back; ``lse`` stays (B * H, S).
 
     Jitted for the reason ``_flash_fwd_pallas`` is: one trace of the
     kernel's body for all of a model's layers."""
-    bh, s, d = q3.shape
+    bh, s, d, rep = _rows_of(q3, k3, heads)
     sk = k3.shape[1]
     selected = isinstance(mask, SelectedKeysMask)
     if block_q is None or block_k is None:
@@ -1420,16 +1514,23 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
                                 None if selected else mask)
         block_q, block_k = block_q or tq, block_k or tk
     sub = SUB_BLOCK if sub is None else sub
-    rep = bh // k3.shape[0]
     _note_tiles((s, sk, d, q3.dtype.name), block_q, block_k, bwd=True,
-                mask=mask, causal=causal, sub=sub, rep=rep)
+                mask=mask, causal=causal, sub=sub, rep=rep,
+                bshd=heads is not None)
     windowed = isinstance(mask, WindowMask)
     q_tiles, n_k = s // block_q, sk // block_k
     # the query axis' steps: under a band, the tiles it can touch
     n_q = mask.query_steps(s, block_q, block_k) if windowed else q_tiles
     # delta = rowsum(do * out), float32, in XLA: one pass over two arrays
     # the step already holds; a row vector per head, as the log-sum-exp
-    delta = (do3.astype(jnp.float32) * o3.astype(jnp.float32)).sum(-1)
+    delta = do3.astype(jnp.float32) * o3.astype(jnp.float32)
+    if heads is None:
+        delta = delta.sum(-1)
+    else:
+        # summed over each head's own columns where they lie, and the (B,
+        # S / 8, H, 8) sums (not the operands) turned to the rows the kernel
+        # reads
+        delta = by_head(delta, heads).sum(-1).transpose(0, 2, 1, 3)
     kern = functools.partial(
         _flash_bwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_q=n_q, n_k=n_k, mask=mask, sub=sub,
@@ -1451,17 +1552,25 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
         first = lambda ki, qi: qi
     # the index maps take the grid's indices and, under a selection, the
     # scalar-prefetch table after them
-    q_spec = pl.BlockSpec((1, block_q, d),
-                          lambda b, ki, qi, *_: (b, first(ki, qi), 0))
+    q_at, kv_at = _head_at(heads), _head_at(heads, rep)
+
+    def of_head(at, tile):
+        # the index map of an operand's block: its head by ``at``, its tile
+        # by ``tile(ki, qi)``
+        def index(b, ki, qi, *_):
+            row, col = at(b)
+            return row, tile(ki, qi), col
+        return index
+
+    q_spec = pl.BlockSpec((1, block_q, d), of_head(q_at, first))
     row_spec = pl.BlockSpec((1, 1, block_q),
                             lambda b, ki, qi, *_: (b, 0, first(ki, qi)))
     # k and v come from the group's key-value row (the forward's kv_map);
     # dk and dv go out one a query row
-    k_spec = pl.BlockSpec((1, block_k, d),
-                          lambda b, ki, qi, *_: (_kv_row(b, rep), ki, 0))
-    dk_spec = pl.BlockSpec((1, block_k, d), lambda b, ki, qi, *_: (b, ki, 0))
+    k_spec = pl.BlockSpec((1, block_k, d), of_head(kv_at, lambda ki, qi: ki))
+    dk_spec = pl.BlockSpec((1, block_k, d), of_head(q_at, lambda ki, qi: ki))
     in_specs = [q_spec, q_spec, row_spec, row_spec, k_spec, k_spec]
-    out_specs = [pl.BlockSpec((1, s, d), lambda b, ki, qi, *_: (b, 0, 0)),
+    out_specs = [pl.BlockSpec((1, s, d), of_head(q_at, lambda ki, qi: 0)),
                  dk_spec, dk_spec]
     scratch_shapes = [
         pltpu.VMEM((s, d), jnp.float32),
@@ -1470,13 +1579,15 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     ]
     operands = (q3, do3, lse.reshape(bh, 1, s), delta.reshape(bh, 1, s), k3,
                 v3)
+    # dk, dv: k's shape with the queries' heads
+    wide = (bh, sk, d) if heads is None else (q3.shape[0], sk, q3.shape[2])
     if selected:
         # the keys' bitmap block of the query tile's group
-        heads = bh // selection.by_key.shape[0]
+        sel_heads = bh // selection.by_key.shape[0]
         in_specs.append(pl.BlockSpec(
             (1, 1, block_k, _LANES), lambda b, ki, qi, *_: (
-                b // heads, first(ki, qi) * block_q // SEL_GROUP, ki, 0)))
-        kern = functools.partial(_flash_bwd_kernel_sel, heads=heads,
+                b // sel_heads, first(ki, qi) * block_q // SEL_GROUP, ki, 0)))
+        kern = functools.partial(_flash_bwd_kernel_sel, heads=sel_heads,
                                  **kern.keywords)
         operands = (SelectedKeysMask.tile_fates(
             selection.blocks, block_q, block_k, by_key=True),) + operands \
@@ -1492,9 +1603,9 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
         name="flash_bwd" if mask is None else _kernel_name(mask, "bwd"),
         **grid,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v3.dtype),
+            jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+            jax.ShapeDtypeStruct(wide, k3.dtype),
+            jax.ShapeDtypeStruct(wide, v3.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
@@ -1504,19 +1615,20 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, interpret,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, mask):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, mask,
+           heads):
     out, _ = _flash_fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               interpret=interpret, mask=mask)
+                               interpret=interpret, mask=mask, heads=heads)
     return out
 
 
 def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
-                    mask):
+                    mask, heads):
     out, lse = _flash_fwd_pallas(q3, k3, v3, scale=scale, causal=causal,
                                  block_q=block_q, block_k=block_k,
-                                 interpret=interpret, mask=mask)
+                                 interpret=interpret, mask=mask, heads=heads)
     # names a block's remat policy can keep (models/routed_lm.py SAVED):
     # on the values themselves, before they go into the result and the
     # residuals, so that a policy which saves them needs no second call.
@@ -1530,27 +1642,34 @@ def _flash_fwd_rule(q3, k3, v3, scale, causal, block_q, block_k, interpret,
     return out, (q3, k3, v3, out, lse)
 
 
-def _sum_groups(dx3, like):
+def _sum_groups(dx3, like, heads=None):
     """dk or dv as the backward kernel leaves it, one a query row (B * H, SK,
     D), summed over each key-value head's group to ``like``'s (B * KV, SK,
     D), in float32 (what the reduction that transposed ``jnp.repeat`` did).
+    With ``heads = H``: (B, SK, H * D) summed over ``rep`` on its by-head
+    view, (B, SK / 8, KV, rep, 8, D), to ``like``'s (B, SK, KV * D).
     Without groups ``dx3`` itself."""
-    rows = like.shape[0]
-    if dx3.shape[0] == rows:
+    if dx3.shape == like.shape:
         return dx3
-    return dx3.reshape(rows, -1, *dx3.shape[1:]).sum(
-        axis=1, dtype=jnp.float32).astype(dx3.dtype)
+    if heads is None:
+        return dx3.reshape(like.shape[0], -1, *dx3.shape[1:]).sum(
+            axis=1, dtype=jnp.float32).astype(dx3.dtype)
+    dx5 = by_head(dx3, heads)
+    b, s8, _, _, d = dx5.shape
+    rep = dx3.shape[2] // like.shape[2]
+    return from_heads(dx5.reshape(b, s8, heads // rep, rep, 8, d).sum(
+        axis=3, dtype=jnp.float32).astype(dx3.dtype))
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, mask, res,
-                    do3):
+def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, mask, heads,
+                    res, do3):
     # the forward's tiles stop here: the backward derives its own from the
     # shapes (backward_tiles), whatever the forward was given
     q3, k3, v3, out, lse = res
     dq, dk, dv = _flash_bwd_pallas(q3, k3, v3, out, lse, do3, scale=scale,
                                    causal=causal, interpret=interpret,
-                                   mask=mask)
-    return dq, _sum_groups(dk, k3), _sum_groups(dv, v3)
+                                   mask=mask, heads=heads)
+    return dq, _sum_groups(dk, k3, heads), _sum_groups(dv, v3, heads)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1559,32 +1678,34 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # under a SelectedKeysMask: a function of its own (the selection is an
 # operand and the log-sum-exp a result), so that without the rule ``_flash``
 # and what it lowers to are what they were
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_sel(q3, k3, v3, selection, scale, causal, block_q, block_k,
-               interpret):
+               interpret, heads):
     return _flash_fwd_pallas(
         q3, k3, v3, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, mask=SelectedKeysMask(),
-        selection=selection)
+        selection=selection, heads=heads)
 
 
 def _flash_sel_fwd_rule(q3, k3, v3, selection, scale, causal, block_q,
-                        block_k, interpret):
+                        block_k, interpret, heads):
     out, lse = _flash_sel(q3, k3, v3, selection, scale, causal, block_q,
-                          block_k, interpret)
+                          block_k, interpret, heads)
     out = checkpoint_name(out, "flash_out")         # as _flash_fwd_rule
     lse = checkpoint_name(lse, "flash_lse")
     return (out, lse), (q3, k3, v3, selection, out, lse)
 
 
-def _flash_sel_bwd_rule(scale, causal, block_q, block_k, interpret, res, cts):
+def _flash_sel_bwd_rule(scale, causal, block_q, block_k, interpret, heads,
+                        res, cts):
     # the log-sum-exp's cotangent is dropped: what reads it (the indexer's
     # KL term) takes it as a constant
     q3, k3, v3, selection, out, lse = res
     dq, dk, dv = _flash_bwd_pallas(
         q3, k3, v3, out, lse, cts[0], scale=scale, causal=causal,
-        interpret=interpret, mask=SelectedKeysMask(), selection=selection)
-    return dq, _sum_groups(dk, k3), _sum_groups(dv, v3), None
+        interpret=interpret, mask=SelectedKeysMask(), selection=selection,
+        heads=heads)
+    return dq, _sum_groups(dk, k3, heads), _sum_groups(dv, v3, heads), None
 
 
 _flash_sel.defvjp(_flash_sel_fwd_rule, _flash_sel_bwd_rule)
@@ -1596,7 +1717,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
                     mask=None, selection: Optional[Selection] = None,
-                    return_lse: bool = False):
+                    return_lse: bool = False, heads: Optional[int] = None):
     """Fused attention, (B, S, H, D) layout (``full_attention`` oracle).
 
     ``k`` and ``v`` are (B, SK, KV, D) with ``KV`` a divisor of ``H``
@@ -1607,6 +1728,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block from head ``h // rep``, and the backward's dk, dv, one a query
     head out of the kernel, are summed over each group in float32.  ``KV ==
     H`` is plain multi-head attention, the program it always was.
+
+    The operands' rank decides the kernels' operand layout.  Rank 4, as
+    above: every operand is turned to ``(B * H, S, D)`` and the result
+    turned back, as it always was.  Rank 3: q ``(B, S, H * D)`` and k, v
+    ``(B, SK, KV * D)`` with ``heads = H``, the arrays as the projections
+    leave them, where a head is whole lane tiles (``lane_tiled``: ``D %
+    128 == 0``, ``S % 8 == 0``).  The kernels then read each head's ``D``
+    columns where they lie, the result comes back ``(B, S, H * D)`` for
+    ``o_proj`` to read as it is, the cotangent takes the same way, and
+    nothing is turned.  That pays only for a caller whose own work on q, k
+    and the result stays on those arrays (``by_head``): on this chip a ``(B,
+    S, H, D)`` view of them is other bytes, a pass over the array (PERF.md
+    section 6, PR 45), which is why four-axis operands keep their path.
 
     Sequence lengths must be multiples of ``DEFAULT_BLOCK`` (pad upstream;
     ``TransformerLM`` does).  ``block_q`` / ``block_k`` override the
@@ -1630,10 +1764,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     if interpret is None:
         interpret = _default_interpret()
+    if q.ndim == 3:
+        b, s, width = q.shape
+        if not heads or width % heads \
+                or not lane_tiled(width // heads, s, k.shape[1]) \
+                or k.shape[2] % (width // heads):
+            raise ValueError(
+                f"(B, S, H * D) operands come with heads=H, D whole tiles of "
+                f"{_LANES} lanes and the lengths multiples of 8: got q "
+                f"{q.shape}, k {k.shape}, heads={heads}")
+        h, d = heads, width // heads
+        sk, kv = k.shape[1], k.shape[2] // d
+    elif heads is not None:
+        raise ValueError(f"heads={heads} goes with (B, S, H * D) operands, "
+                         f"not with q {q.shape}")
+    else:
+        b, s, h, d = q.shape
+        sk, kv = k.shape[1], k.shape[2]
     if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-    b, s, h, d = q.shape
-    sk, kv = k.shape[1], k.shape[2]
+        scale = 1.0 / (d ** 0.5)
     if h % kv or v.shape != k.shape:
         raise ValueError(f"{kv} key-value heads do not divide {h} query "
                          f"heads, or k {k.shape} and v {v.shape} differ")
@@ -1664,13 +1813,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             raise ValueError(f"seq lengths ({s}, {sk}) must be multiples "
                              f"of blocks ({block_q}, {block_k}), and those "
                              f"of {_LANES}")
-    # heads after the batch: row b * H + h of q, row b * KV + h // rep of k, v
-    to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, x.shape[1], d)
+    if heads is not None:
+        # the projections' arrays: the kernels read each head where it lies
+        to3 = back = lambda x: x
+    else:
+        # heads after the batch: row b * H + h of q, row b * KV + h // rep
+        # of k, v
+        to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, x.shape[1], d)
+        back = lambda out3: jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2)
     if selected:
         out3, lse = _flash_sel(to3(q), to3(k), to3(v), selection, scale,
-                               causal, block_q, block_k, interpret)
+                               causal, block_q, block_k, interpret, heads)
     else:
         out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q,
-                      block_k, interpret, mask)
-    out = jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2)
+                      block_k, interpret, mask, heads)
+    out = back(out3)
     return (out, lse.reshape(b, h, s)) if return_lse else out

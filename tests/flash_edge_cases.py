@@ -110,3 +110,28 @@ def pairs_computed_gauges(trace):
         obs_metrics.set_enabled(None)
         obs_metrics.registry().clear()
         attn._note_tiles.cache_clear()
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, a kernel's
+    own body apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+
+def placed(**kw):
+    """``flash_attention(**kw)`` over (B, S, H, D) arguments handed to it as
+    the projections' arrays, q (B, S, H * D) and k, v (B, SK, KV * D) with
+    ``heads=H``: the rank that takes the kernels' in-place layout.  The
+    result viewed (B, S, H, D) again (a log-sum-exp beside it as it is)."""
+    def run(q, k, v):
+        flat = lambda t: t.reshape(*t.shape[:2], -1)  # noqa: E731
+        out = attn.flash_attention(flat(q), flat(k), flat(v),
+                                   heads=q.shape[2], **kw)
+        if isinstance(out, tuple):
+            return (out[0].reshape(q.shape),) + tuple(out[1:])
+        return out.reshape(q.shape)
+    return run

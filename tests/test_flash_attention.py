@@ -491,21 +491,28 @@ GROUPED_RULES = {
     "block_diffusion": dict(mask=attn.BlockDiffusionMask(128, 4)),
     "window": dict(mask=attn.WindowMask(100)),
 }
-GROUPS = [(1, 128), (4, 64), (8, 128)]
+GROUPS = [(1, 128), (4, 64), (4, 128), (8, 128)]
+# and the same heads of 128 handed over as the projections' (B, S, H * D)
+# arrays (PR 45): the kernels' in-place layout
+LAYOUTS = [g + (False,) for g in GROUPS] + [
+    g + (True,) for g in GROUPS if g[1] % 128 == 0]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("rep,d", GROUPS)
+@pytest.mark.parametrize("rep,d,in_place", LAYOUTS, ids=[
+    f"{r}-{d}" + ("-placed" if p else "") for r, d, p in LAYOUTS])
 @pytest.mark.parametrize("rule", sorted(GROUPED_RULES))
-def test_grouped_heads_match_the_oracle_on_spread_heads(rule, rep, d, dtype):
+def test_grouped_heads_match_the_oracle_on_spread_heads(rule, rep, d,
+                                                        in_place, dtype):
     """(B, S, KV, D) keys and values under each static rule: the output
     and the three gradients are the oracle's on heads spread with
     ``jnp.repeat``; dk and dv come back with ``KV`` heads, summed over
-    each group."""
+    each group.  ``in_place``: the same operands as rank-3 arrays."""
     kw = GROUPED_RULES[rule]
     args, args32, w = _grouped_inputs(rep + d, rep, d, dtype)
-    flash = lambda q, k, v: flash_attention(q, k, v, **kw)  # noqa: E731
+    flash = edge.placed(**kw) if in_place \
+        else lambda q, k, v: flash_attention(q, k, v, **kw)  # noqa: E731
     if rule == "causal":
         ref = lambda q, k, v: full_attention(  # noqa: E731
             q, _spread(k, q.shape[2]), _spread(v, q.shape[2]), causal=True)
@@ -557,6 +564,145 @@ def test_tile_notes_name_the_group(caplog, prefix):
         labels = [lk for name, lk, _ in obs_metrics.registry().gauges_export()
                   if name.startswith(f"flash.{prefix}")]
         assert len(labels) == 3 and all(lk["rep"] == "4" for lk in labels)
+    finally:
+        obs_metrics.set_enabled(None)
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()
+
+
+# -- operands in the projections' layout at head size 128 (PR 45) -------------
+
+def _grad_equations(d, rep, in_place, kv=2, b=2, s=256, **kw):
+    """The equations of the gradient of a sum through ``flash_attention`` at
+    head size ``d``, (B, S, KV * rep, D) queries over (B, S, KV, D) keys,
+    or with ``in_place`` the same as (B, S, H * D) arrays."""
+    q, k = jnp.zeros((b, s, kv * rep, d)), jnp.zeros((b, s, kv, d))
+    flash = edge.placed(**kw) if in_place \
+        else lambda q, k, v: flash_attention(q, k, v, **kw)  # noqa: E731
+    return list(edge.equations(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash(q, k, v).sum(), argnums=(0, 1, 2)))(
+            q, k, k).jaxpr))
+
+
+def _kernel_operands(eqns):
+    """The operands' shapes of each ``pallas_call``, the forward's first."""
+    return [[v.aval.shape for v in e.invars] for e in eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("rep", [1, 4, 8])
+@pytest.mark.parametrize("rule", sorted(GROUPED_RULES))
+def test_rank_3_operands_are_not_turned(rule, rep, b=2, s=256, kv=2, d=128):
+    """Handed (B, S, H * D) q and (B, S, KV * D) k, v, both kernels take
+    them and out, do as they are and give dq, dk, dv back so: the
+    gradient's jaxpr holds no ``transpose`` of a (..., H, D) array.  What it
+    does hold: the by-head view's permutation on delta's product and on dk,
+    dv where a group is summed (``by_head``: no byte moves under the (8,
+    128) tiling), and one turn of delta's (B, S / 8, H, 8) sums."""
+    eqns = _grad_equations(d, rep, True, **GROUPED_RULES[rule])
+    h = kv * rep
+    turned = [(e.invars[0].aval.shape, e.params["permutation"])
+              for e in eqns if e.primitive.name == "transpose"]
+    views = [shape for shape, perm in turned if perm == (0, 1, 3, 2, 4)]
+    assert len(views) == (1 if rep == 1 else 5), turned
+    assert [t for t in turned if t[1] != (0, 1, 3, 2, 4)] == [
+        ((b, s // 8, h, 8), (0, 2, 1, 3))], turned
+    wide, narrow, row = (b, s, h * d), (b, s, kv * d), (b * h, 1, s)
+    fwd, bwd = _kernel_operands(eqns)
+    assert fwd == [wide, narrow, narrow]
+    assert bwd == [wide, wide, row, row, narrow, narrow]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [v.aval.shape for v in calls[0].outvars][0] == wide
+    assert [v.aval.shape for v in calls[1].outvars] == [wide] * 3
+
+
+def test_rank_3_operands_need_their_heads_and_whole_tiles():
+    q, k = jnp.zeros((1, 128, 4 * 128)), jnp.zeros((1, 128, 2 * 128))
+    for bad in (dict(), dict(heads=3), dict(heads=8)):      # 8: heads of 64
+        with pytest.raises(ValueError, match="whole tiles"):
+            flash_attention(q, k, k, causal=True, **bad)
+    with pytest.raises(ValueError, match="goes with"):
+        flash_attention(q.reshape(1, 128, 4, 128), k.reshape(1, 128, 2, 128),
+                        k.reshape(1, 128, 2, 128), causal=True, heads=4)
+    assert attn.lane_tiled(128, 8192) and attn.lane_tiled(256, 8, 16)
+    assert not attn.lane_tiled(64, 8192) and not attn.lane_tiled(128, 12)
+
+
+def _stripped(fn, *args):
+    """A gradient's jaxpr as text, without the addresses of the closures a
+    ``custom_vjp`` call carries."""
+    import re
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.grad(fn, argnums=(0, 1, 2)))(*args)))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("rep", [1, 4])
+@pytest.mark.parametrize("rule", sorted(GROUPED_RULES))
+def test_rank_4_operands_take_the_old_path_to_the_letter(rule, rep, d, b=2,
+                                                         s=256, kv=2):
+    """(B, S, H, D) operands, at head size 64 (where a (1, block, 64) block
+    of an (H * 64)-wide array is no legal Mosaic block) and at 128 (where
+    the view of them as (B, S, H * D) would be a pass over the array): heads
+    go after the batch as they always did, the kernels take (B * H, S, D)
+    operands, and the gradient's jaxpr is the one of that formulation
+    written out here."""
+    kw = GROUPED_RULES[rule]
+    h = kv * rep
+    eqns = _grad_equations(d, rep, False, **kw)
+    tall, short, row = (b * h, s, d), (b * kv, s, d), (b * h, 1, s)
+    assert _kernel_operands(eqns) == [
+        [tall, short, short], [tall, tall, row, row, short, short]]
+    # q, k, v and the cotangent in, out and dq, dk, dv back
+    assert sum(e.primitive.name == "transpose"
+               and e.invars[0].aval.ndim == 4 for e in eqns) == 8
+
+    def by_hand(q, k, v):
+        to3 = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, x.shape[1], d)  # noqa: E731
+        out3 = attn._flash(to3(q), to3(k), to3(v), d ** -0.5,
+                           kw.get("causal", False), None, None, True,
+                           kw.get("mask"), None)
+        return jnp.moveaxis(out3.reshape(b, h, s, d), 1, 2).sum()
+
+    q, k = jnp.zeros((b, s, h, d)), jnp.zeros((b, s, kv, d))
+    assert _stripped(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True, **kw).sum(),
+        q, k, k) == _stripped(by_hand, q, k, k)
+
+
+@pytest.mark.parametrize("prefix", ["", "bwd_"], ids=["forward", "backward"])
+@pytest.mark.parametrize("d,in_place", [(128, True), (128, False),
+                                        (64, False)])
+def test_tile_notes_name_the_layout(caplog, prefix, d, in_place):
+    """Where the kernels read the projections' arrays the debug line ends
+    in ``layout=bshd`` and the gauges carry the label ``layout``; with
+    four-axis operands neither is there."""
+    import logging
+    from dt_tpu.obs import metrics as obs_metrics
+    obs_metrics.set_enabled(True)
+    try:
+        obs_metrics.registry().clear()
+        attn._note_tiles.cache_clear()
+        attn._flash_fwd_pallas.clear_cache()
+        attn._flash_bwd_pallas.clear_cache()
+        q, k = jnp.zeros((1, 256, 8, d)), jnp.zeros((1, 256, 2, d))
+        flash = edge.placed(causal=True) if in_place \
+            else lambda q, k, v: flash_attention(q, k, v, causal=True)  # noqa: E731
+        run = lambda q: flash(q, k, k).sum()  # noqa: E731
+        if prefix:
+            run = jax.grad(run)
+        with caplog.at_level(logging.DEBUG, logger="dt_tpu"):
+            jax.eval_shape(run, q)
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith(f"# flash_{prefix}tiles")]
+        assert len(lines) == 1, lines
+        assert lines[0].endswith(" rep=4 layout=bshd" if in_place
+                                 else " rep=4"), lines
+        labels = [lk for name, lk, _ in obs_metrics.registry().gauges_export()
+                  if name.startswith(f"flash.{prefix}")]
+        assert len(labels) == 3
+        assert [lk.get("layout") for lk in labels] == \
+            [("bshd" if in_place else None)] * 3
     finally:
         obs_metrics.set_enabled(None)
         obs_metrics.registry().clear()

@@ -253,3 +253,50 @@ def test_mosaic_takes_grouped_heads_at_the_cells_shapes(one_chip, cell, bh,
     compiled = bwd.lower(x, kv, kv, x, lse, x, *sel).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert [o.shape for o in compiled.out_info] == [(bh, s, d)] * 3
+
+
+# with the operands as the projections leave them (PR 45): (B, S, H * D) q,
+# out, do and (B, SK, KV * D) k, v, a block one head's 128 columns; the
+# three cells with heads of 128, by (batch, query heads, key-value heads)
+BSHD = [
+    ("laguna.win", 2, 64, 8, 8192, attn.WindowMask(512), True),
+    ("laguna.full", 2, 48, 8, 8192, None, True),
+    ("sdar30b", 2, 32, 4, 8192, attn.BlockDiffusionMask(4096, 4), False),
+    ("keye30b", 1, 32, 4, 16384, attn.SelectedKeysMask(), True),
+]
+
+
+@pytest.mark.parametrize("cell,b,h,kv,s,rule,causal", BSHD,
+                         ids=[c[0] for c in BSHD])
+def test_mosaic_takes_the_projections_layout_at_the_cells_shapes(
+        one_chip, cell, b, h, kv, s, rule, causal, d=128):
+    """Both passes compile with ``(1, block, 128)`` blocks of (B, S, H *
+    128) arrays under every mask rule, under the names the benchmark's
+    ``kernel.flash_*`` find them by, and give out, dq, dk, dv back in that
+    layout (dk, dv one a query head)."""
+    shape = lambda *a: jax.ShapeDtypeStruct(*a, sharding=one_chip)  # noqa: E731
+    x, kx = shape((b, s, h * d), jnp.bfloat16), \
+        shape((b, s, kv * d), jnp.bfloat16)
+    lse = shape((b * h, s), jnp.float32)
+    sel = ()
+    if isinstance(rule, attn.SelectedKeysMask):
+        words = shape((b, -(-s // attn.SEL_GROUP), s, 128), jnp.int32)
+        sel = (attn.Selection(words, words, shape((b, s // 128, s // 128),
+                                                  jnp.bool_)),)
+    kw = dict(scale=d ** -0.5, causal=causal, interpret=False, mask=rule,
+              heads=h)
+    fwd = jax.jit(lambda q, k, v, *sel: attn._flash_fwd_pallas(
+        q, k, v, block_q=None, block_k=None, selection=(sel or (None,))[0],
+        **kw))
+    bwd = jax.jit(lambda q, k, v, o, lse, do, *sel: attn._flash_bwd_pallas(
+        q, k, v, o, lse, do, selection=(sel or (None,))[0], **kw))
+    compiled = (fwd.lower(x, kx, kx, *sel).compile(),
+                bwd.lower(x, kx, kx, x, lse, x, *sel).compile())
+    names = ("", "flash_bwd") if rule is None else (
+        attn._kernel_name(rule, "fwd"), attn._kernel_name(rule, "bwd"))
+    for done, name in zip(compiled, names):
+        calls = [ln.split("=")[0] for ln in done.as_text().splitlines()
+                 if "tpu_custom_call" in ln and "custom-call(" in ln]
+        assert calls and all(name in c for c in calls), calls
+    assert [o.shape for o in compiled[0].out_info] == [x.shape, lse.shape]
+    assert [o.shape for o in compiled[1].out_info] == [x.shape] * 3

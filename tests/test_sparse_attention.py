@@ -141,8 +141,11 @@ def test_the_walk_under_a_selection_beside_causal_matches_a_dense_softmax(
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("rep,d", [(1, 128), (4, 64), (8, 128)])
-def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, dtype):
+@pytest.mark.parametrize("rep,d,in_place", [
+    (1, 128, False), (4, 64, False), (4, 128, False), (8, 128, False),
+    (1, 128, True), (4, 128, True), (8, 128, True)])
+def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, in_place,
+                                                            dtype):
     """(B, S, KV, D) keys and values under a selection beside ``causal``
     (PR 44: the index maps read key-value head ``h // rep``, and the
     bitmaps' own maps still divide by the query heads): output, log-sum-exp
@@ -158,9 +161,11 @@ def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, dtype):
         dtype) for key, h in zip(keys, (kv * rep, kv, kv)))
     args32 = tuple(a.astype(jnp.float32) for a in args)
     w = jax.random.normal(keys[3], args[0].shape)
-    flash = lambda *a: flash_attention(  # noqa: E731
-        *a, causal=True, mask=SelectedKeysMask(), selection=sel,
-        interpret=True, return_lse=True)
+    kw = dict(causal=True, mask=SelectedKeysMask(), selection=sel,
+              interpret=True, return_lse=True)
+    # ``in_place``: the same heads as the projections' (B, S, H * D) arrays
+    flash = edge.placed(**kw) if in_place \
+        else lambda *a: flash_attention(*a, **kw)  # noqa: E731
     dense = lambda q, k, v: _dense(  # noqa: E731
         q, *(jnp.repeat(x, rep, axis=2) for x in (k, v)), allowed,
         with_lse=True)

@@ -24,6 +24,7 @@ Usage:  python tools/pallas_drive.py                       # full sweep
         python tools/pallas_drive.py --only grouped_mm_tiles_8  # 8 x 1,792
         python tools/pallas_drive.py --only ssd_scan  # Mamba-2 scan, by hb
         python tools/pallas_drive.py --only flash_edge_walk  # crossed tiles
+        python tools/pallas_drive.py --only flash_layout  # operand layouts
         DT_FORCE_CPU=1 python tools/pallas_drive.py --small   # smoke
 """
 
@@ -342,6 +343,101 @@ def flash_edge_walk_sweep(rng, B, S, H, D, dt, rule=None, subs=(0, 128, 256),
             rec[f"{name}_pairs_computed_pct"] = round(100 * computed / run,
                                                       2)
         yield rec
+
+
+def _device_ms(fn, *args, iters=8):
+    """The median device time of the longest-running operation of ``fn``
+    (the kernel, when ``fn`` is one kernel call) over ``iters`` calls, from
+    a ``jax.profiler`` trace read through ``benchmark/xplane.py`` (a
+    trace without a device line, a CPU's, raises)."""
+    import shutil
+    import statistics
+    import tempfile
+    import jax
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import xplane
+    jax.block_until_ready(fn(*args))
+    where = tempfile.mkdtemp(prefix="flash_layout_")
+    try:
+        with jax.profiler.trace(where):
+            for _ in range(iters):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        by_name = {}
+        for plane, line, events in xplane.load(xplane.find(where)):
+            if plane.startswith("/device:") and line == xplane.OPS_LINE:
+                for name, _, dur, _ in events:
+                    by_name.setdefault(name, []).append(dur)
+    finally:
+        shutil.rmtree(where, ignore_errors=True)
+    if not by_name:
+        raise RuntimeError("the trace holds no device operations")
+    return statistics.median(max(by_name.values(), key=sum)) / 1e6
+
+
+# the three cells with heads of 128 (laguna-xs2...'s sliding and full layers,
+# sdar30b..., keye30b...): batch, positions, query heads, key-value heads,
+# the rule
+FLASH_LAYOUT_CASES = [(2, 8192, 64, 8, 512), (2, 8192, 48, 8, None),
+                      (2, 8192, 32, 4, (4096, 4)), (1, 16384, 32, 4, "sel")]
+
+
+def flash_layout_sweep(rng, B, S, H, KV, rule, dt, D=128, iters=8,
+                       interpret=None):
+    """Both kernels alone with ``(B * H, S, D)`` operands, heads after the
+    batch, and with ``(B, S, H * D)`` operands, a block one head's 128
+    columns of rows ``H * D`` apart (PERF.md section 6, PR 45): one record
+    a layout, device event times (the host clock on a CPU) and the gap
+    between the two layouts' results."""
+    import jax
+    import jax.numpy as jnp
+    from dt_tpu.ops.pallas import attention as attn
+    selected = rule == "sel"
+    mask = attn.SelectedKeysMask() if selected else None if rule is None \
+        else attn.WindowMask(rule) if isinstance(rule, int) \
+        else attn.BlockDiffusionMask(*rule)
+    causal = not isinstance(mask, attn.BlockDiffusionMask)
+    if interpret is None:
+        interpret = attn._default_interpret()
+    mk = lambda h: jnp.asarray(rng.randn(B, S, h, D) * 0.3, dt)  # noqa: E731
+    q, k, v, do = mk(H), mk(KV), mk(KV), mk(H)
+    sel = None
+    if selected:    # every query its own key and the 2,048 before it
+        pos = jnp.arange(S)
+        near = (pos[None, :] <= pos[:, None]) \
+            & (pos[None, :] > pos[:, None] - 2048)
+        sel = attn.pack_selection(jnp.broadcast_to(near, (B, S, S)))
+    layouts = {
+        "bhsd": (None, lambda x: jnp.moveaxis(x, 2, 1).reshape(
+            -1, S, D), lambda x3: jnp.moveaxis(
+                x3.reshape(B, -1, S, D), 1, 2)),
+        "bshd": (H, lambda x: x.reshape(B, S, -1),
+                 lambda x3: x3.reshape(B, S, -1, D))}
+    want = None
+    for name, (heads, to3, back) in layouts.items():
+        kw = dict(scale=D ** -0.5, causal=causal, interpret=interpret,
+                  mask=mask, selection=sel, heads=heads)
+        fwd = jax.jit(lambda q, k, v, kw=kw: attn._flash_fwd_pallas(
+            q, k, v, block_q=None, block_k=None, **kw))
+        bwd = jax.jit(lambda *a, kw=kw: attn._flash_bwd_pallas(*a, **kw))
+        args = tuple(to3(x) for x in (q, k, v))
+        out, lse = fwd(*args)
+        grads = bwd(*args, out, lse, to3(do))
+        got = (back(out), lse) + tuple(back(g) for g in grads)
+        want = want or got
+        time_of = _timeit if jax.default_backend() == "cpu" else _device_ms
+        yield {"kernel": "flash_layout", "layout": name,
+               "shape": f"B{B}xS{S}xH{H}xKV{KV}xD{D} {jnp.dtype(dt).name}"
+               + (f" {mask}" if mask else ""),
+               "vs_bhsd_max_abs_err": _err(got, want),
+               "fwd_ms": round(time_of(fwd, *args, iters=iters), 4),
+               "bwd_ms": round(time_of(bwd, *args, out, lse, to3(do),
+                                       iters=iters), 4),
+               "clock": "host" if time_of is _timeit else "device",
+               "backend": jax.default_backend()}
 
 
 # the two routed cells' buffers (sdar30b..., keye30b...) against gate/up and
@@ -671,6 +767,16 @@ def main():
                                  else FLASH_EDGE_CASES):
             for rec in flash_edge_walk_sweep(rng, B, S, H, D, dt, rule,
                                              iters=args.iters):
+                print(json.dumps(rec), flush=True)
+
+    # ---- both flash kernels by operand layout at head size 128 (PR 45) ---
+    if wanted("flash_layout"):
+        for B, S, H, KV, rule in ([(1, 256, 4, 2, None), (1, 256, 4, 1, 128),
+                                   (1, 256, 2, 2, (128, 4)),
+                                   (1, 256, 4, 2, "sel")] if args.small
+                                  else FLASH_LAYOUT_CASES):
+            for rec in flash_layout_sweep(rng, B, S, H, KV, rule, dt,
+                                          iters=min(args.iters, 8)):
                 print(json.dumps(rec), flush=True)
 
     # ---- the grouped products at 32 groups of 2,048 x 512 (PR 41) --------
