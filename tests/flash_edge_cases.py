@@ -46,25 +46,39 @@ def kernels_match(allowed, d: int, sub, *, oracle=dense, mask=None,
     sub-blocks of ``sub`` against ``oracle(q, k, v, allowed)`` on the spiky
     inputs; ``block`` forces square tiles of that side on both passes."""
     q, k, v, do = spiky_inputs(allowed, d)
-    allowed = jnp.asarray(allowed)
     kw = dict(scale=d ** -0.5, causal=causal, interpret=True, mask=mask,
               selection=selection, sub=sub, block_q=block, block_k=block)
     out, lse = attn._flash_fwd_pallas(q, k, v, **kw)
-    want, vjp = jax.vjp(lambda *a: oracle(*a, allowed), q, k, v)
+    want, wants, gap = _oracle_side(oracle, q, k, v, do, np.asarray(allowed))
     np.testing.assert_allclose(out, want, atol=atol, err_msg="forward")
     grads = attn._flash_bwd_pallas(q, k, v, out, lse, do, **kw)
-    for name, got, ref in zip(("dq", "dk", "dv"), grads, vjp(do)):
+    for name, got, ref in zip(("dq", "dk", "dv"), grads, wants):
         scale = float(jnp.max(jnp.abs(ref)))
         np.testing.assert_allclose(got, ref, atol=atol * max(scale, 1.0),
                                    err_msg=name)
     # the inputs do show a sub-block left out: the first allowed key's
     # value is a large part of every row's result
+    assert float(gap) > 1e-2
+
+
+def _oracle_side(oracle, q, k, v, do, allowed):
+    """What ``kernels_match`` holds the kernels to, in one jitted program:
+    the oracle's output, its three gradients under the cotangent ``do``, and
+    how far the dense result moves, at the least, in the rows with more than
+    one allowed key when each row's first allowed key is struck out."""
     without = np.array(allowed)
     rows = np.flatnonzero(without.sum(axis=1) > 1)
     without[rows, without[rows].argmax(axis=1)] = False
-    gap = jnp.abs(dense(q, k, v, jnp.asarray(without))
-                  - dense(q, k, v, allowed))[:, rows]
-    assert float(gap.max(axis=-1).min()) > 1e-2
+
+    allowed, without = jnp.asarray(allowed), jnp.asarray(without)
+
+    @jax.jit
+    def run(q, k, v, do):
+        want, vjp = jax.vjp(lambda *a: oracle(*a, allowed), q, k, v)
+        gap = jnp.abs(dense(q, k, v, without)
+                      - dense(q, k, v, allowed))[:, rows]
+        return want, vjp(do), gap.max(axis=-1).min()
+    return run(q, k, v, do)
 
 
 def walked_by_hand(allowed_fn, s: int, block: int, sub: int):
@@ -120,6 +134,18 @@ def equations(jaxpr):
         if eqn.primitive.name != "pallas_call":
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from equations(sub)
+
+
+def out_and_grads(f, args, loss):
+    """``f(*args)`` and the gradient of ``loss(f(*args))`` in every argument,
+    from one jitted trace: the kernel under ``f`` is traced and compiled once
+    for both, and an oracle runs as one program and not op by op."""
+    def run(*a):
+        out = f(*a)
+        return loss(out), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        run, argnums=tuple(range(len(args))), has_aux=True))(*args)
+    return out, grads
 
 
 def placed(**kw):

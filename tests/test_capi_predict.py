@@ -15,7 +15,9 @@ import sys
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import spawned
+from spawned import REPO
+
 NATIVE = os.path.join(REPO, "dt_tpu", "native")
 
 
@@ -67,14 +69,10 @@ def test_c_host_serves_onnx_model(tmp_path):
 
     # 3) run the C host (its embedded interpreter must find the venv +
     # repo, and must not touch a wedged TPU backend)
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
     site = [p for p in sys.path if p.endswith("site-packages")]
-    env["PYTHONPATH"] = os.pathsep.join([REPO] + site)
-    env["DT_FORCE_CPU"] = "1"
-    r = subprocess.run([exe, onnx_path, "1", "6", "6", "1"],
-                       capture_output=True, text=True, timeout=300,
-                       env=env)
+    r = spawned.run([exe, onnx_path, "1", "6", "6", "1"],
+                    PYTHONPATH=os.pathsep.join([REPO] + site),
+                    DT_FORCE_CPU=1)
     assert r.returncode == 0, r.stdout[-500:] + r.stderr[-1500:]
     lines = r.stdout.strip().splitlines()
     assert lines[0].startswith("OUT ")

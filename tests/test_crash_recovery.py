@@ -9,15 +9,9 @@ removal.
 
 import json
 import os
-import signal
-import subprocess
-import sys
 import time
 
 from dt_tpu.elastic import Scheduler
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-WORKER = os.path.join(HERE, "elastic_worker.py")
 
 
 def _write_hosts(path, hosts):
@@ -27,18 +21,14 @@ def _write_hosts(path, hosts):
     os.replace(tmp, path)
 
 
-def _spawn(port, host, out, num_epoch):
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["ELASTIC_TRAINING_ENABLED"] = "1"
-    return subprocess.Popen(
-        [sys.executable, WORKER, "--scheduler-port", str(port),
-         "--host", host, "--num-epoch", str(num_epoch), "--out", out,
-         "--heartbeat", "0.2"],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+def _spawn(workers, port, host, out, num_epoch, **env):
+    return workers.spawn(
+        "elastic_worker.py", "--scheduler-port", port, "--host", host,
+        "--num-epoch", num_epoch, "--out", out, "--heartbeat", "0.2",
+        ELASTIC_TRAINING_ENABLED=1, **env)
 
 
-def test_sigkill_worker_is_evicted_and_job_completes(tmp_path):
+def test_sigkill_worker_is_evicted_and_job_completes(tmp_path, workers):
     hw = str(tmp_path / "host_worker")
     _write_hosts(hw, ["w0", "w1", "w2"])
     outs = {h: str(tmp_path / f"{h}.json") for h in ("w0", "w1", "w2")}
@@ -47,19 +37,15 @@ def test_sigkill_worker_is_evicted_and_job_completes(tmp_path):
     try:
         num_epoch = 40  # long enough that the kill lands mid-run
         for h in ("w0", "w1", "w2"):
-            procs[h] = _spawn(sched.port, h, outs[h], num_epoch)
+            procs[h] = _spawn(workers, sched.port, h, outs[h], num_epoch)
         # wait until training is underway, then SIGKILL w2 (no cleanup,
         # no goodbye — the crash case)
-        deadline = time.time() + 300  # 1-core box: 3x jax-import under load
-        while sched._last_completed_epoch < 2:
-            assert time.time() < deadline, "training never started"
-            time.sleep(0.1)
+        workers.until(lambda: sched._last_completed_epoch >= 2,
+                      "training never started")
         procs["w2"].kill()
 
         for h in ("w0", "w1"):
-            rc = procs[h].wait(timeout=240)
-            assert rc == 0, f"{h} rc={rc}:\n" \
-                f"{procs[h].stdout.read().decode()[-3000:]}"
+            workers.finish(procs[h], h)
 
         r0 = json.load(open(outs["w0"]))
         r1 = json.load(open(outs["w1"]))
@@ -75,12 +61,9 @@ def test_sigkill_worker_is_evicted_and_job_completes(tmp_path):
         assert not os.path.exists(outs["w2"])  # w2 died before finishing
     finally:
         sched.close()
-        for p in procs.values():
-            if p.poll() is None:
-                p.kill()
 
 
-def test_crashed_worker_reenters_under_old_identity(tmp_path):
+def test_crashed_worker_reenters_under_old_identity(tmp_path, workers):
     """Identity reissue (ps-lite ``van.cc:187-218`` ``is_recovery``): a
     SIGKILLed worker is evicted, restarts under its OLD host name with
     ``DT_RECOVERY=1``, is re-admitted at the next membership barrier AS
@@ -92,45 +75,27 @@ def test_crashed_worker_reenters_under_old_identity(tmp_path):
     go_file = str(tmp_path / "go_recover")
     sched = Scheduler(host_worker_file=hw, auto_evict_dead_s=6.0)
     procs = {}
-    restarted = None
     try:
         num_epoch = 100  # wide re-entry window: under heavy load the
         # restarted worker needs many epoch boundaries to catch one
         for h in ("w0", "w1", "w2"):
-            procs[h] = _spawn(sched.port, h, outs[h], num_epoch)
-        deadline = time.time() + 300  # 1-core box: 3x jax-import under load
-        while sched._last_completed_epoch < 2:
-            assert time.time() < deadline, "training never started"
-            time.sleep(0.1)
+            procs[h] = _spawn(workers, sched.port, h, outs[h], num_epoch)
+        workers.until(lambda: sched._last_completed_epoch >= 2,
+                      "training never started")
         procs["w2"].kill()
 
         # pre-warm the replacement process NOW (it parks on go_file);
         # registration must wait until the eviction landed, or it would
         # take the ordinary quick-restart path instead of recovery
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        env["ELASTIC_TRAINING_ENABLED"] = "1"
-        env["DT_RECOVERY"] = "1"
-        env["DT_WAIT_FILE"] = go_file
-        restarted = subprocess.Popen(
-            [sys.executable, WORKER, "--scheduler-port", str(sched.port),
-             "--host", "w2", "--num-epoch", str(num_epoch),
-             "--out", outs["w2"], "--heartbeat", "0.2"],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+        procs["w2"] = _spawn(workers, sched.port, "w2", outs["w2"],
+                             num_epoch, DT_RECOVERY=1, DT_WAIT_FILE=go_file)
 
-        deadline = time.time() + 60
-        while "w2" not in sched._removed_hosts:
-            assert time.time() < deadline, "eviction never happened"
-            time.sleep(0.1)
+        workers.until(lambda: "w2" in sched._removed_hosts,
+                      "eviction never happened")
         open(go_file, "w").close()  # release the recovery registration
 
-        rcs = {}
-        for h in ("w0", "w1"):
-            rcs[h] = procs[h].wait(timeout=300)
-        rcs["w2"] = restarted.wait(timeout=300)
-        for h, rc in rcs.items():
-            p = restarted if h == "w2" else procs[h]
-            assert rc == 0, f"{h} rc={rc}:\n{p.stdout.read().decode()[-3000:]}"
+        for h in ("w0", "w1", "w2"):
+            workers.finish(procs[h], h)
 
         results = {h: json.load(open(outs[h])) for h in ("w0", "w1", "w2")}
         # exact sync across ALL THREE, and the job ended as a 3-worker job
@@ -146,9 +111,6 @@ def test_crashed_worker_reenters_under_old_identity(tmp_path):
         assert sorted(hosts) == ["w0", "w1", "w2"]
     finally:
         sched.close()
-        for p in list(procs.values()) + ([restarted] if restarted else []):
-            if p.poll() is None:
-                p.kill()
 
 
 def test_quick_restart_recovery_before_eviction(tmp_path):

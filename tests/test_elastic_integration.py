@@ -17,15 +17,10 @@ IDENTICAL parameters (exact sync).
 
 import json
 import os
-import subprocess
-import sys
 
 import pytest
 
 from dt_tpu.elastic import Scheduler
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-WORKER = os.path.join(HERE, "elastic_worker.py")
 
 
 def _write_hosts(path, hosts):
@@ -35,18 +30,14 @@ def _write_hosts(path, hosts):
     os.replace(tmp, path)
 
 
-def _spawn(port, host, out, num_epoch=6, extra_env=None):
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["ELASTIC_TRAINING_ENABLED"] = "1"
-    env.update(extra_env or {})
-    return subprocess.Popen(
-        [sys.executable, WORKER, "--scheduler-port", str(port),
-         "--host", host, "--num-epoch", str(num_epoch), "--out", out],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
+def _spawn(workers, port, host, out, num_epoch=6, **env):
+    return workers.spawn(
+        "elastic_worker.py", "--scheduler-port", port, "--host", host,
+        "--num-epoch", num_epoch, "--out", out,
+        ELASTIC_TRAINING_ENABLED=1, **env)
 
 
-def test_elastic_add_remove_cycle(tmp_path):
+def test_elastic_add_remove_cycle(tmp_path, workers):
     hw = str(tmp_path / "host_worker")
     _write_hosts(hw, ["w0", "w1"])
     outs = {h: str(tmp_path / f"{h}.json") for h in ("w0", "w1", "w2")}
@@ -56,9 +47,8 @@ def test_elastic_add_remove_cycle(tmp_path):
     def launch_new_worker(host, epoch):
         # the reference shells out `launch.py --launch-worker True
         # --env NEW_WORKER:1 --env EPOCH_BEGIN:<e>` (elastic_training.cc:26-62)
-        procs[host] = _spawn(
-            sched.port, host, outs[host], num_epoch,
-            extra_env={"NEW_WORKER": "1", "EPOCH_BEGIN": str(epoch)})
+        procs[host] = _spawn(workers, sched.port, host, outs[host],
+                             num_epoch, NEW_WORKER=1, EPOCH_BEGIN=epoch)
 
     # "operator" schedule, applied right before the barrier's host_worker
     # diff (the EC2 manager thread analog, launch.py:88-235): add w2 at the
@@ -72,22 +62,16 @@ def test_elastic_add_remove_cycle(tmp_path):
     sched = Scheduler(host_worker_file=hw, launch_callback=launch_new_worker,
                       pre_change_hook=operator)
     try:
-        procs["w0"] = _spawn(sched.port, "w0", outs["w0"], num_epoch)
-        procs["w1"] = _spawn(sched.port, "w1", outs["w1"], num_epoch)
-
         for h in ("w0", "w1"):
-            rc = procs[h].wait(timeout=240)
-            assert rc == 0, f"{h} rc={rc}:\n" \
-                f"{procs[h].stdout.read().decode()[-3000:]}"
+            procs[h] = _spawn(workers, sched.port, h, outs[h], num_epoch)
+        for h in ("w0", "w1"):
+            workers.finish(procs[h], h)
         assert "w2" in procs, "scheduler never launched w2"
-        rc = procs["w2"].wait(timeout=60)
-        assert rc == 0, f"w2 rc={rc}:\n" \
-            f"{procs['w2'].stdout.read().decode()[-3000:]}"
+        workers.finish(procs["w2"], "w2")
 
         r0 = json.load(open(outs["w0"]))
         r1 = json.load(open(outs["w1"]))
         r2 = json.load(open(outs["w2"]))
-        del procs["w2"]  # already waited
 
         # base workers ran all epochs and ended in exact sync
         assert r0["final_step"] == r1["final_step"]
@@ -109,12 +93,9 @@ def test_elastic_add_remove_cycle(tmp_path):
         assert int(s2) == int(s1) + 1
     finally:
         sched.close()
-        for p in procs.values():
-            if p.poll() is None:
-                p.kill()
 
 
-def test_elastic_accuracy_matches_static(tmp_path):
+def test_elastic_accuracy_matches_static(tmp_path, workers):
     """BASELINE north-star at CPU scale: an add+remove cycle with FIXED
     global batch must track the uninterrupted run's held-out validation
     curve after the change and land at the same final accuracy (<0.2%
@@ -133,9 +114,8 @@ def test_elastic_accuracy_matches_static(tmp_path):
         procs = {}
 
         def launch_new(host, epoch):
-            procs[host] = _spawn(sched.port, host, outs[host], num_epoch,
-                                 extra_env={"NEW_WORKER": "1",
-                                            "EPOCH_BEGIN": str(epoch)})
+            procs[host] = _spawn(workers, sched.port, host, outs[host],
+                                 num_epoch, NEW_WORKER=1, EPOCH_BEGIN=epoch)
 
         def operator(epoch):
             if not elastic_cycle:
@@ -150,19 +130,14 @@ def test_elastic_accuracy_matches_static(tmp_path):
                           pre_change_hook=operator)
         try:
             for h in ("w0", "w1"):
-                procs[h] = _spawn(sched.port, h, outs[h], num_epoch)
+                procs[h] = _spawn(workers, sched.port, h, outs[h], num_epoch)
             for h in ("w0", "w1"):
-                rc = procs[h].wait(timeout=300)
-                assert rc == 0, \
-                    f"{tag}/{h}:\n{procs[h].stdout.read().decode()[-2000:]}"
+                workers.finish(procs[h], f"{tag}/{h}")
             if "w2" in procs:
-                procs["w2"].wait(timeout=60)
-            return json.load(open(outs[f"w0"]))
+                workers.wait(procs["w2"])
+            return json.load(open(outs["w0"]))
         finally:
             sched.close()
-            for p in procs.values():
-                if p.poll() is None:
-                    p.kill()
 
     static = run("static", elastic_cycle=False)
     elastic = run("elastic", elastic_cycle=True)
@@ -188,7 +163,7 @@ def test_elastic_accuracy_matches_static(tmp_path):
     assert sum(deltas) / len(deltas) <= 0.002 + 1e-9, (deltas, sc, ec)
 
 
-def test_elastic_add_remove_cycle_over_sharded_plane(tmp_path):
+def test_elastic_add_remove_cycle_over_sharded_plane(tmp_path, workers):
     """The full scripted add/remove cycle with the host-sync gradient
     plane routed across a 2-server RangeServer fleet: exact sync, joiner
     bootstrap, and the audit trail all hold when the funnel is sharded
@@ -202,9 +177,8 @@ def test_elastic_add_remove_cycle_over_sharded_plane(tmp_path):
     num_epoch = 6
 
     def launch_new_worker(host, epoch):
-        procs[host] = _spawn(
-            sched.port, host, outs[host], num_epoch,
-            extra_env={"NEW_WORKER": "1", "EPOCH_BEGIN": str(epoch)})
+        procs[host] = _spawn(workers, sched.port, host, outs[host],
+                             num_epoch, NEW_WORKER=1, EPOCH_BEGIN=epoch)
 
     def operator(epoch):
         if epoch == 2:
@@ -219,21 +193,16 @@ def test_elastic_add_remove_cycle_over_sharded_plane(tmp_path):
                            advertise_host="127.0.0.1")
                for i in range(2)]
     try:
-        procs["w0"] = _spawn(sched.port, "w0", outs["w0"], num_epoch)
-        procs["w1"] = _spawn(sched.port, "w1", outs["w1"], num_epoch)
         for h in ("w0", "w1"):
-            rc = procs[h].wait(timeout=240)
-            assert rc == 0, f"{h} rc={rc}:\n" \
-                f"{procs[h].stdout.read().decode()[-3000:]}"
+            procs[h] = _spawn(workers, sched.port, h, outs[h], num_epoch)
+        for h in ("w0", "w1"):
+            workers.finish(procs[h], h)
         assert "w2" in procs, "scheduler never launched w2"
-        rc = procs["w2"].wait(timeout=60)
-        assert rc == 0, f"w2 rc={rc}:\n" \
-            f"{procs['w2'].stdout.read().decode()[-3000:]}"
+        workers.finish(procs["w2"], "w2")
 
         r0 = json.load(open(outs["w0"]))
         r1 = json.load(open(outs["w1"]))
         r2 = json.load(open(outs["w2"]))
-        del procs["w2"]
         assert r0["final_step"] == r1["final_step"]
         assert r0["param_hash"] == pytest.approx(r1["param_hash"],
                                                  abs=1e-12)
@@ -246,6 +215,3 @@ def test_elastic_add_remove_cycle_over_sharded_plane(tmp_path):
         sched.close()
         for s in servers:
             s.close()
-        for p in procs.values():
-            if p.poll() is None:
-                p.kill()

@@ -4,11 +4,14 @@ Interpret mode on the CPU mesh (exact values); TPU numerics are verified
 by drives per CLAUDE.md.
 """
 
+import functools
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
+import flash_edge_cases as edge
 from dt_tpu.ops.pallas import attention as attn
 from dt_tpu.ops.pallas.attention import flash_attention
 from dt_tpu.parallel.ring_attention import full_attention
@@ -53,8 +56,8 @@ def test_flash_backward_matches_oracle(causal):
         o = full_attention(q, k, v, causal=causal)
         return (o.astype(jnp.float32) ** 2).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for name, a, b in zip("qkv", gf, gr):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-4,
@@ -101,9 +104,34 @@ def _tiles_for(s, tiles):
         else (None, None)
 
 
+def _squared(out):
+    return (out.astype(jnp.float32) ** 2).sum()
+
+
 def _grads(f, q, k, v):
-    loss = lambda q, k, v: (f(q, k, v).astype(jnp.float32) ** 2).sum()
-    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return edge.out_and_grads(f, (q, k, v), _squared)[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle_at(seed, s, h, causal, dtype=jnp.float32):
+    """``_qkv(seed, 1, s, h, 64)`` as ``dtype``, and the oracle's output and
+    gradients in float32 on those values: one oracle for every tile pair of
+    a length."""
+    qkv = tuple(t.astype(dtype) for t in _qkv(
+        np.random.RandomState(seed), b=1, s=s, h=h, d=64))
+    return (qkv,) + edge.out_and_grads(
+        lambda q, k, v: full_attention(q, k, v, causal=causal),
+        tuple(t.astype(jnp.float32) for t in qkv), _squared)
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_at(seed, s, h, causal, bq, bk, dtype=jnp.float32):
+    """The kernels' output and gradients at ``_oracle_at``'s operands under
+    one pair of tiles (640's four pairs are one derived default)."""
+    return edge.out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal, block_q=bq,
+                                        block_k=bk),
+        _oracle_at(seed, s, h, causal, dtype)[0], _squared)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -114,16 +142,12 @@ def test_flash_tiles_forward_and_gradients_match_oracle(tiles, s, causal):
     multiple of 128 and of nothing larger: its pairs fall to the derived
     default, which must still divide it)."""
     bq, bk = _tiles_for(s, tiles)
-    rng = np.random.RandomState(s + 7 * causal)
-    q, k, v = _qkv(rng, b=1, s=s, h=1, d=64)
-    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
-                                            block_q=bq, block_k=bk)
-    ref = lambda q, k, v: full_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(ref(q, k, v)),
+    seed = s + 7 * causal
+    got, grads = _flash_at(seed, s, 1, causal, bq, bk)
+    _, want, wants = _oracle_at(seed, s, 1, causal)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-5)
-    for name, a, b in zip("qkv", _grads(flash, q, k, v),
-                          _grads(ref, q, k, v)):
+    for name, a, b in zip("qkv", grads, wants):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-4,
                                    err_msg=f"d{name} {bq}x{bk} s={s}")
@@ -136,20 +160,15 @@ def test_flash_bfloat16_operands_match_float32_oracle(tiles, causal):
     bfloat16 values: the products q k^T are exact, the probabilities and
     the output round to bfloat16, so the gap is a bfloat16 step (2^-8 of
     the largest value) and not a float32 one."""
-    rng = np.random.RandomState(11 + causal)
-    qkv = [t.astype(jnp.bfloat16) for t in _qkv(rng, b=1, s=512, h=2, d=64)]
-    qkv32 = [t.astype(jnp.float32) for t in qkv]
-    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
-                                            block_q=tiles[0],
-                                            block_k=tiles[1])
-    ref = lambda q, k, v: full_attention(q, k, v, causal=causal)
+    at = (11 + causal, 512, 2, causal)
+    _, want, wants = _oracle_at(*at, jnp.bfloat16)
+    got, grads = _flash_at(*at, *tiles, jnp.bfloat16)
     step = 2.0 ** -8
-    got, want = flash(*qkv), ref(*qkv32)
     assert got.dtype == jnp.bfloat16
     want = np.asarray(want)
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=0, atol=2 * step * np.abs(want).max())
-    for name, a, b in zip("qkv", _grads(flash, *qkv), _grads(ref, *qkv32)):
+    for name, a, b in zip("qkv", grads, wants):
         assert a.dtype == jnp.bfloat16
         b = np.asarray(b)
         np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=0,
@@ -319,8 +338,6 @@ def test_tiles_are_recorded_once_per_shape(caplog, prefix):
 
 
 # -- crossed tiles walked in sub-blocks (PR 42) -------------------------------
-
-import flash_edge_cases as edge   # noqa: E402
 
 
 def _causal_oracle(q, k, v, allowed):
@@ -498,6 +515,27 @@ LAYOUTS = [g + (False,) for g in GROUPS] + [
     g + (True,) for g in GROUPS if g[1] % 128 == 0]
 
 
+def _weighed(w):
+    return lambda out: (out.astype(jnp.float32) * w).sum()
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_case(rule, rep, d, dtype):
+    """``_grouped_inputs``' operands and weight for a case, with the
+    oracle's output and gradients on the same values in float32 and on
+    spread heads: one oracle for both layouts of the operands."""
+    kw = GROUPED_RULES[rule]
+    args, args32, w = _grouped_inputs(rep + d, rep, d, dtype)
+    if rule == "causal":
+        ref = lambda q, k, v: full_attention(  # noqa: E731
+            q, _spread(k, q.shape[2]), _spread(v, q.shape[2]), causal=True)
+    else:
+        pos = np.arange(args[0].shape[1])
+        allowed = kw["mask"].allowed(pos[:, None], pos[None, :])
+        ref = lambda q, k, v: _dense_spread(q, k, v, allowed)  # noqa: E731
+    return (args, w) + edge.out_and_grads(ref, args32, _weighed(w))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("rep,d,in_place", LAYOUTS, ids=[
@@ -510,22 +548,12 @@ def test_grouped_heads_match_the_oracle_on_spread_heads(rule, rep, d,
     ``jnp.repeat``; dk and dv come back with ``KV`` heads, summed over
     each group.  ``in_place``: the same operands as rank-3 arrays."""
     kw = GROUPED_RULES[rule]
-    args, args32, w = _grouped_inputs(rep + d, rep, d, dtype)
+    args, w, want, wants = _grouped_case(rule, rep, d, dtype)
     flash = edge.placed(**kw) if in_place \
         else lambda q, k, v: flash_attention(q, k, v, **kw)  # noqa: E731
-    if rule == "causal":
-        ref = lambda q, k, v: full_attention(  # noqa: E731
-            q, _spread(k, q.shape[2]), _spread(v, q.shape[2]), causal=True)
-    else:
-        pos = np.arange(args[0].shape[1])
-        allowed = kw["mask"].allowed(pos[:, None], pos[None, :])
-        ref = lambda q, k, v: _dense_spread(q, k, v, allowed)  # noqa: E731
-    got = flash(*args)
+    got, grads = edge.out_and_grads(flash, args, _weighed(w))
     assert got.dtype == dtype
-    _close(got, ref(*args32), dtype)
-    weighed = lambda f: lambda *a: (f(*a).astype(jnp.float32) * w).sum()  # noqa: E731
-    grads = jax.grad(weighed(flash), argnums=(0, 1, 2))(*args)
-    wants = jax.grad(weighed(ref), argnums=(0, 1, 2))(*args32)
+    _close(got, want, dtype)
     for name, a, x, b in zip("qkv", grads, args, wants):
         assert a.shape == x.shape and a.dtype == dtype, name
         _close(a, b, dtype, grad=True)

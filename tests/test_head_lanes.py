@@ -5,6 +5,8 @@ D)`` arrays (``attention.by_head``), where the flash kernels read and write
 holds ``(B, S, H, D)`` arrays and a dense softmax; the interpreter, small
 sizes."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -56,17 +58,30 @@ def _layer_and_inputs(name, attention, dtype=jnp.float32):
     return layer, {"params": made}, x, pos
 
 
+def _weighed_sum_and_grads(name, attention, dtype=jnp.float32):
+    """The layer's output weighed and summed in float32, and that sum's
+    gradient in the variables and the input, as one jitted program."""
+    layer, variables, x, pos = _layer_and_inputs(name, attention, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    run = lambda v, x: jnp.sum(  # noqa: E731
+        layer.apply(v, x, pos, mutable=["counters"])[0].astype(jnp.float32)
+        * w)
+    return jax.jit(jax.value_and_grad(run, argnums=(0, 1)))(variables, x)
+
+
+@functools.lru_cache(maxsize=None)
+def _by_head(name, dtype):
+    """The layer on the projections' arrays: each test below holds it to
+    another path, in float32 both to the same run."""
+    return _weighed_sum_and_grads(name, "flash", dtype)
+
+
 @pytest.mark.parametrize("name", sorted(LAYERS))
 def test_the_by_head_path_is_the_plain_paths_layer(name):
     """Output and every gradient of the layer under ``attention="flash"``
     at head size 128 against the plain path in float32."""
-    flash, variables, x, pos = _layer_and_inputs(name, "flash")
-    plain = _layer_and_inputs(name, None)[0]
-    w = jax.random.normal(jax.random.PRNGKey(2), x.shape)
-    run = lambda m: lambda v, x: jnp.sum(  # noqa: E731
-        m.apply(v, x, pos, mutable=["counters"])[0] * w)
-    (a, da), (c, dc) = (jax.value_and_grad(run(m), argnums=(0, 1))(
-        variables, x) for m in (flash, plain))
+    (a, da), (c, dc) = (_by_head(name, jnp.float32),
+                        _weighed_sum_and_grads(name, None))
     np.testing.assert_allclose(a, c, rtol=2e-5)
     for got, want in zip(jax.tree_util.tree_leaves(da),
                          jax.tree_util.tree_leaves(dc)):
@@ -85,14 +100,9 @@ def test_the_by_head_path_is_the_four_axis_path_in_the_cells_precision(
     arithmetic between the same roundings, so output and gradients agree to
     float32's noise in float32 and to a bfloat16 step of the largest value in
     bfloat16, the cells' precision."""
-    layer, variables, x, pos = _layer_and_inputs(name, "flash", dtype)
-    w = jax.random.normal(jax.random.PRNGKey(2), x.shape)
-    run = lambda v, x: jnp.sum(  # noqa: E731
-        layer.apply(v, x, pos, mutable=["counters"])[0].astype(jnp.float32)
-        * w)
-    ours = jax.value_and_grad(run, argnums=(0, 1))(variables, x)
+    ours = _by_head(name, dtype)
     monkeypatch.setattr(routed_lm, "lane_tiled", lambda *a: False)
-    theirs = jax.value_and_grad(run, argnums=(0, 1))(variables, x)
+    theirs = _weighed_sum_and_grads(name, "flash", dtype)
     step = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-5
     for got, want in zip(jax.tree_util.tree_leaves(ours),
                          jax.tree_util.tree_leaves(theirs)):

@@ -64,6 +64,7 @@ def test_libsvm_iter(tmp_path):
         data.LibSVMIter(str(pbad), data_shape=(4,), batch_size=1)
 
 
+@pytest.mark.slow
 def test_inception_bn_and_v4_forward():
     for name, size in (("inception_bn", 64), ("inception_v4", 299)):
         model = models.create(name, num_classes=4)

@@ -93,18 +93,19 @@ def test_kernels_under_the_band_match_a_dense_masked_softmax(s, window, bq,
                for key in jax.random.split(jax.random.PRNGKey(s + window), 3))
     flash = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, mask=rule, block_q=bq, block_k=bk, interpret=True)
-    want = _dense_attention(q, k, v, rule)
-    np.testing.assert_allclose(flash(q, k, v), want, atol=2e-6)
+    # the weight is the cosine of the oracle's output, a constant
+    want, ref = edge.out_and_grads(
+        lambda *a: _dense_attention(*a, rule), (q, k, v),
+        lambda out: (out * jnp.cos(jax.lax.stop_gradient(out))).sum())
     weigh = jnp.cos(want)
-    got = jax.grad(lambda *a: (flash(*a) * weigh).sum(), argnums=(0, 1, 2))(
-        q, k, v)
-    ref = jax.grad(lambda *a: (_dense_attention(*a, rule) * weigh).sum(),
-                   argnums=(0, 1, 2))(q, k, v)
+    out, got = edge.out_and_grads(flash, (q, k, v),
+                                  lambda out: (out * weigh).sum())
+    np.testing.assert_allclose(out, want, atol=2e-6)
     for a, b in zip(got, ref):
         np.testing.assert_allclose(a, b, atol=2e-5)
     if window >= s:     # then the band is every causal key
         np.testing.assert_allclose(
-            flash(q, k, v), flash_attention(q, k, v, causal=True,
+            out, flash_attention(q, k, v, causal=True,
                                             interpret=True), atol=2e-6)
     else:               # and one key at the band's edge is seen in the result
         wider = _dense_attention(q, k, v, WindowMask(window + 1))

@@ -41,10 +41,10 @@ def test_small_models_forward(name, shape, classes):
     ("vgg11_bn", 64),
     ("alexnet", 224),
     ("mobilenet", 64),
-    ("mobilenet_v2", 64),
+    pytest.param("mobilenet_v2", 64, marks=pytest.mark.slow),
     ("squeezenet", 64),
-    ("densenet121", 64),
-    ("googlenet", 64),
+    pytest.param("densenet121", 64, marks=pytest.mark.slow),
+    pytest.param("googlenet", 64, marks=pytest.mark.slow),
     ("resnext50", 64),
 ])
 def test_imagenet_models_forward(name, size):
@@ -56,6 +56,7 @@ def test_imagenet_models_forward(name, size):
     assert bool(jnp.isfinite(logits).all())
 
 
+@pytest.mark.slow
 def test_inception_v3_forward():
     model = models.create("inception-v3", num_classes=5)
     x = jnp.ones((1, 299, 299, 3))
@@ -63,6 +64,7 @@ def test_inception_v3_forward():
     assert out.shape == (1, 5)
 
 
+@pytest.mark.slow
 def test_inception_resnet_v2_forward():
     model = models.create("inception_resnet_v2", num_classes=5)
     x = jnp.ones((1, 299, 299, 3))
@@ -80,8 +82,9 @@ def test_resnet_v2_variant():
 def test_resnet50_param_count():
     """ResNet-50 v1 must have the canonical ~25.6M params."""
     model = models.create("resnet50", num_classes=1000)
-    variables = model.init({"params": jax.random.PRNGKey(0)},
-                           jnp.ones((1, 224, 224, 3)), training=False)
+    variables = jax.eval_shape(
+        lambda x: model.init({"params": jax.random.PRNGKey(0)}, x,
+                             training=False), jnp.ones((1, 224, 224, 3)))
     n = sum(np.prod(p.shape) for p in
             jax.tree_util.tree_leaves(variables["params"]))
     assert 25.4e6 < n < 25.8e6, n
@@ -157,7 +160,9 @@ def test_resnet_per_block_remat_equivalence():
     outs = {}
     for remat in (False, True):
         m = models.create("resnet20_cifar", num_classes=4, remat=remat)
-        v = m.init({"params": jax.random.PRNGKey(0)}, x, training=False)
+        # the weights as one program; the two runs compared stay op by op
+        v = jax.jit(lambda x: m.init({"params": jax.random.PRNGKey(0)}, x,
+                                     training=False))(x)
 
         def loss(p):
             out, _ = m.apply({"params": p,
@@ -189,7 +194,9 @@ def test_transformer_per_layer_remat_equivalence():
         m = models.create("transformer_lm", vocab_size=50, num_layers=2,
                           embed_dim=32, num_heads=4, max_len=16,
                           remat=remat)
-        v = m.init({"params": jax.random.PRNGKey(0)}, x, training=False)
+        # the weights as one program; the two runs compared stay op by op
+        v = jax.jit(lambda x: m.init({"params": jax.random.PRNGKey(0)}, x,
+                                     training=False))(x)
 
         def loss(p):
             lg = m.apply({"params": p}, x, training=False)

@@ -121,16 +121,12 @@ def test_pipeline_trains():
     executions (upstream runtime race; does not affect TPU).  A wrong
     RESULT still fails immediately — only abnormal termination retries.
     """
-    import os
-    import subprocess
     import sys
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    import spawned
     for attempt in range(2):
-        r = subprocess.run([sys.executable, "-c", _TRAIN_SCRIPT],
-                           capture_output=True, text=True, timeout=300,
-                           env=env, cwd=repo)
+        r = spawned.run([sys.executable, "-c", _TRAIN_SCRIPT],
+                        cwd=spawned.REPO)
         if r.returncode == 0:
             assert "PIPELINE_TRAIN_OK" in r.stdout
             return

@@ -53,11 +53,11 @@ def test_ring_grad_flows():
     def loss(q, k, v):
         return jnp.sum(ring_attention(q, k, v, mesh) ** 2)
 
-    g = jax.grad(loss)(q, k, v)
+    g = jax.jit(jax.grad(loss))(q, k, v)
     # oracle grads
     def loss_o(q, k, v):
         return jnp.sum(full_attention(q, k, v) ** 2)
-    go = jax.grad(loss_o)(q, k, v)
+    go = jax.jit(jax.grad(loss_o))(q, k, v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(go), rtol=1e-3,
                                atol=1e-4)
 

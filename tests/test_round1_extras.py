@@ -2,6 +2,7 @@
 
 import gzip
 import struct
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +10,7 @@ import numpy as np
 import optax
 import pytest
 
+import spawned
 from dt_tpu import data, models, optim
 from dt_tpu.training import Module
 
@@ -101,10 +103,28 @@ def test_dataloader_shuffle_covers_all():
     assert seen != seen2
 
 
+def _before_the_backend(check):
+    """``check`` (a function of this module) in a process that has not
+    touched the JAX backend.  ``DataLoader`` forks its pool, and asks for
+    that before XLA's threads exist (its docstring): a pytest worker that
+    has run other files has some ninety, and a child forked there can block
+    for good on a lock one of them held, with the test waiting on it
+    (2,605 s in this PR's first run of the suite, until the child was
+    killed by hand)."""
+    r = spawned.run([sys.executable, "-c", "import test_round1_extras as t; "
+                     f"t.{check.__name__}()"], cwd=spawned.HERE,
+                    PYTHONPATH=spawned.REPO)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+
+
 def test_dataloader_with_workers():
     """num_workers>0 forks a real process pool (reference
     gluon/data/dataloader.py:26-75): batches match the in-process path
     exactly, order preserved, epochs repeat, transforms run in workers."""
+    _before_the_backend(_loader_with_workers)
+
+
+def _loader_with_workers():
     ds = data.ArrayDataset(np.arange(12).reshape(12, 1).astype(np.float32))
     loader = data.DataLoader(ds, batch_size=4, num_workers=2)
     try:
@@ -135,6 +155,10 @@ def test_dataloader_with_workers():
 def test_dataloader_workers_shuffle_matches_inprocess():
     """Same seed -> same shuffled order with and without workers (the
     sampler runs in the master; workers only evaluate batches)."""
+    _before_the_backend(_loader_workers_shuffle)
+
+
+def _loader_workers_shuffle():
     ds = data.ArrayDataset(np.arange(20).reshape(20, 1))
     a = data.DataLoader(ds, batch_size=4, shuffle=True, seed=7,
                         num_workers=2)

@@ -132,14 +132,12 @@ def test_kernels_under_the_block_mask_match_a_dense_masked_softmax(half,
     keys = jax.random.split(jax.random.PRNGKey(half + block), 4)
     q, k, v, g = (jax.random.normal(kk, (1, 2 * half, 2, 32), jnp.float32)
                   for kk in keys)
-    np.testing.assert_allclose(flash_attention(q, k, v, mask=rule),
-                               _dense_attention(q, k, v, rule),
-                               rtol=2e-5, atol=2e-5)
-    grads = lambda fn: jax.grad(  # noqa: E731
-        lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(q, k, v)
-    for name, a, b in zip("qkv", grads(
-            lambda q, k, v: flash_attention(q, k, v, mask=rule)),
-            grads(lambda q, k, v: _dense_attention(q, k, v, rule))):
+    (got, grads), (want, wants) = (edge.out_and_grads(
+        fn, (q, k, v), lambda out: jnp.sum(out * g)) for fn in (
+            lambda q, k, v: flash_attention(q, k, v, mask=rule),
+            lambda q, k, v: _dense_attention(q, k, v, rule)))
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for name, a, b in zip("qkv", grads, wants):
         np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4,
                                    err_msg=f"d{name} half={half}")
 
@@ -202,12 +200,12 @@ def test_a_length_that_needs_padding_to_the_tile():
     flash = routed_lm.RotaryAttention(attention="flash", **kw)
     plain = routed_lm.RotaryAttention(attention=None, **kw)
     variables = plain.init(jax.random.PRNGKey(1), x)
-    np.testing.assert_allclose(flash.apply(variables, x),
-                               plain.apply(variables, x), atol=2e-5)
-    grad = lambda m: jax.grad(lambda v, x: jnp.sum(  # noqa: E731
-        jnp.sin(m.apply(v, x))), argnums=(0, 1))(variables, x)
-    for a, b in zip(jax.tree_util.tree_leaves(grad(flash)),
-                    jax.tree_util.tree_leaves(grad(plain))):
+    (got, grads), (want, wants) = (edge.out_and_grads(
+        m.apply, (variables, x), lambda out: jnp.sum(jnp.sin(out)))
+        for m in (flash, plain))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(wants)):
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
@@ -228,11 +226,12 @@ def test_grouped_heads_reach_the_kernels_unspread():
     remat_held.assert_keys_reach_the_kernels_unspread(
         jax.grad(objective(flash), argnums=(0, 1)), (variables, x), b, s, h, kv,
         d)
-    np.testing.assert_allclose(flash.apply(variables, x),
-                               plain.apply(variables, x), atol=2e-5)
-    grad = lambda m: jax.grad(objective(m), argnums=(0, 1))(variables, x)  # noqa: E731
-    for a, c in zip(jax.tree_util.tree_leaves(grad(flash)),
-                    jax.tree_util.tree_leaves(grad(plain))):
+    (got, grads), (want, wants) = (edge.out_and_grads(
+        m.apply, (variables, x), lambda out: jnp.sum(jnp.sin(out)))
+        for m in (flash, plain))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for a, c in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(wants)):
         np.testing.assert_allclose(a, c, atol=5e-5)
 
 

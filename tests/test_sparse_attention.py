@@ -9,6 +9,7 @@ causal objective against the benchmark's plain reference
 loss, gradients and three steps, and which parameters each term's gradient
 reaches.  Widths in the tens; the real widths run on the chip."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -105,13 +106,12 @@ def test_both_kernels_under_a_selection_match_a_dense_masked_softmax(
     flash = lambda *a: flash_attention(  # noqa: E731
         *a, causal=causal, mask=SelectedKeysMask(), selection=sel,
         block_q=tiles[0], block_k=tiles[1], interpret=True, return_lse=True)
-    out, lse = flash(q, kk, v)
-    want, want_lse = _dense(q, kk, v, allowed, with_lse=True)
+    weighed = lambda out: jnp.sum(out[0] * w)  # noqa: E731
+    (out, lse), got = edge.out_and_grads(flash, (q, kk, v), weighed)
+    (want, want_lse), ref = edge.out_and_grads(
+        lambda *a: _dense(*a, allowed, with_lse=True), (q, kk, v), weighed)
     np.testing.assert_allclose(out, want, atol=2e-6)
     np.testing.assert_allclose(lse, want_lse, atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a)[0] * w), (0, 1, 2))(q, kk, v)
-    ref = jax.grad(lambda *a: jnp.sum(_dense(*a, allowed) * w),
-                   (0, 1, 2))(q, kk, v)
     for a, b in zip(got, ref):
         np.testing.assert_allclose(a, b, atol=5e-6)
 
@@ -139,6 +139,29 @@ def test_the_walk_under_a_selection_beside_causal_matches_a_dense_softmax(
                               sub) is None
 
 
+def _weighed(w):
+    return lambda out: jnp.sum(out[0].astype(jnp.float32) * w)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_case(rep, d, dtype, t=256, kv=2):
+    """A grouped case's selection, operands and weight, with the dense
+    softmax's output, log-sum-exp and gradients on the same values in
+    float32 and on spread heads: one oracle for both layouts."""
+    allowed = _top_keys(t, 48, True)
+    sel = pack_selection(allowed | (
+        _top_keys(t, 48, False, 1) & ~jnp.tril(jnp.ones((t, t), bool))))
+    keys = jax.random.split(jax.random.PRNGKey(rep + d), 4)
+    args = tuple((0.5 * jax.random.normal(key, (BATCH, t, h, d))).astype(
+        dtype) for key, h in zip(keys, (kv * rep, kv, kv)))
+    w = jax.random.normal(keys[3], args[0].shape)
+    dense = lambda q, k, v: _dense(  # noqa: E731
+        q, *(jnp.repeat(x, rep, axis=2) for x in (k, v)), allowed,
+        with_lse=True)
+    return (sel, args, w) + edge.out_and_grads(
+        dense, tuple(a.astype(jnp.float32) for a in args), _weighed(w))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("rep,d,in_place", [
@@ -153,22 +176,12 @@ def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, in_place,
     ``jnp.repeat``; float32 tight, bfloat16 within steps of the largest
     value as ``tests/test_flash_attention.py`` holds it."""
     t, kv = 256, 2
-    allowed = _top_keys(t, 48, True)
-    sel = pack_selection(allowed | (
-        _top_keys(t, 48, False, 1) & ~jnp.tril(jnp.ones((t, t), bool))))
-    keys = jax.random.split(jax.random.PRNGKey(rep + d), 4)
-    args = tuple((0.5 * jax.random.normal(key, (BATCH, t, h, d))).astype(
-        dtype) for key, h in zip(keys, (kv * rep, kv, kv)))
-    args32 = tuple(a.astype(jnp.float32) for a in args)
-    w = jax.random.normal(keys[3], args[0].shape)
+    sel, args, w, (want, want_lse), ref = _grouped_case(rep, d, dtype)
     kw = dict(causal=True, mask=SelectedKeysMask(), selection=sel,
               interpret=True, return_lse=True)
     # ``in_place``: the same heads as the projections' (B, S, H * D) arrays
     flash = edge.placed(**kw) if in_place \
         else lambda *a: flash_attention(*a, **kw)  # noqa: E731
-    dense = lambda q, k, v: _dense(  # noqa: E731
-        q, *(jnp.repeat(x, rep, axis=2) for x in (k, v)), allowed,
-        with_lse=True)
 
     def close(got, want, steps):
         want = np.asarray(want, np.float32)
@@ -176,13 +189,10 @@ def test_grouped_heads_under_a_selection_match_spread_heads(rep, d, in_place,
             if dtype == jnp.bfloat16 else 5e-6
         np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                    rtol=0, atol=atol)
-    (out, lse), (want, want_lse) = flash(*args), dense(*args32)
+    (out, lse), got = edge.out_and_grads(flash, args, _weighed(w))
     assert out.dtype == dtype and lse.shape == (BATCH, kv * rep, t)
     close(out, want, 2)
     close(lse, want_lse, 2)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a)[0].astype(jnp.float32) * w),
-                   (0, 1, 2))(*args)
-    ref = jax.grad(lambda *a: jnp.sum(dense(*a)[0] * w), (0, 1, 2))(*args32)
     for a, x, b in zip(got, args, ref):
         assert a.shape == x.shape and a.dtype == dtype
         close(a, b, 4)
@@ -363,11 +373,12 @@ def test_the_kl_term_and_the_gradients_its_forward_pass_computes(chunk):
     def plain(q_i, k_i, w, q, k):
         return jnp.sum(weights * _dense_kl(q_i, k_i, w, q, k, chosen))
 
-    np.testing.assert_allclose(mine(q_i, k_i, w, q, k, lse),
-                               plain(q_i, k_i, w, q, k), rtol=2e-5)
-    assert float(plain(q_i, k_i, w, q, k)) > 0.01
-    got = jax.grad(mine, (0, 1, 2, 3, 4, 5))(q_i, k_i, w, q, k, lse)
-    want = jax.grad(plain, (0, 1, 2))(q_i, k_i, w, q, k)
+    value, got = jax.jit(jax.value_and_grad(mine, (0, 1, 2, 3, 4, 5)))(
+        q_i, k_i, w, q, k, lse)
+    plain_value, want = jax.jit(jax.value_and_grad(plain, (0, 1, 2)))(
+        q_i, k_i, w, q, k)
+    np.testing.assert_allclose(value, plain_value, rtol=2e-5)
+    assert float(plain_value) > 0.01
     for a, b in zip(got[:3], want):
         assert float(jnp.max(jnp.abs(b))) > 1e-5
         np.testing.assert_allclose(a, b, atol=2e-6 + 1e-4 * float(
@@ -435,15 +446,25 @@ def _terms(job, tree, data, labels):
 INDEX = ("index_q", "index_k", "index_w", "index_k_norm")
 
 
-@pytest.mark.parametrize("attention,remat", [(None, False), ("flash", True)])
-def test_objective_and_gradient_match_the_reference(attention, remat):
-    cfg = {**SMALL, "attention": attention, "remat_blocks": remat}
-    params = REF.init(jax.random.PRNGKey(3), cfg)
+@functools.lru_cache(maxsize=None)
+def _reference():
+    """The seeded weights with the reference's objective, its two terms and
+    its gradient on them and ``_batch()``: it reads neither ``attention`` nor
+    ``remat_blocks``, so one run serves every case of ``SMALL``."""
+    params = REF.init(jax.random.PRNGKey(3), SMALL)
     data, labels = _batch()
     with jax.default_matmul_precision("highest"):
         (want, (loss, want_kl)), grads = jax.jit(jax.value_and_grad(
-            lambda p: REF.loss_fn(p, data, labels, cfg), has_aux=True))(
+            lambda p: REF.loss_fn(p, data, labels, SMALL), has_aux=True))(
                 params)
+    return params, want, loss, want_kl, grads
+
+
+@pytest.mark.parametrize("attention,remat", [(None, False), ("flash", True)])
+def test_objective_and_gradient_match_the_reference(attention, remat):
+    cfg = {**SMALL, "attention": attention, "remat_blocks": remat}
+    params, want, loss, want_kl, grads = _reference()
+    data, labels = _batch()
     job = _job(cfg)
 
     def objective(tree):
@@ -471,8 +492,10 @@ def test_each_terms_gradient_reaches_its_own_parameters_only():
     job = _job(SMALL)
     tree = job.program_tree(REF.init(jax.random.PRNGKey(3), SMALL))
     data, labels = _batch()
-    by_kl = jax.grad(lambda t: _terms(job, t, data, labels)[1])(tree)
-    by_ce = jax.grad(lambda t: sum(_terms(job, t, data, labels)[0:3:2]))(tree)
+    by_kl, by_ce = jax.jit(lambda tree: (
+        jax.grad(lambda t: _terms(job, t, data, labels)[1])(tree),
+        jax.grad(lambda t: sum(_terms(job, t, data, labels)[0:3:2]))(tree)))(
+            tree)
     def flat(g):
         return {jax.tree_util.keystr(p): float(jnp.max(jnp.abs(v)))
                 for p, v in jax.tree_util.tree_flatten_with_path(g)[0]}
@@ -552,11 +575,8 @@ def test_leaving_a_part_out_is_seen(what, monkeypatch):
     (the selection left out for ``t >= k``) or without the index key's norm
     is not the reference's, in the objective or in a gradient."""
     cfg = dict(SMALL)
-    params = REF.init(jax.random.PRNGKey(3), cfg)
+    params, want, _, _, grads = _reference()
     data, labels = _batch()
-    with jax.default_matmul_precision("highest"):
-        want, grads = jax.jit(jax.value_and_grad(
-            lambda p: REF.loss_fn(p, data, labels, cfg)[0]))(params)
     broken = dict(cfg)
     if what == "the KL term":
         broken["indexer_kl_weight"] = 0.0

@@ -81,6 +81,9 @@ def worker_proc(port, host, rank, steps, batch, pace_s, out_q):
                                       np.float32))
     rng = np.random.RandomState(rank)
     losses = []
+    # start together: a worker forked late would otherwise find the fastest
+    # one done, and no push would ever land between a basis and its push
+    ctrl.barrier()
     for t in range(steps):
         idx = rng.randint(0, len(Xs), batch)
         loss, g = _loss_grad(w, Xs[idx], ys[idx])
@@ -88,7 +91,7 @@ def worker_proc(port, host, rank, steps, batch, pace_s, out_q):
         losses.append(float(loss))
         if pace_s:
             time.sleep(pace_s)  # skewed paces -> genuine asynchrony
-    stats = ctrl.async_stats() if rank == 0 else None
+    stats = ctrl.async_stats()
     out_q.put((host, losses[0], losses[-1], stats))
     ctrl.close()
 
@@ -127,7 +130,11 @@ def run(n_workers=3, steps=150, batch=32, acc_gate=0.90):
     Xtr, ytr, Xva, yva = _digits()
     train_acc = _accuracy(final_w, Xtr, ytr)
     val_acc = _accuracy(final_w, Xva, yva)
-    stats = next(s for (_, _, s) in results.values() if s)
+    # each worker's reading at its own end: the last to end saw every push
+    # (the fastest worker's, taken alone, can precede every push of a slower
+    # one that started late, and then reads no staleness at all)
+    stats = max((s for (_, _, s) in results.values()),
+                key=lambda s: s["measured_pushes"])
     out = {
         "what": "dist_async convergence: N numpy-softmax workers at "
                 "skewed paces pushing through the async plane "
