@@ -34,6 +34,7 @@ from dt_tpu.models.transformer import (
     PipelinedTransformerLM as PipelinedTransformerLM)
 from dt_tpu.models.hybrid_lm import HybridLM as HybridLM
 from dt_tpu.models.routed_lm import RoutedLM as RoutedLM
+from dt_tpu.models.pattern_lm import PatternLM as PatternLM
 from dt_tpu.models.ssd import (SSD as SSD, ssd_loss as ssd_loss,
                                ssd_detect as ssd_detect)
 from dt_tpu.models.rcnn import (FasterRCNNMini as FasterRCNNMini,
@@ -56,7 +57,8 @@ def create(name: str, **kwargs):
     lstm_lm, transformer_lm, hybrid_lm (state-space and attention layers by
     a pattern), routed_lm (rotary attention and routed experts, trained
     by diffusion over blocks or next-token, there also over the keys a
-    learned index picks)."""
+    learned index picks), pattern_lm (blocks of one part each by a pattern
+    string: Mamba-2, attention, experts in a latent, each a held share)."""
     key = name.lower().replace("-", "_")
     if key in _REGISTRY:
         return _REGISTRY[key](**kwargs)
@@ -94,6 +96,7 @@ def _setup_registry():
              lambda **kw: PipelinedTransformerLM(**kw))
     register("hybrid_lm", lambda **kw: HybridLM(**kw))
     register("routed_lm", lambda **kw: RoutedLM(**kw))
+    register("pattern_lm", lambda **kw: PatternLM(**kw))
     register("ssd", lambda **kw: SSD(**kw))
     register("faster_rcnn", lambda **kw: FasterRCNNMini(**kw))
 

@@ -75,45 +75,94 @@ class GatedMLP(linen.Module):
         return dense(x.shape[-1], "down")(h)
 
 
+def held_share(held, whole: int, per_group: int, what: str):
+    """``held`` (first, count) of ``whole`` heads, or None for all of them ->
+    (the heads held, the groups of ``per_group`` heads they read), checked:
+    a share is whole groups, or an even part of one group, which it then
+    reads whole (a key-value head can be every chip's that reads it; a
+    group of B and C under a norm of its own cannot, and ``Mamba2Mixer``
+    refuses that)."""
+    if held is None:
+        return whole, whole // per_group
+    first, count = held
+    if first < 0 or count < 1 or first + count > whole:
+        raise ValueError(f"heads {first}..{first + count} of {whole}")
+    if first % per_group == 0 and count % per_group == 0:
+        return count, count // per_group
+    if per_group % count == 0 and first % count == 0:
+        return count, 1
+    raise ValueError(f"heads {first}..{first + count} of {whole} {what} "
+                     f"heads are neither whole groups of {per_group} nor "
+                     f"an even part of one")
+
+
 class GroupedQueryAttention(linen.Module):
     """``num_kv_heads`` key-value heads, each serving
     ``num_heads // num_kv_heads`` consecutive query heads; no positions;
-    causal softmax of ``scale * q k^T``."""
+    causal softmax of ``scale * q k^T``.
+
+    ``held = (first, count)``: this chip holds query heads ``first ..
+    first + count`` of the ``num_heads`` and the key-value heads they read,
+    as tensor parallelism deals them: whole key-value heads with all their
+    query heads, or, where the chips outnumber the key-value heads, an even
+    part of one key-value head's query heads with that head (which the
+    other chips that read it hold too: 32 query heads over 2 deal over 8
+    chips as 4 and 1).  ``q_proj``, ``k_proj`` and ``v_proj`` have those
+    heads' columns, ``o_proj`` their rows, and the layer returns their part
+    of the result, ``o_held Wo[held rows]``.
+    Summing the parts of all the shares gives the layer (no bias, and the
+    softmax is a head's own).  What the other heads would have added is left
+    out; no code stands in for the chips that hold them.  None: all the
+    heads, the computation it always was.
+
+    Under ``attention="flash"`` with heads of whole lane tiles
+    (``attention.lane_tiled``: a head size that 128 divides) the kernels read
+    q, k, v as the projections leave them, (B, S, H * D), and write the
+    output there for ``o_proj`` (PERF.md section 6, PR 45); at any other
+    head size the operands are (B, S, H, D) as before."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
     scale: float
     attention: Optional[str] = "flash"   # 'flash' (Pallas) | None (plain)
     dtype: Any = F32
+    held: Optional[tuple] = None         # (first, count) query heads
 
     @linen.compact
     def __call__(self, x):
         b, s, d = x.shape
+        heads, kv_heads = held_share(
+            self.held, self.num_heads, self.num_heads // self.num_kv_heads,
+            "query")
+        rep = heads // kv_heads
         dense = lambda n, name: linen.Dense(  # noqa: E731
             n, use_bias=False, dtype=self.dtype, name=name)
-        q = dense(self.num_heads * self.head_dim, "q_proj")(x)
-        k = dense(self.num_kv_heads * self.head_dim, "k_proj")(x)
-        v = dense(self.num_kv_heads * self.head_dim, "v_proj")(x)
-        q = q.reshape(b, s, self.num_heads, self.head_dim)
-        k, v = (t.reshape(b, s, self.num_kv_heads, self.head_dim)
-                for t in (k, v))
+        q = dense(heads * self.head_dim, "q_proj")(x)
+        k = dense(kv_heads * self.head_dim, "k_proj")(x)
+        v = dense(kv_heads * self.head_dim, "v_proj")(x)
+        from dt_tpu.ops.pallas.attention import (flash_attention,
+                                                 DEFAULT_BLOCK, lane_tiled)
+        # heads of whole lane tiles stay where the projections leave them
+        tiled = self.attention == "flash" and lane_tiled(self.head_dim, s)
+        if not tiled:
+            q = q.reshape(b, s, heads, self.head_dim)
+            k, v = (t.reshape(b, s, kv_heads, self.head_dim)
+                    for t in (k, v))
         if self.attention == "flash":
             # k and v keep their heads: the kernels' index maps read the
             # head that serves a query head
-            from dt_tpu.ops.pallas.attention import (flash_attention,
-                                                     DEFAULT_BLOCK)
             pad = (-s) % DEFAULT_BLOCK
             if pad:   # as TransformerLM: padded keys lie after every real query
-                q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                q, k, v = (jnp.pad(t, ((0, 0), (0, pad))
+                                   + ((0, 0),) * (t.ndim - 2))
                            for t in (q, k, v))
-            out = flash_attention(q, k, v, causal=True,
-                                  scale=self.scale)[:, :s]
+            out = flash_attention(q, k, v, causal=True, scale=self.scale,
+                                  heads=heads if tiled else None)[:, :s]
         else:
             from dt_tpu.parallel.ring_attention import full_attention
             # the oracle takes one head count: each key-value head spread
             # over the query heads it serves
-            k, v = (jnp.repeat(t, self.num_heads // self.num_kv_heads, axis=2)
-                    for t in (k, v))
+            k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
             out = full_attention(q, k, v, causal=True, scale=self.scale)
         return checkpoint_name(
             dense(d, "o_proj")(out.reshape(b, s, -1)), "mixer_out")
@@ -133,6 +182,27 @@ def _dt_bias_init(key, shape, dtype=F32, lo=1e-3, hi=0.1):
 
 
 class Mamba2Mixer(linen.Module):
+    """The Mamba-2 mixer of the module's docstring: ``n_heads`` heads of
+    ``d_head`` channels reading ``n_groups`` groups of B and C (head ``h``
+    reads group ``h // (n_heads // n_groups)``).
+
+    The gated norm is over each group's own channels (``d_inner /
+    n_groups`` of them: Mamba-2's own norm under tensor parallelism, and the
+    published norm of the decoders that have ``n_groups`` > 1 for that);
+    with one group that is the norm over all ``d_inner``.
+
+    ``held = (first, count)``: this chip holds heads ``first .. first +
+    count`` of the ``n_heads`` and the groups they read (whole ones: a share
+    is a multiple of ``n_heads // n_groups`` heads), as tensor parallelism
+    deals them: ``in_proj`` has the columns ``[z | x | B | C | dt]`` of those
+    heads and groups, the taps, ``dt_bias``, ``A_log``, ``D`` and the norm's
+    scale their channels, ``out_proj`` their rows, and the layer returns
+    their part of the result, ``n_held Wout[held rows]``.  Summing the parts
+    of all the shares gives the layer: nothing in it crosses a group (the
+    convolution is depthwise, the recurrence a head's own, the norm a
+    group's own).  What the other heads would have added is left out; no
+    code stands in for the chips that hold them.  None: all the heads, the
+    computation it always was."""
     n_heads: int
     d_head: int
     d_state: int
@@ -142,11 +212,17 @@ class Mamba2Mixer(linen.Module):
     conv_bias: bool = True
     eps: float = 1e-5
     dtype: Any = F32
+    held: Optional[tuple] = None          # (first, count) heads
 
     @linen.compact
     def __call__(self, x):
         b, l, d = x.shape
-        h, p, g, n = self.n_heads, self.d_head, self.n_groups, self.d_state
+        per_group = self.n_heads // self.n_groups
+        h, g = held_share(self.held, self.n_heads, per_group, "state-space")
+        if h % per_group:
+            raise ValueError("a share of the state-space heads is whole "
+                             "groups, each normed alone")
+        p, n = self.d_head, self.d_state
         d_inner, conv_dim = h * p, h * p + 2 * g * n
         zxbcdt = checkpoint_name(
             linen.Dense(d_inner + conv_dim + h, use_bias=False,
@@ -174,7 +250,7 @@ class Mamba2Mixer(linen.Module):
             y = y + (skip[:, None] * xs.astype(F32)).astype(y.dtype)
         with jax.named_scope("gated_norm"):
             y = ssm.gated_rms_norm(y.reshape(b, l, d_inner), z, norm_scale,
-                                   self.eps)
+                                   self.eps, g)
         return checkpoint_name(
             linen.Dense(d, use_bias=False, dtype=self.dtype,
                         name="out_proj")(y), "mixer_out")

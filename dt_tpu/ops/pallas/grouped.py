@@ -151,7 +151,24 @@ def row_tile(m: int, k: int, n: int, lhs_itemsize: int, rhs_itemsize: int,
     eight row tiles for one fetch of its 7 MiB matrix, and the ``G - 1`` = 7
     shared tiles make 103 visits of 96, +7%: a call is 180 GFLOP, 0.92 ms at
     the peak, and reads 81 to 85% of it alone (1.08 to 1.14 ms; PERF.md
-    section 6, PR 43)."""
+    section 6, PR 43).
+
+    At experts that work in a latent (``k x n`` = 1,024 x 2,688 for ``up``
+    and 2,688 x 1,024 for ``down``, 8 groups, a buffer of 4,224 rows: 1.5
+    times an even load of 2,816, which 256 does not divide, so ``tm`` is
+    128 and the buffer 33 row tiles): 2 x (0.25 + 5.25 + 1.31) + 1.31 =
+    14.9 MiB for ``up`` and 13.3 for ``down``; turned 14.1 both; transposed
+    2 x (0.25 + 1.31 + 5.25) + 10.5 = 24.1 MiB and 23.3, within
+    ``VMEM_BUDGET_T``; Mosaic takes all six
+    (``tests/test_grouped_compile_tpu.py``).  A visit is 0.70 GFLOP, 3.6 us
+    at the peak; a group is 352 rows at even load, under three row tiles
+    for one fetch of its 5.25 MiB matrix, and the ``G - 1`` = 7 shared
+    tiles make 40 visits of 33, +21%.  A call is 23.3 GFLOP, 0.118 ms at
+    the peak, and moves 8.7 MB of rows, 44 MB of matrices and 45 MB of
+    float32 result, 0.120 ms at 819 GB/s: operations and bytes balance as
+    at 32 groups of 2,048 x 512, with the matrices, not the rows, most of
+    the bytes; in the cell's step a call takes 0.206 ms, 58% of that
+    (PERF.md section 5, PR 47)."""
     if k % _LANES or n % _LANES:
         return None
     budget = VMEM_BUDGET_T if transposed else VMEM_BUDGET

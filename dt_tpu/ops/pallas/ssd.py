@@ -150,7 +150,19 @@ def head_block(heads: int, groups: int, p: int, n: int, q: int,
     largest of ``HEAD_BLOCKS`` that divides a group's heads, is whole packs
     and keeps ``vmem_bytes`` within ``VMEM_BUDGET``.  None where the kernels
     do not take the shape: ``q`` or ``n`` not whole lane tiles, ``p`` no
-    divisor or multiple of 128, no candidate."""
+    divisor or multiple of 128, no candidate.
+
+    The shapes the choice was checked at (Mosaic for a described v5e,
+    ``tests/test_ssd_compile_tpu.py``, and on the chip): 64 heads of 64 in
+    one group, states of 128, chunks of 256, bfloat16 (PR 40: 8 heads a
+    step, 8.7 MiB by ``vmem_bytes``; 16 a step took 6% and 4% off the two
+    kernels for twice the body); one chip's share of a mixer of 128 heads
+    in 8 groups, 16 heads and their one group, at chunks of 128 over 8,192
+    positions (PR 47: 8 heads a step, two steps a group, 3.6 MiB; the
+    scratch holds 16 heads' states, 0.5 MiB, where the first holds 2 MiB,
+    and a chunk's squares are a quarter of the size, so a grid step's fixed
+    cost is a larger share of it: 0.15 ms a forward call and 0.25 a
+    backward call in the cell's step, PERF.md section 5, PR 47)."""
     if q % _LANES or n % _LANES or _pack(p) is None or heads % groups:
         return None
     hp = _pack(p)[0]

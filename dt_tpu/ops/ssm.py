@@ -137,9 +137,20 @@ def ssd_scan_xla(x, dt, a, b, c, *, chunk: int):
     return y.reshape(bsz, nc * q, h, p)[:, :l].astype(dtype)
 
 
-def gated_rms_norm(y, z, scale, eps: float = 1e-5):
+def gated_rms_norm(y, z, scale, eps: float = 1e-5, groups: int = 1):
     """``rms(y * silu(z)) * scale`` over the last axis (the norm comes after
-    the gate), computed in float32, returned in ``y``'s type."""
+    the gate), computed in float32, returned in ``y``'s type.  With
+    ``groups`` the last axis is that many equal runs of channels and each is
+    normed by its own mean square (Mamba-2's norm of a mixer with ``groups``
+    groups of B and C: a chip that holds whole groups norms them as the
+    whole mixer would); one group is the norm over all channels."""
     v = y.astype(F32) * jax.nn.silu(z.astype(F32))
-    v = v * lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + eps)
+    if groups == 1:
+        v = v * lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True)
+                          + eps)
+    else:
+        by_group = v.reshape(v.shape[:-1] + (groups, v.shape[-1] // groups))
+        v = (by_group * lax.rsqrt(jnp.mean(
+            jnp.square(by_group), axis=-1, keepdims=True) + eps)).reshape(
+                v.shape)
     return (v * scale.astype(F32)).astype(y.dtype)

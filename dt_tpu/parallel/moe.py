@@ -9,7 +9,10 @@ expert parallelism.  Two layers.
 ``RoutedExperts`` is the layer the models use (the sparse-expert decoders
 of ``models/routed_lm.py``): ``k > 1`` routing over the share of the
 experts one chip holds, the held assignments sorted into a static buffer,
-three grouped products over it (``ops/pallas/grouped.py``).  Scores are the
+the experts' grouped products over it (``ops/pallas/grouped.py``: three
+where an expert is a gated-SiLU feed-forward, two where it is a squared-ReLU
+one without a gate), at the stream's width or, with ``latent``, at a
+narrower one that two projections lead into and out of.  Scores are the
 softmax over all the router's outputs or, with ``scoring="sigmoid"``, each
 output's own sigmoid; the top ``k`` are renormalised to sum to one (plus
 ``norm_eps``) and may carry a scale (``routed_scale``); a shared expert
@@ -233,6 +236,26 @@ class RoutedExperts(linen.Module):
         w_e = p_e / sum_S p
         y = sum_{e in S, e held} w_e * Wdown_e(silu(x Wgate_e) * (x Wup_e))
 
+    Two facts of a model beside those, set from its configuration (the
+    defaults are the layer above).  ``expert_form`` ``"relu2"``: an expert
+    is ``Wdown_e(relu(x Wup_e)^2)``, squared ReLU and no gate: two grouped
+    products where the gated form has three, and no parameter ``gate`` (nor
+    the name ``moe_gate``); the shared expert takes the same form
+    (``shared_up``, ``shared_down``).  ``latent``: the experts work at that
+    width and not the stream's::
+
+        l = x Wlin                                      (d -> latent, module latent_in)
+        r = sum_{e in S, e held} w_e * expert_e(l)      Wup_e latent x I, Wdown_e I x latent
+        y = r Wlout                                     (latent -> d, module latent_out)
+
+    The router still reads ``x`` at the stream's width; the dispatch, the
+    grouped products and the combine run at ``latent`` (what an
+    expert-parallel exchange would carry); ``Wlout`` takes the routed sum
+    back, so summing the shares' ``r`` and projecting once is summing their
+    ``y``: both projections are every chip's alike, as the router is.  The
+    shared expert reads ``x`` itself and is added after.  The two
+    projections lie under ``jax.named_scope("latent")``.
+
     Five switches, whose defaults leave that as it is.  ``scoring``
     ``"sigmoid"``: ``p = sigmoid_f32(x Wr)``, each expert scored alone
     (``route_top_k``; the load-balancing term then reads ``p / sum_E p``).
@@ -269,8 +292,9 @@ class RoutedExperts(linen.Module):
     other chips.  The router is float32 at its full width (its product at
     ``highest`` precision).  The assignments to held experts are sorted by
     expert into a buffer of ``buffer_rows`` rows, the receive side of an
-    expert-parallel exchange, and three grouped products
-    (``ops.pallas.grouped.grouped_matmul``: a Pallas kernel each where the
+    expert-parallel exchange, and the experts' grouped products (three, or
+    two under ``expert_form="relu2"``;
+    ``ops.pallas.grouped.grouped_matmul``: a Pallas kernel each where the
     widths are whole lane tiles, ``jax.lax.ragged_dot`` at toy sizes) run
     over the whole buffer: rows past the load are padding the products still
     multiply (they go through the last expert with weight zero) and the
@@ -287,7 +311,14 @@ class RoutedExperts(linen.Module):
     (``COUNTER_TAIL``):
     ``Module`` hands them to the host with the metric's statistics.
     ``jax.named_scope``s ``route``, ``dispatch``, ``experts`` and
-    ``combine`` tell the parts apart in an operation's scope path."""
+    ``combine`` (and ``latent``, ``shared``) tell the parts apart in an
+    operation's scope path.
+
+    **A held share computes its experts' part of the routed sum, and summing
+    the shares gives the layer**: each assignment lies with exactly one
+    share, its weight comes from the router every share computes alike, and
+    what is every chip's alike (the shared expert; with ``latent`` the
+    projection out, which is linear) is counted once."""
     num_experts: int
     top_k: int
     intermediate: int
@@ -301,6 +332,8 @@ class RoutedExperts(linen.Module):
     norm_eps: float = 0.0
     selection_bias: bool = False
     bias_update_speed: float = 0.001
+    expert_form: str = "gated_silu"       # or 'relu2': no gate
+    latent: Optional[int] = None          # the experts' width; None: d
 
     @linen.compact
     def __call__(self, x: Array) -> Array:
@@ -310,13 +343,20 @@ class RoutedExperts(linen.Module):
         tokens = x.reshape(b * s, d)
         t = b * s
         rows = t * k if self.buffer_rows is None else int(self.buffer_rows)
+        if self.expert_form not in ("gated_silu", "relu2"):
+            raise ValueError(f"no expert form {self.expert_form!r}")
+        gated = self.expert_form == "gated_silu"
+        width = self.latent or d          # what the experts read and write
         init = linen.initializers.normal(0.02)
         router = self.param("router", init, (d, self.num_experts),
                             jnp.float32)
-        w_gate, w_up = (self.param(n, init, (count, d, self.intermediate),
-                                   jnp.float32) for n in ("gate", "up"))
-        w_down = self.param("down", init, (count, self.intermediate, d),
+        into = (count, width, self.intermediate)
+        w_gate = self.param("gate", init, into, jnp.float32) if gated else None
+        w_up = self.param("up", init, into, jnp.float32)
+        w_down = self.param("down", init, (count, self.intermediate, width),
                             jnp.float32)
+        dense = lambda n, name: linen.Dense(  # noqa: E731
+            n, use_bias=False, dtype=self.dtype, name=name)
 
         with jax.named_scope("route"):
             logits = jnp.dot(tokens.astype(jnp.float32), router,
@@ -345,30 +385,44 @@ class RoutedExperts(linen.Module):
             groups = sizes.at[count - 1].add(rows - jnp.sum(sizes))
             row_weight = jnp.where(placed, weights.reshape(-1)[order], 0.0)
             self._count(experts, first, count, order, placed, b, s * k)
+        if self.latent:
+            with jax.named_scope("latent"):
+                tokens = checkpoint_name(
+                    dense(width, "latent_in")(tokens), "moe_latent")
         with jax.named_scope("dispatch"):
             buf = jnp.take(tokens, source, axis=0).astype(self.dtype)
         with jax.named_scope("experts"):
             grouped = lambda lhs, w: grouped_matmul(  # noqa: E731
                 lhs, w.astype(self.dtype), groups, jnp.float32)
-            # the two products in the float32 they are made in, by name
-            hidden = jax.nn.silu(
-                checkpoint_name(grouped(buf, w_gate), "moe_gate")) \
-                * checkpoint_name(grouped(buf, w_up), "moe_up")
+            # the products in the float32 they are made in, by name
+            if gated:
+                hidden = jax.nn.silu(
+                    checkpoint_name(grouped(buf, w_gate), "moe_gate")) \
+                    * checkpoint_name(grouped(buf, w_up), "moe_up")
+            else:
+                hidden = jnp.square(jax.nn.relu(
+                    checkpoint_name(grouped(buf, w_up), "moe_up")))
             out = grouped(hidden.astype(self.dtype), w_down)
         with jax.named_scope("combine"):
             out = (out * row_weight[:, None]).astype(self.dtype)
-            y = jnp.zeros((t, d), self.dtype).at[source].add(out)
+            y = jnp.zeros((t, width), self.dtype).at[source].add(out)
+        if self.latent:
+            with jax.named_scope("latent"):
+                y = dense(d, "latent_out")(y)
         y = y.reshape(b, s, d)
         if self.shared_intermediate:
             with jax.named_scope("shared"):
-                dense = lambda n, name: linen.Dense(  # noqa: E731
-                    n, use_bias=False, dtype=self.dtype, name=name)
-                width = self.shared_intermediate
-                y = y + dense(d, "shared_down")(jax.nn.silu(
-                    checkpoint_name(dense(width, "shared_gate")(x),
-                                    "shared_gate"))
-                    * checkpoint_name(dense(width, "shared_up")(x),
-                                      "shared_up"))
+                wide = self.shared_intermediate
+                if gated:
+                    hidden = jax.nn.silu(
+                        checkpoint_name(dense(wide, "shared_gate")(x),
+                                        "shared_gate")) \
+                        * checkpoint_name(dense(wide, "shared_up")(x),
+                                          "shared_up")
+                else:
+                    hidden = jnp.square(jax.nn.relu(checkpoint_name(
+                        dense(wide, "shared_up")(x), "shared_up")))
+                y = y + dense(d, "shared_down")(hidden)
         return y
 
     def _route_biased(self, logits, b):

@@ -604,8 +604,9 @@ class Module:
     def _model_gauges(self):
         """What the steps just built compute.  For a model whose blocks
         keep named values when they are rematerialised (``saved_names``:
-        ``models.HybridLM``, ``models.RoutedLM``): whether each block is
-        rematerialised and how many names it then keeps.  For one whose
+        ``models.HybridLM``, ``models.RoutedLM``, ``models.PatternLM``):
+        whether each block is rematerialised and how many names it then
+        keeps.  For one whose
         layers are read from a pattern (``models.HybridLM``) also the count
         of layers of each kind and the state-space scan's chunk.  Gauges of
         the metrics plane; nothing where it is off."""

@@ -80,6 +80,7 @@ START_ORDER = (
     "test_flash_attention.py",                  # 178
     "test_models.py",                           # 128
     "test_flash_compile_tpu.py",                # 111
+    "test_pattern_lm.py",                       # 99 (PR 47, alone)
     "test_mixed_attention.py",                  # 98
     "test_examples.py",                         # 93
     "test_onnx.py",                             # 84
@@ -104,6 +105,7 @@ START_ORDER = (
     "benchmark/test_program_spans.py",          # 124
     "benchmark/test_laguna_cell.py",            # 107
     "benchmark/test_lfm2_cell.py",              # 103
+    "benchmark/test_nemotron_cell.py",          # 55 (PR 47, alone)
     "benchmark/test_build_metrics.py",          # 41
 )
 

@@ -39,6 +39,10 @@ SHAPES = [
     # holds 33.5 MiB by vmem_bytes' reckoning, under its own budget (PR 43)
     (24576, 2048, 1792, 8),
     (24576, 1792, 2048, 8),
+    # experts that work in a 1,024-wide latent: 8 held, of width 2,688, over
+    # a buffer of 33 row tiles of 128 (256 does not divide 4,224) (PR 47)
+    (4224, 1024, 2688, 8),
+    (4224, 2688, 1024, 8),
 ]
 
 

@@ -28,11 +28,14 @@ def one_chip():
 
 # (B, L, H, P, G, N, chunk, dtype): the hybrid cell's nine layers
 # (granite-4.0-h-micro: 64 heads of 64, one group, states of 128, chunks of
-# 256, bfloat16); a length with a tail; two groups in float32
+# 256, bfloat16); a length with a tail; two groups in float32; one chip's
+# share of a mixer of 128 heads in 8 groups (16 heads, their one group) at
+# chunks of 128 over 8,192 positions (PR 47)
 SHAPES = [
     (2, 4096, 64, 64, 1, 128, 256, jnp.bfloat16),
     (1, 1000, 16, 64, 1, 128, 256, jnp.bfloat16),
     (1, 512, 16, 64, 2, 128, 256, jnp.float32),
+    (1, 8192, 16, 64, 1, 128, 128, jnp.bfloat16),
 ]
 
 
@@ -73,3 +76,4 @@ def test_the_head_block_is_within_the_budget():
     """At the cell's shapes the reckoning leaves room under the budget the
     compiler is given twice of."""
     assert ssd.vmem_bytes(8, 64, 64, 128, 256, 2) <= ssd.VMEM_BUDGET
+    assert ssd.vmem_bytes(8, 16, 64, 128, 128, 2) <= ssd.VMEM_BUDGET
