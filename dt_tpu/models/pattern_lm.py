@@ -93,8 +93,9 @@ PARTS = {"M": ("mamba", Mamba2Mixer), "*": ("attn", GroupedQueryAttention),
 #:   ssm_in_proj  T x (2 H P + 2 G N + H) x c   an M block's in_proj output
 #:   flash_out    T x heads x D x c             a * block's kernel output
 #:   flash_lse    T x heads x 4                 its log-sum-exp, float32
-#:   moe_route    T x k x 4 + 2 x R x 4 + held x 4   an E block's weights,
-#:                order, the token each row holds, sizes
+#:   moe_route    T x k x 4 + 2 x R x 4 + held x 4 + E x 4   an E block's
+#:                weights, order, the token each row holds, sizes, and the
+#:                selection's load (the load-balancing term's gradient)
 #:   moe_latent   T x L x c                     the tokens in the latent
 #:   moe_up       R x I x 4                     float32, as the grouped product returns it
 #: Named and not kept: shared_up (T x shared width x c: 88 MB a block at

@@ -698,7 +698,9 @@ class ShortConv(linen.Module):
 #:   attn_out    T x d x c          o_proj's output
 #:   moe_route   T x k x 4 + 2 x R x 4 + held x 4   weights; order and the
 #:               token each row holds; sizes (and 2 x R x 4 more: the
-#:               indices jax derives from those two for the two gathers)
+#:               indices jax derives from those two for the two gathers;
+#:               and E x 4, the selection's load over the E router outputs,
+#:               where the objective holds the load-balancing term)
 #:   moe_up      R x I x 4          float32, as the grouped product returns it
 #: and under an index (``RotaryAttention(indexer=...)``), for H_I index heads
 #: of D_I:

@@ -189,39 +189,66 @@ def route_top_k(logits: Array, k: int, scoring: str = "softmax",
     return experts.astype(jnp.int32), weights, probs
 
 
-def moved_bias(bias: Array, experts: Array, speed: float) -> Array:
-    """The selection bias after a step whose selection was ``experts`` (T,
-    k), over all ``E = len(bias)`` router outputs: ``bias_e + speed *
-    sign(mean_e(load) - load_e)`` with ``load_e`` the assignments to expert
-    ``e`` (arXiv:2408.15664's rule): an expert under the mean load is
-    easier to choose next step, one over it harder, one at it unmoved."""
-    load = jnp.zeros(bias.shape, jnp.float32).at[experts.reshape(-1)].add(1.0)
+def selection_load(experts: Array, num_experts: int) -> Array:
+    """The selection ``experts`` (T, k) counted, once a layer call: ``load``
+    (num_experts,) int32, the assignments to each router output; the
+    load-balancing term, the selection bias and the buffer's group sizes
+    all read this one array.  A dense sum and no scatter-add (which takes
+    the chip about 9 ns for each of the ``T x k`` scalar updates): an
+    output's number is ``32 high + low``, and the count of the picks with
+    that pair is the product, on the matrix unit, of two one-hot matrices of
+    0 and 1, ``(E / 32, T x k)`` by ``(32, T x k)``: ``T x k x (E / 32 +
+    32)`` compares where a one-hot over all outputs makes ``T x k x E``, and
+    no array of that size in any form of the program.  Exact: sums of ones
+    in float32, for fewer than 2^24 picks."""
+    if experts.size >= 1 << 24:
+        raise ValueError(f"{experts.size} picks: float32 counts to 2^24")
+    flat = experts.reshape(1, -1)
+
+    def one_hot(n, part):
+        return (jnp.arange(n, dtype=flat.dtype)[:, None] == part).astype(
+            jnp.bfloat16)
+    pairs = jax.lax.dot_general(
+        one_hot(-(-num_experts // 32), flat >> 5), one_hot(32, flat & 31),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return pairs.reshape(-1)[:num_experts].astype(jnp.int32)
+
+
+def moved_bias(bias: Array, load: Array, speed: float) -> Array:
+    """The selection bias after a step whose selection made ``load``
+    (``selection_load`` over all ``E = len(bias)`` router outputs): ``bias_e
+    + speed * sign(mean_e(load) - load_e)`` (arXiv:2408.15664's rule): an
+    expert under the mean load is easier to choose next step, one over it
+    harder, one at it unmoved."""
+    load = load.astype(jnp.float32)     # counts below 2**24: exact
     return bias + speed * jnp.sign(jnp.mean(load) - load)
 
 
-def load_balancing_term(experts: Array, probs: Array) -> Array:
+def load_balancing_term(load: Array, probs: Array) -> Array:
     """Switch eq. 4 for ``k`` experts a token: ``E * sum_e f_e P_e`` with
-    ``f_e`` the assignments to expert ``e`` over the tokens (they sum to
-    ``k``; a count, so no gradient) and ``P_e`` the mean router
-    probability; ``k`` where the load is even."""
+    ``f_e = load_e / T`` the assignments to expert ``e`` over the tokens
+    (``selection_load``; they sum to ``k``; a count, so no gradient) and
+    ``P_e`` the mean router probability; ``k`` where the load is even."""
     t, e = probs.shape
-    f = jnp.zeros((e,), jnp.float32).at[experts.reshape(-1)].add(1.0) / t
+    f = load.astype(jnp.float32) / t
     return e * jnp.sum(jax.lax.stop_gradient(f) * jnp.mean(probs, axis=0))
 
 
-def sort_held(experts: Array, first: int, count: int, rows: int):
+def sort_held(experts: Array, load: Array, first: int, count: int,
+              rows: int):
     """The assignments ``experts`` (T, k) to the ``count`` experts from
     ``first``, sorted by expert into a buffer of ``rows`` rows ->
     (assignment (rows,), sizes (count,), held (count,)): the flat index
     ``token * k + slot`` each row holds, how many rows each expert has in
-    the buffer, and how many assignments it had (``held - sizes`` found no
+    the buffer, and how many assignments it had, which is its part of
+    ``load`` (``selection_load(experts, E)``; ``held - sizes`` found no
     room: the buffer fills in expert order).  Rows past the load hold
     assignments to experts that are not here, in order; their weight is
     zero (``RoutedExperts``)."""
     local = experts.reshape(-1) - first
     local = jnp.where((local >= 0) & (local < count), local, count)
     order = jnp.argsort(local, stable=True)[:rows].astype(jnp.int32)
-    held = jnp.zeros((count + 1,), jnp.int32).at[local].add(1)[:count]
+    held = load[first:first + count]
     ends = jnp.minimum(jnp.cumsum(held), rows)
     sizes = jnp.diff(ends, prepend=0)
     return order, sizes, held
@@ -290,7 +317,10 @@ class RoutedExperts(linen.Module):
     What the experts that are not held would have added is left out: on one
     chip the layer runs without its exchange, and no code stands in for the
     other chips.  The router is float32 at its full width (its product at
-    ``highest`` precision).  The assignments to held experts are sorted by
+    ``highest`` precision).  The selection is counted once a call
+    (``selection_load``, over all ``num_experts`` outputs), and the
+    load-balancing term, the bias's move and the buffer's group sizes read
+    that one array.  The assignments to held experts are sorted by
     expert into a buffer of ``buffer_rows`` rows, the receive side of an
     expert-parallel exchange, and the experts' grouped products (three, or
     two under ``expert_form="relu2"``;
@@ -362,23 +392,25 @@ class RoutedExperts(linen.Module):
             logits = jnp.dot(tokens.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
             if self.selection_bias:
-                experts, weights, probs = self._route_biased(logits, b)
+                experts, weights, probs, load = self._route_biased(logits, b)
             else:
                 experts, weights, probs = route_top_k(
                     logits, k, self.scoring, norm_eps=self.norm_eps)
+                load = selection_load(experts, self.num_experts)
             if self.routed_scale != 1.0:
                 weights = weights * self.routed_scale
-            order, sizes, _ = sort_held(experts, first, count, rows)
+            order, sizes, _ = sort_held(experts, load, first, count, rows)
             # what route hands on, under one name a block's remat policy
             # can keep (models/routed_lm.py SAVED): with these held the
-            # backward pass does not sort again.  probs carries no name:
-            # nothing in the backward pass reads it (the softmax's own
-            # derivative keeps its own copy)
-            experts, weights, order, source, sizes = checkpoint_name(
-                (experts, weights, order, order // k, sizes), "moe_route")
+            # backward pass neither sorts nor counts again.  probs carries
+            # no name: nothing in the backward pass reads it (the softmax's
+            # own derivative keeps its own copy)
+            experts, weights, order, source, sizes, load = checkpoint_name(
+                (experts, weights, order, order // k, sizes, load),
+                "moe_route")
             if self.aux_weight:
                 self.sow("aux_loss", "load_balance", self.aux_weight
-                         * load_balancing_term(experts, probs))
+                         * load_balancing_term(load, probs))
             placed = jnp.arange(rows) < jnp.sum(sizes)
             # the padding goes through the last expert: every row of the
             # buffer is in a group, and the products' cost is the buffer's
@@ -426,7 +458,8 @@ class RoutedExperts(linen.Module):
         return y
 
     def _route_biased(self, logits, b):
-        """``route_top_k`` under the layer's selection bias; the bias moved
+        """``route_top_k`` under the layer's selection bias, with the
+        selection's ``selection_load``; the bias moved against that load
         where the step may write it, and the picks it moved counted."""
         k = self.top_k
         held = self.variable("batch_stats", "selection_bias", jnp.zeros,
@@ -434,9 +467,10 @@ class RoutedExperts(linen.Module):
         bias = jax.lax.stop_gradient(held.value)
         experts, weights, probs = route_top_k(
             logits, k, self.scoring, bias, self.norm_eps)
+        load = selection_load(experts, self.num_experts)
         if not self.is_initializing() \
                 and self.is_mutable_collection("batch_stats"):
-            held.value = moved_bias(bias, experts, self.bias_update_speed)
+            held.value = moved_bias(bias, load, self.bias_update_speed)
         # a second top-k and a T x k x k compare, for the counter alone: 1.1
         # ms a layer less on the chip than a gather of the chosen experts'
         # scores held against the k-th largest (PERF.md section 6, PR 43)
@@ -445,7 +479,7 @@ class RoutedExperts(linen.Module):
         self.sow("counters", "moe_bias", jnp.stack(
             [jnp.sum(moved.reshape(b, -1), axis=1, dtype=jnp.int32),
              jnp.full((b,), moved.size // b, jnp.int32)], axis=1))
-        return experts, weights, probs
+        return experts, weights, probs, load
 
     def _count(self, experts, first, count, order, placed, b, per_row):
         """Sow the layer's counters: per row of the batch, its assignments

@@ -430,11 +430,14 @@ def test_an_overflow_is_counted_and_shows_in_the_result():
 
 def test_sort_held_groups_and_clips_in_expert_order():
     experts = jnp.asarray([[0, 5], [5, 6], [6, 9], [5, 1], [7, 5]])
-    order, sizes, held = moe.sort_held(experts, first=5, count=3, rows=6)
+    load = moe.selection_load(experts, 10)
+    assert load.tolist() == [1, 1, 0, 0, 0, 4, 2, 1, 0, 1]
+    order, sizes, held = moe.sort_held(experts, load, first=5, count=3,
+                                       rows=6)
     assert held.tolist() == [4, 2, 1] and sizes.tolist() == [4, 2, 0]
     flat = np.asarray(experts).reshape(-1)
     assert flat[np.asarray(order)].tolist() == [5, 5, 5, 5, 6, 6]
-    order, sizes, _ = moe.sort_held(experts, first=5, count=3, rows=9)
+    order, sizes, _ = moe.sort_held(experts, load, first=5, count=3, rows=9)
     assert sizes.tolist() == [4, 2, 1]
     assert flat[np.asarray(order)][:7].tolist() == [5, 5, 5, 5, 6, 6, 7]
 

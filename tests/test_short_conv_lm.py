@@ -387,13 +387,15 @@ def test_the_update_moves_each_bias_against_its_load():
     experts = jnp.asarray([[0, 1]] * 6 + [[0, 2]] * 2)     # 8 tokens, k = 2
     # loads 8, 6, 2, 0 of mean 4
     bias = jnp.asarray([0.1, 0.0, -0.1, 0.0])
-    np.testing.assert_allclose(moe.moved_bias(bias, experts, 0.25),
+    load = moe.selection_load(experts, 4)
+    assert load.tolist() == [8, 6, 2, 0]
+    np.testing.assert_allclose(moe.moved_bias(bias, load, 0.25),
                                [-0.15, -0.25, 0.15, 0.25], atol=1e-7)
     # at the mean: unmoved
-    even = jnp.asarray([[0, 1], [2, 3]])
+    even = moe.selection_load(jnp.asarray([[0, 1], [2, 3]]), 4)
     np.testing.assert_array_equal(moe.moved_bias(bias, even, 0.25), bias)
     np.testing.assert_allclose(
-        moe.moved_bias(bias, experts, 0.25),
+        moe.moved_bias(bias, load, 0.25),
         REF.moved_bias(bias, experts, {"expert_bias_update_speed": 0.25}),
         atol=1e-7)
     # through the layer: a router whose first two outputs always win, a
